@@ -37,16 +37,15 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     if args.smoke:
-        # dev-box mode: force the CPU backend (with virtual devices for
-        # --dp/--mp) BEFORE the backend initializes — never claims a TPU
+        # dev-box mode: the CPU backend (with virtual devices for
+        # --dp/--mp), set BEFORE jax is imported — no accelerator is touched
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8"
             ).strip()
+        os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    if args.smoke:
-        jax.config.update("jax_platforms", "cpu")
     import paddle_tpu as paddle
     from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
                                    LlamaPretrainingCriterion)

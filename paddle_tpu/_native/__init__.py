@@ -6,12 +6,15 @@ paddle/phi/core/flags.cc (flag registry), paddle/fluid/memory stats, and the
 DataLoader shared-memory worker path. pybind11 is not in this image, so the
 boundary is a C ABI loaded via ctypes.
 
-The library auto-builds from csrc/ on first import when the .so is missing or
-stale (source mtime newer); builds take <5s with the baked-in g++.
+The library auto-builds from csrc/ on first load when the .so is missing or
+was built from other sources: a SHA-256 of the csrc/ inputs is stored beside
+the .so and compared (modification times mean nothing after a copy or a
+checkout). Builds take <5s with the baked-in g++.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import pickle
 import subprocess
@@ -21,8 +24,10 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_HERE))
 _CSRC = os.path.join(_REPO, "csrc")
 _SO = os.path.join(_HERE, "libpaddle_tpu_rt.so")
+_SO_HASH = _SO + ".srchash"
 
 _lib = None
+_built_this_process = False
 _build_lock = threading.Lock()
 
 
@@ -35,17 +40,34 @@ class NativeUnavailable(RuntimeError):
 _CAPI_ONLY = ("capi.cc", "pd_inference_c_api.h")
 
 
+def _sources():
+    return [os.path.join(_CSRC, f) for f in sorted(os.listdir(_CSRC))
+            if f.endswith((".cc", ".h")) and f not in _CAPI_ONLY]
+
+
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for path in _sources():
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _stored_hash():
+    try:
+        with open(_SO_HASH) as f:
+            return f.read().strip()
+    except FileNotFoundError:
+        return None
+
+
 def _needs_build() -> bool:
     if not os.path.isdir(_CSRC):
         return not os.path.exists(_SO)  # prebuilt .so without sources is fine
     if not os.path.exists(_SO):
         return True
-    so_m = os.path.getmtime(_SO)
-    for f in os.listdir(_CSRC):
-        if f.endswith((".cc", ".h")) and f not in _CAPI_ONLY:
-            if os.path.getmtime(os.path.join(_CSRC, f)) > so_m:
-                return True
-    return False
+    return _stored_hash() != _source_hash()
 
 
 def _build():
@@ -60,14 +82,19 @@ def _build():
                 return
             # capi.cc links libpython and builds separately (make capi);
             # the core runtime lib must stay python-free
-            srcs = [os.path.join(_CSRC, f) for f in sorted(os.listdir(_CSRC))
-                    if f.endswith(".cc") and f not in _CAPI_ONLY]
+            global _built_this_process
+            src_hash = _source_hash()
+            srcs = [p for p in _sources() if p.endswith(".cc")]
             tmp = f"{_SO}.tmp.{os.getpid()}"
             cmd = ["g++", "-O2", "-std=c++17", "-fPIC",
                    "-fvisibility=hidden", "-Wall", "-pthread", "-shared",
                    "-o", tmp] + srcs + ["-lrt"]
             subprocess.run(cmd, check=True, capture_output=True, text=True)
             os.replace(tmp, _SO)
+            with open(f"{_SO_HASH}.tmp.{os.getpid()}", "w") as f:
+                f.write(src_hash)
+            os.replace(f.name, _SO_HASH)
+            _built_this_process = True
         finally:
             fcntl.flock(lock_f, fcntl.LOCK_UN)
 
@@ -104,6 +131,19 @@ def load():
 
 def is_loaded() -> bool:
     return _lib is not None
+
+
+def build_record() -> dict:
+    """What this process did about the native library: whether it was
+    loaded at all, whether this process compiled it, and the hash of the
+    csrc/ inputs the loaded binary was built from."""
+    if _lib is None:
+        return {"loaded": False, "built": False,
+                "note": "not needed: nothing on this run's path loaded it"}
+    return {"loaded": True, "built": _built_this_process,
+            "source_sha256": _stored_hash(),
+            "sources": [os.path.basename(p) for p in _sources()]
+            if os.path.isdir(_CSRC) else []}
 
 
 def available() -> bool:

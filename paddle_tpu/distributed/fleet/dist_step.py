@@ -15,6 +15,7 @@ the stage axis with ppermute handoff, differentiated by jax.grad.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable, List, Optional
 
@@ -30,6 +31,16 @@ from ...jit.bridge import _clip_grads_functional
 from ...observability import enabled as _obs_enabled
 from ...observability import tracing as _tracing
 from ...observability.train_metrics import StepTelemetry, batch_tokens
+from ...kernels._common import kernel_partition_scope
+
+
+@contextlib.contextmanager
+def _step_scope(mesh):
+    """What every trace and dispatch of the step runs under: the
+    process mesh the TP layers read, and the mesh the Pallas kernels are
+    partitioned over by hand (kernels._common.partitioned)."""
+    with mesh_scope(mesh), kernel_partition_scope(mesh):
+        yield
 
 
 def _partition_spec_for(p, stage3: bool, mesh: Mesh):
@@ -761,7 +772,7 @@ class DistTrainStep:
             donate_argnums=donate)
 
         def run(*args):
-            with mesh_scope(mesh_):
+            with _step_scope(mesh_):
                 return jitted(*args)
         run._jitted = jitted
         return run
@@ -806,7 +817,7 @@ class DistTrainStep:
             donate_argnums=donate)
 
         def run(*args):
-            with mesh_scope(mesh_):
+            with _step_scope(mesh_):
                 return jitted(*args)
         run._jitted = jitted
         return run
@@ -908,7 +919,7 @@ class DistTrainStep:
             donate_argnums=donate)
 
         def run(p_vals, b_vals, opt_state, key, lr, arrays, scaler_st):
-            with mesh_scope(mesh_):
+            with _step_scope(mesh_):
                 return jitted(p_vals, b_vals, opt_state, key, lr, arrays,
                               scaler_st)
         run._jitted = jitted  # for cost_analysis (lower without running)
@@ -918,11 +929,13 @@ class DistTrainStep:
     def opt_state(self):
         return self._opt_state
 
-    def cost_analysis(self, *batch):
-        """XLA's cost model for the whole hybrid-parallel step
-        (fwd+bwd+update) at this batch signature — same contract as
-        TrainStep.cost_analysis: reads the LOWERED module (no backend
-        compile/execute)."""
+    def lower(self, *batch):
+        """Lower the whole step (fwd+bwd+update) for this batch
+        signature without compiling or running it: ``.as_text()`` shows
+        which kernels and shardings the program carries,
+        ``.cost_analysis()`` XLA's cost model, ``.compile()`` the
+        memory analysis. Consumes no donated buffer and leaves the
+        global RNG alone."""
         arrays = [b._value if isinstance(b, Tensor) else jnp.asarray(b)
                   for b in batch]
         sig = tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
@@ -946,16 +959,46 @@ class DistTrainStep:
         # call (e.g. the telemetry MFU probe) would silently change the
         # training trajectory (same stance as PipelineTrainStep.
         # memory_analysis)
-        with mesh_scope(self._mesh):
-            lowered = run._jitted.lower(
+        with _step_scope(self._mesh):
+            return run._jitted.lower(
                 [p._value for p in self._p], [b._value for b in self._b],
                 self._opt_state, jax.random.key(0),
                 self._opt._lr_operand(), arrays,
                 sc_in)
-        ca = lowered.cost_analysis()
+
+    def cost_analysis(self, *batch):
+        """XLA's cost model for the whole hybrid-parallel step
+        (fwd+bwd+update) at this batch signature — same contract as
+        TrainStep.cost_analysis: reads the LOWERED module (no backend
+        compile/execute)."""
+        ca = self.lower(*batch).cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
         return ca
+
+    def params_with_grad(self):
+        """One bool per trainable parameter: whether its Adam first
+        moment is non-zero, i.e. whether a gradient has reached it.
+        Reads the step's own state layout (per-parameter dicts, and
+        flat fused buckets sliced by each parameter's segment), so a
+        caller need not know it."""
+        fused = self._opt_state["fused"] \
+            if isinstance(self._opt_state, dict) else []
+        per_param = self._opt_state["per_param"] \
+            if isinstance(self._opt_state, dict) else self._opt_state
+        flags = {}
+        for i, st in zip(self._rest_idx, per_param):
+            flags[i] = jnp.any(st["moment1"] != 0)
+        if fused:
+            idx = self._fused["idx"]
+            for b, st in zip(self._fused["bucketer"].buckets, fused):
+                for k, j in enumerate(b.idx):
+                    off = int(b.offsets[k])
+                    seg = jax.lax.slice_in_dim(st["moment1"], off,
+                                               off + b.sizes[k])
+                    flags[idx[j]] = jnp.any(seg != 0)
+        got = jax.device_get([flags[i] for i in range(len(self._p))])
+        return [bool(v) for v in got]
 
     def __call__(self, *batch):
         if self._accum_n > 1:

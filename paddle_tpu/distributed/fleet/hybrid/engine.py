@@ -132,6 +132,19 @@ class HybridTrainStep:
         with mesh_scope(self._mesh):
             return self._inner(*batch)
 
+    def lower(self, *batch):
+        """The executing step lowered for ``batch``'s signature, not
+        compiled and not run (the GSPMD step's ``DistTrainStep.lower``;
+        the pipeline engine has no single program to lower)."""
+        lower = getattr(self._inner, "lower", None)
+        if lower is None:
+            raise NotImplementedError(
+                "lower() is the GSPMD step's: the pipeline engine has "
+                "no single lowered program — use a data=/model=-only "
+                "plan (pp=1) instead, or PipelineTrainStep."
+                "memory_analysis for the pipeline's own analysis")
+        return lower(*batch)
+
     # --------------------------------------------------------- deploy --
     def save_bundle(self, path: str, *batch):
         """Serialize this step's compiled executable for ``batch``'s

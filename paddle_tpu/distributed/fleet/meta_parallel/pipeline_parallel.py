@@ -137,10 +137,8 @@ def _ring_shard_map(staged, stacked_params, x_micro, rng_key, mesh, axis,
     attention inside the body runs its local kernel over the manual
     'context' axis (nested manual computations cannot be lowered).
 
-    check_vma=True is required: this jax version's partial-manual
-    shard_map mis-builds internal specs with check_vma=False. (On old
-    jax without the top-level alias, framework.jax_compat degrades the
-    call to experimental shard_map with auto=/check_rep.)
+    check_vma=True is required: partial-manual shard_map mis-builds
+    internal specs with check_vma=False (jax 0.9.0).
     """
     from ....framework.jax_compat import shard_map as _shard_map_compat
     manual = {axis} | {a for a in x_spec if a is not None}
@@ -433,6 +431,13 @@ def pipeline_1f1b(body_fn: Callable, stacked_params, x_micro,
     def staged(p_local, xm, hargs, post_v, keys):
         k_body, k_head = keys
         sid = jax.lax.axis_index(axis)
+        # the head's parameters arrive replicated; differentiated as
+        # such, their cotangent would be summed over the stages (the
+        # transpose of the implicit invariant-to-varying cast), mixing
+        # in the head gradients of stages that hold no real output.
+        # Marked varying, each stage keeps its own, and `take_h` picks
+        # the last stage's.
+        post_v = _varying(vary, list(post_v))
         p_mine = jax.tree_util.tree_map(lambda a: a[0], p_local)
         xshape = tuple(xm.shape[1:])
         act0 = _varying(vary, jnp.zeros(xshape, xm.dtype))
@@ -470,7 +475,9 @@ def pipeline_1f1b(body_fn: Callable, stacked_params, x_micro,
             loss_m, vjp_h = jax.vjp(
                 lambda pv, yv: head_fn(pv, yv, lbl, k_h),
                 list(post_v), out)
-            gp_m, g_out = vjp_h(inv_m.astype(loss_m.dtype))
+            # the cotangent must carry the loss's own type: varying
+            # over the stage axis (jax 0.9.0 checks it)
+            gp_m, g_out = vjp_h(_varying(vary, inv_m.astype(loss_m.dtype)))
             last = sid == S - 1
             take_h = jnp.logical_and(last, valid_f)
             pacc = jax.tree_util.tree_map(
@@ -1055,10 +1062,7 @@ class PipelineTrainStep:
             donate_argnums=donate)
 
         def run(*args):
-            from ....framework.jax_compat import (x64_safe_shard_map_trace,
-                                                  narrow_x64_leaves)
-            args = narrow_x64_leaves(args)
-            with mesh_scope(mesh), x64_safe_shard_map_trace():
+            with mesh_scope(mesh):
                 return jitted(*args)
         run._jitted = jitted  # exposed for memory_analysis (no execute)
         return run
@@ -1144,10 +1148,7 @@ class PipelineTrainStep:
             donate_argnums=donate)
 
         def run(*args):
-            from ....framework.jax_compat import (x64_safe_shard_map_trace,
-                                                  narrow_x64_leaves)
-            args = narrow_x64_leaves(args)
-            with mesh_scope(mesh), x64_safe_shard_map_trace():
+            with mesh_scope(mesh):
                 return jitted(*args)
         run._jitted = jitted
         return run
@@ -1228,14 +1229,12 @@ class PipelineTrainStep:
             else ()
         key = jax.random.key(0)
         lr = jnp.asarray(0.0, jnp.float32)
-        from ....framework.jax_compat import (x64_safe_shard_map_trace,
-                                              narrow_x64_leaves)
-        args = narrow_x64_leaves((
+        args = (
             [p._value for p in self._pre_p], list(self._stacked),
             [p._value for p in self._post_p],
             [b._value for b in self._edge_b],
-            self._opt_state, key, lr, arrays, sc_in))
-        with mesh_scope(self._mesh), x64_safe_shard_map_trace():
+            self._opt_state, key, lr, arrays, sc_in)
+        with mesh_scope(self._mesh):
             lowered = jitted.lower(*args)
             cache[sig] = lowered.compile().memory_analysis()
         return cache[sig]

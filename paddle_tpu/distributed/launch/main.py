@@ -317,8 +317,7 @@ class PodController:
     def rank_states(self) -> List[dict]:
         """Per-rank liveness snapshot for the heartbeat: a rank whose
         pid is alive but whose log stopped growing is the wedged-rank
-        signature (five TPU bench rounds died undiagnosable without
-        this; see BENCH_r0*.json)."""
+        signature."""
         out = []
         for lr, p in zip(self.local_ranks, self.procs):
             path = os.path.join(self.ctx.log_dir, f"workerlog.{lr}")

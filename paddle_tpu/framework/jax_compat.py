@@ -1,134 +1,37 @@
-"""Version-bridging shims over jax APIs that moved between releases.
+"""The two spots where the engine's call shape differs from jax's own.
 
-The engine targets the modern surface (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.lax.pcast``); older jax releases only
-ship ``jax.experimental.shard_map.shard_map`` (``auto``/``check_rep``)
-and have no varying-manual-axis (vma) type system at all. Every caller
-imports from here so the whole codebase degrades together instead of
-each site growing its own try/except ladder.
+One installation is supported (jax 0.9.0): ``jax.shard_map`` with
+``axis_names``/``check_vma`` and the varying-manual-axes type system
+are simply there. What is left is a keyword adapter and a ``pcast``
+that tolerates a value which already varies.
 """
 from __future__ import annotations
 
 import jax
 
 
-class ShardMapUnsupported(NotImplementedError):
-    """The requested shard_map lowering does not exist on this jax
-    release. Raised ONLY by :func:`shard_map` for the partial-manual
-    case (manual over a subset of the >1-sized mesh axes) on jax
-    without the top-level ``jax.shard_map``. Callers/tests that want
-    to degrade gracefully must catch exactly this type — catching bare
-    ``NotImplementedError`` would also swallow unrelated missing
-    features and mask real regressions (tests/test_pipeline.py
-    ``_partial_manual_or_skip``)."""
-
-
-def _modern_shard_map():
-    """jax >= 0.8 top-level alias, or None on older releases."""
-    sm = getattr(jax, "shard_map", None)
-    # jax 0.4.x exposes a deprecation stub raising AttributeError from
-    # module __getattr__, so getattr alone is enough of a probe
-    return sm
-
-
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma=True):
-    """``jax.shard_map`` facade with the modern keyword surface.
-
-    axis_names: the axes manualized by this shard_map (None = all mesh
-    axes). On old jax, size-1 non-manual axes are folded into the
-    manual set (identical semantics), genuinely-partial regions raise
-    (old shard_map's partial-auto lowering crashes XLA), and
-    replication checking is forced off (see inline note).
-    """
-    sm = _modern_shard_map()
-    if sm is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    mesh_axes = set(getattr(mesh, "axis_names", ()) or ())
-    manual = mesh_axes if axis_names is None else set(axis_names)
-    # size-1 axes are identical manual or auto (there is nothing to
-    # shard); manualizing them keeps hybrid meshes like
-    # build_mesh(pp=2) — which names every axis at degree 1 — on the
-    # well-supported full-manual path of old shard_map
-    sizes = dict(getattr(mesh, "shape", {}) or {})
-    auto = frozenset(a for a in mesh_axes - manual if sizes.get(a, 1) > 1)
-    if auto:
-        # old shard_map's partial-auto lowering is broken beyond repair
-        # (SPMD partitioner CHECK-fails and aborts the process on the
-        # scan+ppermute schedules); fail like an ordinary python error
-        # so callers/tests see a diagnosable exception instead of a
-        # crashed interpreter
-        raise ShardMapUnsupported(
-            "partial-manual shard_map (manual "
-            f"{sorted(manual)} / auto {sorted(auto)}) is unsupported on "
-            "this jax: use jax >= 0.8 (jax.shard_map), or keep the "
-            "region fully manual by collapsing the auto axes to size 1")
-    # check_rep stays OFF on old jax regardless of check_vma: its
-    # replication oracle predates the varying-manual-axis types
-    # (lax.pcast is a no-op here, see pcast below), so scan carries
-    # that are legitimately device-varying — the pipeline schedules'
-    # ppermute rings — cannot be marked as such and would be rejected
-    # as replication violations. The modern path keeps full checking.
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def x64_safe_shard_map_trace():
-    """Context for tracing jitted programs that contain a shard_map'd
-    scan. Under jax_enable_x64 the old shard_map's full-to-shard
-    transpose emits dynamic-update-slices whose partition-offset
-    arithmetic mixes s64/s32 and fails HLO verification after SPMD
-    partitioning; tracing with x64 off keeps every index s32 and
-    sidesteps the bug. On jax with the modern shard_map this is a
-    no-op."""
-    import contextlib
-    if _modern_shard_map() is not None:
-        return contextlib.nullcontext()
-    from jax.experimental import disable_x64
-    return disable_x64()
-
-
-def narrow_x64_leaves(tree):
-    """Cast 64-bit array leaves to their 32-bit counterparts, leaves of
-    other dtypes (including PRNG keys) pass through. Companion to
-    x64_safe_shard_map_trace: tracing with x64 off canonicalizes avals
-    to 32 bits, so concrete 64-bit inputs (e.g. int64 token ids from
-    to_tensor under global x64) must be narrowed before the call or the
-    lowered module fails dtype verification. No-op on jax with the
-    modern shard_map."""
-    if _modern_shard_map() is not None:
-        return tree
-    import jax.numpy as jnp
-    import numpy as np
-
-    narrow = {np.dtype(np.int64): jnp.int32,
-              np.dtype(np.uint64): jnp.uint32,
-              np.dtype(np.float64): jnp.float32,
-              np.dtype(np.complex128): jnp.complex64}
-
-    def leaf(a):
-        dt = getattr(a, "dtype", None)
-        try:
-            to = narrow.get(np.dtype(dt)) if dt is not None else None
-        except TypeError:  # extended dtypes (PRNG keys)
-            return a
-        return a.astype(to) if to is not None else a
-
-    return jax.tree_util.tree_map(leaf, tree)
+    """``jax.shard_map`` with ``axis_names`` optional (None = manual
+    over every mesh axis)."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def pcast(val, axes, to="varying"):
-    """``jax.lax.pcast`` when the vma type system exists; identity
-    otherwise (pre-vma jax has no varying/invariant distinction, so the
-    cast is meaningless there and values flow through unchanged)."""
+    """``jax.lax.pcast`` over a pytree. Casting to "varying" skips the
+    axes a leaf already varies over: jax 0.9.0 refuses a
+    varying-to-varying cast, and a scan carry is marked once up front
+    whatever its operands' types turn out to be."""
     if isinstance(axes, str):
         axes = (axes,)
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None:
-        return val
-    return fn(val, tuple(axes), to=to)
+
+    def leaf(a):
+        want = tuple(axes)
+        if to == "varying":
+            have = getattr(jax.typeof(a), "vma", frozenset())
+            want = tuple(ax for ax in want if ax not in have)
+        return jax.lax.pcast(a, want, to=to) if want else a
+
+    return jax.tree_util.tree_map(leaf, val)

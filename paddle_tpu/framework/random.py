@@ -23,7 +23,7 @@ import jax
 class _GlobalGenerator:
     """Key creation is LAZY: materializing a jax PRNG key initializes the
     XLA backend, and doing that at `import paddle_tpu` time makes import
-    block on (possibly slow/tunnelled) TPU client bring-up."""
+    block on TPU client bring-up."""
 
     def __init__(self, seed: int = 0):
         self._lazy_key = None

@@ -89,13 +89,17 @@ class PagedKVPool:
     """
 
     def __init__(self, n_layers, num_pages, page_size, n_kv_heads,
-                 head_dim, dtype="float32", mesh=None):
+                 head_dim, dtype="float32", mesh=None, device=None):
         import jax.numpy as jnp
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         shape = (num_pages, page_size, n_kv_heads, head_dim)
-        self.k = [jnp.zeros(shape, dtype) for _ in range(n_layers)]
-        self.v = [jnp.zeros(shape, dtype) for _ in range(n_layers)]
+        # `device` commits an unsharded pool to one device (a router
+        # replica's own); None leaves it on the default device
+        self.k = [jnp.zeros(shape, dtype, device=device)
+                  for _ in range(n_layers)]
+        self.v = [jnp.zeros(shape, dtype, device=device)
+                  for _ in range(n_layers)]
         # tensor-parallel serving: pages shard over the KV-head axis of
         # a 'model' mesh (the paged kernels are head-parallel by
         # construction, so every program variant composes). The host-

@@ -154,12 +154,9 @@ def _expert_constrain(t):
     # inside another shard_map (e.g. a pipeline stage body) the
     # constraint must be expressed over the context abstract mesh, whose
     # already-manual axes are typed Manual
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        if ctx is not None and not ctx.empty and ctx._any_axis_manual:
-            mesh = ctx
-    except AttributeError:
-        pass
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty and ctx.manual_axes:
+        mesh = ctx
     sh = NamedSharding(mesh, PartitionSpec("expert"))
     return apply(lambda v: jax.lax.with_sharding_constraint(v, sh),
                  _coerce(t))

@@ -23,6 +23,7 @@ from .._grad_mode import no_grad
 from ..framework import faults as _faults
 from ..observability import metrics as _obsm
 from ..observability import tracing as _obstr
+from ..kernels._common import kernel_partition_scope
 
 
 class DecodeWedgedError(RuntimeError):
@@ -543,6 +544,16 @@ class ContinuousBatchingPredictor:
         self._tp_plan = None
         self.tp_devices = []
         self.tp_topology = "replicated"
+        # a single-device replica given its device (the router hands
+        # every replica its own) commits weights and pool there; the
+        # serve programs follow their committed operands
+        self._device = None
+        if self.tp == 1 and devices is not None:
+            devs = list(devices)
+            if len(devs) != 1:
+                raise ValueError(
+                    f"tp_degree=1 takes one device, got {len(devs)}")
+            self._device = devs[0]
         if self.tp > 1:
             from ..distributed.fleet.hybrid.plan import HybridParallelPlan
             devs = list(devices) if devices is not None else jax.devices()
@@ -556,10 +567,6 @@ class ContinuousBatchingPredictor:
             self._tp_mesh = self._tp_plan.build_mesh(
                 devices=self.tp_devices)
             self.tp_topology = self._tp_plan.topology()
-            # the Pallas tiling gates must judge PER-SHARD head counts
-            # from here on (kernels._common / _paged_gate)
-            from ..kernels._common import set_tp_shard_degree
-            set_tp_shard_degree(self.tp)
             # device-group label: per-replica report views group the
             # utilization table by it so a 2-device replica reads as
             # one row spanning "0-1", not two phantom replicas
@@ -569,6 +576,14 @@ class ContinuousBatchingPredictor:
                 f"{ids[0]}-{ids[-1]}"
                 if ids == list(range(ids[0], ids[-1] + 1))
                 else ",".join(str(i) for i in ids))
+        # where the small per-step operands are committed (_place):
+        # replicated over the TP mesh, on the replica's own device, or
+        # nowhere in particular
+        self._operand_placement = self._device
+        if self._tp_mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._operand_placement = NamedSharding(self._tp_mesh,
+                                                    PartitionSpec())
         # replicas of one model run in separate threads (serving/
         # router.py) but TRACE through the same model object: jax
         # tracing executes the Python forward with jit.bridge
@@ -616,7 +631,8 @@ class ContinuousBatchingPredictor:
             kv_mesh = None
         self.pool = PagedKVPool(cfg.num_hidden_layers, num_pages + 1,
                                 page_size, cfg.num_key_value_heads,
-                                head_dim, dtype=kv_dtype, mesh=kv_mesh)
+                                head_dim, dtype=kv_dtype, mesh=kv_mesh,
+                                device=self._device)
         # inactive slots need somewhere harmless to point their block
         # table (the decode step writes one K/V row for EVERY slot):
         # a dedicated trash page absorbs those writes
@@ -738,7 +754,25 @@ class ContinuousBatchingPredictor:
         self._spec_k = max(0, int(spec_draft_tokens))
         self._ngram_max = max(1, int(spec_ngram_max))
         self.sampling_enabled = bool(sampling_enabled)
-        self._m_spec_prop = _obsm.counter("serving.spec.proposed_tokens")
+        # the mixed and verify steps ride the ragged varq kernel, whose
+        # VMEM need grows with the span bucket: refuse a bucket the TPU
+        # compiler would refuse, here and by name, instead of at the
+        # first long prompt
+        span = max(self._chunk_max, self._spec_k + 1 if self._spec_k else 0)
+        if self.use_ragged and span > 1:
+            from ..kernels._common import pallas_interpret
+            from ..kernels.paged_attention import max_varq_span
+            fit = max_varq_span(cfg.num_attention_heads // self.tp,
+                                head_dim, self.page,
+                                np.dtype(kv_dtype).itemsize)
+            if not pallas_interpret() and span > fit:
+                raise ValueError(
+                    f"prefill_chunk_tokens/spec_draft_tokens ask for a "
+                    f"{span}-query span; the ragged varq kernel fits at "
+                    f"most {fit} at {cfg.num_attention_heads // self.tp} "
+                    f"heads x {head_dim} (kernels.paged_attention."
+                    f"max_varq_span)")
+        self._m_spec_prop =_obsm.counter("serving.spec.proposed_tokens")
         self._m_spec_acc = _obsm.counter("serving.spec.accepted_tokens")
         self._m_spec_rate = _obsm.gauge("serve.spec.accept_rate")
         self.stats["spec_ticks"] = 0
@@ -918,7 +952,9 @@ class ContinuousBatchingPredictor:
         cheap. GSPMD propagates the rest of the partitioning through
         the jitted serve programs."""
         if self._tp_mesh is None:
-            return vals
+            if self._device is None:
+                return vals
+            return [jax.device_put(v, self._device) for v in vals]
         from jax.sharding import NamedSharding, PartitionSpec
         out = []
         for v in vals:
@@ -964,14 +1000,54 @@ class ContinuousBatchingPredictor:
             hit = self._engine.get(sig)
             if hit is not None:
                 return hit(*args)
-            return self._engine.compile_fallback(sig, fn, args,
-                                                 self._trace_lock)
+            with self._kernel_scope():
+                return self._engine.compile_fallback(sig, fn, args,
+                                                     self._trace_lock)
         if sig in self._traced_sigs:
-            return fn(*args)
-        with self._trace_lock:
+            # jit keys on argument placement too, so a signature this
+            # table calls traced can still trace again: keep the kernel
+            # scope around every dispatch (entering it costs nothing)
+            with self._kernel_scope():
+                return fn(*args)
+        with self._trace_lock, self._kernel_scope():
             out = fn(*args)
         self._traced_sigs.add(sig)
         return out
+
+    def _place(self, x):
+        """Commit a small step operand where this replica's programs
+        run. The first dispatch feeds a host value and the chained ones
+        the previous step's device output; placed alike, jit sees one
+        argument placement and compiles the step once."""
+        if self._operand_placement is None:
+            return x
+        return jax.device_put(x, self._operand_placement)
+
+    def _kernel_scope(self):
+        """Trace-time declaration of this replica's TP mesh: the Pallas
+        gates judge PER-SHARD head counts under it and the kernels are
+        partitioned over it by hand (kernels._common.partitioned)."""
+        return kernel_partition_scope(self._tp_mesh)
+
+    def lower_decode_step(self):
+        """The greedy decode step, lowered for this predictor's weights,
+        pool and batch geometry exactly as the serve loop dispatches it
+        (ragged metadata included when the ragged kernel is on), without
+        running it: ``.as_text()`` shows whether the paged kernel is in
+        the program, ``.compile()`` gives its memory analysis and the
+        collectives a tensor-parallel replica got."""
+        self._ensure_ready()
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        meta = ()
+        if self.use_ragged:
+            from ..kernels.paged_attention import RaggedMetaBuilder
+            meta = tuple(i32(self.B * self.pages_per_seq)
+                         for _ in RaggedMetaBuilder.FIELDS)
+        with self._trace_lock, self._kernel_scope():
+            return self._decode_jit.lower(
+                self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+                i32(self.B, self.pages_per_seq), i32(self.B), i32(self.B),
+                *meta)
 
     def _raw_prefill(self, p_vals, b_vals, kl, vl, ids, pos, lens,
                      page_rows):
@@ -2423,6 +2499,7 @@ class ContinuousBatchingPredictor:
             tok_in = jnp.where(jnp.asarray(override.copy()),
                                jnp.asarray(last_tok_host.copy()),
                                inflight["tok"])
+        tok_in = self._place(tok_in)
         override[:] = False
         # .copy(): the CPU backend may alias numpy memory zero-copy into
         # the device buffer, and the host mutates tables/ctx/meta in
@@ -2528,6 +2605,7 @@ class ContinuousBatchingPredictor:
             tok_in = jnp.where(jnp.asarray(override.copy()),
                                jnp.asarray(last_tok_host.copy()),
                                inflight["tok"])
+        tok_in = self._place(tok_in)
         override[:] = False
         # .copy() on every host operand: double buffering mutates them
         # while this step is still in flight (see _dispatch_step)
@@ -2599,7 +2677,7 @@ class ContinuousBatchingPredictor:
             from ..kernels.paged_attention import RaggedMetaBuilder
             meta_args = tuple(m[k].copy()
                               for k in RaggedMetaBuilder.FIELDS)
-        tok_in = jnp.asarray(last_tok_host.copy())
+        tok_in = self._place(jnp.asarray(last_tok_host.copy()))
         override[:] = False
         if samp is None:
             # sampling disabled: constant greedy operands — one spec

@@ -53,6 +53,28 @@ MANIFEST = "manifest.json"
 FORMAT = 1
 
 
+def _device_ids(compiled):
+    """Ids of the devices `compiled` runs on, in its own order."""
+    import jax
+    shardings = jax.tree_util.tree_leaves(compiled.input_shardings) \
+        or jax.tree_util.tree_leaves(compiled.output_shardings)
+    return [d.id for d in shardings[0]._device_assignment]
+
+
+def _load_executable(ser, in_tree, out_tree, device_ids):
+    """Deserialize onto the devices the executable was compiled for.
+    jax 0.9.0's loader otherwise assumes EVERY device of the backend,
+    and a one-device program then demands one shard per device."""
+    import jax
+    from jax.experimental import serialize_executable as _se
+    devices = None
+    if device_ids is not None:
+        by_id = {d.id: d for d in jax.devices()}
+        devices = [by_id[i] for i in device_ids]
+    return _se.deserialize_and_load(ser, in_tree, out_tree,
+                                    execution_devices=devices)
+
+
 class BundleInvalid(RuntimeError):
     """The bundle must not be loaded: missing/corrupt manifest, digest
     mismatch, or a fingerprint the current runtime cannot honor. The
@@ -232,10 +254,9 @@ class EngineBundle:
         if _integrity.sha256_bytes(raw) != rec["sha256"]:
             raise BundleInvalid("digest", f"artifact {key} digest "
                                           "mismatch")
-        from jax.experimental import serialize_executable as _se
         blob = pickle.loads(raw)
-        return _se.deserialize_and_load(blob["ser"], blob["in_tree"],
-                                        blob["out_tree"])
+        return _load_executable(blob["ser"], blob["in_tree"],
+                                blob["out_tree"], blob.get("device_ids"))
 
     def add_artifact(self, sig, compiled) -> Dict:
         """Serialize a compiled executable into the bundle (the
@@ -243,14 +264,16 @@ class EngineBundle:
         manifest atomically."""
         from jax.experimental import serialize_executable as _se
         ser, in_tree, out_tree = _se.serialize(compiled)
+        device_ids = _device_ids(compiled)
         # round-trip fence BEFORE persisting: some executables (e.g.
-        # ones the backend handed back from a persistent-cache hit on
-        # this jaxlib) serialize into blobs that cannot deserialize
-        # ("Symbols not found"); writing one would poison every future
-        # warm start of this signature
-        _se.deserialize_and_load(ser, in_tree, out_tree)
+        # ones the backend handed back from a persistent-cache hit,
+        # still so on jaxlib 0.9.0) serialize into blobs that cannot
+        # be loaded again; writing one would poison every future warm
+        # start of this signature
+        _load_executable(ser, in_tree, out_tree, device_ids)
         raw = pickle.dumps({"sig": sig, "ser": ser, "in_tree": in_tree,
-                            "out_tree": out_tree}, protocol=4)
+                            "out_tree": out_tree,
+                            "device_ids": device_ids}, protocol=4)
         key = sig_key(sig)
         with self._lock:
             # refresh from disk before merging: replicas across

@@ -6,16 +6,12 @@ Two cache tiers sit between a process restart and the first token:
   verified XLA executables for every calibrated shape bucket. A hit
   dispatches straight into ``Compiled.__call__`` — no trace, no
   compile, no HLO anywhere on the path (``aot.bundle_hits``).
-- **Tier 2 — the XLA persistent compilation cache**
-  (``jax_compilation_cache_dir``, wired to ``<bundle>/xla_cache``): a
-  bucket MISS still traces and calls the compiler, but the backend
-  compile is served from disk across restarts. The
-  0.5s min-compile-time threshold set by ``paddle_tpu/__init__.py`` is
-  KEPT — on jax 0.4.37 the persistent-cache round-trip of small
-  donated kernels returns executables with WRONG numerics on cache-hit
-  runs (docs/DEPLOYMENT.md, .claude/skills/verify/SKILL.md), and the
-  threshold is what keeps those kernels out. ``wire_xla_cache`` will
-  raise rather than lower it.
+- **Tier 2 — the XLA persistent compilation cache**: a bucket MISS
+  still traces and calls the compiler, but the backend compile is
+  served from disk across restarts. Where the environment places the
+  cache (``JAX_COMPILATION_CACHE_DIR``) it stays there; otherwise
+  ``wire_xla_cache`` points it at ``<bundle>/xla_cache`` so a deployed
+  bundle carries its own.
 
 Both tiers are fenced by invalidation-on-mismatch: a bundle whose
 jaxlib/platform fingerprint or model hash disagrees with the current
@@ -46,10 +42,6 @@ __all__ = ["InferenceEngine", "load_engine", "warm_start",
            "wire_xla_cache", "default_engine_dir"]
 
 _logger = logging.getLogger("paddle_tpu.aot")
-
-# the floor below which the persistent cache is KNOWN UNSAFE on this
-# jax line (wrong numerics on cache-hit for small donated kernels)
-MIN_COMPILE_TIME_FLOOR_S = 0.5
 
 # predictor ctor kwargs that are baked INTO the compiled executables
 # (shapes, paged-pool layout, eos/pad semantics): differing values at
@@ -108,37 +100,35 @@ def _invalidate(reason: str, detail: str = "", tier: str = "bundle"):
 def _reset_cache_object():
     """jax initializes its persistent-cache object ONCE per process;
     a later ``jax_compilation_cache_dir`` update is silently ignored
-    unless the cache object is reset. Every dir change in this module
-    goes through here or it does nothing."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+    unless the cache object is reset (still so on jax 0.9.0). Every dir
+    change in this module goes through here or it does nothing."""
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 @contextlib.contextmanager
 def _no_persistent_cache():
     """Disable the XLA persistent compilation cache for the duration.
 
-    Engine artifacts MUST come from a real backend compile: on this
-    jaxlib an executable that was deserialized from a persistent-cache
-    hit RE-serializes into a blob missing its object code ("Symbols
-    not found" at load) — writing one into the bundle would poison
-    every future warm start of that signature. Process-global toggle:
-    a concurrent compile on another thread merely skips the cache for
-    its one compile (correctness unaffected)."""
+    Engine artifacts MUST come from a real backend compile: an
+    executable that was deserialized from a persistent-cache hit
+    RE-serializes into a blob that cannot be loaded again (seen on
+    jaxlib 0.4.37 as "Symbols not found"; on jaxlib 0.9.0, CPU, as
+    "Function ... not found" at the first call) — writing one into the
+    bundle would poison every future warm start of that signature.
+    Process-global toggle: a concurrent compile on another thread
+    merely skips the cache for its one compile (correctness
+    unaffected)."""
     import jax
-    prev = jax.config.jax_compilation_cache_dir
-    if prev is None:
+    if not jax.config.jax_enable_compilation_cache:
         yield
         return
-    jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_enable_compilation_cache", False)
     _reset_cache_object()
     try:
         yield
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_enable_compilation_cache", True)
         _reset_cache_object()
 
 
@@ -147,9 +137,12 @@ def wire_xla_cache(cache_dir: str) -> str:
     `cache_dir`, fenced by a runtime-fingerprint file: a directory
     written by a different jaxlib/platform is wiped (counted in
     ``aot.invalidations{tier="xla_cache"}``) instead of risking a
-    stale-executable hit. The 0.5s min-compile-time threshold is
-    asserted, never lowered (see module docstring)."""
+    stale-executable hit. Where ``JAX_COMPILATION_CACHE_DIR`` is set the
+    environment has placed the cache and a bundle does not move it:
+    the directory in force is returned untouched."""
     import jax
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return jax.config.jax_compilation_cache_dir
     cache_dir = os.path.abspath(cache_dir)
     fp_path = os.path.join(cache_dir, "cache_fingerprint.json")
     cur = runtime_fingerprint()
@@ -163,14 +156,6 @@ def wire_xla_cache(cache_dir: str) -> str:
     os.makedirs(cache_dir, exist_ok=True)
     if not os.path.exists(fp_path):
         _integrity.atomic_write_json(fp_path, cur)
-    floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    if floor is not None and floor < MIN_COMPILE_TIME_FLOOR_S:
-        raise RuntimeError(
-            f"jax_persistent_cache_min_compile_time_secs={floor} is "
-            f"below the {MIN_COMPILE_TIME_FLOOR_S}s safety floor: on "
-            "this jax line small donated kernels round-trip the "
-            "persistent cache with WRONG numerics (docs/DEPLOYMENT.md)."
-            " Refusing to wire the tier-2 cache.")
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     _reset_cache_object()   # dir updates are no-ops without this
     return cache_dir

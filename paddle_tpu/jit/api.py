@@ -116,8 +116,7 @@ class StaticFunction:
         global _IN_TO_STATIC
         if not _TO_STATIC_ENABLED:
             return self._fn(*args, **kwargs)
-        import jax.core as _jcore
-        if not _jcore.trace_state_clean():
+        if not jax.core.trace_ctx.is_top_level():
             # already under an outer jax trace (another to_static, a
             # jitted serving program, the AOT engine builder): nesting
             # a second jax.jit here would pin trace-time constants

@@ -1,10 +1,13 @@
 """Shared helpers for the Pallas kernel modules."""
 from __future__ import annotations
 
+import contextlib
 import logging
+import threading
 
 import numpy as np
 import jax
+from jax.sharding import PartitionSpec
 
 from ..framework.flags import flag_value
 
@@ -26,10 +29,7 @@ def use_pallas() -> bool:
         return False
     if flag_value("pallas_interpret"):
         return True
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def pallas_interpret() -> bool:
@@ -47,21 +47,102 @@ def pallas_dtype_ok(*arrays) -> bool:
     return True
 
 
-# Tensor-parallel shard degree the paged kernels are being traced
-# under: with GSPMD sharding the head axis over 'model', each shard
-# sees only H / tp heads, so the Pallas tiling constraints must hold
-# PER SHARD. The serving predictor declares its degree here (trace-time
-# state, like the gate itself); 1 = unsharded.
-_tp_shard_degree = 1
+# Mosaic kernels cannot be partitioned automatically: under GSPMD the
+# TPU lowering refuses a pallas_call whose program spans more than one
+# device ("wrap the call in a shard_map"; jax 0.9.0). A step that traces
+# kernels for a multi-device mesh (the tensor-parallel serve programs,
+# the hybrid-parallel train step) therefore declares its mesh for the
+# duration of the trace, and every kernel call goes through
+# `partitioned`, which wraps it in jax.shard_map with the batch dim over
+# 'data' and the head dim over 'model'. Thread-local trace-time state:
+# router replicas trace on their own threads.
+_partition = threading.local()
+
+_ROLE_AXIS = {"batch": "data", "heads": "model"}
 
 
-def set_tp_shard_degree(n: int) -> None:
-    global _tp_shard_degree
-    _tp_shard_degree = max(1, int(n))
+@contextlib.contextmanager
+def kernel_partition_scope(mesh):
+    """Declare `mesh` as the one the programs traced inside are
+    partitioned over (None: a single-device program)."""
+    prev = getattr(_partition, "mesh", None)
+    _partition.mesh = mesh
+    try:
+        yield
+    finally:
+        _partition.mesh = prev
+
+
+def kernel_mesh():
+    """The declared mesh when it spans more than one device, else None."""
+    mesh = getattr(_partition, "mesh", None)
+    if mesh is None or mesh.devices.size == 1:
+        return None
+    return mesh
 
 
 def tp_shard_degree() -> int:
-    return _tp_shard_degree
+    """Size of the declared mesh's 'model' axis: with the head axis
+    sharded over it each shard sees only H / tp heads, so the Pallas
+    tiling constraints must hold PER SHARD. 1 = unsharded."""
+    mesh = kernel_mesh()
+    return int(mesh.shape.get("model", 1)) if mesh is not None else 1
+
+
+def partitioned(fn, in_roles, out_roles, *args):
+    """``fn(*args)`` for a function of arrays that issues Pallas calls.
+
+    With no multi-device mesh declared this is a plain call. Otherwise
+    the call is wrapped in ``jax.shard_map`` over the declared mesh.
+    `in_roles`/`out_roles` give, per array, one role per dimension:
+    ``"batch"`` (sharded over 'data'), ``"heads"`` (over 'model') or
+    None; a whole entry of None replicates the array. A role is sharded
+    only when EVERY dimension carrying it divides by its axis (query
+    and KV head counts must split alike for the GQA mapping inside a
+    shard to stay right); otherwise that role is replicated and XLA
+    gathers the operand."""
+    mesh = kernel_mesh()
+    if mesh is None or getattr(_partition, "active", None) is not None:
+        return fn(*args)
+    sizes = {role: int(mesh.shape.get(axis, 1))
+             for role, axis in _ROLE_AXIS.items()}
+    for roles, a in zip(in_roles, args):
+        for role, dim in zip(roles or (), a.shape):
+            if role is not None and dim % sizes[role]:
+                sizes[role] = 1
+
+    def spec(roles):
+        if roles is None:
+            return PartitionSpec()
+        return PartitionSpec(*[
+            _ROLE_AXIS[r] if r is not None and sizes[r] > 1 else None
+            for r in roles])
+
+    def body(*shards):
+        _partition.active = sizes      # trace-time: what this call split
+        try:
+            return fn(*shards)
+        finally:
+            _partition.active = None
+
+    out_specs = tuple(spec(r) for r in out_roles) \
+        if isinstance(out_roles, list) else spec(out_roles)
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(spec(r) for r in in_roles),
+        out_specs=out_specs, check_vma=False)(*args)
+
+
+def shard_index():
+    """Linear index of the shard being traced, over the axes the
+    enclosing `partitioned` call really split (0 outside one)."""
+    sizes = getattr(_partition, "active", None)
+    if not sizes:
+        return 0
+    idx = 0
+    for role, axis in _ROLE_AXIS.items():
+        if sizes[role] > 1:
+            idx = idx * sizes[role] + jax.lax.axis_index(axis)
+    return idx
 
 
 # one log line per (kernel, reason) per process — production losing the

@@ -28,7 +28,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._common import (_Z, _NEG_INF, use_pallas as _use_pallas,
-                      pallas_dtype_ok, pallas_interpret, mxu_precision)
+                      pallas_dtype_ok, pallas_interpret, mxu_precision,
+                      partitioned, shard_index)
+
+# How a multi-device step splits the flash kernels' operands
+# (_common.partitioned): batch over 'data', heads over 'model'.
+_BSHD = ("batch", None, "heads", None)        # q/k/v/out/do [B, S, H, D]
+_BHS = ("batch", "heads", None)               # lse [B, H, S]
 
 
 def _zero_tail_rows(arr, blk_idx, block, limit):
@@ -705,18 +711,78 @@ def _flash_blocks():
         int(flag_value("flash_block_k"))
 
 
+def _extras(b, h, kv_lens, mask3, mask_dims, seeds):
+    """The optional kernel operands as (arrays, roles) for
+    `partitioned`. The flattened [Bm*Hm, Sq', Sk] mask travels as 4-D so
+    that a per-batch / per-head mask splits with q; it is flattened
+    again inside the shard."""
+    arrays, roles = [], []
+    if kv_lens is not None:
+        arrays.append(kv_lens)
+        roles.append(("batch",))
+    if mask3 is not None:
+        Bm, Hm = mask_dims
+        arrays.append(mask3.reshape(Bm, Hm, *mask3.shape[1:]))
+        roles.append(("batch" if Bm == b else None,
+                      "heads" if Hm == h else None, None, None))
+    if seeds is not None:
+        arrays.append(seeds)
+        roles.append(None)
+    return arrays, roles
+
+
+def _take_extras(rest, has_lens, has_mask, has_seeds):
+    """Inverse of `_extras` inside the shard: (kv_lens, mask3,
+    mask_dims, seeds)."""
+    it = iter(rest)
+    lens = next(it) if has_lens else None
+    m3, dims = None, (1, 1)
+    if has_mask:
+        m4 = next(it)
+        dims = (m4.shape[0], m4.shape[1])
+        m3 = m4.reshape(dims[0] * dims[1], *m4.shape[2:])
+    seeds = next(it) if has_seeds else None
+    if seeds is not None:
+        idx = shard_index()
+        if not isinstance(idx, int):     # python 0: the call is not split
+            # every shard draws its own dropout stream (the in-kernel
+            # hash keys on the LOCAL row); fwd and bwd shift alike
+            seeds = seeds.at[0, 0, 0].add(idx.astype(seeds.dtype))
+    return lens, m3, dims, seeds
+
+
+def _fwd_pallas_bshd(q, k, v, scale, causal, kv_lens=None, mask3=None,
+                     mask_dims=(1, 1), seeds=None, dropout_p=0.0):
+    """[B,S,H,D]-layout marshalling around _flash_fwd_pallas, shared by
+    every custom_vjp fwd: flatten heads, run the kernel, unflatten.
+    Returns (out [B,S,H,D], lse [B,H,S])."""
+    flags = (kv_lens is not None, mask3 is not None, seeds is not None)
+
+    def local(q, k, v, *rest):
+        lens, m3, dims, sd = _take_extras(rest, *flags)
+        b, sq, h, d = q.shape
+        hkv = k.shape[2]
+        bq, bk = _flash_blocks()
+
+        def to3(x, nh):
+            return x.transpose(0, 2, 1, 3).reshape(b * nh, x.shape[1], d)
+        out, lse = _flash_fwd_pallas(
+            to3(q, h), to3(k, hkv), to3(v, hkv), scale, causal,
+            block_q=bq, block_k=bk, n_heads=h, n_kv_heads=hkv,
+            kv_lens=lens, mask3=m3, mask_dims=dims, seeds=sd,
+            dropout_p=dropout_p)
+        return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
+                lse.reshape(b, h, sq))
+
+    extra, roles = _extras(q.shape[0], q.shape[2], kv_lens, mask3,
+                           mask_dims, seeds)
+    return partitioned(local, [_BSHD, _BSHD, _BSHD] + roles,
+                       [_BSHD, _BHS], q, k, v, *extra)
+
+
 def _flash_fwd(q, k, v, scale, causal):
-    b, sq, h, d = q.shape
-    hkv = k.shape[2]
-    bq, bk = _flash_blocks()
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, k.shape[1], d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, v.shape[1], d)
-    out, lse = _flash_fwd_pallas(qt, kt, vt, scale, causal,
-                                 block_q=bq, block_k=bk,
-                                 n_heads=h, n_kv_heads=hkv)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    return out, (q, k, v, out, lse.reshape(b, h, sq))
+    out, lse = _fwd_pallas_bshd(q, k, v, scale, causal)
+    return out, (q, k, v, out, lse)
 
 
 def _bwd_pallas_bshd(q, k, v, out, lse, g, scale, causal, kv_lens=None,
@@ -725,24 +791,36 @@ def _bwd_pallas_bshd(q, k, v, out, lse, g, scale, causal, kv_lens=None,
     """[B,S,H,D]-layout marshalling around _flash_bwd_pallas, shared by
     every custom_vjp bwd: flatten heads, run the kernels, unflatten and
     group-sum dk/dv down to the kv heads (GQA)."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    hkv = k.shape[2]
+    flags = (kv_lens is not None, mask3 is not None, seeds is not None)
 
-    def to3(x, s, nh):
-        return x.transpose(0, 2, 1, 3).reshape(b * nh, s, d)
-    bq, bk = _flash_blocks()
-    dq3, dk3, dv3 = _flash_bwd_pallas(
-        to3(q, sq, h), to3(k, sk, hkv), to3(v, sk, hkv),
-        to3(out, sq, h), lse.reshape(b * h, sq),
-        to3(g.astype(q.dtype), sq, h), scale, causal,
-        block_q=bq, block_k=bk, n_heads=h, n_kv_heads=hkv,
-        kv_lens=kv_lens, mask3=mask3, mask_dims=mask_dims,
-        seeds=seeds, dropout_p=dropout_p)
-    dq = dq3.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    dk = dk3.reshape(b, hkv, h // hkv, sk, d).sum(2).transpose(0, 2, 1, 3)
-    dv = dv3.reshape(b, hkv, h // hkv, sk, d).sum(2).transpose(0, 2, 1, 3)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+    def local(q, k, v, out, lse, g, *rest):
+        lens, m3, dims, sd = _take_extras(rest, *flags)
+        b, sq, h, d = q.shape
+        sk = k.shape[1]
+        hkv = k.shape[2]
+
+        def to3(x, s, nh):
+            return x.transpose(0, 2, 1, 3).reshape(b * nh, s, d)
+        bq, bk = _flash_blocks()
+        dq3, dk3, dv3 = _flash_bwd_pallas(
+            to3(q, sq, h), to3(k, sk, hkv), to3(v, sk, hkv),
+            to3(out, sq, h), lse.reshape(b * h, sq),
+            to3(g.astype(q.dtype), sq, h), scale, causal,
+            block_q=bq, block_k=bk, n_heads=h, n_kv_heads=hkv,
+            kv_lens=lens, mask3=m3, mask_dims=dims,
+            seeds=sd, dropout_p=dropout_p)
+        dq = dq3.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+        dk = dk3.reshape(b, hkv, h // hkv, sk, d).sum(2) \
+            .transpose(0, 2, 1, 3)
+        dv = dv3.reshape(b, hkv, h // hkv, sk, d).sum(2) \
+            .transpose(0, 2, 1, 3)
+        return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+
+    extra, roles = _extras(q.shape[0], q.shape[2], kv_lens, mask3,
+                           mask_dims, seeds)
+    return partitioned(
+        local, [_BSHD, _BSHD, _BSHD, _BSHD, _BHS, _BSHD] + roles,
+        [_BSHD, _BSHD, _BSHD], q, k, v, out, lse, g, *extra)
 
 
 def _flash_bwd(scale, causal, res, g):
@@ -800,18 +878,8 @@ def _flash_core_varlen(q, k, v, kv_lens, scale, causal):
 
 
 def _flash_fwd_varlen(q, k, v, kv_lens, scale, causal):
-    b, sq, h, d = q.shape
-    hkv = k.shape[2]
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, k.shape[1], d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, v.shape[1], d)
-    bq, bk = _flash_blocks()
-    out, lse = _flash_fwd_pallas(qt, kt, vt, scale, causal,
-                                 block_q=bq, block_k=bk,
-                                 n_heads=h, n_kv_heads=hkv,
-                                 kv_lens=kv_lens)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    return out, (q, k, v, kv_lens, out, lse.reshape(b, h, sq))
+    out, lse = _fwd_pallas_bshd(q, k, v, scale, causal, kv_lens=kv_lens)
+    return out, (q, k, v, kv_lens, out, lse)
 
 
 def _flash_bwd_varlen(scale, causal, res, g):
@@ -861,19 +929,11 @@ def _flash_core_gen(q, k, v, mask3, extras, scale, cfg):
 
 def _flash_fwd_gen(q, k, v, mask3, extras, scale, cfg):
     causal, dropout_p, Bm, Hm = cfg
-    b, sq, h, d = q.shape
-    hkv = k.shape[2]
-    bq, bk = _flash_blocks()
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, k.shape[1], d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, v.shape[1], d)
-    out, lse = _flash_fwd_pallas(
-        qt, kt, vt, scale, causal, block_q=bq, block_k=bk,
-        n_heads=h, n_kv_heads=hkv, kv_lens=extras.get("kv_lens"),
+    out, lse = _fwd_pallas_bshd(
+        q, k, v, scale, causal, kv_lens=extras.get("kv_lens"),
         mask3=mask3, mask_dims=(Bm, Hm), seeds=extras.get("seeds"),
         dropout_p=dropout_p)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    return out, (q, k, v, mask3, extras, out, lse.reshape(b, h, sq))
+    return out, (q, k, v, mask3, extras, out, lse)
 
 
 def _gen_reference(q, k, v, mask3, kv_lens, seeds, scale, causal,

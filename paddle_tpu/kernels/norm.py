@@ -19,7 +19,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._common import (_Z, _NEG_INF, use_pallas as _use_pallas,
-                      pallas_dtype_ok, pallas_interpret)
+                      pallas_dtype_ok, pallas_interpret, partitioned)
+
+# rows of the flattened [B*S, D] activations split with the batch; the
+# weight vectors are whole on every shard
+_ROWS = ("batch", None)
 
 
 # ------------------------------------------------------------- rms norm ----
@@ -57,7 +61,8 @@ def _rms_fwd(x, w, eps):
     d = shape[-1]
     x2 = x.reshape(-1, d)
     if _use_pallas() and d % 128 == 0 and pallas_dtype_ok(x2, w):
-        out2 = _rms_pallas(x2, w, eps)
+        out2 = partitioned(lambda a, g: _rms_pallas(a, g, eps),
+                           [_ROWS, None], _ROWS, x2, w)
     else:
         # f64 inputs keep f64 statistics (the x64 user asked for it)
         cdt = jnp.promote_types(x.dtype, jnp.float32)
@@ -131,7 +136,8 @@ def _ln_fwd(x, w, b, eps):
     d = shape[-1]
     x2 = x.reshape(-1, d)
     if _use_pallas() and d % 128 == 0 and pallas_dtype_ok(x2, w):
-        out2 = _ln_pallas(x2, w, b, eps)
+        out2 = partitioned(lambda a, g, c: _ln_pallas(a, g, c, eps),
+                           [_ROWS, None, None], _ROWS, x2, w, b)
     else:
         cdt = jnp.promote_types(x.dtype, jnp.float32)
         xf = x2.astype(cdt)

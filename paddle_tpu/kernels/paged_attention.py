@@ -28,7 +28,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._common import (_Z, _NEG_INF, use_pallas as _use_pallas,
                       pallas_dtype_ok, pallas_interpret, note_fallback,
-                      tp_shard_degree)
+                      tp_shard_degree, partitioned)
+
+# How a tensor-parallel replica splits the paged kernels' operands
+# (_common.partitioned): heads over 'model'; block tables, lengths and
+# ragged metadata whole on every shard.
+_Q_HEADS = (None, "heads", None)              # q/out [B, H, D]
+_SPAN_HEADS = (None, None, "heads", None)     # q/out [B, Qb, H, D]
+_PAGE_HEADS = (None, None, "heads", None)     # pages [P, page, Hkv, D]
+_META_FIELDS = ("seq", "page", "ordinal", "first", "last", "valid")
 
 
 def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
@@ -37,8 +45,8 @@ def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
     via ``kernels.pallas_fallbacks{kernel,reason}`` (docs/
     OBSERVABILITY.md) so production silently dropping to plain XLA is
     observable. Under tensor-parallel serving (``tp_degree`` > 1, else
-    the ambient ``_common.tp_shard_degree()``) the head axes are GSPMD-
-    sharded over 'model', so the tiling constraints must hold for the
+    the declared mesh's, ``_common.tp_shard_degree()``) the head axes
+    are sharded over 'model', so the tiling constraints must hold for the
     PER-SHARD head count H / tp — a global H that tiles but a shard
     that doesn't is recorded as reason ``tp_head_shard``."""
     h = q.shape[-2]
@@ -198,8 +206,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     interpret = interpret or pallas_interpret()
     if _paged_gate("paged_attention", q, k_pages, v_pages,
                    interpret):
-        return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
-                                       context_lens, sc, interpret=interpret)
+        return partitioned(
+            lambda q_, k_, v_, bt, cl: _paged_attention_pallas(
+                q_, k_, v_, bt, cl, sc, interpret=interpret),
+            [_Q_HEADS, _PAGE_HEADS, _PAGE_HEADS, None, None], _Q_HEADS,
+            q, k_pages, v_pages, block_tables, context_lens)
     return _paged_attention_xla(q, k_pages, v_pages, block_tables,
                                 context_lens, sc)
 
@@ -282,7 +293,7 @@ class RaggedMetaBuilder:
     reuses one compiled kernel.
     """
 
-    FIELDS = ("seq", "page", "ordinal", "first", "last", "valid")
+    FIELDS = _META_FIELDS
 
     def __init__(self, n_slots, pages_per_seq, page_size, trash_page=0):
         self.B = int(n_slots)
@@ -404,11 +415,25 @@ def paged_attention_ragged(q, k_pages, v_pages, context_lens, meta,
     build_ragged_meta (same page_size as the pools). Sequences with
     context_lens == 0 produce zeros. H == Hkv, D % 128 == 0, H % 8 == 0
     (the fixed-grid `paged_attention` covers the rest)."""
+    sc = scale if scale is not None else 1.0 / pymath.sqrt(q.shape[-1])
+    interpret = interpret or pallas_interpret()
+    lens = jnp.asarray(context_lens, jnp.int32)
+    out = partitioned(
+        lambda q_, k_, v_, ln, *m: _paged_attention_ragged_pallas(
+            q_, k_, v_, ln, m, sc, interpret),
+        [_Q_HEADS, _PAGE_HEADS, _PAGE_HEADS] + [None] * 7, _Q_HEADS,
+        q, k_pages, v_pages, lens,
+        *[jnp.asarray(meta[f], jnp.int32) for f in _META_FIELDS])
+    # sequences with no pages never write their output row
+    return jnp.where((lens > 0)[:, None, None], out, 0)
+
+
+def _paged_attention_ragged_pallas(q, k_pages, v_pages, lens, meta, scale,
+                                   interpret):
+    """`meta`: the six ragged arrays in _META_FIELDS order."""
     b, h, d = q.shape
     page = k_pages.shape[1]
-    sc = scale if scale is not None else 1.0 / pymath.sqrt(d)
-    interpret = interpret or pallas_interpret()
-    G = int(meta["seq"].shape[0])
+    G = int(meta[0].shape[0])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
@@ -431,23 +456,13 @@ def paged_attention_ragged(q, k_pages, v_pages, context_lens, meta,
             pltpu.VMEM((h, d), jnp.float32),
         ],
     )
-    kernel = functools.partial(_ragged_kernel, scale=sc, page_size=page)
-    out = pl.pallas_call(
+    kernel = functools.partial(_ragged_kernel, scale=scale, page_size=page)
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(meta["seq"], jnp.int32),
-      jnp.asarray(meta["page"], jnp.int32),
-      jnp.asarray(meta["ordinal"], jnp.int32),
-      jnp.asarray(meta["first"], jnp.int32),
-      jnp.asarray(meta["last"], jnp.int32),
-      jnp.asarray(meta["valid"], jnp.int32),
-      jnp.asarray(context_lens, jnp.int32),
-      q, k_pages, v_pages)
-    # sequences with no pages never write their output row
-    has = jnp.asarray(context_lens, jnp.int32) > 0
-    return jnp.where(has[:, None, None], out, 0)
+    )(*meta, lens, q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -513,11 +528,48 @@ def paged_attention_varq(q, k_pages, v_pages, block_tables, kv_lens,
                                      kv_lens, q_lens, sc)
 
 
+# The span is processed VARQ_Q_CHUNK queries at a time: the per-page
+# score and P.V products are (page, chunk, H, D) f32 intermediates, and
+# at Llama-2-7B widths (32 x 128) a whole 64-query span makes them
+# 16 MiB each — the TPU compiler refused the kernel for exceeding the
+# 16 MiB scoped VMEM limit (jax 0.9.0 / libtpu 0.0.34, described
+# v5e:2x2). Eight queries keep them at 2 MiB.
+VARQ_Q_CHUNK = 8
+# Scoped VMEM the TPU compiler grants a kernel by default on v5e.
+_VMEM_SCOPED_BYTES = 16 * 1024 * 1024
+
+
+def varq_vmem_bytes(qb, h, d, page, itemsize):
+    """VMEM the ragged varq kernel needs for a `qb`-query span bucket,
+    from its shapes: the three f32 accumulators (qb, h, 128|128|d), the
+    double-buffered q/out blocks (qb, h, d) and k/v pages, and the two
+    (page, chunk, h, d) f32 products of one chunk."""
+    qc = min(qb, VARQ_Q_CHUNK)
+    scratch = qb * h * (128 + 128 + d) * 4
+    blocks = 2 * 2 * (qb + page) * h * d * itemsize
+    products = 2 * page * qc * h * d * 4
+    return scratch + blocks + products
+
+
+def max_varq_span(h, d, page, itemsize):
+    """Largest power-of-two span bucket whose varq kernel fits the
+    scoped VMEM limit at this head geometry (0 = not even one query).
+    The predictor bounds its chunk and speculative-verify buckets with
+    this at construction; above it `paged_attention_ragged_varq`
+    raises instead of compiling a kernel the chip would refuse."""
+    qb = 0
+    nxt = 1
+    while varq_vmem_bytes(nxt, h, d, page, itemsize) <= _VMEM_SCOPED_BYTES:
+        qb, nxt = nxt, nxt * 2
+    return qb
+
+
 def _ragged_varq_kernel(seq_ref, page_ref, ord_ref, first_ref, last_ref,
                         valid_ref, kvlen_ref, qlen_ref, q_ref, k_ref,
                         v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
-                        page_size):
+                        page_size, q_chunk):
     g = pl.program_id(0)
+    n_chunks = q_ref.shape[1] // q_chunk
 
     @pl.when(first_ref[g] == 1)
     def _init():
@@ -530,29 +582,39 @@ def _ragged_varq_kernel(seq_ref, page_ref, ord_ref, first_ref, last_ref,
         b = seq_ref[g]
         kl = kvlen_ref[b]
         ql = qlen_ref[b]
-        q = q_ref[0].astype(jnp.float32)   # (Qb, H, D)
         k = k_ref[0].astype(jnp.float32)   # (page, H, D)
         v = v_ref[0].astype(jnp.float32)
-        s = jnp.sum(q[None, :, :, :] * k[:, None, :, :],
-                    axis=-1) * np.float32(scale)          # (page, Qb, H)
-        tok = ord_ref[g] * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        qpos = (kl - ql) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # keys causal to each span query AND inside the written context;
-        # a span's ordinal-0 page always holds key 0, so every real
-        # query row sees >= 1 valid key on its first page (no exp(0)
-        # pollution of the online softmax)
-        s = jnp.where((tok <= qpos) & (tok < kl), s, _NEG_INF)
-        m_prev = m_scr[:, :, 0]                           # (Qb, H)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        p = jnp.exp(s - m_new[None, :, :])                # (page, Qb, H)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, :, 0] * alpha + jnp.sum(p, axis=0)
-        pv = jnp.sum(p[:, :, :, None] * v[:, None, :, :],
-                     axis=0)                              # (Qb, H, D)
-        acc_scr[:] = acc_scr[:] * alpha[:, :, None] + pv
-        m_scr[:] = jnp.broadcast_to(m_new[:, :, None], m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new[:, :, None], l_scr.shape)
+
+        def chunk(c, carry):
+            rows = pl.ds(pl.multiple_of(c * q_chunk, q_chunk), q_chunk)
+            q = q_ref[0, rows].astype(jnp.float32)        # (Qc, H, D)
+            s = jnp.sum(q[None, :, :, :] * k[:, None, :, :],
+                        axis=-1) * np.float32(scale)      # (page, Qc, H)
+            tok = ord_ref[g] * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            qpos = (kl - ql) + c * q_chunk + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            # keys causal to each span query AND inside the written
+            # context; a span's ordinal-0 page always holds key 0, so
+            # every real query row sees >= 1 valid key on its first
+            # page (no exp(0) pollution of the online softmax)
+            s = jnp.where((tok <= qpos) & (tok < kl), s, _NEG_INF)
+            m_prev = m_scr[rows][:, :, 0]                 # (Qc, H)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None, :, :])            # (page, Qc, H)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[rows][:, :, 0] * alpha + jnp.sum(p, axis=0)
+            pv = jnp.sum(p[:, :, :, None] * v[:, None, :, :],
+                         axis=0)                          # (Qc, H, D)
+            acc_scr[rows] = acc_scr[rows] * alpha[:, :, None] + pv
+            m_scr[rows] = jnp.broadcast_to(
+                m_new[:, :, None], (q_chunk,) + m_scr.shape[1:])
+            l_scr[rows] = jnp.broadcast_to(
+                l_new[:, :, None], (q_chunk,) + l_scr.shape[1:])
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_chunks), chunk,
+                          jnp.int32(0))
 
     @pl.when(last_ref[g] == 1)
     def _finalize():
@@ -564,9 +626,19 @@ def _ragged_varq_kernel(seq_ref, page_ref, ord_ref, first_ref, last_ref,
 def _paged_attention_ragged_varq_pallas(q, k_pages, v_pages, kv_lens,
                                         q_lens, meta, scale,
                                         interpret=False):
+    """`meta`: the six ragged arrays in _META_FIELDS order."""
     b, qb, h, d = q.shape
     page = k_pages.shape[1]
-    G = int(meta["seq"].shape[0])
+    G = int(meta[0].shape[0])
+    q_chunk = next(c for c in range(min(qb, VARQ_Q_CHUNK), 0, -1)
+                   if qb % c == 0)
+    fit = max_varq_span(h, d, page, q.dtype.itemsize)
+    if not interpret and qb > fit:
+        raise ValueError(
+            f"varq span bucket {qb} at {h} heads x {d} needs "
+            f"{varq_vmem_bytes(qb, h, d, page, q.dtype.itemsize)} bytes "
+            f"of VMEM, over the {_VMEM_SCOPED_BYTES} the TPU compiler "
+            f"grants a kernel; the largest bucket that fits is {fit}")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
@@ -592,21 +664,13 @@ def _paged_attention_ragged_varq_pallas(q, k_pages, v_pages, kv_lens,
         ],
     )
     kernel = functools.partial(_ragged_varq_kernel, scale=scale,
-                               page_size=page)
+                               page_size=page, q_chunk=q_chunk)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, qb, h, d), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(meta["seq"], jnp.int32),
-      jnp.asarray(meta["page"], jnp.int32),
-      jnp.asarray(meta["ordinal"], jnp.int32),
-      jnp.asarray(meta["first"], jnp.int32),
-      jnp.asarray(meta["last"], jnp.int32),
-      jnp.asarray(meta["valid"], jnp.int32),
-      jnp.asarray(kv_lens, jnp.int32),
-      jnp.asarray(q_lens, jnp.int32),
-      q, k_pages, v_pages)
+    )(*meta, kv_lens, q_lens, q, k_pages, v_pages)
 
 
 def paged_attention_ragged_varq(q, k_pages, v_pages, kv_lens, q_lens,
@@ -627,9 +691,14 @@ def paged_attention_ragged_varq(q, k_pages, v_pages, kv_lens, q_lens,
     interpret = interpret or pallas_interpret()
     if _paged_gate("paged_attention_ragged_varq", q, k_pages,
                    v_pages, interpret):
-        out = _paged_attention_ragged_varq_pallas(
-            q, k_pages, v_pages, kv_lens, q_lens, meta, sc,
-            interpret=interpret)
+        out = partitioned(
+            lambda q_, k_, v_, kl, ql, *m:
+            _paged_attention_ragged_varq_pallas(
+                q_, k_, v_, kl, ql, m, sc, interpret=interpret),
+            [_SPAN_HEADS, _PAGE_HEADS, _PAGE_HEADS] + [None] * 8,
+            _SPAN_HEADS, q, k_pages, v_pages,
+            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+            *[jnp.asarray(meta[f], jnp.int32) for f in _META_FIELDS])
         qb = q.shape[1]
         qvalid = jnp.arange(qb, dtype=jnp.int32)[None, :] \
             < jnp.asarray(q_lens, jnp.int32)[:, None]

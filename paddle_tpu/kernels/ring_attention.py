@@ -38,12 +38,8 @@ _NEG_INF = -1e30
 def _inside_manual(axis_name):
     """True when tracing inside a shard_map that already manualizes
     axis_name (values are local shards; collectives over it are legal)."""
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        return (ctx is not None and not ctx.empty
-                and axis_name in set(getattr(ctx, "manual_axes", ()) or ()))
-    except AttributeError:
-        return False
+    ctx = jax.sharding.get_abstract_mesh()
+    return not ctx.empty and axis_name in set(ctx.manual_axes)
 
 
 def _pvary(x, axis_name):
@@ -52,17 +48,12 @@ def _pvary(x, axis_name):
     carry to agree on vma; the online-softmax init states start out
     replicated, while the q/k/v they merge with vary over axis_name AND
     any outer shard_map's manual axes (e.g. the pipeline 'stage')."""
+    from ..framework.jax_compat import pcast
     axes = {axis_name}
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        if ctx is not None and not ctx.empty:
-            axes |= set(ctx.manual_axes)
-    except AttributeError:
-        pass
-    try:
-        return lax.pcast(x, tuple(sorted(axes)), to="varying")
-    except (AttributeError, TypeError):
-        return x
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty:
+        axes |= set(ctx.manual_axes)
+    return pcast(x, tuple(sorted(axes)), to="varying")
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, axis_name):
@@ -74,20 +65,12 @@ def _shard_map(fn, mesh, in_specs, out_specs, axis_name):
     # check_vma=True is required for a correct transpose: with vma
     # checking off, the backward of the nested ring mis-placed psums and
     # produced silently wrong dq/dk/dv under an outer pipeline shard_map.
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        if ctx is not None and not ctx.empty and ctx._any_axis_manual:
-            mesh = ctx
-    except AttributeError:
-        pass
-    try:
-        from jax import shard_map as _sm  # jax >= 0.8
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names={axis_name}, check_vma=True)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty and ctx.manual_axes:
+        mesh = ctx
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={axis_name},
+                         check_vma=True)
 
 
 def _sharded_attn(local_core, mesh, spec, q, k, v, kv_lens, lens_spec,

@@ -62,6 +62,9 @@ class LlamaConfig:
 
     @staticmethod
     def llama2_7b(**kw):
+        # published Llama-2-7B config.json: rms_norm_eps 1e-05 (the
+        # dataclass default 1e-6 is Llama-1's)
+        kw.setdefault("rms_norm_eps", 1e-5)
         return LlamaConfig(**kw)
 
     @staticmethod

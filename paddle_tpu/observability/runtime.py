@@ -244,8 +244,7 @@ def export_record(rec: dict):
 
 # ---------------------------------------------------------- heartbeat ------
 class RankHeartbeat:
-    """Per-rank liveness lines so a wedged rank is diagnosable
-    (BENCH_r0* postmortems: five rounds of silently wedged TPU runs).
+    """Per-rank liveness lines so a wedged rank is diagnosable.
 
     Appends JSONL lines {"ts", "kind": "heartbeat", "rank"/"epoch", ...}
     at most once per `interval` seconds; `beat(**fields)` is safe to

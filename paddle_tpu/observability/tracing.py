@@ -18,11 +18,10 @@ crashed run*. Two pieces:
   in-memory ring; still-open spans are tracked separately. On crash
   paths — the uncaught-exception hook installed here, the Trainer's
   SIGTERM/SIGINT chain, ``AnomalousTrainingError``,
-  ``DecodeWedgedError``/decode-watchdog, bench backend-init wedge —
+  ``DecodeWedgedError``/decode-watchdog —
   :func:`flight_dump` writes the ring, the open spans (the forensic
   gold: *which phase was in progress*), armed-fault events, and a
-  registry snapshot to ``flight_<pid>.json``. BENCH_r01–r05 all died as
-  opaque ``rc=3`` wedges with zero forensic output; this is the fix.
+  registry snapshot to ``flight_<pid>.json``.
 
 Cost contract (same bar as the metrics layer, asserted by
 tests/test_tracing.py): spans are pure host-side bookkeeping — they add

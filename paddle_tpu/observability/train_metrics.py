@@ -67,9 +67,14 @@ def sharded_bytes(leaves):
     return tot, per
 
 
-def peak_flops(dtype: str = "bfloat16") -> float:
-    from ..trainer import device_peak_flops
-    return device_peak_flops(dtype)
+def peak_flops(dtype: str = "bfloat16") -> Optional[float]:
+    """The chip's published peak (trainer.PEAK_BF16_FLOPS), or None on
+    a device that has none: ``train.mfu`` is then not recorded."""
+    from ..trainer import UnknownDevicePeak, device_peak_flops
+    try:
+        return device_peak_flops(dtype)
+    except UnknownDevicePeak:
+        return None
 
 
 def batch_tokens(arrays) -> int:
@@ -150,10 +155,11 @@ class StepTelemetry:
             self.c_tokens.inc(tokens)
             tps = tokens / dt if dt > 0 else 0.0
             self.g_tps.set(tps)
-            fps = self._flops_for(tokens)
+            peak = peak_flops(self.dtype)
+            fps = self._flops_for(tokens) if peak else 0.0
             if fps:
-                peak = peak_flops(self.dtype) * self.n_devices
-                self.g_mfu.set((fps / dt) / peak if dt > 0 else 0.0)
+                self.g_mfu.set((fps / dt) / (peak * self.n_devices)
+                               if dt > 0 else 0.0)
         for op, axis, calls, nbytes in self.comm_per_step:
             self.c_comm_calls.inc(calls, op=op, axis=axis)
             self.c_comm_bytes.inc(nbytes, op=op, axis=axis)

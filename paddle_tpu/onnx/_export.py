@@ -102,7 +102,7 @@ class _Lowerer:
         self.env: Dict = {}     # jax Var -> name (str) or np const
 
     def read(self, atom):
-        from jax._src.core import Literal
+        from jax.extend.core import Literal
         if isinstance(atom, Literal):
             return np.asarray(atom.val)
         return self.env[atom]

@@ -484,30 +484,39 @@ class Router:
         self._lock = threading.Lock()
         self._req_seq = 0
         self.replicas: List[Replica] = []
-        # tensor-parallel replicas occupy a device GROUP, not one
-        # device: partition the visible devices into disjoint groups
-        # of tp so replica i's GSPMD programs never contend with
-        # replica j's for a chip
-        tp = int(predictor_kw.get("tp_degree") or 0)
-        device_groups = None
-        if tp > 1 and any(not hasattr(p, "serve_stream")
-                          for p in predictors):
+        # every replica built here gets its own device group of
+        # max(tp, 1) devices, with its weights and KV pool committed
+        # there: replica i's programs never contend with replica j's
+        # for a chip. More single-device replicas than devices wrap
+        # around (several replicas share a chip); tensor-parallel
+        # groups are never shared.
+        tp = predictor_kw.get("tp_degree")
+        if tp is None:
+            from ..framework.runtime_config import RuntimeConfig
+            rc = predictor_kw.get("runtime_config") \
+                or RuntimeConfig.from_flags()
+            tp = getattr(rc, "tp_degree", 1)
+        tp = max(1, int(tp or 1))
+        n_built = sum(1 for p in predictors
+                      if not hasattr(p, "serve_stream"))
+        device_groups = []
+        if n_built and "devices" not in predictor_kw:
             import jax
             devs = jax.devices()
-            need = tp * sum(1 for p in predictors
-                            if not hasattr(p, "serve_stream"))
-            if len(devs) < need:
+            if tp > 1 and len(devs) < tp * n_built:
                 raise ValueError(
-                    f"tp_degree={tp} x {need // tp} replicas needs "
-                    f"{need} devices, got {len(devs)}")
-            device_groups = [devs[j * tp:(j + 1) * tp]
-                             for j in range(need // tp)]
+                    f"tp_degree={tp} x {n_built} replicas needs "
+                    f"{tp * n_built} devices, got {len(devs)}")
+            n_groups = max(1, len(devs) // tp)
+            device_groups = [
+                devs[(j % n_groups) * tp:(j % n_groups + 1) * tp]
+                for j in range(n_built)]
         for i, p in enumerate(predictors):
             role = roles[i] if roles is not None else None
             if not hasattr(p, "serve_stream"):   # a model: wrap it
                 from ..inference import ContinuousBatchingPredictor
                 kw = dict(predictor_kw)
-                if device_groups is not None:
+                if device_groups:
                     kw["devices"] = device_groups.pop(0)
                 if role is not None:
                     # per-role specialization: the role's RuntimeConfig

@@ -29,7 +29,8 @@ from ..framework import faults as _faults
 from ..framework.flags import flag_value as _fv
 
 __all__ = ["TrainingArguments", "Trainer", "SpeedMeter",
-           "device_peak_flops", "AnomalousTrainingError"]
+           "device_peak_flops", "PEAK_BF16_FLOPS", "UnknownDevicePeak",
+           "AnomalousTrainingError"]
 
 
 class AnomalousTrainingError(RuntimeError):
@@ -38,20 +39,41 @@ class AnomalousTrainingError(RuntimeError):
     checkpoint is intact — anomalous steps are never checkpointed."""
 
 
-def device_peak_flops(dtype: str = "bfloat16") -> float:
-    """Peak FLOP/s of one local accelerator chip, for MFU accounting.
-    Known TPU generations by device_kind; conservative 1e12 fallback."""
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "cpu").lower()
-    table = {  # bf16 peak per chip
-        "tpu v4": 275e12, "tpu v5 lite": 197e12, "tpu v5e": 197e12,
-        "tpu v5p": 459e12, "tpu v5": 459e12, "tpu v6e": 918e12,
-        "tpu v6 lite": 918e12,
-    }
-    for k, v in table.items():
-        if k in kind:
-            return v if dtype in ("bfloat16", "float16") else v / 2
-    return 1e12
+# Published bf16 peak FLOP/s of one chip, keyed by the `device_kind` JAX
+# reports. Source: Google Cloud TPU documentation, the per-generation
+# system-architecture pages ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e");
+# the v5e row is the one the on-chip-measurement guide quotes (197
+# TFLOP/s bf16, 16 GB HBM at 819 GB/s). A device that is not here has no
+# peak: asking for one is an error, never a default.
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,      # v5e
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v5": 459e12,           # how some releases name v5p
+    "TPU v6 lite": 918e12,      # v6e
+    "TPU v6e": 918e12,
+}
+
+
+class UnknownDevicePeak(LookupError):
+    """No published peak for this device: MFU is not defined on it."""
+
+
+def device_peak_flops(dtype: str = "bfloat16", device=None) -> float:
+    """Peak FLOP/s of one local accelerator chip, for MFU accounting,
+    from PEAK_BF16_FLOPS by `device_kind` (f32 counted at half the bf16
+    rate). Raises UnknownDevicePeak for any other device."""
+    dev = device if device is not None else jax.devices()[0]
+    kind = getattr(dev, "device_kind", "")
+    peak = PEAK_BF16_FLOPS.get(kind)
+    if peak is None:
+        raise UnknownDevicePeak(
+            f"no published peak FLOP/s for device_kind {kind!r} "
+            f"(platform {getattr(dev, 'platform', '?')!r}); known: "
+            f"{sorted(PEAK_BF16_FLOPS)}. A utilisation comes only from a "
+            "chip in this table.")
+    return peak if dtype in ("bfloat16", "float16") else peak / 2
 
 
 @dataclass
@@ -81,8 +103,13 @@ class SpeedMeter:
         return sum(self._tokens[1:]) / dt if dt > 0 else 0.0
 
     @property
-    def mfu(self) -> float:
-        peak = device_peak_flops(self.dtype) * self.n_devices
+    def mfu(self) -> Optional[float]:
+        """None on a device with no published peak (the CPU): the
+        number is then not measured, not estimated."""
+        try:
+            peak = device_peak_flops(self.dtype) * self.n_devices
+        except UnknownDevicePeak:
+            return None
         return (6.0 * self.n_params * self.tokens_per_sec) / peak
 
 
@@ -453,9 +480,10 @@ class Trainer:
                     with _obs.span("train.loss_sync", parent=st_sp,
                                    step=step + 1):
                         loss_val = float(loss)  # sync at log boundary only
+                mfu = meter.mfu
                 rec = {"step": step + 1, "loss": round(loss_val, 6),
                        "tokens_per_sec": round(meter.tokens_per_sec, 2),
-                       "mfu": round(meter.mfu, 4)}
+                       "mfu": None if mfu is None else round(mfu, 4)}
                 logs.append(rec)
                 self._log(rec)
                 if _obs.enabled():

@@ -289,8 +289,10 @@ class TestFallbackAndInvalidation:
 
 
 class TestTier2Cache:
-    def test_wire_fences_and_keeps_min_compile_floor(self, tmp_path):
+    def test_wire_fences_and_obeys_the_environment(self, tmp_path,
+                                                   monkeypatch):
         import jax
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         prev = jax.config.jax_compilation_cache_dir
         cache = str(tmp_path / "xc")
         try:
@@ -315,18 +317,17 @@ class TestTier2Cache:
                             tier="xla_cache") >= 1
             finally:
                 obs.enabled(was)
-            # the 0.5s numerics floor is ENFORCED, never lowered
-            floor = jax.config.jax_persistent_cache_min_compile_time_secs
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.1)
-                with pytest.raises(RuntimeError, match="floor"):
-                    aot.wire_xla_cache(cache)
-            finally:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", floor)
+            # where the environment places the cache, a bundle does
+            # not move it
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+            other = str(tmp_path / "other")
+            assert aot.wire_xla_cache(other) == got
+            assert jax.config.jax_compilation_cache_dir == got
+            assert not os.path.exists(other)
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
+            from paddle_tpu.inference.aot.engine import _reset_cache_object
+            _reset_cache_object()
 
 
 class TestToolingAndSatellites:

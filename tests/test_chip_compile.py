@@ -1,0 +1,165 @@
+"""The main path's kernels, at Llama-2-7B widths, through the installed
+TPU compiler for a described ``v5e:2x2`` with no chip attached (the
+third rehearsal of the on-chip-measurement guide, kept as a test).
+
+Interpret mode cannot show what this shows: the ragged varq kernel had
+passed every interpret-mode test and was refused here at a 64-token span
+for exceeding the 16 MiB scoped VMEM limit. A compile that passes is
+not a chip run: nothing here executes, and nothing here is a time.
+Whole step programs are compiled by ``tools/chip_rehearsal.py``.
+"""
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+import paddle_tpu  # noqa: E402,F401  (x64 + matmul precision as in production)
+from paddle_tpu.kernels import attention, norm  # noqa: E402
+from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
+
+# Llama-2-7B: 32 heads of 128, hidden 4096, bf16
+H, D, HIDDEN = 32, 128, 4096
+BF16, I32 = jnp.bfloat16, jnp.int32
+# the serve geometry chip_smoke.py uses
+B, PAGE, PAGES_PER_SEQ, POOL = 8, 16, 64, 1024
+SCALE = D ** -0.5
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one chip of a described v5e:2x2."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:    # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _compile(chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert " f64[" not in text
+    return text
+
+
+def _pool():
+    return ((POOL, PAGE, H, D), BF16)
+
+
+def _meta():
+    return [((B * PAGES_PER_SEQ,), I32)] * len(pa.RaggedMetaBuilder.FIELDS)
+
+
+def test_rms_norm(chip):
+    _compile(chip, lambda x, w: norm._rms_pallas(x, w, 1e-5),
+             ((2048, HIDDEN), BF16), ((HIDDEN,), BF16))
+
+
+def test_layer_norm(chip):
+    _compile(chip, lambda x, w, b: norm._ln_pallas(x, w, b, 1e-5),
+             ((2048, HIDDEN), BF16), ((HIDDEN,), BF16), ((HIDDEN,), BF16))
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
+def test_flash_forward(chip, kv_heads):
+    q = ((2 * H, 2048, D), BF16)
+    kv = ((2 * kv_heads, 2048, D), BF16)
+    _compile(chip, lambda q, k, v: attention._flash_fwd_pallas(
+        q, k, v, SCALE, True, n_heads=H, n_kv_heads=kv_heads), q, kv, kv)
+
+
+def test_flash_backward(chip):
+    x = ((2 * H, 2048, D), BF16)
+    lse = ((2 * H, 2048), jnp.float32)
+    text = _compile(
+        chip, lambda q, k, v, o, lse, do: attention._flash_bwd_pallas(
+            q, k, v, o, lse, do, SCALE, True, n_heads=H, n_kv_heads=H),
+        x, x, x, x, lse, x)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_paged_attention_block_tables(chip):
+    _compile(chip, lambda q, k, v, bt, cl: pa._paged_attention_pallas(
+        q, k, v, bt, cl, SCALE),
+        ((B, H, D), BF16), _pool(), _pool(),
+        ((B, PAGES_PER_SEQ), I32), ((B,), I32))
+
+
+def test_paged_attention_ragged(chip):
+    _compile(chip, lambda q, k, v, ln, *m: pa._paged_attention_ragged_pallas(
+        q, k, v, ln, m, SCALE, False),
+        ((B, H, D), BF16), _pool(), _pool(), ((B,), I32), *_meta())
+
+
+# every span bucket the predictor can request at these widths: chunk
+# buckets page * 2^k up to the VMEM bound, a speculative-verify span
+# (k + 1 = 5), and the single decode token
+@pytest.mark.parametrize("span", [1, 5, 16, 32, 64, 128])
+def test_paged_attention_ragged_varq(chip, span):
+    assert span <= pa.max_varq_span(H, D, PAGE, 2)
+    _compile(
+        chip, lambda q, k, v, kl, ql, *m:
+        pa._paged_attention_ragged_varq_pallas(q, k, v, kl, ql, m, SCALE),
+        ((B, span, H, D), BF16), _pool(), _pool(), ((B,), I32), ((B,), I32),
+        *_meta())
+
+
+def test_varq_span_bound_is_the_compilers(chip):
+    """The largest bucket `max_varq_span` admits compiles (above); the
+    next one is refused by name before the compiler is asked, and by the
+    compiler itself when the check is lifted."""
+    fit = pa.max_varq_span(H, D, PAGE, 2)
+    assert fit == 128
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in (
+        ((B, 2 * fit, H, D), BF16), _pool(), _pool(), ((B,), I32),
+        ((B,), I32), *_meta())]
+
+    def call(q, k, v, kl, ql, *m):
+        return pa._paged_attention_ragged_varq_pallas(q, k, v, kl, ql, m,
+                                                      SCALE)
+    with pytest.raises(ValueError, match="largest bucket that fits is 128"):
+        jax.jit(call).lower(*args)
+    limit = pa._VMEM_SCOPED_BYTES
+    pa._VMEM_SCOPED_BYTES = 1 << 40
+    try:
+        with pytest.raises(Exception, match="vmem|RESOURCE_EXHAUSTED"):
+            jax.jit(call).lower(*args).compile()
+    finally:
+        pa._VMEM_SCOPED_BYTES = limit
+
+
+def test_predictor_refuses_a_span_over_the_bound():
+    """The bound reaches the user at construction: a chunk size whose
+    bucket the kernel cannot hold is a ValueError that names it, not a
+    compiler refusal at the first long prompt."""
+    from paddle_tpu.inference import ContinuousBatchingPredictor
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    cfg = LlamaConfig.tiny(hidden_size=1024, num_attention_heads=8,
+                           num_key_value_heads=8, num_hidden_layers=1,
+                           intermediate_size=128, vocab_size=64,
+                           tensor_parallel=False)
+    model = LlamaForCausalLM(cfg)
+    fit = pa.max_varq_span(8, 128, 16, 4)
+    geometry = dict(max_batch_size=2, page_size=16, max_seq_len=4 * fit,
+                    use_ragged=True)
+    ContinuousBatchingPredictor(model, prefill_chunk_tokens=fit, **geometry)
+    with pytest.raises(ValueError, match="max_varq_span"):
+        ContinuousBatchingPredictor(model, prefill_chunk_tokens=2 * fit,
+                                    **geometry)

@@ -93,7 +93,7 @@ class TestCollectiveEagerFallback:
     def test_spmd_region_psum(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = _fresh_mesh(dp=8)
         g = dist.new_group(axis="data")

@@ -606,42 +606,37 @@ class TestOverlap:
 
 
 # ===========================================================================
-# shard_map shim: narrowed skip contract
+# shard_map adapter: partial-manual regions on the installed jax
 # ===========================================================================
 class TestShardMapShim:
-    def test_partial_manual_raises_typed_error(self):
-        from paddle_tpu.framework.jax_compat import (
-            shard_map, ShardMapUnsupported, _modern_shard_map)
+    def test_partial_manual_region_runs(self):
+        """Manual over 'stage' only, auto over 'data': the region a
+        hybrid pp x dp mesh needs is simply available on jax 0.9.0."""
+        from paddle_tpu.framework.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
-        if _modern_shard_map() is not None:
-            pytest.skip("modern jax: partial-manual is supported")
         mesh = build_mesh(dp=2, pp=2)
-        with pytest.raises(ShardMapUnsupported,
-                           match="partial-manual shard_map"):
-            shard_map(lambda x: x, mesh=mesh, in_specs=(P("stage"),),
-                      out_specs=P("stage"), axis_names={"stage"})
-        # the narrowed type IS a NotImplementedError (back-compat for
-        # callers catching the base), but the reverse must not hold:
-        # a bare NotImplementedError from user code is NOT skippable
-        assert issubclass(ShardMapUnsupported, NotImplementedError)
+        x = jnp.arange(8.0).reshape(2, 4)
+        out = jax.jit(shard_map(
+            lambda a: a * (1.0 + jax.lax.axis_index("stage")),
+            mesh=mesh, in_specs=(P("stage"),), out_specs=P("stage"),
+            axis_names={"stage"}))(x)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(x) * np.array([[1.0], [2.0]]))
 
-    def test_pipeline_hybrid_mesh_fails_clean_not_crash(self):
-        """A pipeline step on a hybrid (partial-manual) mesh must
-        surface ShardMapUnsupported as an ordinary exception — the
-        process stays alive (the old partial-auto lowering CHECK-failed
-        and aborted the interpreter)."""
-        from paddle_tpu.framework.jax_compat import (
-            ShardMapUnsupported, _modern_shard_map)
+    def test_pipeline_on_hybrid_mesh_matches_sequential(self):
+        """pipeline_spmd on a pp=2 x dp=2 mesh (a partial-manual
+        shard_map) computes what the stages compute in sequence."""
         from paddle_tpu.distributed.fleet.meta_parallel.\
             pipeline_parallel import pipeline_spmd
-        if _modern_shard_map() is not None:
-            pytest.skip("modern jax: partial-manual is supported")
         mesh = build_mesh(dp=2, pp=2)
-        w = jnp.zeros((2, 4, 4), jnp.float32)
-        xm = jnp.zeros((2, 4, 4), jnp.float32)
-        with pytest.raises(ShardMapUnsupported):
-            pipeline_spmd(lambda p, x, k: x @ p[0], [w], xm,
-                          num_stages=2, mesh=mesh)
+        rng = np.random.RandomState(0)
+        w = jnp.asarray(rng.randn(2, 4, 4), jnp.float32)
+        xm = jnp.asarray(rng.randn(2, 4, 4), jnp.float32)
+        out = pipeline_spmd(lambda p, x, k: x @ p[0], [w], xm,
+                            num_stages=2, mesh=mesh)
+        want = np.asarray(xm) @ np.asarray(w[0]) @ np.asarray(w[1])
+        np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5,
+                                   atol=1e-5)
 
 
 # ===========================================================================

@@ -289,7 +289,7 @@ class TestDistStepTelemetry:
         for rec in recs:
             series.setdefault(rec["name"], []).append(rec)
         for required in ("train.step_time_seconds", "train.tokens_per_sec",
-                         "train.mfu", "train.grad_norm", "comm.bytes",
+                         "train.grad_norm", "comm.bytes",
                          "mem.bytes_in_use", "mem.peak_bytes_in_use",
                          "train.steps", "train.tokens"):
             assert required in series, (required, sorted(series))
@@ -298,7 +298,9 @@ class TestDistStepTelemetry:
         assert series["train.steps"][-2]["value"] == steps0 + 20
         assert series["train.step_time_seconds"][-1]["count"] == h0 + 20
         assert series["train.tokens_per_sec"][-1]["value"] > 0
-        assert series["train.mfu"][-1]["value"] > 0
+        # a utilisation needs a chip with a published peak: on the CPU
+        # the series is absent, not estimated
+        assert "train.mfu" not in series
         assert series["train.grad_norm"][-1]["value"] > 0
         comm = [rec for rec in series["comm.bytes"]
                 if rec["labels"].get("axis") == "data"

@@ -19,24 +19,6 @@ from paddle_tpu.distributed.fleet.meta_parallel import PipelineLayer
 from paddle_tpu.jit import TrainStep
 
 
-import contextlib
-
-
-@contextlib.contextmanager
-def _partial_manual_or_skip():
-    """Hybrid pp x (dp|mp) meshes need partial-manual shard_map; on jax
-    without the top-level jax.shard_map the compat layer raises
-    ShardMapUnsupported. Skip on exactly that type — a bare
-    NotImplementedError from anywhere else in the traced step must
-    FAIL, not skip (catching the base class here masked real
-    regressions; tests/test_hybrid.py pins the narrowed contract)."""
-    from paddle_tpu.framework.jax_compat import ShardMapUnsupported
-    try:
-        yield
-    except ShardMapUnsupported as e:
-        pytest.skip(str(e))
-
-
 class Block(nn.Layer):
     def __init__(self, d):
         super().__init__()
@@ -210,9 +192,8 @@ def test_pipeline_zero_sharding_loss_parity(zero):
         if zero >= 3:
             pspecs = [sh.spec for sh in pstep._stacked_sh]
             assert any("data" in tuple(s) for s in pspecs), pspecs
-        with _partial_manual_or_skip():
-            losses = [float(pstep(paddle.to_tensor(x), paddle.to_tensor(y)))
-                      for _ in range(steps)]
+        losses = [float(pstep(paddle.to_tensor(x), paddle.to_tensor(y)))
+                  for _ in range(steps)]
     finally:
         set_mesh(None)
     np.testing.assert_allclose(losses, ref_losses, rtol=2e-4, atol=2e-5)
@@ -248,9 +229,7 @@ def test_pipeline_remat_activation_memory():
                                     mesh=mesh, use_remat=remat)
                 return jnp.sum(out ** 2)
 
-            from paddle_tpu.framework.jax_compat import (
-                x64_safe_shard_map_trace)
-            with mesh_scope(mesh), x64_safe_shard_map_trace():
+            with mesh_scope(mesh):
                 c = jax.jit(jax.grad(loss)).lower([W]).compile()
             return c.memory_analysis().temp_size_in_bytes
         finally:
@@ -370,9 +349,8 @@ def test_pipeline_times_context_parallel_loss_parity():
         popt = paddle.optimizer.AdamW(1e-2, parameters=pipe.parameters())
         pstep = PipelineTrainStep(pipe, popt, loss_fn,
                                   num_microbatches=2, mesh=mesh)
-        with _partial_manual_or_skip():
-            losses = [float(pstep(paddle.to_tensor(x), paddle.to_tensor(y)))
-                      for _ in range(steps)]
+        losses = [float(pstep(paddle.to_tensor(x), paddle.to_tensor(y)))
+                  for _ in range(steps)]
     finally:
         set_mesh(None)
     np.testing.assert_allclose(losses, ref_losses, rtol=5e-4, atol=5e-5)
@@ -421,10 +399,9 @@ def test_pipeline_times_tensor_parallel():
         dm = fleet.distributed_model(model)
         opt = paddle.optimizer.AdamW(learning_rate=1e-2,
                                      parameters=model.parameters())
-        with _partial_manual_or_skip():
-            losses = [float(dm.train_batch(
-                [paddle.to_tensor(x), paddle.to_tensor(y)],
-                optimizer=opt, loss_fn=loss_fn)) for _ in range(steps)]
+        losses = [float(dm.train_batch(
+            [paddle.to_tensor(x), paddle.to_tensor(y)],
+            optimizer=opt, loss_fn=loss_fn)) for _ in range(steps)]
     finally:
         set_mesh(None)
 

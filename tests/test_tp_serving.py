@@ -42,18 +42,6 @@ def _tp_mesh(tp=2):
     return plan.build_mesh(devices=jax.devices()[:tp])
 
 
-@pytest.fixture(autouse=True)
-def _ambient_tp_degree():
-    """The TP predictor declares its shard degree in trace-time module
-    state (kernels._common) — restore it so a TP test can't skew the
-    Pallas gate judgments of whatever runs after."""
-    from paddle_tpu.kernels._common import (set_tp_shard_degree,
-                                            tp_shard_degree)
-    was = tp_shard_degree()
-    yield
-    set_tp_shard_degree(was)
-
-
 # ---------------------------------------------------------------------------
 # bitwise greedy parity, TP=2 vs TP=1
 # ---------------------------------------------------------------------------

@@ -389,7 +389,7 @@ class TestWeightUpdateSharding:
 
 class TestQuantizedComm:
     def test_wire_quantized_all_reduce_close_to_psum(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from paddle_tpu.distributed import collective as C
         try:
@@ -425,7 +425,7 @@ class TestQuantizedComm:
     def test_comm_bytes_accounting_q8(self):
         """comm.bytes records the int8 WIRE payload (2 phases + scale
         exchanges), not the fp32 logical size — a 4x reduction."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from paddle_tpu.distributed import collective as C
         was = obs.enabled()

@@ -167,10 +167,18 @@ class TestSpeedMeter:
         time.sleep(0.01)
         m.update(100)
         assert m.tokens_per_sec > 0
-        assert m.mfu > 0
+        # the CPU has no published peak: MFU is not measured here
+        assert m.mfu is None
 
-    def test_peak_flops_positive(self):
-        assert device_peak_flops("bfloat16") > 0
+    def test_peak_flops_from_the_table_or_an_error(self):
+        from types import SimpleNamespace
+        from paddle_tpu.trainer import PEAK_BF16_FLOPS, UnknownDevicePeak
+        v5e = SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+        assert device_peak_flops("bfloat16", device=v5e) == 197e12
+        assert device_peak_flops("float32", device=v5e) == 197e12 / 2
+        assert all(v > 0 for v in PEAK_BF16_FLOPS.values())
+        with pytest.raises(UnknownDevicePeak, match="device_kind"):
+            device_peak_flops("bfloat16")        # the CPU under test
 
 
 class TestVisualDLCallback:

@@ -23,7 +23,7 @@ flight-recorder dump (`flight_<pid>.json`, written to
   / loss-sync child phases as aligned bars.
 - **Site table** — duration stats per span name (every instrumented
   site: serve.*, train.*, ckpt.*, dist.compile, comm.*, launch.epoch,
-  launch.recovery, bench.backend_init).
+  launch.recovery).
 - **Recovery timeline** (`--recovery`) — the hang→kill→restart→resume
   incident reconstruction: the wedged rank's last heartbeat, the
   stale-heartbeat detector's kill, the restart epoch, the resume step,

@@ -1,0 +1,9 @@
+"""Median device time of one run of the decode-step program, from the trace's XLA Modules line."""
+from benchmarks.lib import readers
+
+NAME, UNIT = "step.decode_ms.closed", "ms"
+LAYER, MOVES = "serve programs", "serve_tokens_per_s"
+
+
+def read(record, trace):
+    return readers.decode_ms(record, trace)

@@ -44,6 +44,12 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in _os.environ:
     _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
+# JAX's trace, compile and cache events become the jit.* counters and the
+# time-stamped compile log (observability.runtime) from the first program
+# on, so a run can say what its set-up compiled and what compiled later.
+from .observability.runtime import watch_compiles as _watch_compiles
+_watch_compiles()
+
 __version__ = "0.1.0"
 
 from .framework import dtype as _dtype_mod

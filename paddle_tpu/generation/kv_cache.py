@@ -85,7 +85,10 @@ class PagedKVPool:
 
     An optional `reclaimer` (the PrefixCache) is consulted when `alloc`
     runs short: cached-but-unused pages are dropped to satisfy the
-    request, and `free_count` reports them as available.
+    request, and `free_count` reports them as available. The pool keeps
+    their count itself (`cache_hold`/`cache_drop` say which pages the
+    trie holds, `retain`/`release` see a reference move between 1 and
+    2), so `free_count` walks nothing.
     """
 
     def __init__(self, n_layers, num_pages, page_size, n_kv_heads,
@@ -127,13 +130,18 @@ class PagedKVPool:
         self._free = list(range(num_pages))
         self._refs = {}
         self.reclaimer = None
+        # page -> references the prefix trie holds on it, and how many
+        # of those pages have the trie as their ONLY holder (refcount 1)
+        self._cache_held = {}
+        self._reclaimable = 0
 
     @property
     def free_count(self):
         """Pages obtainable right now: the free list plus cache-held
-        pages the reclaimer would drop on demand."""
-        extra = (self.reclaimer.reclaimable_count(self)
-                 if self.reclaimer is not None else 0)
+        pages the reclaimer would drop on demand (what
+        `PrefixCache.reclaimable_count` finds by walking the trie,
+        kept as a count)."""
+        extra = self._reclaimable if self.reclaimer is not None else 0
         return len(self._free) + extra
 
     def alloc(self, n):
@@ -150,7 +158,10 @@ class PagedKVPool:
 
     def retain(self, ids):
         for p in ids:
-            self._refs[p] = self._refs.get(p, 0) + 1
+            c = self._refs.get(p, 0) + 1
+            self._refs[p] = c
+            if c == 2 and p in self._cache_held:
+                self._reclaimable -= 1     # a request pins a cached page
 
     def release(self, ids):
         for p in ids:
@@ -160,6 +171,26 @@ class PagedKVPool:
                 self._free.append(p)
             else:
                 self._refs[p] = c
+                if c == 1 and p in self._cache_held:
+                    self._reclaimable += 1     # only the trie is left
+
+    def cache_hold(self, pid):
+        """The prefix trie takes a reference on page `pid`."""
+        self.retain([pid])
+        self._cache_held[pid] = self._cache_held.get(pid, 0) + 1
+        if self._refs[pid] == 1:
+            self._reclaimable += 1
+
+    def cache_drop(self, pid):
+        """The prefix trie gives up one reference on page `pid`."""
+        if self._refs.get(pid) == 1:
+            self._reclaimable -= 1
+        held = self._cache_held.get(pid, 0) - 1
+        if held > 0:
+            self._cache_held[pid] = held
+        else:
+            self._cache_held.pop(pid, None)
+        self.release([pid])
 
     def ref_count(self, pid):
         return self._refs.get(pid, 0)
@@ -418,14 +449,18 @@ def prefix_page_keys(prompt, page_size):
 
 
 class _PrefixNode:
-    __slots__ = ("page", "next_token", "last_use", "children", "partials")
+    __slots__ = ("page", "next_token", "last_use", "children", "partials",
+                 "parent", "key")
 
-    def __init__(self, page=None, next_token=None, last_use=0):
+    def __init__(self, page=None, next_token=None, last_use=0,
+                 parent=None, key=None):
         self.page = page
         self.next_token = next_token
         self.last_use = last_use
         self.children = {}   # full page-size token tuple -> _PrefixNode
         self.partials = {}   # sub-page token tuple -> [page, next_token, use]
+        self.parent = parent  # the node whose `children[key]` this is
+        self.key = key
 
 
 class PrefixCache:
@@ -442,13 +477,19 @@ class PrefixCache:
 
     The trie retains one pool reference per cached page; pages whose
     only reference is the trie are reclaimable on allocation pressure
-    (LRU leaf-first) and are reported as free by the pool.
+    (LRU leaf-first) and are reported as free by the pool. Only the
+    trie's tips can be dropped (a node with nothing under it, or a
+    partial chunk), so the trie keeps them in two sets and `reclaim`
+    looks at those, not at every node: unshared prompts make chains of
+    tens of pages with one tip each.
     """
 
     def __init__(self, page_size):
         self.page = int(page_size)
         self._root = _PrefixNode()
         self._clock = 0
+        self._tips = set()            # nodes with no child and no partial
+        self._with_partials = set()   # nodes that hold partial chunks
 
     def _bump(self):
         self._clock += 1
@@ -509,9 +550,12 @@ class PrefixCache:
             child = node.children.get(chunk)
             if child is None:
                 nt = next_tokens[m + self.page - 1] if next_tokens else None
-                child = _PrefixNode(page_ids[i], nt, self._bump())
-                pool.retain([page_ids[i]])
+                child = _PrefixNode(page_ids[i], nt, self._bump(),
+                                    node, chunk)
+                pool.cache_hold(page_ids[i])
                 node.children[chunk] = child
+                self._tips.discard(node)
+                self._tips.add(child)
             m += self.page
             i += 1
             node = child
@@ -520,31 +564,37 @@ class PrefixCache:
             if rem not in node.partials:
                 nt = next_tokens[n - 1] if next_tokens else None
                 node.partials[rem] = [page_ids[i], nt, self._bump()]
-                pool.retain([page_ids[i]])
+                pool.cache_hold(page_ids[i])
+                self._tips.discard(node)
+                self._with_partials.add(node)
 
     # ---------------------------------------------------------- reclaim --
     def _droppable(self, pool):
-        """Yield (last_use, kind, node, key) for every entry whose page
-        the pool would actually free (trie holds the only reference)."""
+        """(last_use, kind, parent node, key) for every entry whose page
+        the pool would actually free (the trie holds the only
+        reference): the tips of the trie, from the sets kept for it."""
         out = []
-
-        def walk(node):
+        for node in self._with_partials:
             for toks, rec in node.partials.items():
                 if pool.ref_count(rec[0]) == 1:
                     out.append((rec[2], "partial", node, toks))
-            for chunk, child in node.children.items():
-                if (not child.children and not child.partials
-                        and pool.ref_count(child.page) == 1):
-                    out.append((child.last_use, "leaf", node, chunk))
-                else:
-                    walk(child)
-
-        walk(self._root)
+        for node in self._tips:
+            if pool.ref_count(node.page) == 1:
+                out.append((node.last_use, "leaf", node.parent, node.key))
         return out
 
+    def _dropped_under(self, parent):
+        """An entry under `parent` went: it may be a tip itself now."""
+        if not parent.partials:
+            self._with_partials.discard(parent)
+            if not parent.children and parent is not self._root:
+                self._tips.add(parent)
+
     def reclaimable_count(self, pool):
-        """Pages the trie holds that no request is using (one linear
-        walk). Slightly optimistic: a ref-1 interior node above a
+        """Pages the trie holds that no request is using, by one linear
+        walk: the oracle of the count the pool keeps for `free_count`
+        (tests compare the two; nothing on a serving path walks).
+        Slightly optimistic: a ref-1 interior node above a
         pinned descendant counts here but cannot actually be freed
         until the descendant's user evicts — `alloc` handles that by
         re-checking after `reclaim`, and once the pool is idle the
@@ -577,10 +627,12 @@ class PrefixCache:
             for _, kind, parent, key in take:
                 if kind == "partial":
                     rec = parent.partials.pop(key)
-                    pool.release([rec[0]])
+                    pool.cache_drop(rec[0])
                 else:
                     child = parent.children.pop(key)
-                    pool.release([child.page])
+                    self._tips.discard(child)
+                    pool.cache_drop(child.page)
+                self._dropped_under(parent)
                 freed += 1
                 if freed >= need:
                     break
@@ -598,13 +650,15 @@ class PrefixCache:
 
         def walk(node):
             for rec in node.partials.values():
-                pool.release([rec[0]])
+                pool.cache_drop(rec[0])
             for child in node.children.values():
                 walk(child)
-                pool.release([child.page])
+                pool.cache_drop(child.page)
 
         walk(self._root)
         self._root = _PrefixNode()
+        self._tips.clear()
+        self._with_partials.clear()
 
 
 class PagedCacheEntry(NamedTuple):

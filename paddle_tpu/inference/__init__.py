@@ -22,6 +22,7 @@ from ..tensor import Tensor
 from .._grad_mode import no_grad
 from ..framework import faults as _faults
 from ..observability import metrics as _obsm
+from ..observability import runtime as _obsrt
 from ..observability import tracing as _obstr
 from ..kernels._common import kernel_partition_scope
 
@@ -514,6 +515,9 @@ class ContinuousBatchingPredictor:
         # per-replica cache hits/utilization are separable downstream
         self.name = name
         self._mlbl = {"replica": name} if name else {}
+        # the serve loop's current pass (observability.tracing.tick):
+        # the resolvers time their blocking read as a stage of it
+        self._tick = _obstr.NULL_TICK
         # disaggregated serving role (docs/SERVING.md "Disaggregated
         # prefill/decode"): "prefill" replicas fill KV pages and hand
         # off at first token, "decode" replicas resume the sync-free
@@ -1000,16 +1004,17 @@ class ContinuousBatchingPredictor:
             hit = self._engine.get(sig)
             if hit is not None:
                 return hit(*args)
-            with self._kernel_scope():
+            with self._kernel_scope(), _obsrt.jit_tag(sig):
                 return self._engine.compile_fallback(sig, fn, args,
                                                      self._trace_lock)
         if sig in self._traced_sigs:
             # jit keys on argument placement too, so a signature this
             # table calls traced can still trace again: keep the kernel
-            # scope around every dispatch (entering it costs nothing)
-            with self._kernel_scope():
+            # scope around every dispatch (entering it costs nothing),
+            # and the tag that names the signature in the compile log
+            with self._kernel_scope(), _obsrt.jit_tag(sig):
                 return fn(*args)
-        with self._trace_lock, self._kernel_scope():
+        with self._trace_lock, self._kernel_scope(), _obsrt.jit_tag(sig):
             out = fn(*args)
         self._traced_sigs.add(sig)
         return out
@@ -2027,16 +2032,25 @@ class ContinuousBatchingPredictor:
             else:
                 emit(r, "token", token=first, index=1)
 
+        def prefill_stage(group, bucket):
+            """The stage around one prefill program's dispatch. Its
+            annotation names the requests' traces, so a `serve.request`
+            span (wall clock) is found on the profiler's clock."""
+            return self._tick.stage(
+                "serve.prefill", n=len(group), bucket=bucket,
+                traces=",".join(str(req_sp[p["r"]].trace_id)
+                                for p in group))
+
         def admission_round():
             """One pass over the queue in discipline order (FIFO, or
             weighted deficit-round-robin under tiers): fill every free
             slot with the first admissible requests (HOL fix: a stuck
             large request no longer blocks later small ones), then run
             the round's prefills — full misses batched per length
-            bucket."""
+            bucket. Returns how many requests it admitted."""
             free = [b for b in range(self.B) if slot_req[b] < 0]
             if not free or not len(q):
-                return False
+                return 0
             plans, skipped, seq = [], [], []
             budget = len(q)
             while len(plans) < len(free) and budget > 0:
@@ -2062,7 +2076,7 @@ class ContinuousBatchingPredictor:
                     self.stats["hol_skips"] += n_hol
                     self._m_hol.inc(n_hol, **mlbl)
             if not plans:
-                return False
+                return 0
 
             t0 = _time.perf_counter()
             chunked_plans = [p for p in plans if p.get("chunked")]
@@ -2090,7 +2104,9 @@ class ContinuousBatchingPredictor:
                 self._m_pfx_pages.inc(plan["reused"], **mlbl)
 
             for plan in partials:
-                firsts[plan["r"]] = self._suffix_prefill(plan)
+                with prefill_stage([plan], self._bucket_len(
+                        len(plan["prompt"]) - plan["covered"])):
+                    firsts[plan["r"]] = self._suffix_prefill(plan)
                 self.stats["prefix_partial_hits"] += 1
                 self.stats["pages_reused"] += plan["reused"]
                 self._m_pfx_hit.inc(kind="partial", **mlbl)
@@ -2104,7 +2120,8 @@ class ContinuousBatchingPredictor:
                 self.stats["prefix_misses"] += 1
                 self._m_pfx_miss.inc(**mlbl)
             for bucket, group in sorted(by_bucket.items()):
-                firsts.update(self._batch_prefill(bucket, group))
+                with prefill_stage(group, bucket):
+                    firsts.update(self._batch_prefill(bucket, group))
 
             if now_plans:
                 self._m_prefill.observe(_time.perf_counter() - t0,
@@ -2116,13 +2133,12 @@ class ContinuousBatchingPredictor:
                     place_chunked(next(b_i), plan)
                 else:
                     place(next(b_i), plan, firsts[plan["r"]])
-            return True
+            return len(plans)
 
         def _active():
             return [b for b in range(self.B) if slot_req[b] >= 0]
 
         inflight = None
-        evictions_seen = -1
         finished = False
 
         def sampled_chunk_first(b, r):
@@ -2166,165 +2182,183 @@ class ContinuousBatchingPredictor:
             """Resolve a dispatched step, routing speculative steps to
             the spec resolver. False = the watchdog tripped (cleanup
             done) — the caller terminates the loop."""
-            try:
-                if prev.get("spec"):
-                    self._resolve_spec_step(
-                        prev, slot_req, slot_new, slot_hist,
-                        last_tok_host, max_new, ctx, override, builder,
-                        evict, req_sp, emit, chunk_first_token)
-                else:
-                    self._resolve_step(
-                        prev, slot_req, slot_new, last_tok_host,
-                        max_new, evict, req_sp, emit, chunk_first_token,
-                        sampled_first=sampled_chunk_first,
-                        hist=slot_hist)
-                return True
-            except DecodeWedgedError:
-                on_wedged()
-                return False
+            with self._tick.stage("serve.resolve"):
+                try:
+                    if prev.get("spec"):
+                        self._resolve_spec_step(
+                            prev, slot_req, slot_new, slot_hist,
+                            last_tok_host, max_new, ctx, override,
+                            builder, evict, req_sp, emit,
+                            chunk_first_token)
+                    else:
+                        self._resolve_step(
+                            prev, slot_req, slot_new, last_tok_host,
+                            max_new, evict, req_sp, emit,
+                            chunk_first_token,
+                            sampled_first=sampled_chunk_first,
+                            hist=slot_hist)
+                    return True
+                except DecodeWedgedError:
+                    on_wedged()
+                    return False
+
+        def dispatch(active):
+            """Dispatch this tick's step for the active slots (a mixed,
+            speculative or plain decode step); None when none is due."""
+            cur = None
+            if active:
+                self.stats["max_in_flight"] = max(
+                    self.stats["max_in_flight"], len(active))
+                # a dispatch is useless if every active slot's
+                # budget is already met once the in-flight step
+                # resolves — resolve first instead of burning a
+                # junk step
+                # keyed (slot, request): a slot recycled while its
+                # old step is in flight commits NOTHING at resolve
+                # (snap guard) — counting it would start the new
+                # request's sampling-key counter at 1 and shift its
+                # whole fixed-seed stream
+                pend = {b for b, r in inflight["snap"]
+                        if slot_req[b] == r} if inflight else set()
+                useful = any(
+                    len(slot_new[b]) + (1 if b in pend else 0)
+                    < max_new[slot_req[b]] for b in active)
+                if any(slot_pending[b] for b in active):
+                    # a prompt is mid-ingest: this tick runs the
+                    # MIXED program — its chunk advances WHILE the
+                    # decode slots take their normal token step.
+                    # Sampled decode slots PAUSE for the tick (the
+                    # mixed program has no sampling operands): they
+                    # re-dispatch their committed token
+                    # idempotently and resume after the ingest.
+                    paused = [b for b in active
+                              if not slot_pending[b]
+                              and self._wants_sampling(
+                                  samp_of[slot_req[b]])]
+                    for b in paused:
+                        override[b] = True
+                    cur = self._dispatch_mixed_step(
+                        active, slot_req, slot_pending,
+                        slot_ingested, tables, ctx, last_tok_host,
+                        override, builder, inflight, req_sp,
+                        paused=paused)
+                elif useful:
+                    if spec_mode:
+                        sv = samp_vec(set()) \
+                            if self.sampling_enabled else None
+                        cur = self._dispatch_spec_step(
+                            active, slot_req, slot_hist, tables,
+                            ctx, last_tok_host, override, builder,
+                            sv, max_new, slot_new, req_sp)
+                    else:
+                        sv = samp_vec(pend) \
+                            if self.sampling_enabled else None
+                        cur = self._dispatch_step(
+                            active, slot_req, tables, ctx,
+                            last_tok_host, override, builder,
+                            inflight, sv)
+            if cur is not None:
+                # slots awaiting their first SAMPLED token resolve
+                # it this step — ride the chunk_final first-token
+                # machinery in the resolver (TTFT lands there).
+                # Paused slots (mixed tick) keep waiting.
+                firsts = {b for b in active if slot_await_first[b]
+                          and b not in (cur.get("chunk_mid") or ())}
+                if firsts:
+                    cur["chunk_final"] = set(
+                        cur.get("chunk_final") or ()) | firsts
+                    for b in firsts:
+                        slot_await_first[b] = False
+                # sampled requests' FINAL chunks: reroute from the
+                # argmax first-token path to first-token replay
+                cfs = {b for b in (cur.get("chunk_final") or ())
+                       if b not in firsts and slot_req[b] >= 0
+                       and self._wants_sampling(
+                           samp_of[slot_req[b]])}
+                if cfs:
+                    cur["chunk_final"] = \
+                        set(cur["chunk_final"]) - cfs
+                    cur["chunk_final_sampled"] = cfs
+            return cur
 
         try:
             while True:
-                apply_cancels()
-                expire_deadlines()
-                if inflight is not None and (
-                        spec_mode or (self.sampling_enabled
-                                      and "chunk_mid" in inflight)):
-                    # resolve BEFORE dispatching when the next dispatch
-                    # depends on this step's host-state transitions:
-                    # (a) speculative mode — the drafter needs the
-                    # freshly committed tokens in the slot histories
-                    # and ctx/ragged meta rewound to the accepted
-                    # prefix (the multi-token step replaces the
-                    # one-step pipeline at the same single sync per
-                    # tick); (b) a MIXED step on a sampling-enabled
-                    # predictor — its resolve flips sampled slots into
-                    # first-token replay (sampled_chunk_first) and
-                    # un-pauses sampled decode slots, and a
-                    # double-buffered dispatch in between would chain
-                    # the discarded argmax / advance ctx past the
-                    # replay position. Greedy predictors keep the
-                    # fully pipelined mixed path.
-                    prev, inflight = inflight, None
-                    if not resolve(prev):
-                        break
-                if not closed:
-                    batch = intake()
-                    if batch is None:
-                        closed = True
-                    elif batch:
-                        for sreq in batch:
-                            add_request(sreq)
-                        expire_queued()
-                        shed_overflow()
-                admitted = False
-                while admission_round():
-                    admitted = True
-                active = _active()
-                self._m_queue.set(len(q), **mlbl)
-                self._m_flight.set(len(active), **mlbl)
-                if use_tiers:
-                    depths = q.depths()
-                    for t_name in tiers_seen - set(depths):
-                        self._m_tier_q.set(0, tier=t_name, **mlbl)
-                    for t_name, d in depths.items():
-                        tiers_seen.add(t_name)
-                        self._m_tier_q.set(d, tier=t_name, **mlbl)
-                if admitted or self.stats["evictions"] != evictions_seen:
-                    # free_count walks the prefix trie — refresh the
-                    # gauge only when pages actually moved, not per
-                    # decode step
-                    evictions_seen = self.stats["evictions"]
-                    self._m_util.set((self.capacity
-                                      - self.pool.free_count)
-                                     / max(self.capacity, 1), **mlbl)
-                cur = None
-                if active:
-                    self.stats["max_in_flight"] = max(
-                        self.stats["max_in_flight"], len(active))
-                    # a dispatch is useless if every active slot's
-                    # budget is already met once the in-flight step
-                    # resolves — resolve first instead of burning a
-                    # junk step
-                    # keyed (slot, request): a slot recycled while its
-                    # old step is in flight commits NOTHING at resolve
-                    # (snap guard) — counting it would start the new
-                    # request's sampling-key counter at 1 and shift its
-                    # whole fixed-seed stream
-                    pend = {b for b, r in inflight["snap"]
-                            if slot_req[b] == r} if inflight else set()
-                    useful = any(
-                        len(slot_new[b]) + (1 if b in pend else 0)
-                        < max_new[slot_req[b]] for b in active)
-                    if any(slot_pending[b] for b in active):
-                        # a prompt is mid-ingest: this tick runs the
-                        # MIXED program — its chunk advances WHILE the
-                        # decode slots take their normal token step.
-                        # Sampled decode slots PAUSE for the tick (the
-                        # mixed program has no sampling operands): they
-                        # re-dispatch their committed token
-                        # idempotently and resume after the ingest.
-                        paused = [b for b in active
-                                  if not slot_pending[b]
-                                  and self._wants_sampling(
-                                      samp_of[slot_req[b]])]
-                        for b in paused:
-                            override[b] = True
-                        cur = self._dispatch_mixed_step(
-                            active, slot_req, slot_pending,
-                            slot_ingested, tables, ctx, last_tok_host,
-                            override, builder, inflight, req_sp,
-                            paused=paused)
-                    elif useful:
-                        if spec_mode:
-                            sv = samp_vec(set()) \
-                                if self.sampling_enabled else None
-                            cur = self._dispatch_spec_step(
-                                active, slot_req, slot_hist, tables,
-                                ctx, last_tok_host, override, builder,
-                                sv, max_new, slot_new, req_sp)
-                        else:
-                            sv = samp_vec(pend) \
-                                if self.sampling_enabled else None
-                            cur = self._dispatch_step(
-                                active, slot_req, tables, ctx,
-                                last_tok_host, override, builder,
-                                inflight, sv)
-                if cur is not None:
-                    # slots awaiting their first SAMPLED token resolve
-                    # it this step — ride the chunk_final first-token
-                    # machinery in the resolver (TTFT lands there).
-                    # Paused slots (mixed tick) keep waiting.
-                    firsts = {b for b in active if slot_await_first[b]
-                              and b not in (cur.get("chunk_mid") or ())}
-                    if firsts:
-                        cur["chunk_final"] = set(
-                            cur.get("chunk_final") or ()) | firsts
-                        for b in firsts:
-                            slot_await_first[b] = False
-                    # sampled requests' FINAL chunks: reroute from the
-                    # argmax first-token path to first-token replay
-                    cfs = {b for b in (cur.get("chunk_final") or ())
-                           if b not in firsts and slot_req[b] >= 0
-                           and self._wants_sampling(
-                               samp_of[slot_req[b]])}
-                    if cfs:
-                        cur["chunk_final"] = \
-                            set(cur["chunk_final"]) - cfs
-                        cur["chunk_final_sampled"] = cfs
-                prev, inflight = inflight, cur
-                if prev is not None:
-                    if not resolve(prev):
-                        break
-                elif cur is None:
-                    if closed:
-                        break
-                    # idle dynamic loop: intake() is expected to block
-                    # briefly itself; this is only spin insurance
-                    if not out:
-                        _time.sleep(0.0002)
-                while out:
-                    yield out.popleft()
+                with _obstr.tick("serve.tick", self.name or "") as tick:
+                    self._tick = tick
+                    with tick.stage("serve.intake"):
+                        apply_cancels()
+                        expire_deadlines()
+                    if inflight is not None and (
+                            spec_mode or (self.sampling_enabled
+                                          and "chunk_mid" in inflight)):
+                        # resolve BEFORE dispatching when the next
+                        # dispatch depends on this step's host-state
+                        # transitions: (a) speculative mode — the
+                        # drafter needs the freshly committed tokens in
+                        # the slot histories and ctx/ragged meta rewound
+                        # to the accepted prefix (the multi-token step
+                        # replaces the one-step pipeline at the same
+                        # single sync per tick); (b) a MIXED step on a
+                        # sampling-enabled predictor — its resolve flips
+                        # sampled slots into first-token replay
+                        # (sampled_chunk_first) and un-pauses sampled
+                        # decode slots, and a double-buffered dispatch
+                        # in between would chain the discarded argmax /
+                        # advance ctx past the replay position. Greedy
+                        # predictors keep the fully pipelined mixed path.
+                        prev, inflight = inflight, None
+                        if not resolve(prev):
+                            break
+                    if not closed:
+                        with tick.stage("serve.intake"):
+                            batch = intake()
+                            if batch is None:
+                                closed = True
+                            elif batch:
+                                for sreq in batch:
+                                    add_request(sreq)
+                                expire_queued()
+                                shed_overflow()
+                    n_admitted, prefills = 0, self.stats["prefills"]
+                    with tick.stage("serve.admit"):
+                        while (n := admission_round()):
+                            n_admitted += n
+                    active = _active()
+                    with tick.stage("serve.gauges"):
+                        self._m_queue.set(len(q), **mlbl)
+                        self._m_flight.set(len(active), **mlbl)
+                        if use_tiers:
+                            depths = q.depths()
+                            for t_name in tiers_seen - set(depths):
+                                self._m_tier_q.set(0, tier=t_name, **mlbl)
+                            for t_name, d in depths.items():
+                                tiers_seen.add(t_name)
+                                self._m_tier_q.set(d, tier=t_name, **mlbl)
+                        self._m_util.set(
+                            (self.capacity - self.pool.free_count)
+                            / max(self.capacity, 1), **mlbl)
+                    tick.note(active=len(active), admitted=n_admitted,
+                              prefill=self.stats["prefills"] > prefills)
+                    with tick.stage("serve.dispatch"):
+                        cur = dispatch(active)
+                    prev, inflight = inflight, cur
+                    if prev is not None:
+                        if not resolve(prev):
+                            break
+                    elif cur is None:
+                        if closed:
+                            break
+                        # idle dynamic loop: intake() is expected to block
+                        # briefly itself; this is only spin insurance
+                        if not out:
+                            _time.sleep(0.0002)
+                    if out:
+                        # _serve is a generator: the consumer handles
+                        # each event on this thread before the loop
+                        # goes on, and that time is this stage's
+                        with tick.stage("serve.emit"):
+                            while out:
+                                yield out.popleft()
 
             for r, res in enumerate(results):
                 if res is None:   # queue leftovers the loop could not
@@ -2341,6 +2375,7 @@ class ContinuousBatchingPredictor:
                 yield out.popleft()
             finished = True
         finally:
+            self._tick = _obstr.NULL_TICK
             if not finished:
                 # Two ways here: the consumer abandoned the raw
                 # generator (GeneratorExit; TokenStream.close drains
@@ -2726,14 +2761,15 @@ class ContinuousBatchingPredictor:
         chunk_final are resolving their first (sampled) token — TTFT
         lands here via `first_cb`."""
         import time as _time
-        self._await_step(step, (step["tok"], step["acc"],
-                                step["done"]))
-        # graft-lint: ok[GL102] — THE decode-loop sync point: three [B]
-        # vectors of the verify step (spec mode resolves before the
-        # next dispatch; the multi-token step replaces the one-step
-        # pipeline at the same one sync per tick)
-        bonus = np.asarray(step["tok"])
-        acc = np.asarray(step["acc"])    # graft-lint: ok[GL102] (ditto)
+        with self._tick.stage("serve.resolve.wait"):
+            self._await_step(step, (step["tok"], step["acc"],
+                                    step["done"]))
+            # graft-lint: ok[GL102] — THE decode-loop sync point: three
+            # [B] vectors of the verify step (spec mode resolves before
+            # the next dispatch; the multi-token step replaces the
+            # one-step pipeline at the same one sync per tick)
+            bonus = np.asarray(step["tok"])
+            acc = np.asarray(step["acc"])    # graft-lint: ok[GL102] (ditto)
         self._m_tok.observe(_time.perf_counter() - step["t"],
                             **self._mlbl)
         firsts = step.get("chunk_final") or ()
@@ -2834,12 +2870,13 @@ class ContinuousBatchingPredictor:
         marks them at dispatch). Committed tokens are appended to
         `hist` (the prompt-lookup drafting history) when given."""
         import time as _time
-        self._await_step(step, (step["tok"], step["done"]))
-        # graft-lint: ok[GL102] — THE decode-loop sync point (and the
-        # only one): two [B] vectors of a step whose successor is
-        # already dispatched (double buffering)
-        nxt = np.asarray(step["tok"])
-        done = np.asarray(step["done"])  # graft-lint: ok[GL102] (ditto)
+        with self._tick.stage("serve.resolve.wait"):
+            self._await_step(step, (step["tok"], step["done"]))
+            # graft-lint: ok[GL102] — THE decode-loop sync point (and
+            # the only one): two [B] vectors of a step whose successor
+            # is already dispatched (double buffering)
+            nxt = np.asarray(step["tok"])
+            done = np.asarray(step["done"])  # graft-lint: ok[GL102] (ditto)
         self._m_tok.observe(_time.perf_counter() - step["t"],
                             **self._mlbl)
         chunk_mid = step.get("chunk_mid") or ()

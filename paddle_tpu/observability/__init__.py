@@ -45,7 +45,7 @@ from .exporters import (  # noqa: F401
 from .runtime import (  # noqa: F401
     jit_callback, device_memory_stats, configure, maybe_export,
     export_record, telemetry_path, RankHeartbeat, rank_identity,
-    set_identity, export_identity,
+    set_identity, export_identity, watch_compiles, compile_log, jit_tag,
 )
 from .slo import (  # noqa: F401
     Ewma, SLOSpec, SLOEngine, default_serving_slos,
@@ -57,6 +57,7 @@ from .tracing import (  # noqa: F401
     Span, TraceContext, NULL_SPAN, span, start_span, traced,
     current_span, FlightRecorder, flight_recorder, flight_dump,
     flight_dir, set_flight_dir, to_chrome_trace, write_chrome_trace,
+    tick, ticks, clear_ticks,
 )
 from .critpath import (  # noqa: F401
     stage_decomposition, trace_tree,
@@ -69,7 +70,8 @@ __all__ = [
     "TensorBoardExporter", "jit_callback", "device_memory_stats",
     "configure", "maybe_export", "export_record", "telemetry_path",
     "RankHeartbeat", "rank_identity", "set_identity", "export_identity",
-    "Ewma", "SLOSpec", "SLOEngine", "default_serving_slos",
+    "watch_compiles", "compile_log", "jit_tag", "tick", "ticks",
+    "clear_ticks", "Ewma", "SLOSpec", "SLOEngine", "default_serving_slos",
     "FleetAggregator",
     "StragglerDetector", "RankFileTailer",
     "Span", "TraceContext", "NULL_SPAN", "span", "start_span",

@@ -10,6 +10,7 @@ programs (asserted by tests/test_observability.py).
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -21,7 +22,7 @@ from .metrics import enabled, get_registry
 __all__ = ["jit_callback", "device_memory_stats", "configure",
            "maybe_export", "export_record", "telemetry_path",
            "RankHeartbeat", "rank_identity", "set_identity",
-           "export_identity"]
+           "export_identity", "watch_compiles", "compile_log", "jit_tag"]
 
 
 # ------------------------------------------------------- rank identity ------
@@ -118,6 +119,112 @@ def jit_callback(fn: Callable, *traced_args):
             pass  # telemetry must never kill a training step
 
     jax.debug.callback(_guarded, *traced_args)
+
+
+# -------------------------------------------------------- compile log ------
+# JAX reports every trace, lowering and backend compile, and every hit
+# and miss of its persistent cache, through jax.monitoring. Kept here as
+# a time-stamped log: a compile that lands where none should (inside a
+# serving window) is then an entry that says when, how long and, where
+# the dispatcher tagged it (`jit_tag`), for which program signature.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+# one set-up of a serving cell logs 14 k events (every nested trace is
+# one), and the log has to hold a run's set-up, window and what follows
+_COMPILE_LOG_CAPACITY = 65536
+_compile_log: collections.deque = collections.deque(
+    maxlen=_COMPILE_LOG_CAPACITY)
+_compile_watch_lock = threading.Lock()
+_compile_watched = False
+
+
+class _JitTag(threading.local):
+    sig = None
+
+
+_jit_tag = _JitTag()
+
+
+class jit_tag:
+    """``with jit_tag(sig):`` around a jitted call: whatever JAX traces
+    or compiles on this thread meanwhile is logged under `sig`."""
+
+    __slots__ = ("_sig", "_prev")
+
+    def __init__(self, sig):
+        self._sig = sig
+
+    def __enter__(self):
+        self._prev, _jit_tag.sig = _jit_tag.sig, self._sig
+        return self
+
+    def __exit__(self, *exc):
+        _jit_tag.sig = self._prev
+        return False
+
+
+def _on_compile_event(event, seconds=0.0, **_kw):
+    kind = _COMPILE_EVENTS.get(event)
+    if kind is None or not enabled():
+        return
+    seconds = float(seconds)
+    sig = _jit_tag.sig
+    _compile_log.append((time.perf_counter(), kind, seconds,
+                         None if sig is None else str(sig)))
+    reg = get_registry()
+    if kind == "trace":
+        reg.counter("jit.traces").inc()
+        reg.counter("jit.trace_seconds", unit="s").inc(seconds)
+    elif kind in ("lower", "compile"):
+        reg.counter("jit.compile_seconds", unit="s").inc(seconds)
+    elif kind == "cache_hit":
+        reg.counter("jit.cache_hits").inc()
+    else:
+        reg.counter("jit.cache_misses").inc()
+
+
+def watch_compiles():
+    """Listen to JAX's own compile events (idempotent; the package does
+    it on import). Each is kept in `compile_log()` and summed into the
+    counters jit.traces, jit.trace_seconds, jit.compile_seconds (lowering
+    plus backend compile, cache retrieval included), jit.cache_hits and
+    jit.cache_misses."""
+    global _compile_watched
+    with _compile_watch_lock:
+        if _compile_watched:
+            return
+        _compile_watched = True
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_compile_event)
+    monitoring.register_event_listener(_on_compile_event)
+
+
+def stamped_between(records, key, since=None, until=None) -> list:
+    """A copy of `records` (a deque that other threads append to),
+    oldest first; with a bound given, those whose `key` stamp lies in
+    [`since`, `until`) on ``time.perf_counter``."""
+    out = list(records)       # one atomic copy under the GIL
+    if since is None and until is None:
+        return out
+    lo = float("-inf") if since is None else since
+    hi = float("inf") if until is None else until
+    return [r for r in out if lo <= r[key] < hi]
+
+
+def compile_log(since: Optional[float] = None,
+                until: Optional[float] = None) -> list:
+    """The logged compile events, oldest first: ``{"t": perf_counter at
+    receipt, "kind": trace|lower|compile|cache_hit|cache_miss,
+    "seconds", "sig"}`` (those received in [`since`, `until`) when
+    given). A duration event is received when its work ENDS."""
+    return [{"t": t, "kind": kind, "seconds": seconds, "sig": sig}
+            for t, kind, seconds, sig
+            in stamped_between(_compile_log, 0, since, until)]
 
 
 def device_memory_stats() -> dict:

@@ -23,6 +23,14 @@ crashed run*. Two pieces:
   gold: *which phase was in progress*), armed-fault events, and a
   registry snapshot to ``flight_<pid>.json``.
 
+- **Ticks.** A loop that runs thousands of passes a second (the serve
+  loop) cannot afford a Span per stage per pass. :func:`tick` times one
+  pass by stage instead: each stage is a ``jax.profiler.TraceAnnotation``
+  (so it shows on the profiler's clock, beside the device's ops) plus a
+  ``time.perf_counter`` pair, and the pass leaves ONE plain record in a
+  bounded process-wide ring (:func:`ticks`) that outlives the loop's
+  owner. ``flight_dump`` writes the last ticks beside the spans.
+
 Cost contract (same bar as the metrics layer, asserted by
 tests/test_tracing.py): spans are pure host-side bookkeeping — they add
 ZERO operations to jitted programs — and with ``enabled(False)`` every
@@ -40,12 +48,14 @@ import time
 from typing import Dict, List, Optional
 
 from .metrics import enabled, get_registry
+from .runtime import stamped_between
 
 __all__ = [
     "Span", "TraceContext", "NULL_SPAN", "span", "start_span", "traced",
     "current_span", "FlightRecorder", "flight_recorder", "flight_dump",
     "flight_dir", "set_flight_dir", "to_chrome_trace",
-    "write_chrome_trace",
+    "write_chrome_trace", "Tick", "NULL_TICK", "tick", "ticks",
+    "clear_ticks",
 ]
 
 # own RNG: span ids must not perturb (or be perturbed by) user-level
@@ -55,6 +65,8 @@ _rand_lock = threading.Lock()
 
 _MAX_EVENTS = 256          # per-span event cap (decode ticks, retries)
 _DEFAULT_CAPACITY = 2048   # flight ring length (finished spans)
+_TICK_CAPACITY = 65536     # tick ring: a run's warm-up, window and drain
+_DUMP_TICKS = 512          # ticks a flight dump carries
 
 _UNSET = object()
 
@@ -346,8 +358,9 @@ class FlightRecorder:
     def __init__(self, capacity: int = _DEFAULT_CAPACITY):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
-        self._ring: collections.deque = collections.deque(
-            maxlen=self.capacity)
+        self._ring: collections.deque = collections.deque()
+        # trace id -> that trace's spans still in the ring, oldest first
+        self._by_trace: Dict[str, List[dict]] = {}
         self._open: Dict[str, Span] = {}
         self.last_dump: Optional[str] = None
 
@@ -361,14 +374,33 @@ class FlightRecorder:
             self._open[s.span_id] = s
 
     def _close_span(self, s: Span):
+        d = s.as_dict()
         with self._lock:
             self._open.pop(s.span_id, None)
-            self._ring.append(s.as_dict())
+            if self.capacity <= 0:
+                return
+            if len(self._ring) >= self.capacity:
+                old = self._ring.popleft()
+                # a trace's spans enter the ring in the order they sit
+                # in its list, so the ring's oldest is its list's first
+                mine = self._by_trace[old["trace"]]
+                del mine[0]
+                if not mine:
+                    del self._by_trace[old["trace"]]
+            self._ring.append(d)
+            self._by_trace.setdefault(d["trace"], []).append(d)
 
     # ------------------------------------------------------- inspection --
     def spans(self) -> List[dict]:
         with self._lock:
             return list(self._ring)
+
+    def spans_of(self, trace_id) -> List[dict]:
+        """The finished spans of one trace that are still in the ring,
+        oldest first: what filtering :meth:`spans` by ``trace`` gives,
+        at the cost of one lookup."""
+        with self._lock:
+            return list(self._by_trace.get(trace_id, ()))
 
     def open_spans(self) -> List[dict]:
         with self._lock:
@@ -378,6 +410,7 @@ class FlightRecorder:
     def clear(self):
         with self._lock:
             self._ring.clear()
+            self._by_trace.clear()
             self._open.clear()
 
     # ------------------------------------------------------------ dump --
@@ -394,7 +427,8 @@ class FlightRecorder:
                 return None
             payload = {"ts": round(time.time(), 6), "pid": os.getpid(),
                        "reason": reason, "capacity": self.capacity,
-                       "spans": finished, "open_spans": open_}
+                       "spans": finished, "open_spans": open_,
+                       "ticks": ticks()[-_DUMP_TICKS:]}
             try:  # armed-fault forensics (which injected fault fired)
                 from ..framework import faults as _faults
                 payload["fault_events"] = _faults.events()
@@ -464,6 +498,138 @@ def flight_dir() -> str:
     if tp:
         return os.path.dirname(os.path.abspath(tp))
     return os.path.join(os.getcwd(), "output")
+
+
+# ---------------------------------------------------------------------------
+# ticks: one pass of a hot loop, timed by stage on the profiler's clock
+# ---------------------------------------------------------------------------
+_ticks: collections.deque = collections.deque(maxlen=_TICK_CAPACITY)
+
+
+def ticks(since: Optional[float] = None,
+          until: Optional[float] = None) -> List[dict]:
+    """The tick records still in the ring, oldest first (those that
+    began in [`since`, `until`) on ``time.perf_counter`` when given).
+    A record: ``{"name", "replica", "t0", "dur", "stages": {stage:
+    seconds}, ...}`` plus whatever the loop noted (`Tick.note`); a
+    stage entered twice in one pass has its seconds summed, and a stage
+    nested in another is listed beside it, not subtracted from it."""
+    return [_nested(r) for r in stamped_between(_ticks, "t0", since,
+                                                until)]
+
+
+def _nested(rec):
+    """The reader's form of a ring record: the ring keeps a pass FLAT,
+    its stages' seconds under their dotted names beside the fields
+    (whose names have no dot)."""
+    out = {k: v for k, v in rec.items() if "." not in k}
+    out["stages"] = {k: v for k, v in rec.items() if "." in k}
+    return out
+
+
+def clear_ticks():
+    _ticks.clear()
+
+
+class _Stage:
+    """One stage of a tick: an annotation on the profiler's trace of
+    this thread, and its seconds added to the tick's record."""
+
+    __slots__ = ("_name", "_into", "_ann", "_t0")
+
+    def __init__(self, name, into, args):
+        self._name, self._into = name, into
+        self._ann = _annotation(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        into = self._into
+        into[self._name] = into.get(self._name, 0.0) + dt
+        self._ann.__exit__(*exc)
+        return False
+
+
+class Tick:
+    """One pass of a loop (``with tick("serve.tick") as t:``). Stages
+    are ``with t.stage("serve.admit"):`` blocks; leaving the pass, by
+    its end, a ``break``, an exception or the close of a generator,
+    closes the pass's annotation and appends its record to the ring."""
+
+    __slots__ = ("_rec", "_ann")
+
+    def __init__(self, name, replica):
+        # one FLAT dict of strings and numbers: CPython keeps such a
+        # dict out of the garbage collector's lists, so a ring of them
+        # adds nothing to a full collection. (A nested `stages` dict a
+        # pass put 6000 survivors a window on the collector's books and
+        # brought a 330 ms full collection into one window in two:
+        # PERF.md, PR 25.)
+        self._rec = {"name": name, "replica": replica, "t0": 0.0,
+                     "dur": 0.0}
+        self._ann = _annotation(name)
+
+    def stage(self, name, **args):
+        """A stage of this pass, under a dotted name (`serve.admit`:
+        the dot tells a stage's seconds from a field of the record);
+        `args` become the annotation's arguments in the profiler's
+        trace."""
+        return _Stage(name, self._rec, args)
+
+    def note(self, **fields):
+        """Plain facts about the pass for its record (slots active,
+        requests admitted): numbers and strings."""
+        self._rec.update(fields)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._rec["t0"] = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        rec = self._rec
+        rec["dur"] = time.perf_counter() - rec["t0"]
+        self._ann.__exit__(*exc)
+        _ticks.append(rec)
+        return False
+
+
+class _NullTick:
+    """Shared do-nothing tick (telemetry disabled)."""
+
+    __slots__ = ()
+
+    def stage(self, name, **args):
+        return NULL_SPAN
+
+    def note(self, **fields):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NULL_TICK = _NullTick()
+_annotation = None
+
+
+def tick(name: str, replica: str = "") -> "Tick | _NullTick":
+    """Time one pass of a loop by stage. No-op when telemetry is
+    disabled: nothing is annotated and nothing recorded."""
+    global _annotation
+    if not enabled():
+        return NULL_TICK
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return Tick(name, replica)
 
 
 # ------------------------------------------------- uncaught-exception hook --

@@ -701,10 +701,9 @@ class Router:
         if not h.span.recording:
             return
         try:
-            spans = [s for s in _obstr.flight_recorder().spans()
-                     if s.get("trace") == h.span.trace_id]
             d = _critpath.stage_decomposition(
-                spans, trace_id=h.span.trace_id)
+                _obstr.flight_recorder().spans_of(h.span.trace_id),
+                trace_id=h.span.trace_id)
             tl = {"tier": h.tier} if h.tier else {}
             for stage, secs in d["stages"]:
                 self._m_stage.observe(secs, exemplar=h.span.trace_id,

@@ -249,4 +249,4 @@ FLAG_DOC_ROOTS = ("docs", "README.md")
 CATALOG_PREFIXES = ("train", "serve", "serving", "comm", "mem", "pp",
                     "robustness", "aot", "ckpt", "dist", "launch",
                     "bench", "router", "kernels", "autotune", "fleet",
-                    "slo")
+                    "slo", "jit")

@@ -9,8 +9,10 @@ Code side (AST over config.EMISSION_ROOTS — paddle_tpu/ + bench.py,
 independent of the CLI paths):
 - `counter("...")` / `gauge("...")` / `histogram("...")` first-arg
   string literals (module helpers and registry methods alike);
-- `span("...")` / `start_span("...")` / `traced("...")` literals;
-  f-string names (`f"comm.{op}"`) become wildcard prefixes;
+- `span("...")` / `start_span("...")` / `traced("...")` literals,
+  and the tick helper's `tick("...")` / `.stage("...")` (stage spans
+  of a hot loop, observability.tracing); f-string names
+  (`f"comm.{op}"`) become wildcard prefixes;
 - `define_flag("name", ...)` — the FLAGS_* registry.
 
 Docs side:
@@ -34,7 +36,7 @@ from .. import config
 from ..core import Finding, SourceFile, iter_py_files, terminal_name
 
 _METRIC_FNS = {"counter", "gauge", "histogram"}
-_SPAN_FNS = {"span", "start_span", "traced"}
+_SPAN_FNS = {"span", "start_span", "traced", "tick", "stage"}
 
 _BACKTICK_RE = re.compile(r"`([^`\s]+)`")
 _FLAG_RE = re.compile(r"FLAGS_([a-z][a-z0-9_]*)")

@@ -76,33 +76,53 @@ def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
 
 
 # ---------------------------------------------------------------------------
-# XLA reference path (any GQA ratio; used on CPU and as the numeric oracle)
+# XLA block-table path (any GQA ratio): the route of every geometry that
+# fails `_paged_gate`, of the CPU, and the numeric oracle of the kernels.
+# It gathers each slot's whole block table and attends per KV head
+# GROUP: the `rep = H // Hkv` query heads that share a KV head are rows
+# of one [rep, D] x [D, L] contraction against that head's keys, so the
+# gathered table is read as it lies, [B, L, Hkv, D] in the pool's dtype,
+# and never repeated to H heads or widened to float32. MHA is rep = 1.
+# The cost is slots x table length whatever is cached.
 # ---------------------------------------------------------------------------
+
+def _gathered_group_attention(q, k_pages, v_pages, block_tables, ok, scale):
+    """The one body of both XLA paths. q: [B, Q, H, D] (Q query
+    positions a slot); pages: [P, page, Hkv, D]; block_tables:
+    [B, pages_per_seq]; ok: [B, Q, L] bool, the keys each query may
+    see (L = pages_per_seq * page) → [B, Q, H, D] in q's dtype.
+
+    Scores and P.V are `dot_general`s batched on (B, Hkv) whose
+    operands stay in the pool's dtype and accumulate in float32
+    (products of bf16 operands are exact there); the softmax is
+    float32. A query with no visible key attends uniformly (`_NEG_INF`
+    is finite), so nothing is NaN; the varq path zeroes such rows."""
+    b, nq, h, d = q.shape
+    hkv = k_pages.shape[2]
+    rep = h // hkv
+    k = k_pages[block_tables].reshape(b, -1, hkv, d)        # [B, L, Hkv, D]
+    v = v_pages[block_tables].reshape(b, -1, hkv, d)
+    qg = q.reshape(b, nq, hkv, rep, d)
+    s = jnp.einsum("bqgrd,blgd->bgqrl", qg, k,
+                   preferred_element_type=jnp.float32) * np.float32(scale)
+    s = jnp.where(ok[:, None, :, None, :], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bgqrl,blgd->bqgrd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, nq, h, d).astype(q.dtype)
+
 
 def _paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
                          scale):
-    """q: [B, H, D]; pages: [P, page, Hkv, D]; tables: [B, pages_per_seq];
-    context_lens: [B] → out [B, H, D]."""
-    page = k_pages.shape[1]
-    h = q.shape[1]
-    hkv = k_pages.shape[2]
-
-    def one(qb, bt, cl):
-        k = k_pages[bt].reshape(-1, hkv, k_pages.shape[-1])  # [L, Hkv, D]
-        v = v_pages[bt].reshape(-1, hkv, v_pages.shape[-1])
-        if hkv != h:
-            rep = h // hkv
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
-        s = jnp.einsum("hd,khd->hk", qb, k,
-                       preferred_element_type=jnp.float32) * np.float32(scale)
-        valid = jnp.arange(k.shape[0]) < cl
-        s = jnp.where(valid[None, :], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("hk,khd->hd", p.astype(v.dtype), v,
-                          preferred_element_type=jnp.float32).astype(qb.dtype)
-
-    return jax.vmap(one)(q, block_tables, context_lens)
+    """One decode token a slot through the block tables. q: [B, H, D];
+    pages: [P, page, Hkv, D]; tables: [B, pages_per_seq];
+    context_lens: [B] → out [B, H, D]. The Q = 1 case of
+    `_gathered_group_attention`."""
+    n_keys = block_tables.shape[1] * k_pages.shape[1]
+    ok = jnp.arange(n_keys)[None, :] < context_lens[:, None]
+    return _gathered_group_attention(q[:, None], k_pages, v_pages,
+                                     block_tables, ok[:, None, :],
+                                     scale)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -485,36 +505,22 @@ def _paged_attention_ragged_pallas(q, k_pages, v_pages, lens, meta, scale,
 
 def _paged_attention_varq_xla(q, k_pages, v_pages, block_tables, kv_lens,
                               q_lens, scale):
-    """XLA reference (any GQA ratio). q: [B, Qb, H, D]; pages
-    [P, page, Hkv, D]; block_tables [B, pages_per_seq]; kv_lens [B]
-    total keys per slot (span included); q_lens [B] span lengths.
-    Returns [B, Qb, H, D] with padding query rows zeroed."""
-    h = q.shape[2]
-    hkv = k_pages.shape[2]
+    """XLA block-table path of the mixed step and the speculative
+    verify (any GQA ratio; `_gathered_group_attention` with a causal
+    span mask). q: [B, Qb, H, D]; pages [P, page, Hkv, D];
+    block_tables [B, pages_per_seq]; kv_lens [B] total keys per slot
+    (span included); q_lens [B] span lengths. Returns [B, Qb, H, D]
+    with padding query rows zeroed."""
     qb = q.shape[1]
-
-    def one(qs, bt, kl, ql):
-        k = k_pages[bt].reshape(-1, hkv, k_pages.shape[-1])  # [L, Hkv, D]
-        v = v_pages[bt].reshape(-1, hkv, v_pages.shape[-1])
-        if hkv != h:
-            rep = h // hkv
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
-        s = jnp.einsum("qhd,khd->qhk", qs, k,
-                       preferred_element_type=jnp.float32) * np.float32(scale)
-        tok = jnp.arange(k.shape[0], dtype=jnp.int32)
-        qpos = (kl - ql) + jnp.arange(qb, dtype=jnp.int32)
-        ok = (tok[None, :] <= qpos[:, None]) & (tok[None, :] < kl)
-        s = jnp.where(ok[:, None, :], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("qhk,khd->qhd", p.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32).astype(qs.dtype)
-        qvalid = jnp.arange(qb, dtype=jnp.int32) < ql
-        return jnp.where(qvalid[:, None, None], out, 0)
-
-    return jax.vmap(one)(q, block_tables,
-                         jnp.asarray(kv_lens, jnp.int32),
-                         jnp.asarray(q_lens, jnp.int32))
+    kl = jnp.asarray(kv_lens, jnp.int32)[:, None, None]
+    ql = jnp.asarray(q_lens, jnp.int32)[:, None, None]
+    tok = jnp.arange(block_tables.shape[1] * k_pages.shape[1],
+                     dtype=jnp.int32)[None, None, :]
+    qi = jnp.arange(qb, dtype=jnp.int32)[None, :, None]
+    ok = (tok <= (kl - ql) + qi) & (tok < kl)                # [B, Qb, L]
+    out = _gathered_group_attention(q, k_pages, v_pages, block_tables, ok,
+                                    scale)
+    return jnp.where((qi < ql)[..., None], out, 0)
 
 
 def paged_attention_varq(q, k_pages, v_pages, block_tables, kv_lens,

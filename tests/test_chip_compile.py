@@ -8,7 +8,9 @@ for exceeding the 16 MiB scoped VMEM limit. A compile that passes is
 not a chip run: nothing here executes, and nothing here is a time.
 Whole step programs are compiled by ``tools/chip_rehearsal.py``.
 """
+import math
 import os
+import re
 
 import pytest
 
@@ -143,6 +145,38 @@ def test_varq_span_bound_is_the_compilers(chip):
             jax.jit(call).lower(*args).compile()
     finally:
         pa._VMEM_SCOPED_BYTES = limit
+
+
+# The XLA block-table path at the GQA serve geometry of the benchmark's
+# `mistral7b-sessions-closed`: 16 slots x 4096 positions, 32 query heads
+# on 8 KV heads. Per KV head group the compiler consumes the gathered
+# bf16 table as it lies; with the heads repeated it made, each layer, a
+# float32 `convert`, a transposing `copy` and a `broadcast
+# f32[16,4096,8,4,128]` of it (1 GB for K and again for V).
+@pytest.mark.parametrize("span", [None, 8], ids=["decode", "varq8"])
+def test_xla_block_table_path_keeps_the_table_bf16(chip, span):
+    slots, pps, pool, kv_heads = 16, 256, 4096, 8
+    pages = ((pool, PAGE, kv_heads, D), BF16)
+    if span is None:
+        fn = lambda q, k, v, bt, cl: pa._paged_attention_xla(
+            q, k, v, bt, cl, SCALE)
+        shapes = (((slots, H, D), BF16), pages, pages,
+                  ((slots, pps), I32), ((slots,), I32))
+    else:
+        fn = lambda q, k, v, bt, kl, ql: pa._paged_attention_varq_xla(
+            q, k, v, bt, kl, ql, SCALE)
+        shapes = (((slots, span, H, D), BF16), pages, pages,
+                  ((slots, pps), I32), ((slots,), I32), ((slots,), I32))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    table = slots * pps * PAGE * kv_heads * D
+    sizes = {}
+    for dtype, dims in re.findall(r"\b([a-z]+[0-9]+)\[([0-9,]+)\]", text):
+        sizes.setdefault(math.prod(map(int, dims.split(","))),
+                         set()).add(dtype)
+    assert " f64[" not in text
+    assert sizes[table] == {"bf16"}, sizes[table]
+    assert max(sizes) == table, max(sizes)
 
 
 def test_predictor_refuses_a_span_over_the_bound():

@@ -207,6 +207,133 @@ class TestPagedAttention:
             np.testing.assert_allclose(
                 out[b], self._oracle(q, kp, vp, bt, cl, b), atol=1e-4)
 
+    # -- the XLA block-table paths, per KV head group ---------------------
+    # geometry of the grouped cases: 2 KV heads, page 4, 4 pages a slot
+    _G = dict(hkv=2, d=32, page=4, pps=4, pool=24, span=4)
+
+    def _grouped_case(self, rep, dtype, seed=0):
+        """Inputs rounded to `dtype` (so the oracle sees what the pool
+        holds), one slot per length: 0, 1, a page boundary, a full
+        table."""
+        import jax.numpy as jnp
+        g = self._G
+        rs = np.random.RandomState(seed)
+        L = g["page"] * g["pps"]
+        lens = np.array([0, 1, g["page"], L], np.int32)
+        B, H = len(lens), g["hkv"] * rep
+        rnd = lambda *shape: jnp.asarray(
+            rs.randn(*shape).astype(np.float32)).astype(dtype)
+        q = rnd(B, g["span"], H, g["d"])
+        kp = rnd(g["pool"], g["page"], g["hkv"], g["d"])
+        vp = rnd(g["pool"], g["page"], g["hkv"], g["d"])
+        bt = rs.choice(g["pool"], (B, g["pps"]), replace=False).astype(
+            np.int32)
+        return q, kp, vp, bt, lens
+
+    @staticmethod
+    def _grouped_oracle(q, kp, vp, bt, ok):
+        """Plain float32 NumPy: gather the table, REPEAT the KV heads to
+        the query heads, mask with the kernels' finite -1e30 (a query
+        with no key attends uniformly, as the XLA path always has),
+        softmax, P.V. q: [B, Q, H, D]; ok: [B, Q, L]."""
+        q, kp, vp = (np.asarray(x.astype("float32")) for x in (q, kp, vp))
+        B, Q, H, D = q.shape
+        hkv = kp.shape[2]
+        out = np.zeros_like(q)
+        for b in range(B):
+            k = np.repeat(kp[bt[b]].reshape(-1, hkv, D), H // hkv, axis=1)
+            v = np.repeat(vp[bt[b]].reshape(-1, hkv, D), H // hkv, axis=1)
+            s = np.einsum("qhd,khd->qhk", q[b], k) / np.sqrt(np.float32(D))
+            s = np.where(ok[b][:, None, :], s, np.float32(-1e30))
+            p = np.exp(s - s.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            out[b] = np.einsum("qhk,khd->qhd", p, v)
+        return out
+
+    @staticmethod
+    def _grouped_atol(dtype, vp):
+        # float32: today's 1e-4. Narrower pools: P and the output are
+        # each rounded to the dtype, half an ulp (eps / 2, relative)
+        # apiece, against values up to max |v|: eps * max |v| in all.
+        import jax.numpy as jnp
+        if dtype == "float32":
+            return 1e-4
+        vmax = float(np.abs(np.asarray(vp.astype("float32"))).max())
+        return float(jnp.finfo(dtype).eps) * vmax
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("rep", [1, 2, 4, 8])
+    @pytest.mark.parametrize("path", ["decode", "varq"])
+    def test_xla_paths_match_repeating_oracle(self, path, rep, dtype):
+        import jax.numpy as jnp
+        from paddle_tpu.kernels.paged_attention import (
+            _paged_attention_xla, _paged_attention_varq_xla)
+        q, kp, vp, bt, lens = self._grouped_case(rep, dtype)
+        g = self._G
+        scale = 1.0 / np.sqrt(g["d"])
+        tok = np.arange(g["page"] * g["pps"])
+        if path == "decode":
+            q = q[:, :1]
+            ok = (tok[None, :] < lens[:, None])[:, None, :]
+            out = _paged_attention_xla(q[:, 0], kp, vp, jnp.asarray(bt),
+                                       jnp.asarray(lens), scale)[:, None]
+            want = self._grouped_oracle(q, kp, vp, bt, ok)
+        else:
+            q_lens = np.minimum(lens, [0, 1, 3, g["span"]]).astype(np.int32)
+            qpos = (lens - q_lens)[:, None] + np.arange(g["span"])[None, :]
+            ok = ((tok[None, None, :] <= qpos[:, :, None])
+                  & (tok[None, None, :] < lens[:, None, None]))
+            out = _paged_attention_varq_xla(
+                q, kp, vp, jnp.asarray(bt), jnp.asarray(lens),
+                jnp.asarray(q_lens), scale)
+            want = self._grouped_oracle(q, kp, vp, bt, ok)
+            want[np.arange(g["span"])[None, :] >= q_lens[:, None]] = 0
+        assert out.dtype == q.dtype and out.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(out.astype("float32")), want, rtol=0,
+            atol=self._grouped_atol(dtype, vp))
+
+    @pytest.mark.parametrize("path", ["decode", "varq"])
+    def test_xla_paths_never_widen_or_repeat_the_table(self, path):
+        """The mechanism, in the traced program: with a bf16 pool at a
+        GQA shape nothing is larger than the gathered table
+        [B, L, Hkv, D] (a `jnp.repeat` of the KV heads is `rep` times
+        it) and nothing of its size is float32. What XLA makes of it on
+        the chip is tests/test_chip_compile.py's."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.kernels.paged_attention import (
+            _paged_attention_xla, _paged_attention_varq_xla)
+        B, H, hkv, D, page, pps, pool, span = 2, 8, 2, 128, 16, 4, 16, 4
+        bf = jnp.bfloat16
+        pages = jnp.zeros((pool, page, hkv, D), bf)
+        bt = jnp.zeros((B, pps), jnp.int32)
+        lens = jnp.ones((B,), jnp.int32)
+        if path == "decode":
+            jaxpr = jax.make_jaxpr(
+                lambda q, k, v: _paged_attention_xla(q, k, v, bt, lens,
+                                                     0.1))(
+                jnp.zeros((B, H, D), bf), pages, pages)
+        else:
+            jaxpr = jax.make_jaxpr(
+                lambda q, k, v: _paged_attention_varq_xla(
+                    q, k, v, bt, lens, lens, 0.1))(
+                jnp.zeros((B, span, H, D), bf), pages, pages)
+        table = B * pps * page * hkv * D
+
+        def avals(jp):
+            for eqn in jp.eqns:
+                for v in eqn.outvars:
+                    yield eqn.primitive.name, v.aval
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from avals(sub)
+
+        seen = list(avals(jaxpr.jaxpr))
+        assert any(a.size == table for _, a in seen)     # the gather
+        for name, a in seen:
+            assert a.size <= table, (name, a)
+            assert a.size < table or a.dtype == bf, (name, a)
+
     def test_pallas_interpret_matches_xla(self):
         import jax.numpy as jnp
         from paddle_tpu.kernels.paged_attention import (

@@ -16,7 +16,11 @@ allocator over the device-resident paged K/V arrays, with on-device
 copy-on-write) and `PrefixCache` (hash-trie over page-aligned prompt
 prefixes so repeated system prompts skip prefill — cf. vLLM automatic
 prefix caching / SGLang RadixAttention), consumed by
-inference.ContinuousBatchingPredictor (docs/SERVING.md).
+inference.ContinuousBatchingPredictor (docs/SERVING.md). A model whose
+layers are not all attention declares what each layer keeps
+(`LayerCache`, from the model's `cache_layout()`): K/V pages for the
+attention layers, and for the recurrent ones a row a slot in
+`StatePool`, constant in the context's length (`StateCacheEntry`).
 """
 from __future__ import annotations
 
@@ -68,6 +72,46 @@ def static_cache_update(entry: StaticCacheEntry, k, v):
     k_new = apply(upd, entry.k, k, entry.pos, _name="kv_cache_update")
     v_new = apply(upd, entry.v, v, entry.pos, _name="kv_cache_update")
     return k_new, v_new, StaticCacheEntry(k_new, v_new, entry.pos)
+
+
+class LayerCache(NamedTuple):
+    """What one decoder layer keeps between steps, as its model's
+    `cache_layout()` declares it. kind "kv": `shape` = (n_kv_heads,
+    head_dim), paged. kind "state": `shape` = ((d_conv - 1, channels),
+    (heads, head_dim, d_state)), one row a slot."""
+    kind: str
+    shape: tuple
+
+
+class LayerCaches(list):
+    """The per-layer caches a forward pass returns, with `counters`:
+    small device vectors the model summed over its layers ({name:
+    int32 array}), brought down with the step's tokens."""
+
+    def __init__(self, caches, counters=None):
+        super().__init__(caches)
+        self.counters = counters or {}
+
+
+class StatePool:
+    """Per-slot state of the recurrent layers, beside the pages: for
+    each such layer a convolution window and a float32 SSM state, one
+    row a slot and one row more. A slot's row is written whole by its
+    prefill, so a reused slot owes nothing to its last tenant; there is
+    nothing to allocate, share or reclaim."""
+
+    def __init__(self, n_layers, slots, conv_shape, ssm_shape,
+                 conv_dtype="float32", device=None):
+        import jax.numpy as jnp
+        self.slots = int(slots)
+        self.conv = [jnp.zeros((slots + 1,) + tuple(conv_shape), conv_dtype,
+                               device=device) for _ in range(n_layers)]
+        self.ssm = [jnp.zeros((slots + 1,) + tuple(ssm_shape), jnp.float32,
+                              device=device) for _ in range(n_layers)]
+
+    @property
+    def nbytes(self):
+        return int(sum(a.nbytes for a in self.conv + self.ssm))
 
 
 class PagedKVPool:
@@ -689,11 +733,29 @@ class PagedCacheEntry(NamedTuple):
     q_lens: object = None
 
 
-class PagedKVCache:
-    """A list of per-layer PagedCacheEntry, passed as `past_key_values`."""
+class StateCacheEntry(NamedTuple):
+    """A recurrent layer's cache for one decode step: the whole state
+    pool of that layer, updated in place. `conv`: [slots + 1, d_conv - 1,
+    channels], the last inputs of the causal convolution in the
+    activations' dtype; `ssm`: [slots + 1, heads, head_dim, d_state]
+    float32. Row b is slot b's; the last row is nobody's (a prefill's
+    dummy rows write there, as K/V's do on the trash page). A decode
+    step advances every row by one token: an empty slot's row holds
+    don't-care values until the next prefill writes it whole."""
+    conv: object
+    ssm: object
 
-    def __init__(self, entries: List[PagedCacheEntry]):
+
+class PagedKVCache:
+    """A list of per-layer cache entries (`PagedCacheEntry`, or
+    `StateCacheEntry` for a recurrent layer), passed as
+    `past_key_values`. `active` ([B] bool, optional) says which rows of
+    the step carry a request, for a model that counts what its tokens
+    do (expert routing)."""
+
+    def __init__(self, entries: List[PagedCacheEntry], active=None):
         self.entries = entries
+        self.active = active
 
     def __len__(self):
         return len(self.entries)

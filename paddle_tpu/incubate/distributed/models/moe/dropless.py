@@ -1,0 +1,137 @@
+"""A dropless, gated expert layer that is told which experts it holds.
+
+`MoELayer` (moe_layer.py) gives every expert a fixed capacity and drops
+what overflows: right for training at scale, and something no plain
+reference can match token for token. Serving needs the other contract:
+every assignment is computed. This layer routes over ALL `num_experts`
+(the router keeps its published width), takes the `top_k` largest
+logits of each token, gates them by the softmax over those `top_k`
+logits alone, and computes the part of the result that the experts in
+`held` give. An assignment to an expert that lives on another chip adds
+nothing here, and nothing stands in for that chip or for its exchange:
+under expert parallelism the partial results of the ranks add up (the
+shared expert, which every rank computes alike, counted once).
+
+Static shapes at N x top_k assignments, none dropped: the assignments
+are sorted by the local index of their expert (absent experts last),
+and the two expert matmuls run as grouped matmuls over the held
+experts' stacked banks (`jax.lax.ragged_dot`, which the TPU compiler
+lowers to a grouped-matmul kernel of its own: each expert's weights are
+read once, and no row of the other experts is computed). Rows past the
+last group belong to absent experts and are zeroed by their gate.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .....nn.layer_base import Layer
+from .....nn.initializer import Normal
+from .....ops._dispatch import apply
+from .....kernels._common import mxu_precision
+
+# Tokens routed in one piece. A prefill of 8192 tokens makes 81,920
+# assignments, whose gathered inputs and outputs at width 4096 are
+# 0.7 GB each; in pieces of 2048 tokens they are a quarter of that, and
+# the held experts' weights are read once a piece.
+BLOCK_TOKENS = 2048
+
+
+def _count(ids, n):
+    """How many of `ids` (int32, n = none) name each of 0..n-1."""
+    return jnp.zeros((n + 1,), jnp.int32).at[ids].add(1)[:n]
+
+
+def _route_block(x, valid, router_w, w_in, w_out, local_of, n_held, top_k):
+    """x [n, h], valid [n] bool -> (y [n, h] float32, counts int32
+    [2 + n_held] = assignments, local assignments, tokens per held
+    expert; pad and idle rows are computed but not counted)."""
+    n, _ = x.shape
+    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+    topv, topi = jax.lax.top_k(logits, top_k)                  # [n, k]
+    gates = jax.nn.softmax(topv, axis=-1)                      # float32
+    loc = local_of[topi].reshape(-1)          # [n * k], n_held = absent
+    rows = jnp.arange(n * top_k, dtype=jnp.int32)
+    _, order = jax.lax.sort_key_val(loc, rows)     # stable, by expert
+    sizes = _count(loc, n_held)
+    with jax.named_scope("moe.experts"):
+        xs = x[order // top_k]                                 # [n * k, h]
+        # the compiler's grouped kernel is a Mosaic kernel: it refuses
+        # bf16 operands under the global "highest" (kernels/_common.py)
+        prec = mxu_precision(x, w_in)
+        up = jax.lax.ragged_dot(xs, w_in, sizes, precision=prec)
+        f = w_out.shape[1]
+        act = jax.nn.silu(up[:, :f].astype(jnp.float32)) \
+            * up[:, f:].astype(jnp.float32)
+        out = jax.lax.ragged_dot(act.astype(x.dtype), w_out, sizes,
+                                 precision=prec)
+    _, back = jax.lax.sort_key_val(order, rows)    # sorted row of (t, j)
+    out = out[back].reshape(n, top_k, -1).astype(jnp.float32)
+    mine = (loc < n_held).reshape(n, top_k)
+    y = jnp.sum(jnp.where(mine[..., None], out * gates[..., None], 0.0),
+                axis=1)
+    counted = jnp.where(jnp.repeat(valid, top_k), loc, n_held)
+    per = _count(counted, n_held)
+    head = jnp.stack([jnp.sum(valid, dtype=jnp.int32) * top_k,
+                      jnp.sum(per, dtype=jnp.int32)])
+    return y, jnp.concatenate([head, per])
+
+
+def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k):
+    """x [..., h] -> (y like x, counts int32 [2 + len(held)])."""
+    n_experts = router_w.shape[1]
+    local_of = np.full((n_experts,), len(held), np.int32)
+    local_of[list(held)] = np.arange(len(held), dtype=np.int32)
+    local_of = jnp.asarray(local_of)
+    flat = x.reshape(-1, x.shape[-1])
+    ok = jnp.ones(flat.shape[:1], jnp.bool_) if valid is None \
+        else valid.reshape(-1)
+
+    def block(xv):
+        return _route_block(xv[0], xv[1], router_w, w_in, w_out, local_of,
+                            len(held), top_k)
+
+    n = flat.shape[0]
+    if n > BLOCK_TOKENS and n % BLOCK_TOKENS == 0:
+        y, counts = jax.lax.map(
+            block, (flat.reshape(-1, BLOCK_TOKENS, flat.shape[1]),
+                    ok.reshape(-1, BLOCK_TOKENS)))
+        y, counts = y.reshape(n, -1), counts.sum(axis=0, dtype=jnp.int32)
+    else:
+        y, counts = block((flat, ok))
+    return y.astype(x.dtype).reshape(x.shape), counts
+
+
+class DroplessMoELayer(Layer):
+    """Router over `num_experts`, banks of the experts in `held` (all
+    of them when None): `w_in` [held, d_model, 2 x d_ff] (gate half,
+    then up half), `w_out` [held, d_ff, d_model]; expert e computes
+    `w_out_e(silu(gate) * up)`. `forward(x, valid)` returns the held
+    experts' part of the layer's result and the routing counts."""
+
+    def __init__(self, d_model, d_ff, num_experts, top_k, held=None,
+                 initializer_range=0.02):
+        super().__init__()
+        self.held = tuple(range(num_experts)) if held is None \
+            else tuple(int(e) for e in held)
+        if len(set(self.held)) != len(self.held) or not all(
+                0 <= e < num_experts for e in self.held):
+            raise ValueError(f"held experts {self.held} are not distinct "
+                             f"ids below {num_experts}")
+        self.top_k = int(top_k)
+        init = Normal(0.0, initializer_range)
+        self.router = self.create_parameter([d_model, num_experts],
+                                            default_initializer=init)
+        self.w_in = self.create_parameter(
+            [len(self.held), d_model, 2 * d_ff], default_initializer=init)
+        self.w_out = self.create_parameter(
+            [len(self.held), d_ff, d_model], default_initializer=init)
+
+    def forward(self, x, valid=None):
+        def fn(xv, rw, wi, wo, *ok):
+            return dropless_moe(xv, ok[0] if ok else None, rw, wi, wo,
+                                held=self.held, top_k=self.top_k)
+        extra = () if valid is None else (valid,)
+        return apply(fn, x, self.router, self.w_in, self.w_out, *extra,
+                     _name="dropless_moe")

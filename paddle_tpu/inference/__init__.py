@@ -413,7 +413,13 @@ class SpeculativePredictor:
 
 # PagedKVPool moved to generation.kv_cache (it is cache infrastructure
 # shared with PrefixCache); re-exported here for API stability.
-from ..generation.kv_cache import PagedKVPool, PrefixCache  # noqa: E402
+from ..generation.kv_cache import (PagedKVPool, PrefixCache,  # noqa: E402
+                                   StatePool)
+
+
+def _raw(x):
+    """The array under a Tensor (or the array itself)."""
+    return getattr(x, "_value", x)
 
 
 class ContinuousBatchingPredictor:
@@ -424,11 +430,21 @@ class ContinuousBatchingPredictor:
     movement and per-step host/device round-trips dominate TPU serving
     cost):
 
+    - **Caches by the model's declaration.** `model.cache_layout()`
+      says what each layer keeps between steps: an attention layer's
+      K/V is paged (PagedKVPool, over those layers only); a recurrent
+      layer keeps a conv window and a float32 SSM state, one row a
+      slot, constant in the context's length (StatePool). Every program
+      threads both, donated alike. With recurrent layers the prefix
+      cache is derived off and chunked prefill, speculative decoding,
+      tensor parallelism and the prefill/decode roles are refused by
+      name (docs/SERVING.md "Hybrid models: state beside pages").
     - **Device-resident prefill.** Admission runs ONE jitted program
       per (batch, prompt-bucket) that embeds the causal/padding mask
       in-graph, runs the forward, computes the greedy next token for
-      every position on device, and scatters all layers' K/V straight
-      into the paged pool. Prompt K/V never visits the host; the only
+      every position on device, and scatters the attention layers' K/V
+      straight into the paged pool (and each prompt's final recurrent
+      state into its slot's row). Prompt K/V never visits the host; the only
       admission download is the small int32 next-token matrix. Multiple
       queued prompts sharing a length bucket prefill as one batch.
     - **Prefix caching.** A hash-trie over page-aligned prompt prefixes
@@ -623,20 +639,42 @@ class ContinuousBatchingPredictor:
         self.capacity = int(num_pages)  # pages available to requests
         self.pad_token_id = pad_token_id
         self.eos_token_id = eos_token_id
-        head_dim = cfg.hidden_size // cfg.num_attention_heads
+        # what each layer keeps between steps, as the model declares it:
+        # K/V pages for attention layers (one geometry), a row a slot of
+        # (conv window, SSM state) for recurrent ones
+        self._layout = list(model.cache_layout())
+        kv_shapes = {c.shape for c in self._layout if c.kind == "kv"}
+        state_shapes = {c.shape for c in self._layout if c.kind == "state"}
+        if len(kv_shapes) > 1 or len(state_shapes) > 1 or any(
+                c.kind not in ("kv", "state") for c in self._layout):
+            raise ValueError(
+                f"cache_layout(): one K/V geometry and one state geometry "
+                f"are served, got {sorted(kv_shapes)} and "
+                f"{sorted(state_shapes)}")
+        n_kv_heads, head_dim = next(iter(kv_shapes), (
+            cfg.num_key_value_heads,
+            cfg.hidden_size // cfg.num_attention_heads))
+        self._state_shape = next(iter(state_shapes), None)
         # head-sharded paged KV: pages shard over the KV-head axis of
         # the TP mesh when the head count divides; an indivisible model
         # keeps replicated pages (still served, fast path lost) and the
         # downgrade is recorded like any other lost kernel path
         kv_mesh = self._tp_mesh
-        if kv_mesh is not None and cfg.num_key_value_heads % self.tp:
+        if kv_mesh is not None and n_kv_heads % self.tp:
             from ..kernels._common import note_fallback
             note_fallback("paged_kv_pool", "tp_head_shard")
             kv_mesh = None
-        self.pool = PagedKVPool(cfg.num_hidden_layers, num_pages + 1,
-                                page_size, cfg.num_key_value_heads,
-                                head_dim, dtype=kv_dtype, mesh=kv_mesh,
-                                device=self._device)
+        self.pool = PagedKVPool(
+            sum(c.kind == "kv" for c in self._layout), num_pages + 1,
+            page_size, n_kv_heads, head_dim, dtype=kv_dtype, mesh=kv_mesh,
+            device=self._device)
+        self.state_pool = None      # built once the refusals have passed
+        if self._state_shape is not None and enable_prefix_cache:
+            # cached pages hold the attention layers' K/V only: a hit
+            # would resume the recurrent layers from no state at all
+            enable_prefix_cache = False
+            _obsm.counter("kernels.pallas_fallbacks").inc(
+                kernel="prefix_cache", reason="recurrent_state")
         # inactive slots need somewhere harmless to point their block
         # table (the decode step writes one K/V row for EVERY slot):
         # a dedicated trash page absorbs those writes
@@ -776,6 +814,36 @@ class ContinuousBatchingPredictor:
                     f"most {fit} at {cfg.num_attention_heads // self.tp} "
                     f"heads x {head_dim} (kernels.paged_attention."
                     f"max_varq_span)")
+        if self._state_shape is not None:
+            refused = [n for n, on in (
+                ("prefill_chunk_tokens", self._chunk_max > 0),
+                ("spec_draft_tokens", self._spec_k > 0),
+                ("tp_degree", self.tp > 1),
+                (f"role={self.role!r}", self.role != "unified")) if on]
+            if refused:
+                raise ValueError(
+                    f"{', '.join(refused)}: not served for a model with "
+                    f"recurrent layers. Their state is a row a slot, "
+                    f"advanced one token a step: a query span (chunked "
+                    f"prefill, speculative verify) would need snapshots "
+                    f"to roll back to, a page span carries no state to "
+                    f"hand off, and the mixer has no sharding rule "
+                    f"(docs/SERVING.md 'Hybrid models')")
+            conv_shape, ssm_shape = self._state_shape
+            self.state_pool = StatePool(
+                sum(c.kind == "state" for c in self._layout), self.B,
+                conv_shape, ssm_shape, conv_dtype=kv_dtype,
+                device=self._device)
+            _obsm.gauge("serving.state_slots").set(self.B, **self._mlbl)
+            _obsm.gauge("serving.state_pool_bytes").set(
+                self.state_pool.nbytes, **self._mlbl)
+        # counts the model sums over its layers on the device, a vector a
+        # name: each element is one (metric, labels) of `step_counters`
+        self._step_counters = [
+            (key, [(_obsm.counter(name), dict(lbl, **self._mlbl))
+                   for name, lbl in spec])
+            for key, spec in sorted(
+                getattr(model, "step_counters", dict)().items())]
         self._m_spec_prop =_obsm.counter("serving.spec.proposed_tokens")
         self._m_spec_acc = _obsm.counter("serving.spec.accepted_tokens")
         self._m_spec_rate = _obsm.gauge("serve.spec.accept_rate")
@@ -1050,17 +1118,73 @@ class ContinuousBatchingPredictor:
                          for _ in RaggedMetaBuilder.FIELDS)
         with self._trace_lock, self._kernel_scope():
             return self._decode_jit.lower(
-                self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+                self._p_vals, self._b_vals, *self._cache_args(),
                 i32(self.B, self.pages_per_seq), i32(self.B), i32(self.B),
                 *meta)
 
+    # The serve programs take the caches as two lists in layer order
+    # (donated): for a "kv" layer its K and V pages, for a "state" layer
+    # its conv window and SSM state rows.
+    def _cache_args(self):
+        if self.state_pool is None:
+            return self.pool.k, self.pool.v
+        pages = iter(zip(self.pool.k, self.pool.v))
+        rows = iter(zip(self.state_pool.conv, self.state_pool.ssm))
+        pairs = [next(pages if c.kind == "kv" else rows)
+                 for c in self._layout]
+        return [a for a, _ in pairs], [b for _, b in pairs]
+
+    def _cache_store(self, first, second):
+        """Adopt a program's output caches (the inputs were donated)."""
+        if self.state_pool is None:
+            self.pool.k, self.pool.v = list(first), list(second)
+            return
+        kinds = [c.kind for c in self._layout]
+        self.pool.k = [a for a, k in zip(first, kinds) if k == "kv"]
+        self.pool.v = [a for a, k in zip(second, kinds) if k == "kv"]
+        self.state_pool.conv = [a for a, k in zip(first, kinds)
+                                if k == "state"]
+        self.state_pool.ssm = [a for a, k in zip(second, kinds)
+                               if k == "state"]
+
+    def _step_cache(self, kl, vl, tables, ctx, meta):
+        """The decode step's `past_key_values`: one entry a layer, of
+        its kind. With recurrent layers the model is also told which
+        rows carry a request (an empty slot's table is all trash)."""
+        from ..generation.kv_cache import (PagedCacheEntry, PagedKVCache,
+                                           StateCacheEntry)
+        paged = (Tensor(tables), Tensor(ctx), meta)
+        entries = [PagedCacheEntry(kl[i], vl[i], *paged) if c.kind == "kv"
+                   else StateCacheEntry(kl[i], vl[i])
+                   for i, c in enumerate(self._layout)]
+        if self.state_pool is None:
+            return PagedKVCache(entries)
+        return PagedKVCache(entries,
+                            active=tables[:, 0] != jnp.int32(self._trash))
+
+    def _counter_outputs(self, caches):
+        """The model's summed counts, as extra outputs of a program."""
+        counts = getattr(caches, "counters", None) or {}
+        return tuple(_raw(counts[key]) for key, _ in self._step_counters)
+
+    def _note_counters(self, aux):
+        """Add a resolved step's counts to their metrics (the vectors
+        came down with the step's tokens: no read of their own)."""
+        for (_, spec), vec in zip(self._step_counters, aux):
+            for (ctr, lbl), n in zip(spec, np.asarray(vec).tolist()):
+                if n:
+                    ctr.inc(n, **lbl)
+
     def _raw_prefill(self, p_vals, b_vals, kl, vl, ids, pos, lens,
-                     page_rows):
+                     page_rows, *slots):
         """One admission program per (batch, bucket): forward + on-device
         argmax + K/V scatter into the paged pool. ids/pos [N, bucket]
         (left-padded), lens [N], page_rows [N, ceil(bucket/page)].
-        Returns (next_tokens [N, bucket] int32, new_k, new_v). Rows with
-        lens == 0 are dummies: every write lands on the trash page."""
+        Returns (next_tokens [N, bucket] int32, new_k, new_v[, counts]).
+        Rows with lens == 0 are dummies: every write lands on the trash
+        page. A model with recurrent layers also gets `slots` [N] (the
+        state row each prompt's final state is written to, whole; a
+        dummy's is the pool's last row)."""
         from ..jit.bridge import bound_state
         n, bucket = ids.shape
         j = jnp.arange(bucket, dtype=jnp.int32)
@@ -1084,14 +1208,13 @@ class ContinuousBatchingPredictor:
         dst_off = jnp.where(key_valid, tokpos % self.page,
                             0).astype(jnp.int32)
         new_k, new_v = [], []
-        for li, (ck, cv) in enumerate(caches):
-            ka = ck._value if isinstance(ck, Tensor) else ck
-            va = cv._value if isinstance(cv, Tensor) else cv
-            new_k.append(kl[li].at[dst_page, dst_off].set(
-                ka.astype(kl[li].dtype)))
-            new_v.append(vl[li].at[dst_page, dst_off].set(
-                va.astype(vl[li].dtype)))
-        return nexts, new_k, new_v
+        for li, (layer, (ca, cb)) in enumerate(zip(self._layout, caches)):
+            where = (dst_page, dst_off) if layer.kind == "kv" else slots[0]
+            new_k.append(kl[li].at[where].set(
+                _raw(ca).astype(kl[li].dtype)))
+            new_v.append(vl[li].at[where].set(
+                _raw(cb).astype(vl[li].dtype)))
+        return (nexts, new_k, new_v) + self._counter_outputs(caches)
 
     def _raw_suffix_prefill(self, p_vals, b_vals, kl, vl, ids, pos, m,
                             slen, past_rows, page_rows):
@@ -1154,28 +1277,25 @@ class ContinuousBatchingPredictor:
         the host fetches only the two small vectors, and only AFTER
         dispatching the next step (double buffering)."""
         from ..jit.bridge import bound_state
-        from ..generation.kv_cache import PagedCacheEntry, PagedKVCache
         meta = None
         if meta_flat:
             from ..kernels.paged_attention import RaggedMetaBuilder
             meta = dict(zip(RaggedMetaBuilder.FIELDS, meta_flat))
-        entries = [PagedCacheEntry(kl[i], vl[i], Tensor(tables),
-                                   Tensor(ctx), meta)
-                   for i in range(len(kl))]
         with no_grad(), bound_state(self._p_tensors, p_vals,
                                     self._b_tensors, b_vals):
             logits, caches = self.model(
                 Tensor(last_tok[:, None]),
                 position_ids=Tensor(ctx[:, None]),
-                past_key_values=PagedKVCache(entries), use_cache=True)
+                past_key_values=self._step_cache(kl, vl, tables, ctx, meta),
+                use_cache=True)
         nxt = jnp.argmax(logits._value[:, -1], axis=-1).astype(jnp.int32)
         if self.eos_token_id is not None:
             done = nxt == jnp.int32(self.eos_token_id)
         else:
             done = jnp.zeros(nxt.shape, jnp.bool_)
-        new_k = [getattr(e.k_pages, "_value", e.k_pages) for e in caches]
-        new_v = [getattr(e.v_pages, "_value", e.v_pages) for e in caches]
-        return nxt, done, new_k, new_v
+        new_k = [_raw(e[0]) for e in caches]
+        new_v = [_raw(e[1]) for e in caches]
+        return (nxt, done, new_k, new_v) + self._counter_outputs(caches)
 
     def _raw_mixed_step(self, p_vals, b_vals, kl, vl, tables, ctx,
                         span_ids, q_lens, tok_in, *meta_flat):
@@ -1242,30 +1362,27 @@ class ContinuousBatchingPredictor:
         bitwise the greedy program's token — selected in-graph, so one
         compiled program serves any greedy/sampled tenant mix."""
         from ..jit.bridge import bound_state
-        from ..generation.kv_cache import PagedCacheEntry, PagedKVCache
         from ..generation import sampling as _samp
         meta = None
         if meta_flat:
             from ..kernels.paged_attention import RaggedMetaBuilder
             meta = dict(zip(RaggedMetaBuilder.FIELDS, meta_flat))
-        entries = [PagedCacheEntry(kl[i], vl[i], Tensor(tables),
-                                   Tensor(ctx), meta)
-                   for i in range(len(kl))]
         with no_grad(), bound_state(self._p_tensors, p_vals,
                                     self._b_tensors, b_vals):
             logits, caches = self.model(
                 Tensor(last_tok[:, None]),
                 position_ids=Tensor(ctx[:, None]),
-                past_key_values=PagedKVCache(entries), use_cache=True)
+                past_key_values=self._step_cache(kl, vl, tables, ctx, meta),
+                use_cache=True)
         nxt, _ = _samp.sample_tokens(logits._value[:, -1], s_temp,
                                      s_topk, s_topp, s_seed, s_ctr)
         if self.eos_token_id is not None:
             done = nxt == jnp.int32(self.eos_token_id)
         else:
             done = jnp.zeros(nxt.shape, jnp.bool_)
-        new_k = [getattr(e.k_pages, "_value", e.k_pages) for e in caches]
-        new_v = [getattr(e.v_pages, "_value", e.v_pages) for e in caches]
-        return nxt, done, new_k, new_v
+        new_k = [_raw(e[0]) for e in caches]
+        new_v = [_raw(e[1]) for e in caches]
+        return (nxt, done, new_k, new_v) + self._counter_outputs(caches)
 
     def _raw_spec_step(self, p_vals, b_vals, kl, vl, tables, ctx,
                        span_ids, q_lens, tok_in, s_temp, s_topk, s_topp,
@@ -2078,6 +2195,8 @@ class ContinuousBatchingPredictor:
             if not plans:
                 return 0
 
+            for plan, b in zip(plans, free):
+                plan["slot"] = b    # a prefill writes its state row
             t0 = _time.perf_counter()
             chunked_plans = [p for p in plans if p.get("chunked")]
             now_plans = [p for p in plans if not p.get("chunked")]
@@ -2127,12 +2246,11 @@ class ContinuousBatchingPredictor:
                 self._m_prefill.observe(_time.perf_counter() - t0,
                                         **mlbl)
             pf_sp.end()
-            b_i = iter(free)
             for plan in plans:
                 if plan.get("chunked"):
-                    place_chunked(next(b_i), plan)
+                    place_chunked(plan["slot"], plan)
                 else:
-                    place(next(b_i), plan, firsts[plan["r"]])
+                    place(plan["slot"], plan, firsts[plan["r"]])
             return len(plans)
 
         def _active():
@@ -2443,16 +2561,22 @@ class ContinuousBatchingPredictor:
             lens[i] = L
             rows[i, :min(W, len(plan["pages"]))] = \
                 plan["pages"][:W]
-        nexts, new_k, new_v = self._jit_call(
+        slots = ()
+        if self.state_pool is not None:     # dummy rows: the last row
+            slots = (np.full((nb,), self.B, np.int32),)
+            for i, plan in enumerate(group):
+                slots[0][i] = plan["slot"]
+        nexts, new_k, new_v, *aux = self._jit_call(
             ("prefill", ids.shape, rows.shape), self._prefill_jit,
-            self._p_vals, self._b_vals, self.pool.k, self.pool.v,
-            ids, pos, lens, rows)
-        self.pool.k, self.pool.v = list(new_k), list(new_v)
+            self._p_vals, self._b_vals, *self._cache_args(),
+            ids, pos, lens, rows, *slots)
+        self._cache_store(new_k, new_v)
         self._tp_account(nb * bucket)
         # graft-lint: ok[GL102] — the ONLY admission download: [nb,
         # bucket] small ints (every position's argmax, for the prefix
         # cache's cached-continuation tokens)
         nexts = np.asarray(nexts)
+        self._note_counters(aux)
         firsts = {}
         for i, plan in enumerate(group):
             prompt = plan["prompt"]
@@ -2491,9 +2615,9 @@ class ContinuousBatchingPredictor:
         row[:len(plan["pages"])] = plan["pages"]
         nexts, new_k, new_v = self._jit_call(
             ("suffix", ids.shape, past_rows.shape), self._suffix_jit,
-            self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+            self._p_vals, self._b_vals, *self._cache_args(),
             ids, pos, np.int32(covered), np.int32(sl), past_rows, row)
-        self.pool.k, self.pool.v = list(new_k), list(new_v)
+        self._cache_store(new_k, new_v)
         self._tp_account(sb)
         # graft-lint: ok[GL102] — the suffix-prefill admission
         # download, same contract as _batch_prefill's
@@ -2542,26 +2666,27 @@ class ContinuousBatchingPredictor:
         # snapshot them at dispatch
         if samp is not None:
             st, sk, sp_, ss, sc = samp
-            nxt, done, new_k, new_v = self._jit_call(
+            nxt, done, new_k, new_v, *aux = self._jit_call(
                 ("decode_sample", tables.shape,
                  tuple(np.shape(m) for m in meta_args)),
                 self._decode_sample_jit,
-                self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+                self._p_vals, self._b_vals, *self._cache_args(),
                 tables.copy(), ctx.copy(), tok_in, st, sk, sp_, ss, sc,
                 *meta_args)
         else:
-            nxt, done, new_k, new_v = self._jit_call(
+            nxt, done, new_k, new_v, *aux = self._jit_call(
                 ("decode", tables.shape,
                  tuple(np.shape(m) for m in meta_args)), self._decode_jit,
-                self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+                self._p_vals, self._b_vals, *self._cache_args(),
                 tables.copy(), ctx.copy(), tok_in, *meta_args)
-        self.pool.k, self.pool.v = list(new_k), list(new_v)
+        self._cache_store(new_k, new_v)
         self._tp_account(self.B)
         snap = [(b, slot_req[b]) for b in active]
         ctx[active] += 1
         self.stats["decode_steps"] += 1
         self._m_steps.inc(**self._mlbl)
-        return {"tok": nxt, "done": done, "snap": snap, "t": t0}
+        return {"tok": nxt, "done": done, "snap": snap, "t": t0,
+                "aux": aux}
 
     def _chunk_bucket(self, remaining, n_decode):
         """Adaptive page-aligned chunk bucket for one mixed tick:
@@ -2647,10 +2772,10 @@ class ContinuousBatchingPredictor:
         nxt, done, new_k, new_v = self._jit_call(
             ("mixed", qb, tables.shape,
              tuple(np.shape(m) for m in meta_args)), self._mixed_jit,
-            self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+            self._p_vals, self._b_vals, *self._cache_args(),
             tables.copy(), ctx.copy(), span_ids, q_lens.copy(), tok_in,
             *meta_args)
-        self.pool.k, self.pool.v = list(new_k), list(new_v)
+        self._cache_store(new_k, new_v)
         self._tp_account(self.B * qb)
         snap = [(b, slot_req[b]) for b in active]
         adv = [b for b in active if b not in paused]
@@ -2729,10 +2854,10 @@ class ContinuousBatchingPredictor:
         bonus, accepted, done, new_k, new_v = self._jit_call(
             ("spec", qs, tables.shape,
              tuple(np.shape(m) for m in meta_args)), self._spec_jit,
-            self._p_vals, self._b_vals, self.pool.k, self.pool.v,
+            self._p_vals, self._b_vals, *self._cache_args(),
             tables.copy(), ctx.copy(), span_ids, q_lens.copy(), tok_in,
             st, sk, sp_, ss, sc, *meta_args)
-        self.pool.k, self.pool.v = list(new_k), list(new_v)
+        self._cache_store(new_k, new_v)
         self._tp_account(self.B * qs)
         snap = [(b, slot_req[b]) for b in active]
         ctx0 = {b: int(ctx[b]) for b in active}
@@ -2877,6 +3002,7 @@ class ContinuousBatchingPredictor:
             # is already dispatched (double buffering)
             nxt = np.asarray(step["tok"])
             done = np.asarray(step["done"])  # graft-lint: ok[GL102] (ditto)
+        self._note_counters(step.get("aux") or ())
         self._m_tok.observe(_time.perf_counter() - step["t"],
                             **self._mlbl)
         chunk_mid = step.get("chunk_mid") or ()

@@ -10,3 +10,5 @@ from .bert import (BertConfig, BertModel, BertForSequenceClassification,
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM
 from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
                     ErnieForTokenClassification, ErnieForQuestionAnswering)
+from .granite_hybrid import (GraniteMoeHybridConfig,
+                             GraniteMoeHybridForCausalLM)

@@ -318,6 +318,15 @@ class LlamaForCausalLM(Layer, GenerationMixin):
     def backbone(self):
         return self.llama
 
+    def cache_layout(self):
+        """What each layer keeps between steps, for the serve loop
+        (generation/kv_cache.py `LayerCache`): K/V pages, every layer."""
+        from ..generation.kv_cache import LayerCache
+        c = self.config
+        return [LayerCache("kv", (c.num_key_value_heads,
+                                  c.hidden_size // c.num_attention_heads))
+                ] * c.num_hidden_layers
+
     def load_hf_state_dict(self, hf_state_dict):
         """Import HuggingFace Llama weights (ecosystem parity:
         PaddleNLP's convert from transformers checkpoints). Accepts an

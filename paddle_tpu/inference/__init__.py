@@ -739,20 +739,22 @@ class ContinuousBatchingPredictor:
                 2 * int(cfg.num_hidden_layers) * int(cfg.hidden_size)
                 * np.dtype(kv_dtype).itemsize)
         # ragged-grid paged attention: only valid (slot, page) pairs
-        # enter the decode kernel's grid. "auto" enables it when the
-        # kernel's constraints hold (H == Hkv, D % 128 == 0, H % 8 == 0)
-        # and a Pallas path exists; the grid is the constant
-        # B * pages_per_seq so every decode step reuses one compile.
+        # enter the decode kernel's grid. "auto" enables it when a
+        # Pallas path exists and the ragged kernels' gate admits the
+        # head geometry (kernels.paged_attention.paged_gate_reason: MHA
+        # at D % 128 == 0, 8 heads a shard); the grid is the constant
+        # B * pages_per_seq so every decode step reuses one compile. A
+        # GQA model decodes through the block-table kernel, which takes
+        # a group of query heads a KV head and needs no host metadata.
         if use_ragged == "auto":
             from ..kernels._common import (use_pallas as _use_pallas,
                                            pallas_interpret)
-            # under TP the kernel sees H / tp heads per shard, so the
-            # head-count tiling constraint applies to the SHARD
+            from ..kernels.paged_attention import paged_gate_reason
             use_ragged = (
-                (cfg.num_attention_heads == cfg.num_key_value_heads)
-                and head_dim % 128 == 0
-                and cfg.num_attention_heads % (8 * self.tp) == 0
-                and (_use_pallas() or pallas_interpret()))
+                (_use_pallas() or pallas_interpret())
+                and paged_gate_reason(
+                    "paged_attention_ragged", cfg.num_attention_heads,
+                    cfg.num_key_value_heads, head_dim, self.tp) is None)
         self.use_ragged = bool(use_ragged)
         # chunked prefill (docs/SERVING.md "Chunked prefill"): prompts
         # longer than the threshold are ingested as page-aligned chunks

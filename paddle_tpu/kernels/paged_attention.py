@@ -28,7 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._common import (_Z, _NEG_INF, use_pallas as _use_pallas,
                       pallas_dtype_ok, pallas_interpret, note_fallback,
-                      tp_shard_degree, partitioned)
+                      tp_shard_degree, partitioned, mxu_precision)
 
 # How a tensor-parallel replica splits the paged kernels' operands
 # (_common.partitioned): heads over 'model'; block tables, lengths and
@@ -37,6 +37,33 @@ _Q_HEADS = (None, "heads", None)              # q/out [B, H, D]
 _SPAN_HEADS = (None, None, "heads", None)     # q/out [B, Qb, H, D]
 _PAGE_HEADS = (None, None, "heads", None)     # pages [P, page, Hkv, D]
 _META_FIELDS = ("seq", "page", "ordinal", "first", "last", "valid")
+# Scoped VMEM the TPU compiler grants a kernel by default on v5e.
+_VMEM_SCOPED_BYTES = 16 * 1024 * 1024
+# The kernels that take a group of query heads a KV head (GQA); the
+# others contract head against head and need H == Hkv.
+_GROUP_KERNELS = ("paged_attention",)
+
+
+def paged_gate_reason(kernel, h, hkv, d, tp=1):
+    """Why `kernel` cannot take this head geometry, as the reason label
+    of ``kernels.pallas_fallbacks``, or None when it can. The query
+    heads must be whole groups of the KV heads (H % Hkv == 0) for a
+    kernel in `_GROUP_KERNELS` and equal to them for the others
+    (``gqa_ratio`` either way). Under tensor-parallel serving the head
+    axes are sharded over 'model', so the tiling constraints must hold
+    for the PER-SHARD head count H / tp: a global H that tiles but a
+    shard that doesn't is ``tp_head_shard``. Query heads are KV-major,
+    so a shard's query heads are the groups of its own KV heads when
+    both counts divide."""
+    if h % hkv != 0 or (h != hkv and kernel not in _GROUP_KERNELS):
+        return "gqa_ratio"
+    if d % 128 != 0:
+        return "head_dim_tiling"
+    if h % 8 != 0:
+        return "head_count_tiling"
+    if tp > 1 and (h % tp != 0 or hkv % tp != 0 or (h // tp) % 8 != 0):
+        return "tp_head_shard"
+    return None
 
 
 def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
@@ -44,35 +71,20 @@ def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
     when the Pallas path runs; a wanted-but-lost fast path is recorded
     via ``kernels.pallas_fallbacks{kernel,reason}`` (docs/
     OBSERVABILITY.md) so production silently dropping to plain XLA is
-    observable. Under tensor-parallel serving (``tp_degree`` > 1, else
-    the declared mesh's, ``_common.tp_shard_degree()``) the head axes
-    are sharded over 'model', so the tiling constraints must hold for the
-    PER-SHARD head count H / tp — a global H that tiles but a shard
-    that doesn't is recorded as reason ``tp_head_shard``."""
-    h = q.shape[-2]
-    hkv = k_pages.shape[2]
-    d = q.shape[-1]
+    observable. The geometry is judged by `paged_gate_reason`, at
+    ``tp_degree`` if given, else the declared mesh's
+    (``_common.tp_shard_degree()``)."""
+    if not (interpret or _use_pallas()):
+        return False
     tp = int(tp_degree) if tp_degree is not None else tp_shard_degree()
-    wanted = interpret or _use_pallas()
-    if not wanted:
-        return False
-    if h != hkv:
-        note_fallback(kernel, "gqa_ratio")
-        return False
-    if d % 128 != 0:
-        note_fallback(kernel, "head_dim_tiling")
-        return False
-    if h % 8 != 0:
-        note_fallback(kernel, "head_count_tiling")
-        return False
-    if tp > 1 and (h % tp != 0 or hkv % tp != 0
-                   or (h // tp) % 8 != 0):
-        note_fallback(kernel, "tp_head_shard")
-        return False
-    if not interpret and not pallas_dtype_ok(q, k_pages, v_pages):
-        note_fallback(kernel, "dtype")
-        return False
-    return True
+    reason = paged_gate_reason(kernel, q.shape[-2], k_pages.shape[2],
+                               q.shape[-1], tp)
+    if reason is None and not interpret \
+            and not pallas_dtype_ok(q, k_pages, v_pages):
+        reason = "dtype"
+    if reason is not None:
+        note_fallback(kernel, reason)
+    return reason is None
 
 
 # ---------------------------------------------------------------------------
@@ -126,89 +138,174 @@ def _paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel (H == Hkv fast path), block table via scalar prefetch
+# Pallas kernel, block table via scalar prefetch: any group ratio
+# rep = H // Hkv (MHA is rep = 1). It reads a slot's LIVE pages only,
+# `ppb` pages a block, and never writes a gathered table.
+#
+# The pool is taken as it lies, viewed [num_pages * page * Hkv, D] (a
+# bitcast: the trailing (Hkv, D) tiles of a page are contiguous), so a
+# block of pages is a [columns, D] matrix whose key axis interleaves
+# tokens and KV heads: column c is token c // Hkv under KV head c % Hkv.
+# All H query heads contract against it in ONE MXU matmul
+# [H, D] x [D, columns]; a query head keeps the columns of its own KV
+# head (the rep heads of a group are rows over the same columns) and the
+# mask sends the others to -inf before the softmax, so P.V is again one
+# matmul [H, columns] x [columns, D] with no per-head slicing. Operands
+# stay in the pool's dtype, accumulation and the online softmax across
+# blocks are float32: the precision of `_gathered_group_attention`.
+#
+# Pages move by DMAs the kernel issues itself, two buffers deep: block
+# j + 1 is in flight while block j is contracted. The grid runs over
+# slots and the loop over a slot's live blocks, so bytes and time follow
+# the cached length rounded up to a block, not the table's length.
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, page_size):
+# Key columns (tokens x KV heads) of one block. The scores of a block
+# are a float32 [H, columns] value; on a v5e at Mistral-7B's decode
+# shape (16 slots of 1.1k-2.6k tokens, 32 / 8 heads of 128, page 16) the
+# kernel took 0.348 / 0.256 / 0.239 ms at 512 / 1024 / 2048 columns.
+_BLOCK_KEY_COLUMNS = 2048
+
+
+def paged_block_vmem_bytes(ppb, h, hkv, d, page, itemsize):
+    """VMEM the block-table decode kernel needs at `ppb` pages a block,
+    from its shapes: K and V blocks in two buffers each, the float32
+    scores, probabilities and mask of one block with the probabilities'
+    copy in the pool's dtype, the double-buffered q/out blocks and the
+    float32 accumulator."""
+    columns = ppb * page * hkv
+    buffers = 2 * 2 * columns * d * itemsize
+    products = h * columns * (3 * 4 + itemsize)
+    q_out = 2 * 2 * h * d * itemsize + h * d * 4
+    return buffers + products + q_out
+
+
+def paged_pages_per_block(h, hkv, d, page, itemsize, pages_per_seq):
+    """Pages a block of the block-table decode kernel: the largest power
+    of two, at most a slot's table, whose block stays within
+    `_BLOCK_KEY_COLUMNS` and the scoped VMEM limit."""
+    ppb = 1
+    while (2 * ppb <= pages_per_seq
+           and 2 * ppb * page * hkv <= _BLOCK_KEY_COLUMNS
+           and paged_block_vmem_bytes(2 * ppb, h, hkv, d, page, itemsize)
+           <= _VMEM_SCOPED_BYTES):
+        ppb *= 2
+    return ppb
+
+
+def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sem, *, scale, page_size, hkv, ppb):
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
+    h, d = q_ref.shape[1:]
+    rep = h // hkv
+    rows = page_size * hkv                 # key columns of one page
+    i32 = np.int32
     ctx = lens_ref[b]
+    n_pages = jax.lax.div(ctx + i32(page_size - 1), i32(page_size))
+    n_blocks = jax.lax.div(n_pages + i32(ppb - 1), i32(ppb))
 
-    @pl.when(j * page_size < ctx)
-    def _compute():
-        # Mosaic's dot lowering has no batched-dim support, so the
-        # per-head contraction is expressed as VPU multiply+reduce —
-        # for decode (1 query token, small pages) the MXU has nothing
-        # to tile anyway.
-        q = q_ref[0].astype(jnp.float32)   # (H, D)
-        k = k_ref[0].astype(jnp.float32)   # (page, H, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.sum(q[None, :, :] * k, axis=-1) * np.float32(scale)  # (page, H)
-        tok = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(tok < ctx, s, _NEG_INF)
+    def page_copy(blk, buf, i):
+        """The K and V DMAs of page `i` of block `blk` into buffer
+        `buf`. An ordinal past the slot's last live page re-reads that
+        page (its columns are masked), so every block is `ppb` pages to
+        start and to wait for."""
+        o = jnp.minimum(blk * i32(ppb) + i, n_pages - i32(1))
+        src = pl.ds(pl.multiple_of(tables_ref[b, o] * i32(rows), rows), rows)
+        dst = pl.ds(pl.multiple_of(i * i32(rows), rows), rows)
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[buf, dst],
+                                      sem.at[buf, i32(0)]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[buf, dst],
+                                      sem.at[buf, i32(1)]))
 
-        m_prev = m_scr[:, 0]                       # (H,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        p = jnp.exp(s - m_new[None, :])            # (page, H)
+    def each_page(do):
+        # A rolled loop: the kernel is traced and lowered once a layer,
+        # and 2 * ppb descriptors unrolled at each of three sites cost a
+        # 16-layer decode program 22 s of set-up on the chip's host.
+        # `while_loop`, because with x64 on a `fori_loop` over constant
+        # bounds counts in int64, which Mosaic does not lower.
+        def body(i):
+            do(i)
+            return i + i32(1)
+        jax.lax.while_loop(lambda i: i < i32(ppb), body, i32(0))
+
+    def start_block(blk, buf):
+        each_page(lambda i: [c.start() for c in page_copy(blk, buf, i)])
+
+    @pl.when(n_blocks > i32(0))
+    def _first():
+        start_block(i32(0), i32(0))
+
+    q = q_ref[0]                                           # (H, D)
+
+    def block(blk, carry):
+        m_prev, l_prev, acc = carry                        # (H,1) (H,1) (H,D)
+        buf = jax.lax.rem(blk, i32(2))
+
+        @pl.when(blk + i32(1) < n_blocks)
+        def _prefetch():
+            start_block(blk + i32(1), i32(1) - buf)
+
+        each_page(lambda i: [c.wait() for c in page_copy(blk, buf, i)])
+        k = k_buf[buf]                                     # (columns, D)
+        v = v_buf[buf]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=mxu_precision(q, k)) * np.float32(scale)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        tok = blk * i32(ppb * page_size) + jax.lax.div(col, i32(hkv))
+        mine = jax.lax.rem(col, i32(hkv)) == jax.lax.div(row, i32(rep))
+        s = jnp.where(mine & (tok < ctx), s, _NEG_INF)     # (H, columns)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0] * alpha + jnp.sum(p, axis=0)
-        pv = jnp.sum(p[:, :, None] * v, axis=0)    # (H, D)
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + pv
-        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=mxu_precision(v))
+        return m_new, l_new, acc * alpha + pv
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = l_scr[:, 0]
-        safe_l = jnp.where(l == np.float32(0.0), np.float32(1.0), l)
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        i32(0), n_blocks, block,
+        (jnp.full((h, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+    safe_l = jnp.where(l == np.float32(0.0), np.float32(1.0), l)
+    o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, block_tables, context_lens,
                             scale, interpret=False):
-    """H == Hkv path. q: [B, H, D] → [B, H, D]."""
+    """q: [B, H, D] → [B, H, D]; H a multiple of the pool's Hkv. A slot
+    with no cached token gives zeros."""
     b, h, d = q.shape
-    page = k_pages.shape[1]
-    pages_per_seq = block_tables.shape[1]
-
+    _, page, hkv, _ = k_pages.shape
+    ppb = paged_pages_per_block(h, hkv, d, page, k_pages.dtype.itemsize,
+                                block_tables.shape[1])
+    columns = ppb * page * hkv
+    q_spec = pl.BlockSpec((1, h, d), lambda b_, tr, lr: (b_, _Z, _Z))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda b_, j, tr, lr: (b_, _Z, _Z)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda b_, j, tr, lr: (tr[b_, j], _Z, _Z, _Z)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda b_, j, tr, lr: (tr[b_, j], _Z, _Z, _Z)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda b_, j, tr, lr: (b_, _Z, _Z)),
+        grid=(b,),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((2, columns, d), k_pages.dtype),
+            pltpu.VMEM((2, columns, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale=scale, page_size=page)
-    # pages are indexed per (b, j); flatten K/V page dims stay as-is
-    kq = k_pages.reshape(k_pages.shape[0], page, h, d)
-    vq = v_pages.reshape(v_pages.shape[0], page, h, d)
+    kernel = functools.partial(_paged_kernel, scale=scale, page_size=page,
+                               hkv=hkv, ppb=ppb)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, kq, vq)
+      q, k_pages.reshape(-1, d), v_pages.reshape(-1, d))
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
@@ -219,7 +316,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     k_pages/v_pages: [num_pages, page_size, n_kv_heads, D]
     block_tables: [B, pages_per_seq] int32 page ids per sequence
     context_lens: [B] int32 valid token counts
-    Returns [B, H, D].
+    Returns [B, H, D]. The Pallas kernel takes any whole group ratio
+    H // n_kv_heads at D % 128 == 0, H % 8 == 0 (`paged_gate_reason`);
+    the rest goes through the XLA block-table path.
     """
     d = q.shape[-1]
     sc = scale if scale is not None else 1.0 / pymath.sqrt(d)
@@ -541,8 +640,6 @@ def paged_attention_varq(q, k_pages, v_pages, block_tables, kv_lens,
 # 16 MiB scoped VMEM limit (jax 0.9.0 / libtpu 0.0.34, described
 # v5e:2x2). Eight queries keep them at 2 MiB.
 VARQ_Q_CHUNK = 8
-# Scoped VMEM the TPU compiler grants a kernel by default on v5e.
-_VMEM_SCOPED_BYTES = 16 * 1024 * 1024
 
 
 def varq_vmem_bytes(qb, h, d, page, itemsize):

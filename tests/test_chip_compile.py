@@ -104,6 +104,51 @@ def test_paged_attention_block_tables(chip):
         ((B, PAGES_PER_SEQ), I32), ((B,), I32))
 
 
+# The attention of one decode step at the GQA geometries of the
+# benchmark's `mistral7b-sessions-closed` (16 slots x 256 pages) and
+# `granite4h-chat-open` (64 x 128): this step's K/V written into a pool
+# of 4096 pages of 16 x 8 x 128, then 32 query heads attended through
+# the block-table kernel. The kernel takes the pool as it lies: its 2-D
+# view is a bitcast, nothing of the pool's size is copied and no
+# [slots x pages_per_seq, page, Hkv, D] table is gathered.
+@pytest.mark.parametrize("slots,pps", [(16, 256), (64, 128)],
+                         ids=["mistral7b-sessions-closed",
+                              "granite4h-chat-open"])
+def test_paged_attention_block_tables_gqa(chip, slots, pps):
+    pool, kv_heads = 4096, 8
+    ppb = pa.paged_pages_per_block(H, kv_heads, D, PAGE, 2, pps)
+    assert ppb == 16
+    assert pa.paged_block_vmem_bytes(ppb, H, kv_heads, D, PAGE, 2) \
+        <= pa._VMEM_SCOPED_BYTES
+
+    def step(kp, vp, bt, cl, q, k, v):
+        pidx = bt[jnp.arange(slots, dtype=I32), cl // PAGE]
+        kp = kp.at[pidx, cl % PAGE].set(k)
+        vp = vp.at[pidx, cl % PAGE].set(v)
+        return pa._paged_attention_pallas(q, kp, vp, bt, cl + 1,
+                                          SCALE), kp, vp
+    pages = ((pool, PAGE, kv_heads, D), BF16)
+    new = ((slots, kv_heads, D), BF16)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in (
+        pages, pages, ((slots, pps), I32), ((slots,), I32),
+        ((slots, H, D), BF16), new, new)]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " f64[" not in text and " s64[" not in text
+    # a gathered table is a temporary of slots x pages_per_seq pages
+    # (134 MB at 16 x 256, whose shape is the pool's own); the pools are
+    # donated and updated where they lie
+    table = slots * pps * PAGE * kv_heads * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < table // 16
+    if slots * pps != pool:
+        assert f"[{slots * pps},{PAGE},{kv_heads},{D}]" not in text
+    assert f"bf16[{pool * PAGE * kv_heads},{D}]" in text    # the 2-D view
+    for shape in (f"bf16[{pool},{PAGE},{kv_heads},{D}]",
+                  f"bf16[{pool * PAGE * kv_heads},{D}]"):
+        assert not re.search(r"= " + re.escape(shape) + r"\S* copy\(", text)
+
+
 def test_paged_attention_ragged(chip):
     _compile(chip, lambda q, k, v, ln, *m: pa._paged_attention_ragged_pallas(
         q, k, v, ln, m, SCALE, False),
@@ -264,6 +309,10 @@ def test_granite_decode_step_at_real_size(chip, granite):
     assert len(updates) == 9, len(updates)  # one fusion a Mamba layer
     assert not re.search(r"= " + re.escape(state) + r"\S* copy\(", text)
     assert text.count("ragged-dot-none") >= 20      # 2 a layer
+    # the one attention layer decodes through the block-table kernel:
+    # no table of every slot's pages is gathered
+    assert f"[{B * pps},16,8,128]" not in text
+    assert re.search(r"bf16\[%d,32,128\]\S* custom-call\(" % B, text)
 
 
 def test_granite_largest_prefill_at_real_size(chip, granite):

@@ -86,31 +86,36 @@ class TestVarqKernel:
             _restore_flags(old)
 
     def test_single_token_spans_match_decode_kernel_bitwise(self):
-        """q_lens == 1 everywhere degenerates to the decode kernel —
-        bitwise, since the mixed kernel runs the same online-softmax
-        math over the same page grid."""
+        """q_lens == 1 everywhere degenerates to the ragged decode
+        kernel — bitwise, since the mixed kernel runs the same
+        online-softmax math over the same page grid. The block-table
+        decode kernel contracts a block of pages on the MXU, so it
+        agrees to float32 rounding."""
         import jax.numpy as jnp
         old = _interpret_flags()
         try:
             from paddle_tpu.kernels.paged_attention import (
-                paged_attention, paged_attention_ragged_varq,
-                RaggedMetaBuilder)
+                paged_attention, paged_attention_ragged,
+                paged_attention_ragged_varq, RaggedMetaBuilder)
             rs = np.random.RandomState(1)
             kp, vp, tables, trash = self._setup(rs)
             B = 3
             q = jnp.asarray(rs.randn(B, 1, 8, 128).astype("f") * 0.3)
             kv_lens = np.asarray([30, 9, 17], np.int32)
             ones = np.ones((B,), np.int32)
-            o_dec = paged_attention(q[:, 0], kp, vp,
-                                    jnp.asarray(tables), kv_lens)
             builder = RaggedMetaBuilder(B, 6, 8, trash)
             for b in range(B):
                 builder.set_slot(b, tables[b], int(kv_lens[b]))
-            o_v = paged_attention_ragged_varq(
-                q, kp, vp, kv_lens, ones,
-                {k: v.copy() for k, v in builder.meta().items()})
+            meta = lambda: {k: v.copy() for k, v in builder.meta().items()}
+            o_dec = paged_attention_ragged(q[:, 0], kp, vp, kv_lens, meta())
+            o_v = paged_attention_ragged_varq(q, kp, vp, kv_lens, ones,
+                                              meta())
             assert np.array_equal(np.asarray(o_dec),
                                   np.asarray(o_v)[:, 0])
+            o_bt = paged_attention(q[:, 0], kp, vp, jnp.asarray(tables),
+                                   kv_lens)
+            np.testing.assert_allclose(np.asarray(o_bt), np.asarray(o_dec),
+                                       rtol=0, atol=1e-6)
         finally:
             _restore_flags(old)
 

@@ -11,7 +11,7 @@ cross-cutting concerns the step classes don't:
   persistent grad shards.
 - **footprint telemetry**: ``mem.params_bytes{scope}`` /
   ``mem.opt_state_bytes{scope}`` come from the step classes; the
-  engine re-exports them plus the plan description so the bench can
+  engine re-exports them plus the plan description so a test can
   assert the sharding actually bought the memory it claims — FROM the
   JSONL sink, not from trust.
 - **deployment**: ``save_bundle``/``load_bundle`` serialize the

@@ -3,8 +3,8 @@ whole composition — mesh axes, ZeRO stage, pipeline schedule, overlap
 knobs — and renders it three ways:
 
 - a ``jax.sharding.Mesh`` (``build_mesh``) the step classes execute on;
-- a canonical topology string (``topology()``) humans and benches pass
-  around (``bench.py --train --mesh data=4,model=2``);
+- a canonical topology string (``topology()``) humans and tools pass
+  around (``HybridParallelPlan.from_spec("data=4,model=2")``);
 - a fingerprint dict (``fingerprint()``) that JOINS the AOT bundle
   identity (hybrid/aot.py): a serialized train step is only valid on
   the exact mesh topology it was partitioned for, so topology drift

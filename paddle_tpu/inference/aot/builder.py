@@ -268,7 +268,7 @@ class EngineBuilder:
         over the trash page, single-token spans) — the fallback when
         the steering prompt for this bucket cannot fit max_seq_len.
         Keep the signature tuple and operand dtypes in lockstep with
-        the dispatcher; the coldstart bench's zero-compile assertion
+        the dispatcher; tests/test_mixed_step.py's zero-compile case
         guards the pairing."""
         import jax.numpy as jnp
         cb._ensure_ready()

@@ -27,7 +27,7 @@ Instrumented out of the box: fleet.DistTrainStep / PipelineTrainStep
 collective bytes), distributed.collective (per-op call/byte accounting),
 inference.ContinuousBatchingPredictor (queue depth, page utilization,
 TTFT / per-token latency, admissions/evictions/rejections), the Trainer
-loop, bench.py, the elastic launcher (per-rank heartbeats), and the
+loop, the elastic launcher (per-rank heartbeats), and the
 fault-tolerance layer (robustness.* counters: anomalies skipped,
 checkpoint retries/fallbacks, deadline evictions, shed requests,
 watchdog trips, injected faults — docs/ROBUSTNESS.md). The fleet layer

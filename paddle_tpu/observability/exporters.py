@@ -2,8 +2,8 @@
 
 Three sinks, one schema:
 - JsonlExporter      — append-only JSONL file, one sample per line; the
-                       shared schema of runtime telemetry, bench.py
-                       timings and tools/metrics_report.py.
+                       shared schema of runtime telemetry and the
+                       report tools (tools/metrics_report.py).
 - PrometheusExporter — text-format snapshot (/metrics style) for pull
                        scrapers.
 - TensorBoardExporter— scalars through utils/tbwriter.LogWriter (the
@@ -112,7 +112,7 @@ class JsonlExporter:
             self._maybe_rotate_locked()
 
     def write_record(self, rec: dict):
-        """Escape hatch for one-off records (bench.py run metadata,
+        """Escape hatch for one-off records (a run's own metadata,
         tracing span lines) that share the telemetry file but aren't
         registry series. Silent no-op once closed — late writers at
         interpreter teardown must not explode."""

@@ -25,7 +25,7 @@ Every decision is one evidence-carrying ``{"kind": "control"}`` JSONL
 record — rule fired, action, parameters, the input snapshot it was
 decided on, and the cooldown it armed — so the autopilot is auditable
 (and replayable: tools/trace_replay.py rebuild_timeline reconstructs
-the pool state from the records alone; the bench acceptance test
+the pool state from the records alone; tests/test_trace_replay.py
 asserts the reconstruction matches reality). Flap damping is explicit:
 per-rule cooldowns, the autoscale demand EWMA (the same half-life the
 SLO fast window uses), and a consecutive-quiet-ticks gate on scale-in.
@@ -84,7 +84,7 @@ class PoolController:
     `spawn` is the scale-out factory: a zero-arg callable returning a
     ready predictor (or None when capacity is exhausted). Without it
     the controller can still revive replicas it drained itself.
-    `now_fn` is injectable so tests (and the replay bench) drive a
+    `now_fn` is injectable so tests (the trace replay's too) drive a
     synthetic clock; nothing here touches a device.
     """
 

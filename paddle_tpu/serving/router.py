@@ -11,8 +11,8 @@ is what a real multi-host pool keeps) behind a router that:
   leading run of those keys (its pool probably still caches the
   prefix's K/V → admission skips prefill work). Ties and cold prompts
   fall back to least-loaded (queued+running work estimate:
-  Σ prompt_len + max_new). ``policy="random"`` is the control arm the
-  bench compares against.
+  Σ prompt_len + max_new). ``policy="random"`` is the control arm a
+  comparison of routing policies runs against.
 - **streams tokens** — every request gets a :class:`RequestHandle`
   whose `stream()` yields the replica's StreamEvents as decode ticks
   complete; `result()` blocks for the terminal status; `cancel()`
@@ -462,7 +462,7 @@ class Router:
     ``replica0..N``.
 
     `policy`: "affinity" (default) | "least_loaded" | "random" (the
-    bench's control arm). `tier_weights` switches every replica's
+    control arm). `tier_weights` switches every replica's
     admission queue to weighted fair queueing (scheduler.py).
     """
 

@@ -3,7 +3,7 @@
 Offline sandbox: downloads are impossible, so dataset classes load from a
 local `data_file` when given one and otherwise raise with instructions;
 `FakeData` provides a synthetic ImageNet-shaped dataset for benchmarks
-(this is what bench.py/config #1 uses until real data is mounted).
+(synthetic pixels: what a run uses until real data is mounted).
 """
 from __future__ import annotations
 

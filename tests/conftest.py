@@ -8,6 +8,8 @@ no directory and no threshold of their own.
 """
 import os
 
+import pytest
+
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
@@ -87,18 +89,12 @@ _SLOW_TESTS = (
     "test_moe.py::test_scatter_vs_dense_dispatch_parity",
     "test_pp_memory.py::test_pipeline_table",
     "test_models_nlp.py::TestBertHeads::test_mlm_trains",
-    # second tier (the first pass still overran the 870s canonical
-    # window at ~82%): end-to-end scenario benches whose subsystems
-    # keep full unit/integration coverage in the default run, plus the
-    # 4-10s parity tail — each area retains at least one smoke
-    "test_robustness.py::TestChaosBench::test_chaos_recovery",
-    "test_fleet.py::test_bench_fleet_smoke",
     # third tier (PR 13: the canonical window tightened back to ~835s
     # body + ~35s interpreter teardown vs the 870s budget): the five
     # heaviest remaining tests, each leaving fast siblings in its
     # subsystem (pallas keeps flash_mask_fast_path_parity +
     # grad_parity_interpret; hybrid TP keeps model_axis_comm + the
-    # bench smoke; diffusion pipeline keeps text_encoder_shapes +
+    # two-axis llama step; diffusion pipeline keeps text_encoder_shapes +
     # ddim_step; continuous batching and MoE keep their many others)
     "test_pallas_train.py::test_flash_mask_dropout_bf16_gqa_train",
     "test_hybrid.py::TestTensorParallel::"
@@ -109,12 +105,6 @@ _SLOW_TESTS = (
     "test_moe.py::test_moe_dense_equivalence_single_expert",
     "test_robustness.py::TestTrainerPreemption::"
     "test_sigterm_drain_deadline_bounds_exit",
-    "test_serving_frontend.py::TestMultiTenantBenchSection::"
-    "test_serve_mt_bench_acceptance_from_telemetry",
-    # PR 16: the full two-arm replay acceptance (controller vs static
-    # under the spike, ~3-5 min) — the --smoke arm stays tier-1
-    "test_trace_replay.py::TestReplayAcceptance::"
-    "test_replay_full_acceptance_from_telemetry",
     "test_train_fastpath.py::TestFusedEagerParity::"
     "test_matches_per_param[SGD-kw0]",
     "test_train_fastpath.py::TestQuantizedComm::"
@@ -124,14 +114,14 @@ _SLOW_TESTS = (
     # canonical body crept back over ~835s + ~35s teardown vs the 870s
     # window): the heaviest spec tests plus the 3-10s generation
     # parity tail, each leaving fast siblings in the default run
-    # (chunk interplay keeps greedy_spec_bitwise_parity + the bench
-    # smoke, whose warm-start arm serves spec over chunk-capable
-    # geometry; the rejection-sampling statistical check and the
+    # (chunk interplay keeps greedy_spec_bitwise_parity + the
+    # spec+sampling bundle's warm start; the rejection-sampling
+    # statistical check and the
     # cross-path sampled-parity regression keep verify_spans_greedy,
     # the fused-filter equivalence, and the serve-loop determinism
     # tests; generation keeps ragged_prompts_match_solo,
-    # top_k1_equals_greedy, eos_early_stop, the CB parity family, and
-    # the serve bench smoke; beam keeps its scored/batched siblings)
+    # top_k1_equals_greedy, eos_early_stop and the CB parity family;
+    # beam keeps its scored/batched siblings)
     "test_spec_decode.py::TestSpecServeLoop::"
     "test_spec_and_sampling_with_chunked_prefill",
     "test_spec_decode.py::TestSamplingKernels::"
@@ -207,37 +197,22 @@ _SLOW_TESTS = (
     # (static-reference plain decode, spec-verify) move to tier 2 —
     # tier 1 keeps the serve_stream TP=2-vs-TP=1 parity, the
     # head-sharded pool invariants, the topology-invalidation round
-    # trip, and the bench --tp 2 --smoke arm (which re-asserts bitwise
-    # parity and model-axis comm bytes from JSONL)
+    # trip, and the TP-2 bundle's warm start (bitwise the one-device
+    # replica's tokens)
     "test_tp_serving.py::TestTPGreedyParity::test_plain_decode_parity",
     "test_tp_serving.py::TestTPGreedyParity::test_spec_verify_parity",
-    # PR 19: the canonical body crept to ~841s of the 870s window and
-    # the mixed-bench section's p99 latency-RATIO assertions started
-    # flaking at that load margin (passes in isolation). It joins the
-    # other end-to-end bench acceptances in tier 2; tier 1 keeps the
-    # whole chunked-prefill unit/parity family in test_mixed_step.py
-    # (parity_with_unchunked_and_telemetry, bucket adaptivity, deadline
-    # page-free, zero-compile capture) plus the varq kernel tests.
-    "test_mixed_step.py::TestMixedBenchSection::"
-    "test_serve_mixed_bench_smoke",
-    # PR 20: the full two-role disaggregated waterfall (its synthetic
-    # stage/waterfall twins and the unified-pool propagation test stay
-    # tier-1, and the bench --disagg --smoke arm asserts the same
-    # one-trace/stage-sum invariants end-to-end)
-    "test_request_tracing.py::TestDisaggWaterfallSlow::"
-    "test_two_role_pool_one_trace_with_handoff_stages",
     # PR 20 window trim (the canonical body crept to ~908s vs the 870s
     # budget): the heaviest remaining parity/round-trip tests, each
-    # leaving a fast sibling or an end-to-end bench smoke in tier 1 —
+    # leaving a fast sibling in tier 1 —
     # TP serving keeps telemetry/comm accounting, the head-sharded pool
-    # invariants, topology invalidation, and the bench --tp 2 --smoke
-    # arm (bitwise parity re-asserted from JSONL); chunked prefill
+    # invariants, topology invalidation, and the TP-2 bundle's warm
+    # start (bitwise the one-device replica's tokens); chunked prefill
     # keeps parity_with_unchunked_and_telemetry; serving fastpath keeps
     # the queue-policy + prefix-cache + admission families; MoE keeps
     # [gshard]; pallas keeps mask_fast_path + grad_parity_interpret;
     # lint keeps the zero-findings gate + the CLI subprocess smoke;
     # diffusion keeps text_encoder_shapes + ddim_step; hybrid keeps
-    # model_axis_comm + the bench mesh smoke
+    # model_axis_comm + the two-axis llama step
     "test_tp_serving.py::TestTPGreedyParity::test_serve_stream_parity",
     "test_tp_serving.py::TestTPGreedyParity::test_chunked_prefill_parity",
     "test_mixed_step.py::TestChunkedPrefill::"
@@ -265,3 +240,101 @@ def pytest_collection_modifyitems(config, items):
         nid = item.nodeid
         if any(s in nid for s in _SLOW_TESTS):
             item.add_marker(_pt.mark.slow)
+
+
+# ---------------------------------------------------------------------------
+# Trainer workers under the real launcher (the slow recovery and fleet
+# tests): one worker script, parametrised by the fault it arms.
+# ---------------------------------------------------------------------------
+_TRAINER_WORKER = """
+import json, os, sys, time
+hb_path = os.environ.get("PADDLE_RANK_HEARTBEAT")
+
+
+def boot_beat(phase):
+    # raw early beats: the launcher must see progress before
+    # paddle_tpu's own heartbeat can be imported
+    if hb_path:
+        with open(hb_path, "a") as f:
+            f.write(json.dumps({{"ts": time.time(), "kind": "heartbeat",
+                                "phase": phase, "pid": os.getpid(),
+                                "rank": os.environ.get("RANK", "0")}})
+                    + chr(10))
+
+
+boot_beat("boot")
+sys.path.insert(0, {repo!r})
+# dp_degree virtual devices a rank, so a rank's comm telemetry is real
+os.environ["XLA_FLAGS"] = \
+    "--xla_force_host_platform_device_count={dp_degree}"
+import jax
+jax.config.update("jax_platforms", "cpu")
+import numpy as np
+import paddle_tpu as paddle
+import paddle_tpu.nn.functional as F
+from paddle_tpu import nn
+from paddle_tpu.trainer import Trainer, TrainingArguments
+boot_beat("imports_done")
+rank = int(os.environ.get("RANK", "0"))
+world = int(os.environ.get("WORLD_SIZE", "1"))
+epoch = int(os.environ.get("PADDLE_RESTART_EPOCH", "0"))
+if {fault_epochs} is None or epoch in {fault_epochs}:
+    paddle.set_flags({{"fault_injection": {fault!r}}})
+paddle.seed(rank)
+model = nn.Sequential(nn.Linear(8, 32), nn.Tanh(), nn.Linear(32, 4))
+opt = paddle.optimizer.AdamW(learning_rate=1e-2,
+                             parameters=model.parameters())
+boot_beat("model_built")
+
+
+def data_fn(start):
+    def gen():
+        s = start
+        while True:
+            time.sleep({step_s})
+            rs = np.random.RandomState(s)
+            yield (paddle.to_tensor(rs.randn(16, 8).astype(np.float32)),
+                   paddle.to_tensor(rs.randn(16, 4).astype(np.float32)))
+            s += 1
+    return gen()
+
+
+out_dir = os.path.join({out!r}, "rank%d" % rank)
+# the JOB's step budget is fixed: each live rank takes an equal share
+args = TrainingArguments(output_dir=out_dir,
+                         max_steps={total_steps} // world,
+                         logging_steps=1, save_steps={save_steps},
+                         dp_degree={dp_degree})
+res = Trainer(model, opt, lambda o, y: F.mse_loss(o, y), args, data_fn,
+              tokens_per_batch=16).train(resume=True)
+with open(os.path.join(out_dir, "result_e%d.json" % epoch), "w") as f:
+    json.dump({{"rank": rank, "world": world,
+               "start_step": res["start_step"],
+               "final_step": res["final_step"]}}, f)
+"""
+
+
+
+@pytest.fixture
+def launch_trainer_workers(tmp_path):
+    """Run Trainer workers under the real launcher, logs in
+    `<tmp_path>/log`; returns its exit code and every result file the
+    workers wrote."""
+    def run(launcher_args, *, fault, fault_epochs, total_steps,
+            save_steps, step_s=0, dp_degree=1):
+        import glob
+        import json
+        from paddle_tpu.distributed.launch.main import parse_args, launch
+        script = tmp_path / "worker.py"
+        script.write_text(_TRAINER_WORKER.format(
+            repo=os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))),
+            out=str(tmp_path), fault=fault, fault_epochs=fault_epochs,
+            total_steps=total_steps, save_steps=save_steps,
+            step_s=step_s, dp_degree=dp_degree))
+        rc = launch(parse_args(launcher_args + [
+            "--heartbeat_interval", "0.25", "--restart_backoff", "0.05",
+            "--log_dir", str(tmp_path / "log"), str(script)]))
+        return rc, [json.load(open(p)) for p in sorted(glob.glob(
+            str(tmp_path / "rank*" / "result_e*.json")))]
+    return run

@@ -18,9 +18,9 @@ Invariant coverage (ISSUE 18 satellites):
 - per-role RuntimeConfig overlays (for_role) and stage_cost shapes;
 - per-role AOT bundles: warm start on a role+topology match, reason
   `role` on mismatch (strict raises, non-strict self-heals), prefill
-  builds clamp the capture budget to 1 token;
-- the bench.py --serve --disagg smoke arm staying green end-to-end
-  (full spike sweep marked slow).
+  builds clamp the capture budget to 1 token.
+(That every request of a two-role pool is one connected trace:
+tests/test_request_tracing.py.)
 """
 import time
 
@@ -531,56 +531,3 @@ class TestRoleBundle:
                            capture_forward=False,
                            runtime_config=rc.for_role("decode"))
         assert b2.max_new_tokens == 16
-
-
-# ---------------------------------------------------------------------------
-# bench smoke arm
-# ---------------------------------------------------------------------------
-def _load_bench():
-    import importlib.util
-    import os
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_disagg", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-class TestDisaggBenchSection:
-    def test_serve_disagg_bench_smoke(self, tmp_path, capsys):
-        """bench.py --serve --disagg --smoke end-to-end: the 1-prefill
-        + 1-decode fleet vs the unified fleet, greedy parity and the
-        handoff claims asserted from the emitted JSONL."""
-        import json
-        bench = _load_bench()
-        out = str(tmp_path / "disagg.jsonl")
-        assert bench.serve_bench(["--disagg", "--smoke",
-                                  "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = json.loads(line)
-        assert rec["metric"] == "serve_disagg_handoffs"
-        assert rec["value"] >= 1
-        assert rec["aux"]["greedy_parity"] is True
-        assert rec["aux"]["handoff_bytes"] > 0
-        arms = {json.loads(ln)["arm"]: json.loads(ln)
-                for ln in open(out) if ln.strip()
-                and json.loads(ln).get("kind") == "disagg_arm"}
-        assert set(arms) == {"disagg", "unified"}
-        assert arms["disagg"]["handoff"]["fallbacks"] == 0
-
-    @pytest.mark.slow
-    def test_serve_disagg_bench_full(self, tmp_path, capsys):
-        """The full spike sweep (3 arms): decode p99 inter-token stays
-        within the bounded flatness factor of the no-spike baseline
-        while the unified control arm takes the spike unshielded."""
-        import json
-        bench = _load_bench()
-        out = str(tmp_path / "disagg_full.jsonl")
-        assert bench.serve_bench(["--disagg", "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = json.loads(line)
-        assert rec["metric"] == "serve_disagg_itl_p99_spike_over_baseline"
-        assert rec["aux"]["handoffs"]["fallbacks"] == 0

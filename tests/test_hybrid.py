@@ -742,51 +742,67 @@ class TestAutotuneHybrid:
 
 
 # ===========================================================================
-# the 2-axis hybrid bench smoke (tier-1 acceptance)
+# two axes at once: ZeRO-3 over data, TP over model, one llama step
 # ===========================================================================
-class TestHybridBench:
-    def test_bench_train_mesh_smoke(self, tmp_path, capsys):
-        """`bench.py --train --mesh data=4,model=2`: ZeRO-3 + TP +
-        1F1B-scheduled hybrid step on the 8 XLA CPU devices — loss
-        parity, per-axis comm split, sharded footprints, and the
-        topology-fingerprinted AOT round trip, all asserted FROM the
-        JSONL sink."""
-        import bench
-        out = str(tmp_path / "hybrid.jsonl")
-        # --no-fleet: the launcher-driven fleet-observability arm is a
-        # multi-process ~1-2 min scenario — covered by the slow-marked
-        # tests/test_fleet.py::test_bench_fleet_smoke
-        rc = bench.train_bench(["--steps", "2", "--mesh",
-                                "data=4,model=2", "--out", out,
-                                "--no-fleet"])
-        assert rc == 0
-        recs = [json.loads(l) for l in open(out) if l.strip()]
-        hb = [r for r in recs if r.get("kind") == "hybrid_train_bench"]
-        assert len(hb) == 1
-        r = hb[0]
-        assert r["mesh"] == "data=4,model=2"
-        assert r["zero_stage"] == 3 and r["schedule"] == "1F1B"
-        assert all(r["checks"].values()), r["checks"]
-        # per-axis split FROM the sink record
-        assert r["comm_bytes_axis"]["data"] > 0
-        assert r["comm_bytes_axis"]["model"] > 0
-        fp = r["footprint"]
-        assert fp["params_bytes"]["per_replica"] \
-            < fp["params_bytes"]["global"]
-        assert fp["opt_state_bytes"]["per_replica"] \
-            < fp["opt_state_bytes"]["global"]
-        # the registry export carries the footprint gauges too
-        mg = [x for x in recs if x.get("name") == "mem.params_bytes"]
-        assert {s["labels"]["scope"] for s in mg} >= {"global",
-                                                      "per_replica"}
-        # stdout result line
-        res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert res["metric"] == "hybrid_train_smoke"
-        assert res["value"] == 1
+class TestTwoAxisLlama:
+    def test_zero3_tp_llama_loss_parity_comm_split_and_footprint(self):
+        """`data=4,model=2`, ZeRO-3, a TP llama through HybridTrainStep
+        on the 8 virtual devices: the loss curve is the unsharded
+        reference's (sharding is a layout decision), `comm.bytes`
+        carries BOTH a data-axis and a model-axis share, and the
+        per-replica parameter and optimizer-state footprints are under
+        the global ones."""
+        from paddle_tpu.jit import TrainStep
+        from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+                                       LlamaPretrainingCriterion)
+        crit = LlamaPretrainingCriterion(LlamaConfig.tiny())
+        loss_fn = lambda lg, lb: crit(lg, lb)
+        ids = np.random.RandomState(0).randint(1, 256, (8, 32))
+        x = lambda: paddle.to_tensor(ids)
+
+        def axis_bytes():
+            out = {}
+            for s in obs.get_registry().counter("comm.bytes").samples():
+                ax = s.labels.get("axis", "?")
+                out[ax] = out.get(ax, 0) + s.value
+            return out
+
+        paddle.seed(0)
+        ref = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+        rstep = TrainStep(ref, paddle.optimizer.AdamW(
+            1e-3, parameters=ref.parameters()), loss_fn)
+        ref_losses = [float(rstep(x(), x())) for _ in range(2)]
+
+        plan = HybridParallelPlan.from_spec("data=4,model=2",
+                                            zero_stage=3, schedule="1F1B")
+        mesh = plan.build_mesh()
+        was = obs.enabled()
+        obs.enabled(True)
+        set_mesh(mesh)
+        try:
+            before = axis_bytes()
+            paddle.seed(0)
+            model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=True))
+            step = HybridTrainStep(
+                model, paddle.optimizer.AdamW(
+                    1e-3, parameters=model.parameters()),
+                loss_fn, plan=plan, mesh=mesh)
+            losses = [float(step(x(), x())) for _ in range(2)]
+            fp = step.footprint()
+            after = axis_bytes()
+        finally:
+            set_mesh(None)
+            obs.enabled(was)
+        np.testing.assert_allclose(losses, ref_losses, rtol=2e-3,
+                                   atol=2e-4)
+        moved = {k: after[k] - before.get(k, 0) for k in after}
+        assert moved.get("data", 0) > 0 and moved.get("model", 0) > 0
+        for k in ("params_bytes", "opt_state_bytes"):
+            assert fp[k]["per_replica"] < fp[k]["global"], (k, fp[k])
 
 
 # ===========================================================================
-# hybrid engine + AOT (small model — llama variants live in the bench)
+# hybrid engine + AOT (small model)
 # ===========================================================================
 class TestHybridEngine:
     def test_engine_routes_and_aot_round_trip(self, tmp_path):

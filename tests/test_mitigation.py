@@ -1,7 +1,7 @@
 """Mitigation controller tests — the straggler actuator driven as a
 pure state machine (fake clock, in-memory audit sink, no subprocesses).
 The end-to-end path (fleet detector -> controller -> kill -> elastic
-restart) is proven by bench.py --chaos --scenario straggler; these pin
+restart) is tests/test_robustness.py's slow TestLauncherRecovery; these pin
 the DECISION logic: action selection, cooldown, flap damping, the
 rank-0 / sole-stage-host / min-world edges, comm-wait inversion, and
 the audit-stream contract (contiguous seq, no silent paths)."""

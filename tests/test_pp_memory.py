@@ -126,8 +126,8 @@ def test_pipeline_table():
 
 
 class TestCostAnalysis:
-    """TrainStep.cost_analysis: XLA's cost model feeds the bench's
-    mfu_xla (fwd+bwd+update FLOPs, not the 6*N estimate)."""
+    """TrainStep.cost_analysis: XLA's cost model of the whole step
+    (fwd+bwd+update FLOPs, not the 6*N estimate)."""
 
     def test_trainstep_flops_positive_and_scales(self):
         from paddle_tpu import nn

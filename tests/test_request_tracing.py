@@ -4,14 +4,13 @@ decomposition, tail exemplars on latency histograms + SLO breach
 evidence, torn-free concurrent JSONL sink writes, and the
 trace_report cross-role waterfall.
 
-Tier-1 keeps the clock-free synthetic paths (handcrafted span dicts —
-sub-second, no model) plus one small unified-pool propagation test;
-the full two-role disaggregated waterfall is slow-marked via
-tests/conftest.py::_SLOW_TESTS (the bench smoke arm asserts the same
-invariants end-to-end).
+The clock-free synthetic paths (handcrafted span dicts, no model) run
+beside two live pools: a unified one and a two-role one, whose every
+request must be one connected trace.
 """
 import json
 import os
+import subprocess
 import sys
 import threading
 
@@ -387,15 +386,15 @@ class TestRouterPropagation:
         assert stats is not None
 
 
-class TestDisaggWaterfallSlow:
+class TestDisaggWaterfall:
     def test_two_role_pool_one_trace_with_handoff_stages(
             self, tmp_path):
-        """Full-fleet cross-role waterfall (slow-marked in
-        tests/conftest.py; the bench --disagg --smoke arm keeps the
-        tier-1 end-to-end coverage): every request is ONE trace
-        carrying both role spans, the decomposition includes the
-        handoff stages, and the rendered waterfall names both
-        replicas."""
+        """A two-role pool end to end: every completed request is ONE
+        trace (its `router.request` root the only parent-less span,
+        every parent id resolving inside it) carrying both role spans;
+        the decomposition includes the handoff stages; the TTFT
+        histogram's exemplars resolve to exported traces; and the
+        waterfall renders stdlib-only, naming both replicas."""
         path = str(tmp_path / "t.jsonl")
         obs.get_registry().reset()
         obs.configure(path)
@@ -408,6 +407,7 @@ class TestDisaggWaterfallSlow:
             for h in hs:
                 h.result(timeout=120)
             assert all(h.status == "ok" for h in hs)
+        obs_rt.maybe_export()
         obs.configure(None)
         spans = _spans(path)
         roots = [s for s in spans if s["name"] == "router.request"]
@@ -416,6 +416,10 @@ class TestDisaggWaterfallSlow:
         loaded = trace_report.load_spans(path)
         for r in roots:
             tr_spans = _connected(spans, r)
+            # a boundary that re-minted instead of adopting would
+            # leave a second parent-less span in the trace
+            assert [s["name"] for s in tr_spans
+                    if not s["parent"]] == ["router.request"]
             sreqs = [s for s in tr_spans
                      if s["name"] == "serve.request"]
             assert len(sreqs) == 2        # prefill-role + decode-role
@@ -432,3 +436,24 @@ class TestDisaggWaterfallSlow:
             assert "critical path" in out
             for rep in reps:
                 assert rep in out
+        # tail exemplars on the sink's histogram lines name real traces
+        traces = {r["trace"] for r in roots}
+        exemplars = {}
+        for line in open(path):
+            rec = json.loads(line)
+            if rec.get("kind") == "histogram" and rec.get("exemplars"):
+                exemplars.setdefault(rec["name"], set()).update(
+                    e["trace"] for e in rec["exemplars"])
+        assert exemplars["serving.router.ttft_seconds"] <= traces
+        assert all(v <= traces for v in exemplars.values()), exemplars
+        # the operator's entry point: the CLI, isolated mode
+        cli = subprocess.run(
+            [sys.executable, "-I",
+             os.path.join(os.path.dirname(os.path.dirname(
+                 os.path.abspath(__file__))), "tools",
+                 "trace_report.py"),
+             path, "--request", roots[0]["trace"]],
+            capture_output=True, text=True, timeout=120)
+        assert cli.returncode == 0, cli.stderr[-2000:]
+        assert roots[0]["trace"] in cli.stdout
+        assert "critical path" in cli.stdout

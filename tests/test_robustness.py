@@ -739,60 +739,105 @@ class TestLaunchBackoff:
 
 
 # ---------------------------------------------------------------------------
-# chaos smoke (bench.py --chaos, tier-1-safe quick mode)
+# recovery end to end: several faults in one Trainer run, and (slow)
+# through the real launcher with Trainer workers
 # ---------------------------------------------------------------------------
-class TestChaosBench:
-    def test_chaos_recovery(self, tmp_path, capsys):
-        import importlib.util
-        import json
-        repo = os.path.join(os.path.dirname(__file__), "..")
-        spec = importlib.util.spec_from_file_location(
-            "bench_chaos", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = str(tmp_path / "chaos.jsonl")
-        assert bench.chaos_bench(["--out", out]) == 0
-        rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["metric"] == "chaos_recovery" and rec["value"] == 1.0
-        assert all(rec["aux"]["checks"].values())
-        # the recovery evidence is in the sink, one schema with the
-        # other bench sections
-        names = set()
-        with open(out) as f:
-            for line in f:
-                try:
-                    names.add(json.loads(line).get("name"))
-                except json.JSONDecodeError:
-                    pass
-        assert {"robustness.ckpt_retries",
-                "robustness.anomalies_skipped"} <= names
+class TestTrainerRidesOutFaultsTogether:
+    def test_save_error_nan_step_and_slow_store_in_one_run(self,
+                                                           tmp_path):
+        """A transient checkpoint-save error, one NaN step and a store
+        whose every write stalls, armed together: the run completes,
+        the save was retried, the NaN step skipped, and the newest
+        checkpoint verifies and restores."""
+        from paddle_tpu.distributed.checkpoint import VerifiedCheckpointer
+        from paddle_tpu.framework.flags import flag_value
+        prev = {k: flag_value(k) for k in ("ckpt_retry_backoff_s",
+                                           "anomaly_guard")}
+        paddle.set_flags({
+            "fault_injection": "ckpt_save:step=2:err,nan_loss:step=3,"
+                               "ckpt_slow:times=0:sleep=0.25",
+            "ckpt_retry_backoff_s": 0.05, "anomaly_guard": True})
+        retries = _counter_total("robustness.ckpt_retries")
+        skipped = _counter_total("robustness.anomalies_skipped")
+        try:
+            res = _trainer(tmp_path, max_steps=6).train(resume=False)
+        finally:
+            paddle.set_flags(prev)
+        assert res["final_step"] == 6
+        assert math.isfinite(res["final_loss"])
+        assert res["anomalous_steps"] == 1
+        assert _counter_total("robustness.ckpt_retries") >= retries + 1
+        assert _counter_total("robustness.anomalies_skipped") \
+            >= skipped + 1
+        ckpt = VerifiedCheckpointer(str(tmp_path / "checkpoints"))
+        assert ckpt.latest_verified() == 6
+        restored = ckpt.restore_latest()
+        assert restored is not None
+        assert int(np.asarray(restored[1]["step"])) == 6
 
-    def test_chaos_mitigation_smoke(self, tmp_path, capsys):
-        """Tier-1 variant of the straggler scenario: the full launcher
-        A/B is slow-marked (it rides test_chaos_recovery's --scenario
-        all), so the default run drives the mitigation controller
-        clock-only through the same bench entry point and asserts the
-        audit + metric evidence lands in the sink."""
-        import importlib.util
+
+@pytest.mark.slow
+class TestLauncherRecovery:
+    def test_hung_rank_killed_and_resumed_from_verified_checkpoint(
+            self, tmp_path, launch_trainer_workers):
+        """A rank that wedges mid-run (alive pid, silent heartbeat) is
+        detected and killed by the launcher; the restarted worker
+        resumes from the last verified checkpoint and finishes."""
+        from paddle_tpu.distributed.checkpoint import VerifiedCheckpointer
+        hangs = _counter_total("robustness.hangs_detected")
+        # the timeout must exceed the worker's silent import window
+        rc, results = launch_trainer_workers(
+            ["--nproc_per_node", "1", "--max_restart", "2",
+                       "--hang_timeout", "15"],
+            fault="rank_hang:step=5:sleep=600", fault_epochs=(0,),
+            total_steps=8, save_steps=2, step_s=0)
+        assert rc == 0
+        assert _counter_total("robustness.hangs_detected") >= hangs + 1
+        assert [r["final_step"] for r in results] == [8]   # epoch 0 hung
+        assert results[0]["start_step"] > 0
+        ckpt = VerifiedCheckpointer(
+            str(tmp_path / "rank0" / "checkpoints"))
+        assert ckpt.latest_verified() == 8
+        g = obs.get_registry().get("robustness.mttr_seconds")
+        assert g is not None and [s.value for s in g.samples()]
+
+    def test_persistent_straggler_excluded_and_its_work_taken_over(
+            self, tmp_path, launch_trainer_workers):
+        """A rank that is slow in every epoch (a degraded host does not
+        heal on restart): the fleet detector's incident drives the
+        mitigation controller, the pod restarts without the rank, the
+        two survivors finish the job's fixed step budget from their
+        own checkpoints, and every decision is in control.jsonl with a
+        contiguous sequence."""
         import json
-        repo = os.path.join(os.path.dirname(__file__), "..")
-        spec = importlib.util.spec_from_file_location(
-            "bench_chaos_smoke", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = str(tmp_path / "chaos_smoke.jsonl")
-        assert bench.chaos_bench(["--scenario", "straggler", "--smoke",
-                                  "--out", out]) == 0
-        rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["metric"] == "chaos_recovery" and rec["value"] == 1.0
-        assert all(rec["aux"]["checks"].values()), rec["aux"]["checks"]
-        # the mitigation decision evidence is in the sink
-        names = set()
-        with open(out) as f:
-            for line in f:
-                try:
-                    names.add(json.loads(line).get("name"))
-                except json.JSONDecodeError:
-                    pass
-        assert "robustness.mitigation.actions" in names
-        assert "robustness.mitigation.incidents" in names
+        total = 12
+        detected = _counter_total("robustness.stragglers_detected")
+        rc, results = launch_trainer_workers(
+            ["--nproc_per_node", "3", "--max_restart", "2",
+                       "--straggler_factor", "2.0",
+                       "--straggler_steps", "2",
+                       "--mitigation", "exclude",
+                       "--mitigation_cooldown", "5"],
+            fault="rank_slow:times=0:rank=2:factor=8.0",
+            fault_epochs=None, total_steps=total, save_steps=1,
+            step_s=1.0)
+        assert rc == 0
+        assert _counter_total("robustness.stragglers_detected") \
+            >= detected + 1
+        # excluded: results written under a world of two, resumed
+        assert any(r["world"] == 2 and r["start_step"] > 0
+                   for r in results)
+        # work conserved: the survivors' furthest steps add up to the
+        # budget (the excluded rank's partial steps are discarded)
+        furthest = {}
+        for r in results:
+            if r["world"] == 2:
+                furthest[r["rank"]] = max(furthest.get(r["rank"], 0),
+                                          r["final_step"])
+        assert sum(furthest.values()) == total
+        audit = [json.loads(ln) for ln in
+                 open(tmp_path / "log" / "control.jsonl") if ln.strip()]
+        assert "exclude_restart" in [r["action"] for r in audit]
+        assert [r["seq"] for r in audit] \
+            == list(range(1, len(audit) + 1))
+        assert all(r["kind"] == "control" for r in audit)

@@ -11,6 +11,8 @@ Covers, against the continuous-batching predictor:
 - rejection + head-of-line-skip behavior under page pressure;
 - token-for-token decode parity with model.generate;
 - the incremental ragged-meta builder vs the from-scratch flatten;
+- the serving series (prefill, first token, prefix hits and misses)
+  reaching the JSONL sink;
 - the windowed-segment-mean 'area' pooling precision fix.
 """
 import numpy as np
@@ -328,36 +330,45 @@ class TestRaggedMetaBuilder:
             set_flags({k.removeprefix("FLAGS_"): v for k, v in old.items()})
 
 
-class TestServeBenchSection:
-    def test_serve_bench_smoke(self, tmp_path, capsys):
-        """bench.py --serve must stay runnable and emit the serving
-        sweep through the JSONL schema (the fast path can't silently
-        regress to the host round-trip without this number moving)."""
-        import importlib.util
-        import json as _json
-        import os
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_serve", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = str(tmp_path / "serve.jsonl")
-        assert bench.serve_bench(["--loads", "2", "--max-new", "3",
-                                  "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = _json.loads(line)
-        assert rec["metric"] == "serve_cb_decode_tokens_per_sec"
-        assert rec["value"] > 0
-        lvl = rec["aux"]["levels"][0]
-        assert lvl["new_tokens"] == 2 * 3
-        assert lvl["prefills"] + lvl["prefix_hits"] >= 2
-        # the sweep's serving series landed in the shared JSONL schema
-        names = {(_json.loads(ln).get("name"))
-                 for ln in open(out) if ln.strip()}
-        assert "serving.prefill_seconds" in names
-        assert "serving.ttft_seconds" in names
-        assert "serving.prefix_cache_misses" in names
+class TestServingSeriesReachTheSink:
+    def test_prefill_and_prefix_series_in_jsonl(self, tmp_path):
+        """A serve run with a shared prefix leaves the series the
+        report tools and autotune read in the JSONL sink: prefill and
+        first-token histograms, prefix-cache hits and misses."""
+        import json
+        import paddle_tpu.observability as obs
+        from paddle_tpu.inference import ContinuousBatchingPredictor
+        from paddle_tpu.observability import runtime as obs_rt
+        model = _model()
+        rng = np.random.RandomState(0)
+        shared = rng.randint(2, 256, (8,)).tolist()
+        prompts = [shared + rng.randint(2, 256, (n,)).tolist()
+                   for n in (5, 9)] + [rng.randint(2, 256, (7,)).tolist()]
+        path = str(tmp_path / "serve.jsonl")
+        was = obs.enabled()
+        obs.enabled(True)
+        obs.get_registry().reset()
+        obs.configure(path)
+        try:
+            cb = ContinuousBatchingPredictor(model, max_batch_size=1,
+                                             page_size=8, max_seq_len=64)
+            outs = cb.generate(prompts, max_new_tokens=3)
+            obs_rt.maybe_export()
+        finally:
+            obs.configure(None)
+            obs.enabled(was)
+        assert [len(o) for o in outs] == [3, 3, 3]
+        last = {}
+        for line in open(path):
+            rec = json.loads(line)
+            if rec.get("name"):
+                last[rec["name"]] = rec
+        assert last["serving.prefill_seconds"]["count"] >= 2
+        assert last["serving.ttft_seconds"]["count"] == 3
+        assert last["serving.prefix_cache_hits"]["value"] \
+            == cb.stats["prefix_hits"] + cb.stats["prefix_partial_hits"]
+        assert last["serving.prefix_cache_hits"]["value"] >= 1
+        assert last["serving.prefix_cache_misses"]["value"] >= 1
 
 
 class TestAreaPoolingPrecision:

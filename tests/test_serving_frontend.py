@@ -13,10 +13,7 @@ Invariant coverage (ISSUE 6 satellites):
   and consecutive failures eject the replica;
 - generate_stream yields the first token before the full sequence's
   decode completes (span timestamps) and cancellation mid-stream
-  returns the request's KV pages to the pool;
-- the multi-tenant bench scenario's acceptance claims (affinity beats
-  random routing; WFQ holds hi-tier p99 TTFT within 2x unloaded under
-  a flood while FIFO does not) verified FROM THE JSONL TELEMETRY.
+  returns the request's KV pages to the pool.
 """
 import json
 import os
@@ -567,99 +564,48 @@ class TestRouter:
         assert reg.get("serving.autoscale.ttft_burn") is not None
 
 
-# ---------------------------------------------------------------------------
-# multi-tenant bench scenario: acceptance from the JSONL telemetry
-# ---------------------------------------------------------------------------
-class TestMultiTenantBenchSection:
-    def test_serve_mt_bench_acceptance_from_telemetry(self, tmp_path,
-                                                      capsys):
-        """ACCEPTANCE (ISSUE 6): 2 replicas, zipf prefix reuse, 2
-        priority tiers on the CPU tiny model — (a) affinity routing
-        yields strictly more prefix-cache hits than random on the same
-        trace; (b) under a low-tier flood, WFQ holds hi-tier p99 TTFT
-        within 2x its unloaded value while the FIFO baseline does not.
-        Both claims are asserted from the JSONL telemetry file, not
-        from in-process state."""
+class TestReportsOnARoutedRun:
+    def test_router_tier_replica_and_autoscale_sections_render(
+            self, tmp_path):
+        """The operator's two readers on the JSONL sink of a routed,
+        tiered run: metrics_report has the router, per-tier and
+        autoscale views, trace_report the per-tier SLO split and the
+        per-replica table."""
         import importlib.util
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_mt", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = str(tmp_path / "mt.jsonl")
-        assert bench.serve_bench(["--multitenant", "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = json.loads(line)
-        assert rec["metric"] == "serve_mt_wfq_hi_ttft_p99_ratio"
+        from paddle_tpu.observability import runtime as obs_rt
 
-        routing, tier_recs, summary = {}, [], None
-        autoscale = None
-        for ln in open(out):
-            if not ln.strip():
-                continue
-            r = json.loads(ln)
-            if r.get("kind") == "serve_mt_routing":
-                routing[r["policy"]] = r
-            elif r.get("kind") == "serve_mt_tier":
-                tier_recs.append(r)
-            elif r.get("kind") == "serve_mt_summary":
-                summary = r
-            elif r.get("kind") == "autoscale":
-                autoscale = r
+        def tool(name):
+            spec = importlib.util.spec_from_file_location(
+                f"_frontend_{name}", os.path.join(
+                    os.path.dirname(__file__), "..", "tools",
+                    f"{name}.py"))
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            return mod
 
-        # (a) affinity strictly beats random on the same trace, and the
-        # hits concentrate (zipf sessions stick to their home replica)
-        assert routing["affinity"]["prefix_hits"] \
-            > routing["random"]["prefix_hits"]
-        per_rep = routing["affinity"]["per_replica"]
-        assert max(per_rep.values()) >= sum(per_rep.values()) * 0.5
+        path = str(tmp_path / "routed.jsonl")
+        obs.get_registry().reset()
+        obs.configure(path)
+        model = _serve_model()
+        with Router([model, model],
+                    tier_weights={"interactive": 8, "batch": 1}, seed=0,
+                    max_batch_size=2, page_size=8,
+                    max_seq_len=64) as router:
+            hs = [router.submit(p, max_new_tokens=3,
+                                tier=("interactive", "batch")[i % 2])
+                  for i, p in enumerate(_prompts(4))]
+            assert all(h.result(timeout=120) for h in hs)
+            sig = router.autoscale()
+            assert sig["desired_replicas"] >= 1
+        obs_rt.maybe_export()
+        obs.configure(None)
 
-        # (b) weighted-fair bounds the interactive tier under flood;
-        # FIFO does not
-        by = {(r["mode"], r["tier"]): r for r in tier_recs}
-        unloaded = by[("unloaded", "interactive")]["ttft_p99_s"]
-        wfq = by[("wfq", "interactive")]["ttft_p99_s"]
-        fifo = by[("fifo", "interactive")]["ttft_p99_s"]
-        assert unloaded > 0
-        assert wfq <= 2.0 * unloaded
-        assert fifo > 2.0 * unloaded
-        assert fifo > wfq
-        assert summary is not None
-        assert summary["wfq_hi_ttft_p99_ratio"] <= 2.0
-        assert summary["fifo_hi_ttft_p99_ratio"] > 2.0
-
-        # the autoscale record rode the same sink (scaler-signal path)
-        assert autoscale is not None
-        assert autoscale["desired_replicas"] >= 1
-        assert "replica_utilization" in autoscale
-
-        # span lines carry the replica/tier labels the report tools
-        # split on
-        span_labels = [json.loads(ln)["labels"]
-                       for ln in open(out)
-                       if json.loads(ln).get("kind") == "span"
-                       and json.loads(ln).get("name") == "serve.request"]
-        assert any("replica" in lb for lb in span_labels)
-        assert any("tier" in lb for lb in span_labels)
-
-        # the report tools render the per-tier / per-replica breakdown
-        # from that same file (fairness claim readable offline)
-        spec = importlib.util.spec_from_file_location(
-            "trace_report_mt", os.path.join(repo, "tools",
-                                            "trace_report.py"))
-        trr = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(trr)
-        text = trr.render(trr.load_spans(out))
+        trr = tool("trace_report")
+        text = trr.render(trr.load_spans(path))
         assert "per-tier SLO" in text and "interactive TTFT" in text
         assert "per-replica" in text and "replica0" in text
-
-        spec = importlib.util.spec_from_file_location(
-            "metrics_report_mt", os.path.join(repo, "tools",
-                                              "metrics_report.py"))
-        mrr = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mrr)
-        with open(out) as f:
+        mrr = tool("metrics_report")
+        with open(path) as f:
             text = mrr.render(mrr.parse(f, spans={}), None)
         assert "serving front end (router)" in text
         assert "interactive" in text and "autoscale signals" in text

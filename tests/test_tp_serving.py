@@ -12,8 +12,8 @@ Covers, on the 8-device XLA CPU host mesh (conftest):
 - the _paged_gate per-shard tiling judgment (reason tp_head_shard);
 - per-topology AOT bundles: a warm start at a different tp_degree
   invalidates with reason `topology` (strict raises, non-strict
-  self-heals to the requested degree);
-- the bench.py --serve --tp smoke arm staying green end-to-end.
+  self-heals to the requested degree), and the matching degree
+  warm-starts without compiling, bitwise the one-device replica.
 """
 import numpy as np
 import pytest
@@ -276,35 +276,25 @@ class TestTopologyBundle:
         finally:
             obs.enabled(was)
 
-
-# ---------------------------------------------------------------------------
-# bench smoke arm
-# ---------------------------------------------------------------------------
-class TestTPBenchSection:
-    def test_serve_tp_bench_smoke(self, tmp_path, capsys):
-        """bench.py --serve --tp 2 --smoke end-to-end: TP sweep + warm
-        arm run, and every acceptance check (bitwise parity, model-axis
-        comm bytes per tick, zero-compile warm start, topology
-        invalidation) holds — all asserted from the emitted JSONL."""
-        import importlib.util
-        import json as _json
-        import os
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_tp", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = str(tmp_path / "tp.jsonl")
-        assert bench.serve_bench(["--tp", "2", "--smoke",
-                                  "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = _json.loads(line)
-        assert rec["metric"] == "serve_tp_tokens_per_s_ratio"
-        checks = rec["aux"]["checks"]
-        assert checks and all(checks.values()), checks
-        # the sharded sweep's series landed in the shared JSONL schema
-        names = {_json.loads(ln).get("name")
-                 for ln in open(out) if ln.strip()}
-        assert "comm.bytes" in names
-        assert "serving.tp.degree" in names
+    def test_tp2_bundle_warm_starts_zero_compile_with_tp1_parity(
+            self, tmp_path):
+        """The model=2 programs come back from their per-topology
+        bundle: a warm start serves from it alone, sharded over two
+        devices, and its greedy tokens are the one-device replica's."""
+        from paddle_tpu.framework.runtime_config import RuntimeConfig
+        from paddle_tpu.inference.aot import EngineBuilder, warm_start
+        model = _model()
+        rng = np.random.RandomState(0)
+        prompts = [rng.randint(2, 256, (n,)).tolist() for n in (12, 11)]
+        ref = _cb(model, tp=1).generate(prompts, max_new_tokens=8)
+        rc = RuntimeConfig(max_batch_size=2, page_size=8, max_seq_len=64,
+                           prompt_buckets=(16,), tp_degree=2)
+        path = str(tmp_path / "bundle")
+        EngineBuilder(model, batch_sizes=[1, 2], capture_forward=False,
+                      runtime_config=rc, eos_token_id=None).build(
+            path, wire_cache=False)
+        pred, eng = warm_start(model, path, wire_cache=False)
+        assert eng.warm and pred.tp == 2
+        assert pred.pool.kv_sharding is not None
+        assert pred.generate(prompts, max_new_tokens=8) == ref
+        assert eng.stats["hits"] > 0 and eng.stats["misses"] == 0

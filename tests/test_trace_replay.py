@@ -1,4 +1,4 @@
-"""tools/trace_replay.py + the --serve --replay bench arms (PR 16).
+"""tools/trace_replay.py and the control loop it replays (PR 16).
 
 - synthesize(): deterministic production-shaped traces — zipf sessions,
   tenant mix, the spike as EXTRA spike-tier load on top of base traffic
@@ -9,13 +9,10 @@
 - rebuild_timeline(): the control-decision audit replayer, including
   every inconsistency it must refuse.
 - CLI under `python -I` (stdlib-only, like every tools/ reader).
-- `bench.py --serve --replay --smoke`: the tier-1 loop exercise on the
-  checked-in fixture trace, asserted from the JSONL telemetry.
-- `bench.py --serve --replay` (slow): the full acceptance — under the
-  batch-tier spike the controller pool holds the declared interactive
-  p99 TTFT SLO while the static pool breaches it, decode inter-token
-  p99 stays flat, and the decision timeline reconstructs from the
-  {"kind": "control"} records alone.
+- the checked-in fixture trace through a real Router with its SLO
+  engine and PoolController on one injected clock: the
+  {"kind": "control"} records are contiguous, rebuild to the live end
+  state, and trace_report renders them.
 """
 import importlib.util
 import json
@@ -254,89 +251,107 @@ class TestCLIPythonI:
 
 
 # ---------------------------------------------------------------------------
-# bench arms
+# the fixture trace through router, SLO engine and controller
 # ---------------------------------------------------------------------------
-def _bench():
-    return _load("bench_replay", os.path.join(REPO, "bench.py"))
-
-
-class TestReplaySmokeBench:
-    def test_replay_smoke_loop_and_reports(self, tmp_path, capsys):
-        """Tier-1: the fixture trace through the controller-fronted
-        router — the control loop ticks, the audit stream replays
-        consistently, and both report tools render the new sections
-        under `python -I`, all from the JSONL telemetry file."""
-        bench = _bench()
+class TestReplayControlLoop:
+    def test_spike_drives_an_auditable_decision_stream(self, tr,
+                                                       tmp_path):
+        """The checked-in spike trace replayed against a real Router
+        whose PoolController and SLOEngine share one injected clock.
+        The declared target is one no request can meet, so the burn
+        (and with it every rule that fires) does not depend on how
+        fast this machine is. The `{"kind": "control"}` records in the
+        sink are contiguous, rebuild to the live end state, and the
+        report renders them stdlib-only."""
+        import paddle_tpu as paddle
+        import paddle_tpu.observability as obs
+        from paddle_tpu.observability import runtime as obs_rt
+        from paddle_tpu.observability.slo import SLOEngine, SLOSpec
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.inference import ContinuousBatchingPredictor
+        from paddle_tpu.serving import (Router, PoolController,
+                                        ControllerConfig)
+        _, reqs = tr.load_trace(os.path.join(
+            REPO, "tests", "fixtures", "trace_smoke.jsonl"))
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+        vocab = model.config.vocab_size
+        kw = dict(max_batch_size=1, page_size=8, max_seq_len=64)
+        clock = {"t": 1000.0}
+        now = lambda: clock["t"]
         out = str(tmp_path / "replay.jsonl")
-        assert bench.serve_bench(
-            ["--replay", "--smoke", "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = json.loads(line)
-        assert rec["metric"] == "serve_replay_control_decisions"
-        assert rec["aux"]["smoke"] is True
-        assert rec["aux"]["timeline_consistent"] is True
+        was = obs.enabled()
+        obs.enabled(True)
+        obs.get_registry().reset()
+        obs_rt.configure(out)
+        try:
+            spares = [ContinuousBatchingPredictor(model, name="spare0",
+                                                  **kw)]
+            engine = SLOEngine(
+                [SLOSpec("ttft", "serving.router.ttft_seconds",
+                         target=1e-9, objective=0.9),
+                 SLOSpec("ttft_interactive",
+                         "serving.router.ttft_seconds",
+                         target=1e-9, objective=0.9,
+                         labels={"tier": "interactive"},
+                         tier="interactive")],
+                fast_window_s=1.0, slow_window_s=10.0, now_fn=now)
+            with Router([ContinuousBatchingPredictor(
+                    model, name="replica0", **kw)],
+                    tier_weights={"interactive": 1, "batch": 1},
+                    seed=0) as router:
+                ctl = PoolController(
+                    router, slo_engine=engine,
+                    spawn=lambda: spares.pop() if spares else None,
+                    config=ControllerConfig(
+                        slo_name="ttft", shed_burn=1.2,
+                        scale_out_cooldown_s=0.2,
+                        shift_cooldown_s=0.3, max_replicas=2),
+                    now_fn=now)
+                statuses = {}
+                for r in reqs:
+                    # the trace's own arrival times, on the injected
+                    # clock: one control tick after every request
+                    clock["t"] = 1000.0 + float(r["t"])
+                    h = router.submit(
+                        tr.session_prompt(int(r["session"]),
+                                          int(r["prompt_len"]), vocab),
+                        max_new_tokens=int(r["max_new"]),
+                        tier=r["tier"])
+                    h.result(timeout=120)
+                    statuses[h.status] = statuses.get(h.status, 0) + 1
+                    ctl.tick()
+                live = {"pool_size": len(router.healthy()),
+                        "tier_weights": {
+                            k: float(v)
+                            for k, v in router.tier_weights.items()},
+                        "shed_tiers": sorted(router.shed_tiers)}
+                decisions = list(ctl.decisions)
+            obs_rt.maybe_export()
+        finally:
+            obs_rt.configure(None)
+            obs.enabled(was)
+
+        assert statuses.get("ok", 0) >= 1
+        assert sum(statuses.values()) == len(reqs)
+        rules = {d["rule"] for d in decisions}
+        assert {"init", "scale_out", "shed"} <= rules, rules
+        assert live["pool_size"] == 2                # the spare joined
+        assert statuses.get("shed", 0) >= 1          # batch refused
 
         recs = [json.loads(ln) for ln in open(out) if ln.strip()]
         ctrl = [r for r in recs if r.get("kind") == "control"]
-        assert ctrl and ctrl[0]["rule"] == "init"
-        arm = [r for r in recs if r.get("kind") == "serve_replay_arm"]
-        assert arm and arm[0]["arm"] == "controller"
-        assert arm[0]["requests"] > 0
+        assert ctrl == decisions                     # the sink has all
+        assert [r["seq"] for r in ctrl] \
+            == list(range(1, len(ctrl) + 1))
         assert [r for r in recs if r.get("kind") == "autoscale"]
+        rebuilt = tr.rebuild_timeline(recs)
+        assert {k: rebuilt[k] for k in live} == live
 
-        # the timeline replays from the file alone
-        tr_mod = _load("tr_smoke", TR_PATH)
-        tl = tr_mod.rebuild_timeline(recs)
-        assert tl["pool_size"] >= 1
-
-        # trace_report renders the control/SLO audit, stdlib-only
-        r = subprocess.run(
+        rep = subprocess.run(
             [sys.executable, "-I",
              os.path.join(REPO, "tools", "trace_report.py"), out],
             capture_output=True, text=True, timeout=120)
-        assert r.returncode == 0, r.stderr
-        assert "== control decisions ==" in r.stdout
-        assert "init" in r.stdout
-
-
-class TestReplayAcceptance:
-    def test_replay_full_acceptance_from_telemetry(self, tmp_path,
-                                                   capsys):
-        """ACCEPTANCE (ISSUE 16, slow): under the batch-tier spike the
-        controller holds the declared interactive p99 TTFT SLO while
-        the identical static pool breaches it; decode inter-token p99
-        stays flat; and the whole decision history replays from the
-        {"kind": "control"} records alone."""
-        bench = _bench()
-        out = str(tmp_path / "replay_full.jsonl")
-        assert bench.serve_bench(["--replay", "--out", out]) == 0
-        line = [ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")][-1]
-        rec = json.loads(line)
-        assert rec["metric"] == \
-            "serve_replay_static_over_controller_ttft_p99"
-        aux = rec["aux"]
-        assert aux["controller_within_slo"] is True
-        assert aux["static_breaches_slo"] is True
-        assert aux["itl_p99_spike_ratio"] < 2.0
-        assert aux["control_decisions"] > 0
-        assert aux["timeline_consistent"] is True
-
-        # the audit replays from the JSONL alone and matches the live
-        # end state the bench recorded
-        recs = [json.loads(ln) for ln in open(out) if ln.strip()]
-        tr_mod = _load("tr_full", TR_PATH)
-        tl = tr_mod.rebuild_timeline(recs)
-        live = [r for r in recs
-                if r.get("kind") == "serve_replay_timeline"][-1]
-        assert tl["pool_size"] == live["live"]["pool_size"]
-        assert tl["tier_weights"] == {
-            k: float(v)
-            for k, v in live["live"]["tier_weights"].items()}
-        assert tl["shed_tiers"] == live["live"]["shed_tiers"]
-        # both arms and the SLO declaration are on the record
-        arms = {r["arm"] for r in recs
-                if r.get("kind") == "serve_replay_arm"}
-        assert arms == {"controller", "static"}
-        assert [r for r in recs if r.get("kind") == "serve_replay_slo"]
+        assert rep.returncode == 0, rep.stderr
+        assert "== control decisions ==" in rep.stdout
+        assert "scale_out" in rep.stdout

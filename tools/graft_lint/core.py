@@ -3,7 +3,7 @@
 A pass is a callable taking (SourceFile, repo_root) and yielding
 Findings (file passes), or taking (repo_root,) alone (project passes —
 GL105, which scans a configured emission root independent of the CLI
-paths so `graft_lint.py paddle_tpu/` still validates bench.py's spans
+paths so a run over one sub-package still validates every span
 against the catalog).
 """
 from __future__ import annotations
@@ -259,8 +259,8 @@ def run_passes(paths: Sequence[str], repo_root: str,
         findings.extend(fn(repo_root, docs_override, file_cache))
 
     # inline suppressions. Project passes (GL105) anchor findings in
-    # files OUTSIDE the CLI path set (bench.py under the canonical
-    # `graft_lint.py paddle_tpu/` run), so parse those on demand — a
+    # files OUTSIDE the CLI path set (the rest of the package under a
+    # `graft_lint.py paddle_tpu/serving` run), so parse those on demand — a
     # sanction comment must work no matter which paths were passed.
     by_path = {sf.relpath: sf for sf in srcs}
     kept = []

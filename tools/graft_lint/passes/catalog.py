@@ -5,8 +5,8 @@ docs catalogs, and every catalog entry must still have an emission
 site — the catalog can never silently drift again (it did: PR 6/7/8
 each hand-repaired entries).
 
-Code side (AST over config.EMISSION_ROOTS — paddle_tpu/ + bench.py,
-independent of the CLI paths):
+Code side (AST over config.EMISSION_ROOTS — paddle_tpu/, independent
+of the CLI paths):
 - `counter("...")` / `gauge("...")` / `histogram("...")` first-arg
   string literals (module helpers and registry methods alike);
 - `span("...")` / `start_span("...")` / `traced("...")` literals,

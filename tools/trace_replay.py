@@ -16,14 +16,15 @@ This tool closes the gap in both directions:
   production traffic without shipping production prompts.
 - ``show``   — summarize a trace file.
 - ``timeline`` — rebuild the control-loop decision timeline from the
-  ``{"kind": "control"}`` records in a telemetry file; the bench
-  acceptance test asserts this reconstruction matches the live pool.
+  ``{"kind": "control"}`` records in a telemetry file;
+  tests/test_trace_replay.py asserts it matches the live pool.
 
 Trace format (JSONL): one ``{"kind": "trace_header"}`` line with the
 spec, then one ``{"kind": "trace_request"}`` line per request with
 arrival offset ``t`` (seconds from trace start), ``session``, ``tier``,
-``prompt_len``, ``max_new`` and ``phase`` ("base" | "spike"). Replay
-lives in bench.py (``--serve --replay``): prompts are derived
+``prompt_len``, ``max_new`` and ``phase`` ("base" | "spike"). A replay
+(tests/test_trace_replay.py drives one) submits each request to a
+Router at its arrival offset: prompts are derived
 deterministically from the session id so same-session requests share a
 prefix and exercise the router's affinity path.
 

@@ -65,7 +65,7 @@ SERVE_DEPTH = 8
 TRAIN_DEPTH = 2
 REPLICA_DEPTH = 2
 
-SERVE_KERNELS = ("_ragged_kernel",)
+SERVE_KERNELS = ("_paged_kernel",)
 TRAIN_KERNELS = ("_fwd_kernel", "_bwd_dkdv_kernel", "_bwd_dq_kernel",
                  "_rms_kernel")
 
@@ -116,6 +116,19 @@ def _fallbacks():
     from paddle_tpu.observability import metrics
     return int(sum(s.value for s in
                    metrics.counter("kernels.pallas_fallbacks").series()))
+
+
+def _decode_kernels():
+    """{kernel: count} of `kernels.paged_decode`: which kernel the
+    decode programs traced so far attend through."""
+    from paddle_tpu.observability import metrics
+    return {s.labels["kernel"]: int(s.value) for s in
+            metrics.counter("kernels.paged_decode").samples() if s.value}
+
+
+def _decode_kernels_since(before):
+    return {k: n - before.get(k, 0) for k, n in _decode_kernels().items()
+            if n > before.get(k, 0)}
 
 
 def _kernels_in(text, names):
@@ -228,7 +241,7 @@ def serve_phase(*, config, seed=0, dtype="bfloat16", max_batch_size=8,
 
     # -- kernel arm: the path a user gets ------------------------------
     _kernel_flags(True)
-    fb0 = _fallbacks()
+    fb0, dk0 = _fallbacks(), _decode_kernels()
     t0 = time.perf_counter()
     router = Router([model], **geometry)
     pred = router.replicas[0].predictor
@@ -248,6 +261,7 @@ def serve_phase(*, config, seed=0, dtype="bfloat16", max_batch_size=8,
     rec["statuses"] = statuses
     rec["tokens_generated"] = sum(len(g) for g in got)
     rec["use_ragged"] = bool(pred.use_ragged)
+    rec["decode_kernels_traced"] = _decode_kernels_since(dk0)
     rec["pallas_fallbacks"] = _fallbacks() - fb0
     rec["kv_pool_bytes"] = int(sum(a.nbytes for a in pred.pool.k + pred.pool.v))
     stats = dict(pred.stats)
@@ -309,7 +323,8 @@ def serve_phase(*, config, seed=0, dtype="bfloat16", max_batch_size=8,
         "oracle_is_plain_xla": not rec["oracle_has_tpu_custom_call"],
     }
     if require_kernels:
-        checks["use_ragged"] = rec["use_ragged"]
+        checks["block_table_kernel"] = \
+            set(rec["decode_kernels_traced"]) == {"paged_attention"}
         checks["no_fallbacks"] = rec["pallas_fallbacks"] == 0
         checks["kernel_in_decode"] = rec["decode_has_tpu_custom_call"] \
             and all(rec["decode_kernels"].values())
@@ -483,7 +498,7 @@ def tp_serve_phase(*, config, seed=0, dtype="bfloat16", tp=4,
     geometry = dict(max_batch_size=max_batch_size, page_size=page_size,
                     max_seq_len=max_seq_len)
     _kernel_flags(True)
-    fb0 = _fallbacks()
+    fb0, dk0 = _fallbacks(), _decode_kernels()
     one = ContinuousBatchingPredictor(model, tp_degree=1, **geometry)
     want = one.generate(prompts, max_new_tokens=new_tokens)
     del one
@@ -493,6 +508,7 @@ def tp_serve_phase(*, config, seed=0, dtype="bfloat16", tp=4,
     got = cb.generate(prompts, max_new_tokens=new_tokens)
     rec["tp_seconds_incl_compile"] = round(time.perf_counter() - t0, 2)
     rec["use_ragged"] = bool(cb.use_ragged)
+    rec["decode_kernels_traced"] = _decode_kernels_since(dk0)
     rec["pallas_fallbacks"] = _fallbacks() - fb0
     rec["tp_devices"] = [d.id for d in cb.tp_devices]
     rec["kv_shards"] = tp if cb.pool.kv_sharding is not None else 1
@@ -522,7 +538,8 @@ def tp_serve_phase(*, config, seed=0, dtype="bfloat16", tp=4,
         "every_device_holds_state": _all_in_use(rec["mem_per_device"]),
     }
     if require_kernels:
-        checks["use_ragged"] = rec["use_ragged"]
+        checks["block_table_kernel"] = \
+            set(rec["decode_kernels_traced"]) == {"paged_attention"}
         checks["no_fallbacks"] = rec["pallas_fallbacks"] == 0
         checks["kernel_in_decode"] = rec["decode_has_tpu_custom_call"]
     rec["checks"] = checks

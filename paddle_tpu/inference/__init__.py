@@ -459,8 +459,10 @@ class ContinuousBatchingPredictor:
       writes K/V, attends via the paged kernel, and arg-maxes the
       logits on device; the host dispatches step t+1 (feeding step t's
       device-resident token straight back in) BEFORE syncing step t's
-      token, so the device never idles on the host fetch. Ragged-grid
-      metadata is maintained incrementally (kernels.paged_attention.
+      token, so the device never idles on the host fetch. The paged
+      kernel finds a slot's live pages from the block table itself;
+      the ragged-grid metadata the span programs (mixed, verify) read
+      is maintained incrementally (kernels.paged_attention.
       RaggedMetaBuilder) — O(1) per step instead of a full rebuild.
     - **No head-of-line blocking.** Admission scans the whole queue for
       admissible requests instead of only the head; a large request
@@ -738,24 +740,6 @@ class ContinuousBatchingPredictor:
             self._tp_tok_bytes = (
                 2 * int(cfg.num_hidden_layers) * int(cfg.hidden_size)
                 * np.dtype(kv_dtype).itemsize)
-        # ragged-grid paged attention: only valid (slot, page) pairs
-        # enter the decode kernel's grid. "auto" enables it when a
-        # Pallas path exists and the ragged kernels' gate admits the
-        # head geometry (kernels.paged_attention.paged_gate_reason: MHA
-        # at D % 128 == 0, 8 heads a shard); the grid is the constant
-        # B * pages_per_seq so every decode step reuses one compile. A
-        # GQA model decodes through the block-table kernel, which takes
-        # a group of query heads a KV head and needs no host metadata.
-        if use_ragged == "auto":
-            from ..kernels._common import (use_pallas as _use_pallas,
-                                           pallas_interpret)
-            from ..kernels.paged_attention import paged_gate_reason
-            use_ragged = (
-                (_use_pallas() or pallas_interpret())
-                and paged_gate_reason(
-                    "paged_attention_ragged", cfg.num_attention_heads,
-                    cfg.num_key_value_heads, head_dim, self.tp) is None)
-        self.use_ragged = bool(use_ragged)
         # chunked prefill (docs/SERVING.md "Chunked prefill"): prompts
         # longer than the threshold are ingested as page-aligned chunks
         # through the MIXED prefill+decode program — one tick at a time,
@@ -798,12 +782,36 @@ class ContinuousBatchingPredictor:
         self._spec_k = max(0, int(spec_draft_tokens))
         self._ngram_max = max(1, int(spec_ngram_max))
         self.sampling_enabled = bool(sampling_enabled)
-        # the mixed and verify steps ride the ragged varq kernel, whose
-        # VMEM need grows with the span bucket: refuse a bucket the TPU
-        # compiler would refuse, here and by name, instead of at the
-        # first long prompt
+        # which paged kernel a program attends through. The single-token
+        # decode programs take the block-table kernel (`paged_attention`:
+        # live pages by its own DMAs, any group ratio, no host metadata)
+        # unless `use_ragged=True` is set by hand, which keeps them on
+        # `paged_attention_ragged` and its metadata operands. The
+        # programs with a query span (mixed, verify) ride the ragged varq
+        # kernel, which needs the metadata and MHA: "auto" gives it to
+        # them when a Pallas path exists, such a program is compiled in
+        # and the varq gate admits the head geometry (kernels.
+        # paged_attention.paged_gate_reason: H == Hkv, D % 128 == 0, 8
+        # heads a shard); else they attend through the XLA varq path.
+        # The metadata grid is the constant B * pages_per_seq, so every
+        # step reuses one compile.
         span = max(self._chunk_max, self._spec_k + 1 if self._spec_k else 0)
-        if self.use_ragged and span > 1:
+        if use_ragged == "auto":
+            from ..kernels._common import (use_pallas as _use_pallas,
+                                           pallas_interpret)
+            from ..kernels.paged_attention import paged_gate_reason
+            self.use_ragged = False
+            self.span_ragged = (
+                span > 1 and (_use_pallas() or pallas_interpret())
+                and paged_gate_reason(
+                    "paged_attention_ragged_varq", cfg.num_attention_heads,
+                    cfg.num_key_value_heads, head_dim, self.tp) is None)
+        else:
+            self.use_ragged = self.span_ragged = bool(use_ragged)
+        # the varq kernel's VMEM need grows with the span bucket: refuse
+        # a bucket the TPU compiler would refuse, here and by name,
+        # instead of at the first long prompt
+        if self.span_ragged and span > 1:
             from ..kernels._common import pallas_interpret
             from ..kernels.paged_attention import max_varq_span
             fit = max_varq_span(cfg.num_attention_heads // self.tp,
@@ -1107,10 +1115,10 @@ class ContinuousBatchingPredictor:
     def lower_decode_step(self):
         """The greedy decode step, lowered for this predictor's weights,
         pool and batch geometry exactly as the serve loop dispatches it
-        (ragged metadata included when the ragged kernel is on), without
-        running it: ``.as_text()`` shows whether the paged kernel is in
-        the program, ``.compile()`` gives its memory analysis and the
-        collectives a tensor-parallel replica got."""
+        (ragged metadata included under a hand-set `use_ragged=True`),
+        without running it: ``.as_text()`` shows whether the paged
+        kernel is in the program, ``.compile()`` gives its memory
+        analysis and the collectives a tensor-parallel replica got."""
         self._ensure_ready()
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
         meta = ()
@@ -1843,8 +1851,10 @@ class ContinuousBatchingPredictor:
         ctx = np.ones((self.B,), np.int32)   # inactive slots: 1 dummy tok
         last_tok_host = np.zeros((self.B,), np.int32)
         override = np.zeros((self.B,), bool)  # host token overrides device
+        # kept in step on every tick, decode ticks included, so that a
+        # span program finds it current; absent when no program reads it
         builder = RaggedMetaBuilder(self.B, self.pages_per_seq, self.page,
-                                    self._trash) if self.use_ragged \
+                                    self._trash) if self.span_ragged \
             else None
         # speculative decoding + sampling slot state: per-slot sampling
         # operand rows (greedy zeros), the host token history the
@@ -2651,6 +2661,7 @@ class ContinuousBatchingPredictor:
         if builder is not None:
             for b in active:
                 builder.advance_slot(b, int(ctx[b]) + 1)
+        if self.use_ragged:
             m = builder.meta()
             from ..kernels.paged_attention import RaggedMetaBuilder
             meta_args = tuple(m[k].copy() for k in RaggedMetaBuilder.FIELDS)

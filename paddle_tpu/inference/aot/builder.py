@@ -277,16 +277,7 @@ class EngineBuilder:
         span_ids = np.full((cb.B, qb), cb.pad_token_id, np.int32)
         q_lens = np.ones((cb.B,), np.int32)
         tok_in = jnp.asarray(np.zeros((cb.B,), np.int32))
-        meta_args = ()
-        if cb.use_ragged:
-            from ...kernels.paged_attention import RaggedMetaBuilder
-            mb = RaggedMetaBuilder(cb.B, cb.pages_per_seq, cb.page,
-                                   cb._trash)
-            for b in range(cb.B):
-                mb.clear_slot(b)
-            m = mb.meta()
-            meta_args = tuple(m[k].copy()
-                              for k in RaggedMetaBuilder.FIELDS)
+        meta_args = _idle_span_meta(cb)
         sig = ("mixed", qb, tables.shape,
                tuple(np.shape(x) for x in meta_args))
         _, _, new_k, new_v = cb._jit_call(
@@ -320,16 +311,7 @@ class EngineBuilder:
         ops = sampling_operands([None] * cb.B)
         samp = (ops["temperature"], ops["top_k"], ops["top_p"],
                 ops["seed"], np.zeros((cb.B,), np.int32))
-        meta_args = ()
-        if cb.use_ragged:
-            from ...kernels.paged_attention import RaggedMetaBuilder
-            mb = RaggedMetaBuilder(cb.B, cb.pages_per_seq, cb.page,
-                                   cb._trash)
-            for b in range(cb.B):
-                mb.clear_slot(b)
-            m = mb.meta()
-            meta_args = tuple(m[k].copy()
-                              for k in RaggedMetaBuilder.FIELDS)
+        meta_args = _idle_span_meta(cb)
         sig = ("spec", qs, tables.shape,
                tuple(np.shape(x) for x in meta_args))
         _, _, _, new_k, new_v = cb._jit_call(
@@ -369,6 +351,21 @@ class EngineBuilder:
         jf = fn if hasattr(fn, "lower") else jax.jit(fn)
         engine.compile_fallback(("custom", name), jf, args)
         sp.event("custom", name=name)
+
+
+def _idle_span_meta(cb):
+    """The ragged metadata operands of a span program (mixed, verify)
+    with every slot idle over the trash page, as the dispatcher would
+    pass them; none where the predictor's span programs take none
+    (`cb.span_ragged`)."""
+    if not cb.span_ragged:
+        return ()
+    from ...kernels.paged_attention import RaggedMetaBuilder
+    mb = RaggedMetaBuilder(cb.B, cb.pages_per_seq, cb.page, cb._trash)
+    for b in range(cb.B):
+        mb.clear_slot(b)
+    m = mb.meta()
+    return tuple(m[k].copy() for k in RaggedMetaBuilder.FIELDS)
 
 
 def build_engine(model, path: str, prompt_buckets=None,

@@ -87,6 +87,16 @@ def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
     return reason is None
 
 
+def _note_decode_kernel(kernel):
+    """Count which kernel a single-token decode attention was traced
+    with: ``kernels.paged_decode{kernel}``, `kernel` one of
+    "paged_attention", "paged_attention_ragged" or "xla". Like
+    `note_fallback` it runs at trace time only, once a layer of each
+    compiled decode program, and adds nothing to the program."""
+    from ..observability import metrics as _obsm
+    _obsm.counter("kernels.paged_decode").inc(kernel=kernel)
+
+
 # ---------------------------------------------------------------------------
 # XLA block-table path (any GQA ratio): the route of every geometry that
 # fails `_paged_gate`, of the CPU, and the numeric oracle of the kernels.
@@ -325,22 +335,32 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     interpret = interpret or pallas_interpret()
     if _paged_gate("paged_attention", q, k_pages, v_pages,
                    interpret):
+        _note_decode_kernel("paged_attention")
         return partitioned(
             lambda q_, k_, v_, bt, cl: _paged_attention_pallas(
                 q_, k_, v_, bt, cl, sc, interpret=interpret),
             [_Q_HEADS, _PAGE_HEADS, _PAGE_HEADS, None, None], _Q_HEADS,
             q, k_pages, v_pages, block_tables, context_lens)
+    _note_decode_kernel("xla")
     return _paged_attention_xla(q, k_pages, v_pages, block_tables,
                                 context_lens, sc)
 
 
 # ---------------------------------------------------------------------------
-# Ragged variant: the grid runs over ONLY the valid (sequence, page)
-# pairs (cf. PAPERS.md "Ragged Paged Attention"): no wasted DMA or
-# compute for short sequences in a mixed-length batch. Page metadata is
-# host-built (build_ragged_meta) and enters via scalar prefetch; the
-# flat entry count buckets to a power of two so serving steps reuse the
-# compiled kernel.
+# Ragged variant: the grid runs over the (sequence, page) pairs of
+# host-built metadata (build_ragged_meta / RaggedMetaBuilder), which
+# enters via scalar prefetch (cf. PAPERS.md "Ragged Paged Attention").
+#
+# Who still calls it: the serve loop's decode programs only under a
+# hand-set `use_ragged=True` (tools/chip_rehearsal.py, benchmarks/
+# rehearse.py, tests). `use_ragged="auto"` decodes every geometry
+# through the block-table kernel above, which reads live pages only and
+# contracts on the MXU; this one fetches a block every grid step of the
+# constant B * pages_per_seq grid and scores on the VPU in float32 (on a
+# v5e at 32 slots of 32 heads of 128 it reached a third of the live
+# bytes' roofline: PERF.md). The METADATA lives on: the variable-query
+# kernel below, which the mixed and verify steps ride, has no
+# block-table form.
 # ---------------------------------------------------------------------------
 
 def build_ragged_meta(block_tables, context_lens, page_size, bucket_to=None):
@@ -537,6 +557,7 @@ def paged_attention_ragged(q, k_pages, v_pages, context_lens, meta,
     sc = scale if scale is not None else 1.0 / pymath.sqrt(q.shape[-1])
     interpret = interpret or pallas_interpret()
     lens = jnp.asarray(context_lens, jnp.int32)
+    _note_decode_kernel("paged_attention_ragged")
     out = partitioned(
         lambda q_, k_, v_, ln, *m: _paged_attention_ragged_pallas(
             q_, k_, v_, ln, m, sc, interpret),

@@ -104,20 +104,24 @@ def test_paged_attention_block_tables(chip):
         ((B, PAGES_PER_SEQ), I32), ((B,), I32))
 
 
-# The attention of one decode step at the GQA geometries of the
-# benchmark's `mistral7b-sessions-closed` (16 slots x 256 pages) and
-# `granite4h-chat-open` (64 x 128): this step's K/V written into a pool
-# of 4096 pages of 16 x 8 x 128, then 32 query heads attended through
-# the block-table kernel. The kernel takes the pool as it lies: its 2-D
-# view is a bitcast, nothing of the pool's size is copied and no
-# [slots x pages_per_seq, page, Hkv, D] table is gathered.
-@pytest.mark.parametrize("slots,pps", [(16, 256), (64, 128)],
-                         ids=["mistral7b-sessions-closed",
-                              "granite4h-chat-open"])
-def test_paged_attention_block_tables_gqa(chip, slots, pps):
-    pool, kv_heads = 4096, 8
+# The attention of one decode step at the geometries of the benchmark's
+# cells: `dsllm7b-chat-open` (MHA, 32 slots x 64 pages on a pool of
+# 1536), `mistral7b-sessions-closed` (GQA 4:1, 16 x 256) and
+# `granite4h-chat-open` (GQA 4:1, 64 x 128), the last two on a pool of
+# 4096 pages of 16 x 8 x 128. This step's K/V is written into the pool,
+# then 32 query heads are attended through the block-table kernel. The
+# kernel takes the pool as it lies: its 2-D view is a bitcast, nothing
+# of the pool's size is copied and no [slots x pages_per_seq, page,
+# Hkv, D] table is gathered. A block is 2048 key columns whatever the
+# group: 4 pages of 32 KV heads, 16 pages of 8.
+@pytest.mark.parametrize("slots,pps,pool,kv_heads", [
+    (32, 64, 1536, 32), (16, 256, 4096, 8), (64, 128, 4096, 8)],
+    ids=["dsllm7b-chat-open", "mistral7b-sessions-closed",
+         "granite4h-chat-open"])
+def test_paged_attention_block_tables_cells(chip, slots, pps, pool,
+                                            kv_heads):
     ppb = pa.paged_pages_per_block(H, kv_heads, D, PAGE, 2, pps)
-    assert ppb == 16
+    assert ppb * PAGE * kv_heads == pa._BLOCK_KEY_COLUMNS == 2048
     assert pa.paged_block_vmem_bytes(ppb, H, kv_heads, D, PAGE, 2) \
         <= pa._VMEM_SCOPED_BYTES
 

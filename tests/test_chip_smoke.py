@@ -30,7 +30,7 @@ def interpret():
 
 
 def _tiny(**kw):
-    """The smallest config the ragged kernel's gates accept: 8 heads of
+    """The smallest config the paged kernels' gates accept: 8 heads of
     128 (H % 8 == 0, D % 128 == 0)."""
     base = dict(hidden_size=1024, num_attention_heads=8,
                 num_key_value_heads=8, num_hidden_layers=1,
@@ -47,7 +47,9 @@ def test_serve_phase_record(interpret):
     assert rec["ok"], rec["checks"]
     assert rec["requests"] == rec["completed"] == 6
     assert rec["tokens_generated"] == 6 * 4
-    assert rec["use_ragged"] and rec["pallas_fallbacks"] == 0
+    # "auto" decodes MHA through the block-table kernel, no metadata
+    assert not rec["use_ragged"] and rec["pallas_fallbacks"] == 0
+    assert set(rec["decode_kernels_traced"]) == {"paged_attention"}
     # the prefix cache did its three jobs: a full hit, and two partial
     # hits (one through copy-on-write)
     assert rec["prefix_hits"] == 1 and rec["prefix_partial_hits"] == 2

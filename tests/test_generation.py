@@ -726,10 +726,11 @@ class TestRaggedPagedAttention:
 
 
 def test_continuous_batching_ragged_decode_parity():
-    """round 5: the ragged-grid kernel drives the continuous-batching
-    decode (use_ragged auto-enables at H==Hkv, D%128==0) and stays
-    token-exact with the fixed-grid path and the static greedy
-    oracle."""
+    """Under `use_ragged="auto"` an MHA model (H == Hkv, D % 128 == 0)
+    decodes through the block-table kernel with no metadata operands;
+    the ragged-grid kernel (`use_ragged=True`, by hand) stays
+    token-exact with it, with `use_ragged=False` and with the static
+    greedy oracle."""
     from paddle_tpu.framework.flags import set_flags, get_flags
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.inference import (ContinuousBatchingPredictor,
@@ -748,8 +749,13 @@ def test_continuous_batching_ragged_decode_parity():
                    for n in (5, 11, 3, 8)]
         cb = ContinuousBatchingPredictor(model, max_batch_size=2,
                                          page_size=8, max_seq_len=48)
-        assert cb.use_ragged
+        assert not cb.use_ragged and not cb.span_ragged
         out = cb.generate(prompts, max_new_tokens=6)
+        cbr = ContinuousBatchingPredictor(model, max_batch_size=2,
+                                          page_size=8, max_seq_len=48,
+                                          use_ragged=True)
+        assert cbr.use_ragged and cbr.span_ragged
+        assert out == cbr.generate(prompts, max_new_tokens=6)
         cbf = ContinuousBatchingPredictor(model, max_batch_size=2,
                                           page_size=8, max_seq_len=48,
                                           use_ragged=False)

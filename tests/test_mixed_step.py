@@ -309,7 +309,10 @@ class TestChunkedPrefill:
 
     def test_parity_on_interpret_ragged_route(self):
         """The full mixed program through the interpret-mode Pallas
-        varq kernel (use_ragged auto-on) stays token-identical."""
+        varq kernel stays token-identical: under "auto" an MHA
+        predictor with chunked prefill keeps the metadata for its span
+        programs, one without has none anywhere, and `use_ragged=True`
+        (the ragged decode kernel) serves the same tokens."""
         old = _interpret_flags()
         try:
             from paddle_tpu.inference import ContinuousBatchingPredictor
@@ -322,14 +325,20 @@ class TestChunkedPrefill:
             cb0 = ContinuousBatchingPredictor(
                 model, max_batch_size=2, page_size=8, max_seq_len=64,
                 enable_prefix_cache=False)
-            assert cb0.use_ragged
+            assert not cb0.use_ragged and not cb0.span_ragged
             ref = cb0.generate(prompts, max_new_tokens=4)
             cb1 = ContinuousBatchingPredictor(
                 model, max_batch_size=2, page_size=8, max_seq_len=64,
                 enable_prefix_cache=False, prefill_chunk_tokens=8)
+            assert cb1.span_ragged and not cb1.use_ragged
             out = cb1.generate(prompts, max_new_tokens=4)
             assert out == ref
             assert cb1.stats["chunked_requests"] == 1
+            cb2 = ContinuousBatchingPredictor(
+                model, max_batch_size=2, page_size=8, max_seq_len=64,
+                enable_prefix_cache=False, prefill_chunk_tokens=8,
+                use_ragged=True)
+            assert cb2.generate(prompts, max_new_tokens=4) == ref
         finally:
             _restore_flags(old)
 

@@ -77,10 +77,14 @@ def static_cache_update(entry: StaticCacheEntry, k, v):
 class LayerCache(NamedTuple):
     """What one decoder layer keeps between steps, as its model's
     `cache_layout()` declares it. kind "kv": `shape` = (n_kv_heads,
-    head_dim), paged. kind "state": `shape` = ((d_conv - 1, channels),
-    (heads, head_dim, d_state)), one row a slot."""
+    head_dim), paged; a layer whose attention selects its keys by a
+    learned indexer also declares `index_dim`, the width of the one
+    index key a token it keeps in a third paged array under the same
+    page ids. kind "state": `shape` = ((d_conv - 1, channels), (heads,
+    head_dim, d_state)), one row a slot."""
     kind: str
     shape: tuple
+    index_dim: int = 0
 
 
 class LayerCaches(list):
@@ -136,7 +140,8 @@ class PagedKVPool:
     """
 
     def __init__(self, n_layers, num_pages, page_size, n_kv_heads,
-                 head_dim, dtype="float32", mesh=None, device=None):
+                 head_dim, dtype="float32", mesh=None, device=None,
+                 index_dim=0):
         import jax.numpy as jnp
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
@@ -147,6 +152,17 @@ class PagedKVPool:
                   for _ in range(n_layers)]
         self.v = [jnp.zeros(shape, dtype, device=device)
                   for _ in range(n_layers)]
+        # layers with an indexer: one index key a token, a third array a
+        # layer under the SAME page ids, so the allocator, the trash
+        # page and copy-on-write cover it with no table of its own. A
+        # key lies on whole 128-lane rows, zeros past `index_dim`: the
+        # TPU tiles the last axis to 128 lanes whatever it is told (a
+        # [.., 64] bfloat16 array occupies the same bytes), and a row
+        # the kernels can take as it lies spares a copy of the pool
+        lanes = -(-int(index_dim) // 128) * 128
+        self.index = [jnp.zeros((num_pages, page_size, lanes), dtype,
+                                device=device)
+                      for _ in range(n_layers if index_dim else 0)]
         # tensor-parallel serving: pages shard over the KV-head axis of
         # a 'model' mesh (the paged kernels are head-parallel by
         # construction, so every program variant composes). The host-
@@ -247,14 +263,12 @@ class PagedKVPool:
         import jax
         import numpy as np
         if not hasattr(self, "_copy_jit"):
-            def _copy(kl, vl, s, d):
-                return ([k.at[d].set(k[s]) for k in kl],
-                        [v.at[d].set(v[s]) for v in vl])
-            dn = (0, 1) if jax.default_backend() != "cpu" else ()
+            def _copy(pools, s, d):
+                return [[a.at[d].set(a[s]) for a in pool] for pool in pools]
+            dn = (0,) if jax.default_backend() != "cpu" else ()
             self._copy_jit = jax.jit(_copy, donate_argnums=dn)
-        self.k, self.v = self._copy_jit(self.k, self.v,
-                                        np.int32(src), np.int32(dst))
-        self.k, self.v = list(self.k), list(self.v)
+        self.k, self.v, self.index = self._copy_jit(
+            [self.k, self.v, self.index], np.int32(src), np.int32(dst))
 
     # ------------------------------------------------ disaggregation --
     def export_span(self, prompt, page_ids, next_token=None):
@@ -724,6 +738,12 @@ class PagedCacheEntry(NamedTuple):
     to `paged_cache_mixed_update_attend` (span K/V scatter + the
     variable-query ragged kernel) and `ragged_meta`, if present, must
     be built for the post-write lengths context_lens + q_lens.
+
+    `index_pages` (optional): [num_pages, page_size, lanes], the index
+    keys of a layer whose attention selects its keys
+    (`LayerCache.index_dim`, zero-padded to the pool's lanes), under
+    the same page ids; such a layer steps through
+    `paged_cache_sparse_update_attend`.
     """
     k_pages: object
     v_pages: object
@@ -731,6 +751,7 @@ class PagedCacheEntry(NamedTuple):
     context_lens: object
     ragged_meta: object = None
     q_lens: object = None
+    index_pages: object = None
 
 
 class StateCacheEntry(NamedTuple):
@@ -877,3 +898,40 @@ def paged_cache_mixed_update_attend(entry: PagedCacheEntry, q, k, v,
                                 entry.context_lens, entry.ragged_meta,
                                 entry.q_lens)
     return out, new_entry
+
+
+def paged_cache_sparse_update_attend(entry: PagedCacheEntry, q, k, v, qi, w,
+                                     ki, topk, scale=None):
+    """Decode-step contract of a layer with an indexer: write this
+    step's K, V and index key (one token a slot) at each slot's current
+    page position, score the slot's index keys for the query, select
+    the `topk` best exactly and attend over those alone
+    (kernels.paged_attention.paged_sparse_attention). q [B, 1, H, D];
+    k/v [B, 1, Hkv, D]; qi [B, 1, J, Di]; w [B, 1, J]; ki [B, 1, Di] →
+    (out [B, 1, H, D], updated entry, keys selected a slot [B] int32).
+    Gradients are not defined (serving path)."""
+    import jax.numpy as jnp
+    from ..ops._dispatch import apply
+    from ..kernels.paged_attention import (index_key_rows,
+                                           paged_sparse_attention)
+
+    def fn(kp, vp, ip, bt, cl, qv, kv, vv, qiv, wv, kiv):
+        page = kp.shape[1]
+        rows = jnp.arange(qv.shape[0])
+        at = (bt[rows, (cl // page).astype(jnp.int32)],
+              (cl % page).astype(jnp.int32))
+        kp2 = kp.at[at].set(kv[:, 0].astype(kp.dtype))
+        vp2 = vp.at[at].set(vv[:, 0].astype(vp.dtype))
+        ip2 = ip.at[at].set(index_key_rows(kiv[:, 0], ip))
+        out, keep = paged_sparse_attention(
+            qv[:, 0], kp2, vp2, ip2, qiv[:, 0], wv[:, 0],
+            bt, cl + 1, topk, scale)
+        return (out[:, None].astype(qv.dtype), kp2, vp2, ip2,
+                jnp.sum(keep, axis=1, dtype=jnp.int32))
+
+    out, kp2, vp2, ip2, n_sel = apply(
+        fn, entry.k_pages, entry.v_pages, entry.index_pages,
+        entry.block_table, entry.context_lens, q, k, v, qi, w, ki,
+        _name="paged_sparse_attention_decode")
+    return out, entry._replace(k_pages=kp2, v_pages=vp2,
+                               index_pages=ip2), n_sel

@@ -438,7 +438,12 @@ class ContinuousBatchingPredictor:
       threads both, donated alike. With recurrent layers the prefix
       cache is derived off and chunked prefill, speculative decoding,
       tensor parallelism and the prefill/decode roles are refused by
-      name (docs/SERVING.md "Hybrid models: state beside pages").
+      name (docs/SERVING.md "Hybrid models: state beside pages"). A
+      layer whose attention selects its keys by a learned indexer
+      declares an index key a token: a third page array under the same
+      page ids, threaded and donated with K and V; the same four are
+      refused and the prefix cache is derived off (docs/SERVING.md
+      "Sparse attention: an index cache beside the pages").
     - **Device-resident prefill.** Admission runs ONE jitted program
       per (batch, prompt-bucket) that embeds the causal/padding mask
       in-graph, runs the forward, computes the greedy next token for
@@ -645,17 +650,20 @@ class ContinuousBatchingPredictor:
         # K/V pages for attention layers (one geometry), a row a slot of
         # (conv window, SSM state) for recurrent ones
         self._layout = list(model.cache_layout())
-        kv_shapes = {c.shape for c in self._layout if c.kind == "kv"}
+        kv_shapes = {(c.shape, c.index_dim) for c in self._layout
+                     if c.kind == "kv"}
         state_shapes = {c.shape for c in self._layout if c.kind == "state"}
-        if len(kv_shapes) > 1 or len(state_shapes) > 1 or any(
+        if len(kv_shapes) != 1 or len(state_shapes) > 1 or any(
                 c.kind not in ("kv", "state") for c in self._layout):
             raise ValueError(
-                f"cache_layout(): one K/V geometry and one state geometry "
-                f"are served, got {sorted(kv_shapes)} and "
-                f"{sorted(state_shapes)}")
-        n_kv_heads, head_dim = next(iter(kv_shapes), (
-            cfg.num_key_value_heads,
-            cfg.hidden_size // cfg.num_attention_heads))
+                f"cache_layout(): served are layers of kind 'kv' (K/V "
+                f"pages, with or without a page array of index keys) in "
+                f"ONE geometry, at least one of them, and layers of kind "
+                f"'state' (a conv window and an SSM state a slot) in one "
+                f"geometry; got kv (shape, index_dim) {sorted(kv_shapes)}, "
+                f"state {sorted(state_shapes)}, kinds "
+                f"{sorted({c.kind for c in self._layout})}")
+        ((n_kv_heads, head_dim), self._index_dim), = kv_shapes
         self._state_shape = next(iter(state_shapes), None)
         # head-sharded paged KV: pages shard over the KV-head axis of
         # the TP mesh when the head count divides; an indivisible model
@@ -669,14 +677,17 @@ class ContinuousBatchingPredictor:
         self.pool = PagedKVPool(
             sum(c.kind == "kv" for c in self._layout), num_pages + 1,
             page_size, n_kv_heads, head_dim, dtype=kv_dtype, mesh=kv_mesh,
-            device=self._device)
+            device=self._device, index_dim=self._index_dim)
         self.state_pool = None      # built once the refusals have passed
-        if self._state_shape is not None and enable_prefix_cache:
-            # cached pages hold the attention layers' K/V only: a hit
-            # would resume the recurrent layers from no state at all
+        # cached pages hold the attention layers' K/V only: a hit would
+        # resume recurrent layers from no state at all, and a suffix
+        # prefill does not select over cached index pages
+        lost = "recurrent_state" if self._state_shape is not None \
+            else "sparse_index" if self._index_dim else None
+        if lost and enable_prefix_cache:
             enable_prefix_cache = False
             _obsm.counter("kernels.pallas_fallbacks").inc(
-                kernel="prefix_cache", reason="recurrent_state")
+                kernel="prefix_cache", reason=lost)
         # inactive slots need somewhere harmless to point their block
         # table (the decode step writes one K/V row for EVERY slot):
         # a dedicated trash page absorbs those writes
@@ -824,21 +835,39 @@ class ContinuousBatchingPredictor:
                     f"most {fit} at {cfg.num_attention_heads // self.tp} "
                     f"heads x {head_dim} (kernels.paged_attention."
                     f"max_varq_span)")
-        if self._state_shape is not None:
+        if self._state_shape is not None or self._index_dim:
             refused = [n for n, on in (
                 ("prefill_chunk_tokens", self._chunk_max > 0),
                 ("spec_draft_tokens", self._spec_k > 0),
                 ("tp_degree", self.tp > 1),
                 (f"role={self.role!r}", self.role != "unified")) if on]
+            why = (
+                "recurrent layers. Their state is a row a slot, advanced "
+                "one token a step: a query span (chunked prefill, "
+                "speculative verify) would need snapshots to roll back "
+                "to, a page span carries no state to hand off, and the "
+                "mixer has no sharding rule (docs/SERVING.md 'Hybrid "
+                "models')") if self._state_shape is not None else (
+                "an attention indexer. Its keys are selected for one "
+                "query token a slot a step: a query span (chunked "
+                "prefill, speculative verify) would select a set for "
+                "each of its positions, a page span carries no index "
+                "pages to hand off, and the one index key a token has no "
+                "head axis to shard (docs/SERVING.md 'Sparse attention')")
             if refused:
-                raise ValueError(
-                    f"{', '.join(refused)}: not served for a model with "
-                    f"recurrent layers. Their state is a row a slot, "
-                    f"advanced one token a step: a query span (chunked "
-                    f"prefill, speculative verify) would need snapshots "
-                    f"to roll back to, a page span carries no state to "
-                    f"hand off, and the mixer has no sharding rule "
-                    f"(docs/SERVING.md 'Hybrid models')")
+                raise ValueError(f"{', '.join(refused)}: not served for a "
+                                 f"model with {why}")
+        # prompts one prefill program takes (None: a round's whole
+        # bucket). A prompt whose attention selects its keys is thousands
+        # of tokens of compute-bound work on its own: a larger batch
+        # amortises nothing, its temporaries grow with rows x bucket
+        # (2 x 16384 at 12 layers: 2.0 GB), and every further row count
+        # is one more program a bucket to compile before serving
+        self._prefill_rows = 2 if self._index_dim else None
+        if self._index_dim:
+            _obsm.gauge("serving.index_pool_bytes").set(
+                sum(a.nbytes for a in self.pool.index), **self._mlbl)
+        if self._state_shape is not None:
             conv_shape, ssm_shape = self._state_shape
             self.state_pool = StatePool(
                 sum(c.kind == "state" for c in self._layout), self.B,
@@ -1134,11 +1163,14 @@ class ContinuousBatchingPredictor:
 
     # The serve programs take the caches as two lists in layer order
     # (donated): for a "kv" layer its K and V pages, for a "state" layer
-    # its conv window and SSM state rows.
+    # its conv window and SSM state rows. A layer with an indexer gives
+    # its keys as the pair (K pages, index-key pages).
     def _cache_args(self):
+        keys = list(zip(self.pool.k, self.pool.index)) if self._index_dim \
+            else self.pool.k
         if self.state_pool is None:
-            return self.pool.k, self.pool.v
-        pages = iter(zip(self.pool.k, self.pool.v))
+            return keys, self.pool.v
+        pages = iter(zip(keys, self.pool.v))
         rows = iter(zip(self.state_pool.conv, self.state_pool.ssm))
         pairs = [next(pages if c.kind == "kv" else rows)
                  for c in self._layout]
@@ -1146,31 +1178,47 @@ class ContinuousBatchingPredictor:
 
     def _cache_store(self, first, second):
         """Adopt a program's output caches (the inputs were donated)."""
-        if self.state_pool is None:
-            self.pool.k, self.pool.v = list(first), list(second)
-            return
         kinds = [c.kind for c in self._layout]
-        self.pool.k = [a for a, k in zip(first, kinds) if k == "kv"]
+        keys = [a for a, k in zip(first, kinds) if k == "kv"]
+        if self._index_dim:
+            self.pool.index = [x for _, x in keys]
+            keys = [k for k, _ in keys]
+        self.pool.k = keys
         self.pool.v = [a for a, k in zip(second, kinds) if k == "kv"]
-        self.state_pool.conv = [a for a, k in zip(first, kinds)
-                                if k == "state"]
-        self.state_pool.ssm = [a for a, k in zip(second, kinds)
-                               if k == "state"]
+        if self.state_pool is not None:
+            self.state_pool.conv = [a for a, k in zip(first, kinds)
+                                    if k == "state"]
+            self.state_pool.ssm = [a for a, k in zip(second, kinds)
+                                   if k == "state"]
 
     def _step_cache(self, kl, vl, tables, ctx, meta):
         """The decode step's `past_key_values`: one entry a layer, of
-        its kind. With recurrent layers the model is also told which
-        rows carry a request (an empty slot's table is all trash)."""
+        its kind. A model that counts what its tokens do (recurrent
+        layers, step counters) is also told which rows carry a request
+        (an empty slot's table is all trash)."""
         from ..generation.kv_cache import (PagedCacheEntry, PagedKVCache,
                                            StateCacheEntry)
         paged = (Tensor(tables), Tensor(ctx), meta)
-        entries = [PagedCacheEntry(kl[i], vl[i], *paged) if c.kind == "kv"
-                   else StateCacheEntry(kl[i], vl[i])
-                   for i, c in enumerate(self._layout)]
-        if self.state_pool is None:
+
+        def entry(i, c):
+            if c.kind == "state":
+                return StateCacheEntry(kl[i], vl[i])
+            if c.index_dim:
+                return PagedCacheEntry(kl[i][0], vl[i], *paged,
+                                       index_pages=kl[i][1])
+            return PagedCacheEntry(kl[i], vl[i], *paged)
+
+        entries = [entry(i, c) for i, c in enumerate(self._layout)]
+        if self.state_pool is None and not self._step_counters:
             return PagedKVCache(entries)
         return PagedKVCache(entries,
                             active=tables[:, 0] != jnp.int32(self._trash))
+
+    def _step_caches_out(self, caches):
+        """A decode step's updated caches, as the two operand lists."""
+        first = [(_raw(e.k_pages), _raw(e.index_pages)) if c.index_dim
+                 else _raw(e[0]) for c, e in zip(self._layout, caches)]
+        return first, [_raw(e[1]) for e in caches]
 
     def _counter_outputs(self, caches):
         """The model's summed counts, as extra outputs of a program."""
@@ -1196,13 +1244,21 @@ class ContinuousBatchingPredictor:
         state row each prompt's final state is written to, whole; a
         dummy's is the pool's last row)."""
         from ..jit.bridge import bound_state
+        from ..kernels.paged_attention import index_key_rows
         n, bucket = ids.shape
         j = jnp.arange(bucket, dtype=jnp.int32)
         key_valid = j[None, :] >= (bucket - lens)[:, None]      # [N, S]
-        causal = j[None, :] <= j[:, None]                       # [Sq, Sk]
-        ok = key_valid[:, None, :] & causal[None, :, :]         # [N, Sq, Sk]
-        mask = jnp.where(ok, jnp.float32(0),
-                         jnp.float32(-1e30))[:, None, :, :]
+        if self._index_dim:
+            # a model with an indexer takes the keys' validity alone: it
+            # builds causality and its selection from the positions, a
+            # chunk of queries at a time (no [bucket, bucket] array),
+            # and returns the logits of the last position only
+            mask = key_valid
+        else:
+            causal = j[None, :] <= j[:, None]                   # [Sq, Sk]
+            ok = key_valid[:, None, :] & causal[None, :, :]     # [N, Sq, Sk]
+            mask = jnp.where(ok, jnp.float32(0),
+                             jnp.float32(-1e30))[:, None, :, :]
         with no_grad(), bound_state(self._p_tensors, p_vals,
                                     self._b_tensors, b_vals):
             logits, caches = self.model(
@@ -1218,12 +1274,15 @@ class ContinuousBatchingPredictor:
         dst_off = jnp.where(key_valid, tokpos % self.page,
                             0).astype(jnp.int32)
         new_k, new_v = [], []
-        for li, (layer, (ca, cb)) in enumerate(zip(self._layout, caches)):
+        for li, (layer, kept) in enumerate(zip(self._layout, caches)):
             where = (dst_page, dst_off) if layer.kind == "kv" else slots[0]
-            new_k.append(kl[li].at[where].set(
-                _raw(ca).astype(kl[li].dtype)))
-            new_v.append(vl[li].at[where].set(
-                _raw(cb).astype(vl[li].dtype)))
+            put = lambda old, new: old.at[where].set(
+                _raw(new).astype(old.dtype))
+            new_k.append((put(kl[li][0], kept[0]),
+                          put(kl[li][1], index_key_rows(_raw(kept[2]),
+                                                        kl[li][1])))
+                         if layer.index_dim else put(kl[li], kept[0]))
+            new_v.append(put(vl[li], kept[1]))
         return (nexts, new_k, new_v) + self._counter_outputs(caches)
 
     def _raw_suffix_prefill(self, p_vals, b_vals, kl, vl, ids, pos, m,
@@ -1303,9 +1362,8 @@ class ContinuousBatchingPredictor:
             done = nxt == jnp.int32(self.eos_token_id)
         else:
             done = jnp.zeros(nxt.shape, jnp.bool_)
-        new_k = [_raw(e[0]) for e in caches]
-        new_v = [_raw(e[1]) for e in caches]
-        return (nxt, done, new_k, new_v) + self._counter_outputs(caches)
+        return (nxt, done, *self._step_caches_out(caches)) \
+            + self._counter_outputs(caches)
 
     def _raw_mixed_step(self, p_vals, b_vals, kl, vl, tables, ctx,
                         span_ids, q_lens, tok_in, *meta_flat):
@@ -1390,9 +1448,8 @@ class ContinuousBatchingPredictor:
             done = nxt == jnp.int32(self.eos_token_id)
         else:
             done = jnp.zeros(nxt.shape, jnp.bool_)
-        new_k = [_raw(e[0]) for e in caches]
-        new_v = [_raw(e[1]) for e in caches]
-        return (nxt, done, new_k, new_v) + self._counter_outputs(caches)
+        return (nxt, done, *self._step_caches_out(caches)) \
+            + self._counter_outputs(caches)
 
     def _raw_spec_step(self, p_vals, b_vals, kl, vl, tables, ctx,
                        span_ids, q_lens, tok_in, s_temp, s_topk, s_topp,
@@ -2251,8 +2308,11 @@ class ContinuousBatchingPredictor:
                 self.stats["prefix_misses"] += 1
                 self._m_pfx_miss.inc(**mlbl)
             for bucket, group in sorted(by_bucket.items()):
-                with prefill_stage(group, bucket):
-                    firsts.update(self._batch_prefill(bucket, group))
+                rows = self._prefill_rows or len(group)
+                for at in range(0, len(group), rows):
+                    part = group[at:at + rows]
+                    with prefill_stage(part, bucket):
+                        firsts.update(self._batch_prefill(bucket, part))
 
             if now_plans:
                 self._m_prefill.observe(_time.perf_counter() - t0,

@@ -41,7 +41,7 @@ _META_FIELDS = ("seq", "page", "ordinal", "first", "last", "valid")
 _VMEM_SCOPED_BYTES = 16 * 1024 * 1024
 # The kernels that take a group of query heads a KV head (GQA); the
 # others contract head against head and need H == Hkv.
-_GROUP_KERNELS = ("paged_attention",)
+_GROUP_KERNELS = ("paged_attention", "paged_sparse_attention")
 
 
 def paged_gate_reason(kernel, h, hkv, d, tp=1):
@@ -203,8 +203,13 @@ def paged_pages_per_block(h, hkv, d, page, itemsize, pages_per_seq):
     return ppb
 
 
-def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, *, scale, page_size, hkv, ppb):
+def _paged_kernel(tables_ref, lens_ref, q_ref, *refs, scale, page_size, hkv,
+                  ppb, selected=False):
+    # with `selected`, a float32 row a slot (0 on a selected key's
+    # columns, _NEG_INF on the others') comes after q: the kernel still
+    # reads every live page and the selection is a mask on the scores
+    bias_ref = refs[0] if selected else None
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem = refs[1:] if selected else refs
     b = pl.program_id(0)
     h, d = q_ref.shape[1:]
     rep = h // hkv
@@ -262,6 +267,10 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=mxu_precision(q, k)) * np.float32(scale)
+        if selected:
+            cols = ppb * rows
+            s = s + bias_ref[0, :, pl.ds(pl.multiple_of(blk * i32(cols),
+                                                        cols), cols)]
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         tok = blk * i32(ppb * page_size) + jax.lax.div(col, i32(hkv))
@@ -286,19 +295,29 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                            scale, interpret=False):
+                            scale, interpret=False, keep=None):
     """q: [B, H, D] → [B, H, D]; H a multiple of the pool's Hkv. A slot
-    with no cached token gives zeros."""
+    with no cached token gives zeros. `keep` [B, pages_per_seq * page]
+    bool restricts each slot to its selected keys."""
     b, h, d = q.shape
     _, page, hkv, _ = k_pages.shape
     ppb = paged_pages_per_block(h, hkv, d, page, k_pages.dtype.itemsize,
                                 block_tables.shape[1])
     columns = ppb * page * hkv
     q_spec = pl.BlockSpec((1, h, d), lambda b_, tr, lr: (b_, _Z, _Z))
+    selection, selection_specs = (), []
+    if keep is not None:
+        # a key's verdict on each of its Hkv columns, as the blocks lie
+        bias = jnp.repeat(jnp.where(keep, np.float32(0), _NEG_INF), hkv,
+                          axis=1)[:, None, :]
+        selection = (bias,)
+        selection_specs = [pl.BlockSpec((1, 1, bias.shape[2]),
+                                        lambda b_, tr, lr: (b_, _Z, _Z))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+        in_specs=[q_spec, *selection_specs,
+                  pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
@@ -308,14 +327,14 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, context_lens,
         ],
     )
     kernel = functools.partial(_paged_kernel, scale=scale, page_size=page,
-                               hkv=hkv, ppb=ppb)
+                               hkv=hkv, ppb=ppb, selected=keep is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pages.reshape(-1, d), v_pages.reshape(-1, d))
+      q, *selection, k_pages.reshape(-1, d), v_pages.reshape(-1, d))
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
@@ -344,6 +363,212 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     _note_decode_kernel("xla")
     return _paged_attention_xla(q, k_pages, v_pages, block_tables,
                                 context_lens, sc)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention, decode: score, select, attend (the prefill
+# form and the selection itself are in kernels/sparse_attention.py).
+#
+# A layer with an indexer keeps one index key a token in a third paged
+# array [num_pages, page, lanes] under the pool's page ids (a key's Di
+# values, then zeros to whole 128-lane rows). A decode step scores the
+# slot's live index keys for its one query token,
+#   I[s] = sum_j w[j] * relu(qI[j] . kI[s]),
+# selects the `topk` best exactly (all of them at a context of at most
+# `topk`), and attends over the selected keys alone.
+#
+# Scores: a Pallas kernel on the block-table kernel's plan (grid over
+# slots, a slot's live pages by its own DMAs, two buffers deep, loops
+# rolled). The index pool is taken as it lies, viewed [num_pages * page,
+# lanes] (a bitcast); the J index heads, zero-padded like the keys, are
+# the rows of one [J, lanes] x [lanes, tokens] contraction a block, and
+# the weighted sum over them is the block's row of scores, written into
+# the slot's whole row in VMEM.
+#
+# Attention: the block-table kernel above with the selection as a mask
+# on its scores (`keep`). It reads every live page; at contexts of a few
+# thousand keys and pages of 16 nearly every page holds a selected key,
+# so gathering rows would read as many pages by many more descriptors
+# (measured both ways: PERF.md).
+# ---------------------------------------------------------------------------
+
+_INDEX_PAGES_PER_BLOCK = 32
+
+
+def index_key_rows(keys, index_pages):
+    """Index keys or queries [..., Di] as rows of `index_pages`: its
+    dtype, zeros on the lanes past Di."""
+    pad = index_pages.shape[-1] - keys.shape[-1]
+    return jnp.pad(keys.astype(index_pages.dtype),
+                   [(0, 0)] * (keys.ndim - 1) + [(0, pad)])
+
+
+def _index_scores_xla(qi, w, index_pages, block_tables, lens):
+    """qi [B, J, lanes]; w [B, J] float32; index_pages [P, page, lanes];
+    lens [B] keys a slot holds -> [B, L] float32, -inf past `lens`."""
+    b = qi.shape[0]
+    ki = index_pages[block_tables].reshape(b, -1, index_pages.shape[2])
+    s = jnp.einsum("bjd,bld->bjl", qi, ki,
+                   preferred_element_type=jnp.float32)
+    out = jnp.sum(jax.nn.relu(s) * w.astype(jnp.float32)[..., None], axis=1)
+    live = jnp.arange(ki.shape[1], dtype=jnp.int32)[None, :] < lens[:, None]
+    return jnp.where(live, out, -jnp.inf)
+
+
+def _index_scores_kernel(tables_ref, lens_ref, q_ref, w_ref, k_hbm, o_ref,
+                         k_buf, sem, *, page_size, ppb):
+    b = pl.program_id(0)
+    i32 = np.int32
+    n = ppb * page_size                    # tokens a block
+    ctx = lens_ref[b]
+    n_pages = jax.lax.div(ctx + i32(page_size - 1), i32(page_size))
+    n_blocks = jax.lax.div(n_pages + i32(ppb - 1), i32(ppb))
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, jnp.float32)
+
+    def page_copy(blk, buf, i):
+        o = jnp.minimum(blk * i32(ppb) + i, n_pages - i32(1))
+        src = pl.ds(pl.multiple_of(tables_ref[b, o] * i32(page_size),
+                                   page_size), page_size)
+        dst = pl.ds(pl.multiple_of(i * i32(page_size), page_size), page_size)
+        return pltpu.make_async_copy(k_hbm.at[src], k_buf.at[buf, dst],
+                                     sem.at[buf])
+
+    def each_page(do):
+        def body(i):
+            do(i)
+            return i + i32(1)
+        jax.lax.while_loop(lambda i: i < i32(ppb), body, i32(0))
+
+    def start_block(blk, buf):
+        each_page(lambda i: page_copy(blk, buf, i).start())
+
+    @pl.when(n_blocks > i32(0))
+    def _first():
+        start_block(i32(0), i32(0))
+
+    q = q_ref[0]                                           # (J, Di)
+    w = w_ref[0]                                           # (J, 1)
+
+    def block(blk, carry):
+        buf = jax.lax.rem(blk, i32(2))
+
+        @pl.when(blk + i32(1) < n_blocks)
+        def _prefetch():
+            start_block(blk + i32(1), i32(1) - buf)
+
+        each_page(lambda i: page_copy(blk, buf, i).wait())
+        k = k_buf[buf]                                     # (n, Di)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=mxu_precision(q, k))                 # (J, n)
+        row = jnp.sum(jnp.maximum(s, np.float32(0)) * w, axis=0,
+                      keepdims=True)                       # (1, n)
+        tok = blk * i32(n) + jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+        o_ref[0, :, pl.ds(pl.multiple_of(blk * i32(n), n), n)] = jnp.where(
+            tok < ctx, row, -jnp.inf)
+        return carry
+
+    jax.lax.fori_loop(i32(0), n_blocks, block, i32(0))
+
+
+def _index_scores_pallas(qi, w, index_pages, block_tables, lens, ppb,
+                         interpret):
+    b, heads, di = qi.shape
+    _, page, _ = index_pages.shape
+    n_keys = block_tables.shape[1] * page
+    spec = lambda shape: pl.BlockSpec((1,) + shape,
+                                      lambda b_, tr, lr: (b_, _Z, _Z))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[spec((heads, di)), spec((heads, 1)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=spec((1, n_keys)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb * page, di), index_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, page_size=page, ppb=ppb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_keys), jnp.float32),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), qi,
+      w.astype(jnp.float32)[..., None],
+      index_pages.reshape(-1, di))[:, 0]
+
+
+def index_scores_gate_reason(lanes, page, pages_per_seq):
+    """Why the Pallas index-score kernel cannot take this geometry (a
+    reason label of ``kernels.pallas_fallbacks``), or None: keys on
+    whole 128-lane rows, a block of 16 pages whole 128-lane stretches
+    of a slot's row of scores, and a slot's table whole blocks."""
+    if lanes % 128:
+        return "index_dim_tiling"
+    if pages_per_seq % 16 or (16 * page) % 128:
+        return "table_tiling"
+    return None
+
+
+def paged_index_scores(qi, w, index_pages, block_tables, lens,
+                       interpret=False):
+    """Index scores of one query token a slot over the slot's live
+    index keys. qi [B, J, Di]; w [B, J]; index_pages [num_pages, page,
+    lanes >= Di]; block_tables [B, pages_per_seq]; lens [B] keys held
+    (the new token's included) -> [B, pages_per_seq * page] float32,
+    -inf at the positions past `lens`."""
+    interpret = interpret or pallas_interpret()
+    _, page, di = index_pages.shape
+    qi = index_key_rows(qi, index_pages)
+    pps = block_tables.shape[1]
+    if interpret or _use_pallas():
+        reason = index_scores_gate_reason(di, page, pps)
+        if reason is None and not interpret \
+                and not pallas_dtype_ok(qi, index_pages):
+            reason = "dtype"
+        if reason is None:
+            ppb = _INDEX_PAGES_PER_BLOCK if pps % _INDEX_PAGES_PER_BLOCK == 0 \
+                else 16
+            return _index_scores_pallas(qi, w, index_pages, block_tables,
+                                        lens, ppb, interpret)
+        note_fallback("paged_index_scores", reason)
+    return _index_scores_xla(qi, w, index_pages, block_tables, lens)
+
+
+def paged_sparse_attention(q, k_pages, v_pages, index_pages, qi, w,
+                           block_tables, context_lens, topk, scale=None,
+                           interpret=False):
+    """One decode token a slot over the `topk` keys its indexer selects
+    (every key at a context of at most `topk`). q [B, H, D]; qi [B, J,
+    Di] and w [B, J] the token's index queries and their weights;
+    context_lens [B] the keys a slot holds, the new token's included.
+    Returns (out [B, H, D], keep [B, L] bool: the selection)."""
+    from .sparse_attention import select_topk
+    d = q.shape[-1]
+    sc = scale if scale is not None else 1.0 / pymath.sqrt(d)
+    interpret = interpret or pallas_interpret()
+    with jax.named_scope("dsa.indexer"):
+        scores = paged_index_scores(qi, w, index_pages, block_tables,
+                                    context_lens, interpret)
+    with jax.named_scope("dsa.select"):
+        live = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :] \
+            < context_lens[:, None]
+        keep = select_topk(scores, live, topk)
+    with jax.named_scope("dsa.attend"):
+        if _paged_gate("paged_sparse_attention", q, k_pages, v_pages,
+                       interpret):
+            _note_decode_kernel("paged_sparse_attention")
+            out = _paged_attention_pallas(q, k_pages, v_pages, block_tables,
+                                          context_lens, sc,
+                                          interpret=interpret, keep=keep)
+        else:
+            _note_decode_kernel("xla")
+            out = _gathered_group_attention(
+                q[:, None], k_pages, v_pages, block_tables, keep[:, None, :],
+                sc)[:, 0]
+    return out, keep
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,307 @@
+"""Learned sparse attention: an indexer scores every earlier key for a
+query, the `topk` best are selected, and attention runs over those alone
+(the DeepSeek-Sparse-Attention form; models/keye_vl2.py).
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])        s <= t
+    S_t     = the min(topk, t + 1) keys of largest I[t, s], ties to the
+              lower s; one set a token, shared by every head
+
+This module holds what prefill and decode share and the prefill form:
+
+- `select_topk`: the exact selection, as a keep-mask. The k-th largest
+  score is found by bisection over the float32 bit pattern (32 counting
+  passes over the row, no sort: `jax.lax.top_k` is a full sort on the
+  TPU and `approx_max_k` is not exact), then ties at that value are
+  admitted from the lowest position up by a prefix count.
+- `prefill_index_scores`: I for one chunk of queries against a whole
+  prompt's keys (a Pallas kernel that loops the index heads in VMEM; the
+  XLA form writes a [chunk, heads, keys] float32 array first).
+- `sparse_prefill_attention`: scores, selection and masked attention in
+  query chunks, so that nothing of [prompt, prompt] extent outlives a
+  chunk. The attention of a chunk is a flash kernel of its own
+  (`_attend_kernel`): the query heads that share a KV head are rows of
+  one matmul against a key block, the chunk's selection comes in once a
+  group as an additive tile, and key blocks past the chunk's last query
+  are skipped. Without Pallas it is the XLA attention under the mask.
+
+The decode forms read the paged pool and live in
+kernels/paged_attention.py. Scores, selection and softmax are float32;
+the operands keep the cache's dtype.
+"""
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import (_Z, _NEG_INF, use_pallas as _use_pallas,
+                      pallas_interpret, note_fallback, mxu_precision)
+
+F32 = jnp.float32
+U32 = jnp.uint32
+_LANES = 128
+
+
+# ---------------------------------------------------------------------------
+# selection
+# ---------------------------------------------------------------------------
+
+def _sortable(scores):
+    """float32 -> uint32 whose unsigned order is the floats' order
+    (-0.0 counted as 0.0, so that equal scores are equal keys)."""
+    s = scores.astype(F32)
+    s = jnp.where(s == F32(0), F32(0), s)
+    b = jax.lax.bitcast_convert_type(s, jnp.int32)
+    key = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(key, U32) ^ U32(0x80000000)
+
+
+def select_topk(scores, valid, k):
+    """keep [..., L] bool: the min(k, number valid) positions of each
+    row with the largest `scores` among `valid`, ties to the lower
+    position. Exact: the threshold is the k-th largest value itself,
+    found a bit of its pattern a counting pass (two or four bits a pass,
+    three or fifteen candidates counted at once, were slower on the
+    chip: PERF.md)."""
+    u = jnp.where(valid, _sortable(scores), U32(0))
+    k = jnp.int32(k)
+
+    def bit(i, t):
+        cand = t | (U32(1) << (U32(31) - i.astype(U32)))
+        n = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, t)
+
+    # t = the k-th largest key, or 0 where fewer than k are valid
+    t = jax.lax.fori_loop(jnp.int32(0), jnp.int32(32), bit,
+                          jnp.zeros(u.shape[:-1], U32))[..., None]
+    above = u > t
+    tied = (u == t) & valid
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32, keepdims=True)
+    surplus = jnp.sum(tied, axis=-1, dtype=jnp.int32, keepdims=True) > room
+
+    def by_position(_):
+        rank = jnp.cumsum(tied.astype(jnp.int32), axis=-1, dtype=jnp.int32)
+        return above | (tied & (rank <= room))
+
+    # more ties at the threshold than places left is rare: the prefix
+    # count over the row is paid only then
+    return jax.lax.cond(jnp.any(surplus), by_position,
+                        lambda _: above | tied, None)
+
+
+# ---------------------------------------------------------------------------
+# index scores of a chunk of queries against a prompt's keys
+# ---------------------------------------------------------------------------
+
+def _index_scores_xla(qi, w, ki):
+    """qi [N, C, J, Di], w [N, C, J] float32, ki [N, S, Di] ->
+    [N, C, S] float32."""
+    s = jnp.einsum("ncjd,nsd->ncjs", qi, ki, preferred_element_type=F32)
+    return jnp.sum(jax.nn.relu(s) * w.astype(F32)[..., None], axis=2)
+
+
+def _prefill_scores_kernel(last_ref, q_ref, w_ref, k_ref, o_ref, *, heads,
+                           block_k):
+    j = pl.program_id(1)
+
+    # a key block that starts past the chunk's last query holds no key
+    # any of its queries may see: left as it lies, the caller masks it
+    @pl.when(j * np.int32(block_k) <= last_ref[0])
+    def _():
+        k = k_ref[0]                                       # (bk, Di)
+        w = w_ref[0]                                       # (C, J)
+        acc = jnp.zeros(o_ref.shape[1:], F32)
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=F32,
+                precision=mxu_precision(k))                # (C, bk)
+            acc = acc + jnp.maximum(s, F32(0)) * w[:, h:h + 1]
+        o_ref[0] = acc
+
+
+def _index_scores_pallas(qi, w, ki, last, block_k, interpret):
+    n, c, heads, di = qi.shape
+    s = ki.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n, s // block_k),
+        in_specs=[
+            pl.BlockSpec((1, heads, c, di),
+                         lambda b, j, lr: (b, _Z, _Z, _Z)),
+            pl.BlockSpec((1, c, heads), lambda b, j, lr: (b, _Z, _Z)),
+            pl.BlockSpec((1, block_k, di), lambda b, j, lr: (b, j, _Z))],
+        out_specs=pl.BlockSpec((1, c, block_k), lambda b, j, lr: (b, _Z, j)))
+    return pl.pallas_call(
+        functools.partial(_prefill_scores_kernel, heads=heads,
+                          block_k=block_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, c, s), F32),
+        interpret=interpret,
+    )(jnp.reshape(last, (1,)).astype(jnp.int32), qi.transpose(0, 2, 1, 3),
+      w.astype(F32), ki)
+
+
+_SCORE_BLOCK_K = 512
+
+
+def prefill_index_scores(qi, w, ki, last, interpret=False):
+    """I of one chunk: qi [N, C, J, Di] (the chunk's index queries), w
+    [N, C, J] float32, ki [N, S, Di] (every key of the prompt), `last`
+    the position of the chunk's last query (traced) -> [N, C, S]
+    float32. Entries of keys past `last` are undefined: the caller's
+    causal mask drops them."""
+    interpret = interpret or pallas_interpret()
+    c, s = qi.shape[1], ki.shape[1]
+    block_k = min(_SCORE_BLOCK_K, s)
+    if interpret or _use_pallas():
+        if c % 8 == 0 and s % block_k == 0 and block_k % _LANES == 0:
+            return _index_scores_pallas(qi, w, ki, last, block_k, interpret)
+        note_fallback("prefill_index_scores", "chunk_tiling")
+    return _index_scores_xla(qi, w, ki)
+
+
+# ---------------------------------------------------------------------------
+# attention of a chunk of queries over its selected keys
+# ---------------------------------------------------------------------------
+
+_ATTEND_BLOCK_Q, _ATTEND_BLOCK_K = 128, 512
+
+
+def _attend_kernel(last_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_scr,
+                   l_scr, acc_scr, *, scale, rep, block_k):
+    """One (row, KV head, query tile) over the key blocks: q_ref holds
+    the tile's queries of the `rep` heads of the group, head-major
+    [rep * bq, D]; bias_ref the selection of the tile's queries [bq, bk]
+    (0 kept, _NEG_INF not), shared by the heads."""
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # a key block that starts past the chunk's last query is seen by none
+    @pl.when(j * np.int32(block_k) <= last_ref[0])
+    def _compute():
+        q, k, v = q_ref[0, 0, 0], k_ref[0, 0], v_ref[0, 0]
+        bq = bias_ref.shape[1]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32,
+            precision=mxu_precision(q, k)) * np.float32(scale)
+        s = (s.reshape(rep, bq, block_k)
+             + bias_ref[0].astype(F32)[None]).reshape(rep * bq, block_k)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            l_scr[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=F32, precision=mxu_precision(v))
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finalize():
+        l = l_scr[:, 0:1]
+        o_ref[0, 0, 0] = (acc_scr[:] / jnp.where(l == F32(0), F32(1), l)
+                          ).astype(o_ref.dtype)
+
+
+def _attend_pallas(q, k, v, keep, last, scale, bq, bk, interpret):
+    """q [N, C, H, D]; k, v [N, Hkv, S, D]; keep [N, C, S] bool."""
+    n, c, h, d = q.shape
+    hkv, s = k.shape[1], k.shape[2]
+    rep, tiles = h // hkv, c // bq
+    # [N, Hkv, tile, head of the group x query of the tile, D]
+    fold = lambda a: a.reshape(n, tiles, bq, hkv, rep, d).transpose(
+        0, 3, 1, 4, 2, 5).reshape(n, hkv, tiles, rep * bq, d)
+    bias = jnp.where(keep, F32(0), _NEG_INF).astype(jnp.bfloat16)
+    q_spec = pl.BlockSpec((1, 1, 1, rep * bq, d),
+                          lambda b, g, i, j, lr: (b, g, i, _Z, _Z))
+    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b, g, i, j, lr: (b, g, j, _Z))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n, hkv, tiles, s // bk),
+        in_specs=[q_spec,
+                  pl.BlockSpec((1, bq, bk), lambda b, g, i, j, lr: (b, i, j)),
+                  kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((rep * bq, _LANES), F32),
+                        pltpu.VMEM((rep * bq, _LANES), F32),
+                        pltpu.VMEM((rep * bq, d), F32)])
+    out = pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale, rep=rep, block_k=bk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, hkv, tiles, rep * bq, d), q.dtype),
+        interpret=interpret,
+    )(jnp.reshape(last, (1,)).astype(jnp.int32), fold(q), bias, k, v)
+    return out.reshape(n, hkv, tiles, rep, bq, d).transpose(
+        0, 2, 4, 1, 3, 5).reshape(n, c, h, d)
+
+
+def selected_attention(q, k, v, keep, last, scale, interpret=False):
+    """Attention of a chunk of queries over the keys `keep` marks. q
+    [N, C, H, D]; k, v [N, Hkv, S, D] (head-major: the whole prompt's);
+    keep [N, C, S] bool; `last` the position of the chunk's last query
+    (traced) -> [N, C, H, D]. A query that keeps no key is don't-care."""
+    from .attention import _xla_attention
+    interpret = interpret or pallas_interpret()
+    c, d, s = q.shape[1], q.shape[3], k.shape[2]
+    bq, bk = min(_ATTEND_BLOCK_Q, c), min(_ATTEND_BLOCK_K, s)
+    if interpret or _use_pallas():
+        if c % bq == 0 and bq % 8 == 0 and s % bk == 0 \
+                and bk % _LANES == 0 and d % _LANES == 0:
+            return _attend_pallas(q, k, v, keep, last, scale, bq, bk,
+                                  interpret)
+        note_fallback("sparse_prefill_attention", "chunk_tiling")
+    return _xla_attention(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                          scale, False, mask=keep[:, None])
+
+
+# ---------------------------------------------------------------------------
+# prefill: score, select, attend, a chunk of queries at a time
+# ---------------------------------------------------------------------------
+
+def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
+                             chunk):
+    """Causal attention of a left-padded batch over each query's
+    selected keys. q [N, S, H, D]; k, v [N, S, Hkv, D]; qi [N, S, J,
+    Di]; w [N, S, J]; ki [N, S, Di]; key_valid [N, S] bool (False on the
+    padding) -> out [N, S, H, D]. A query with at most `topk` visible
+    keys attends to all of them (plain causal attention); a padding
+    query sees no key and its row is don't-care. Scores, selection and
+    the masked attention run `chunk` queries at a time."""
+    n, s_real, h, d = q.shape
+    c = min(int(chunk), s_real)
+    tail = -s_real % c
+    if tail:        # whole chunks: the tail's keys are seen by no query
+        q, k, v, qi, w, ki, key_valid = (
+            jnp.pad(a, [(0, 0), (0, tail)] + [(0, 0)] * (a.ndim - 2))
+            for a in (q, k, v, qi, w, ki, key_valid))
+    s = s_real + tail
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)   # head-major
+    kpos = jnp.arange(s, dtype=jnp.int32)
+
+    def one(start):
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, c, axis=1)
+        qpos = start + jnp.arange(c, dtype=jnp.int32)
+        last = start + jnp.int32(c - 1)
+        with jax.named_scope("dsa.indexer"):
+            scores = prefill_index_scores(cut(qi), cut(w), ki, last)
+        with jax.named_scope("dsa.select"):
+            seen = key_valid[:, None, :] \
+                & (kpos[None, None, :] <= qpos[None, :, None])
+            keep = select_topk(scores, seen, topk)
+        with jax.named_scope("dsa.attend"):
+            return selected_attention(cut(q), k, v, keep, last, scale)
+
+    out = jax.lax.map(one, jnp.arange(0, s, c, dtype=jnp.int32))
+    return jnp.moveaxis(out, 0, 1).reshape(n, s, h, d)[:, :s_real]

@@ -12,3 +12,4 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
                     ErnieForTokenClassification, ErnieForQuestionAnswering)
 from .granite_hybrid import (GraniteMoeHybridConfig,
                              GraniteMoeHybridForCausalLM)
+from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM

@@ -257,8 +257,10 @@ def test_predictor_refuses_a_span_over_the_bound():
 # fusion a Mamba layer writing the donated state in place, and the
 # expert matmuls the compiler's grouped kernel (a Mosaic kernel, which
 # refuses bf16 operands under the global "highest" precision).
-@pytest.fixture(scope="module")
-def granite(chip):
+def _cell_predictor(chip, cell):
+    """The cell's model with abstract weights behind its predictor, and
+    the programs' fixed operands (weights, buffers, caches) as shapes on
+    the described chip. Yields (pred, n_params, fixed)."""
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, root)
@@ -268,10 +270,11 @@ def granite(chip):
     # code that asks the backend sees the CPU here: the kernel gates
     # follow the flag alone, as they do on the chip
     patch = pytest.MonkeyPatch()
-    for mod in (attention, norm, pa):
+    from paddle_tpu.kernels import sparse_attention
+    for mod in (attention, norm, pa, sparse_attention):
         patch.setattr(mod, "_use_pallas",
                       lambda: bool(flag_value("use_pallas_kernels")))
-    cfg = harness.find_cell(root, "granite4h-chat-open")["cfg"]
+    cfg = harness.find_cell(root, cell)["cfg"]
     builder = harness.load_module(root, "models", cfg["builder"])
     model, n_params = builder.build(cfg, 0, abstract=True)
     pred = ContinuousBatchingPredictor(model, kv_dtype=cfg["dtype"],
@@ -279,11 +282,15 @@ def granite(chip):
     pred._ensure_ready()
     sds = lambda a: jax.ShapeDtypeStruct(tuple(a.shape), a.dtype,
                                          sharding=chip)
-    caches = [[sds(a) for a in side] for side in pred._cache_args()]
-    fixed = ([sds(a) for a in pred._p_vals],
-             [sds(a) for a in pred._b_vals], *caches)
+    fixed = jax.tree_util.tree_map(
+        sds, (pred._p_vals, pred._b_vals, *pred._cache_args()))
     yield pred, n_params, fixed
     patch.undo()
+
+
+@pytest.fixture(scope="module")
+def granite(chip):
+    yield from _cell_predictor(chip, "granite4h-chat-open")
 
 
 def _compile_program(chip, pred, fixed, fn, *shapes):
@@ -328,3 +335,150 @@ def test_granite_largest_prefill_at_real_size(chip, granite):
     assert " f64[" not in text and " s64[" not in text
     assert live < 14.5e9, live              # 13.73 GB when written
     assert ma.temp_size_in_bytes < 2.0e9, ma.temp_size_in_bytes
+
+
+# --- the sparse-attention cell at its real geometry -----------------------
+# 32 slots of up to 16384 positions over 16385 pages, 12 layers of 32 / 4
+# heads of 128 with a 64-wide index key a token, 16 of 128 experts held.
+
+@pytest.fixture
+def pallas_by_flag(monkeypatch):
+    """Code that asks the backend sees the CPU here: the kernel gates
+    follow the flag alone, as they do on the chip."""
+    from paddle_tpu.framework.flags import flag_value
+    from paddle_tpu.kernels import sparse_attention as sa
+    for mod in (attention, pa, sa):
+        monkeypatch.setattr(mod, "_use_pallas",
+                            lambda: bool(flag_value("use_pallas_kernels")))
+
+
+def test_sparse_decode_kernels_at_the_cells_geometry(chip, pallas_by_flag):
+    slots, pps, pool, hkv, j, di = 32, 1024, 16385, 4, 16, 64
+    text = _compile(
+        chip, lambda q, k, v, ip, qi, w, bt, cl: pa.paged_sparse_attention(
+            q, k, v, ip, qi, w, bt, cl, 2048, SCALE),
+        ((slots, H, D), BF16), ((pool, PAGE, hkv, D), BF16),
+        ((pool, PAGE, hkv, D), BF16), ((pool, PAGE, 128), BF16),
+        ((slots, j, di), BF16), ((slots, j), jnp.float32),
+        ((slots, pps), I32), ((slots,), I32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " s64[" not in text
+    # neither pool is gathered, copied or widened on the way in
+    for shape in (f"bf16[{pool},{PAGE},{hkv},{D}]",
+                  f"bf16[{pool},{PAGE},128]"):
+        assert not re.search(r"= " + re.escape(shape) + r"\S* copy\(", text)
+    assert f"[{slots},{pps},{PAGE}," not in text
+
+
+@pytest.mark.parametrize("n,bucket", [(1, 4096), (2, 16384)])
+def test_sparse_prefill_attention_at_the_cells_buckets(chip, pallas_by_flag,
+                                                       n, bucket):
+    from paddle_tpu.kernels import sparse_attention as sa
+    hkv, j, di = 4, 16, 64
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in (
+        ((n, bucket, H, D), BF16), ((n, bucket, hkv, D), BF16),
+        ((n, bucket, hkv, D), BF16), ((n, bucket, j, di), BF16),
+        ((n, bucket, j), jnp.float32), ((n, bucket, di), BF16),
+        ((n, bucket), jnp.bool_))]
+    compiled = jax.jit(lambda *a: sa.sparse_prefill_attention(
+        *a, topk=2048, scale=SCALE, chunk=512)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " f64[" not in text and " s64[" not in text
+    # nothing of [bucket, bucket] extent: a chunk of 512 queries at most
+    assert f"[{n},{bucket},{bucket}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+@pytest.fixture(scope="module")
+def keye(chip):
+    yield from _cell_predictor(chip, "keye2-longprompt-open")
+
+
+def test_keye_decode_step_at_real_size(chip, keye):
+    pred, n_params, fixed = keye
+    assert n_params == 1_240_586_752        # the issue's 1,241 M
+    B, pps = pred.B, pred.pages_per_seq
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_decode_step, (B, pps), (B,), (B,))
+    assert " f64[" not in text and " s64[" not in text
+    assert live < 10.5e9, live
+    # K, V and index pages are updated where they lie
+    pool = sum(a.nbytes for a in pred.pool.k + pred.pool.v + pred.pool.index)
+    assert ma.alias_size_in_bytes >= pool
+    # two kernels a layer (index scores, masked attention) and the
+    # compiler's grouped matmuls; no slot's table is gathered
+    assert text.count('custom_call_target="tpu_custom_call"') >= 24
+    assert text.count("ragged-dot-none") >= 24
+    assert f"[{B},{pps},16," not in text
+
+
+def test_keye_largest_prefill_at_real_size(chip, keye):
+    pred, _, fixed = keye
+    n, bucket = 2, 16384
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_prefill, (n, bucket), (n, bucket),
+        (n,), (n, bucket // pred.page))
+    assert " f64[" not in text and " s64[" not in text
+    assert f"[{n},1,{bucket},{bucket}]" not in text
+    assert f"[{n},{bucket},{pred.model.config.vocab_size}]" not in text
+    assert live < 14.5e9, live
+
+
+# --- the other cells' decode steps have not moved --------------------------
+# sha256 of each cell's lowered decode step at the parent of PR 33
+# (0b146e3), every Mosaic kernel's body re-printed without its debug
+# locations (they carry a checkout's paths and lines). A change to code
+# these cells share with the sparse-attention cell (the predictor's
+# loops over the layout, `_paged_kernel`, the dropless expert layer)
+# must leave these texts as they are, or say why it moved them.
+DECODE_STEP_AT_PARENT = {
+    "dsllm7b-chat-open":
+        "f55762f720b5726f6024dd989156d24969fce41c0163fbbddb32bb9f052a812c",
+    "mistral7b-sessions-closed":
+        "d345aebdde0457cc21ca9a1a03dfb08a121547470e722bbdca52590450005a29",
+    "granite4h-chat-open":
+        "64d706b3c231ec57bbb1bc8f8bb7529b05154f10574fcce7003441b3d0e0e1bf",
+}
+
+
+def _without_debug_locations(text):
+    import base64
+    import json
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        cfg = json.loads(m.group(1).replace("\\22", '"'))
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            cfg["custom_call_config"]["body"] = ir.Module.parse(
+                base64.b64decode(cfg["custom_call_config"]["body"])
+            ).operation.get_asm(enable_debug_info=False)
+        return "backend_config = " + json.dumps(cfg, sort_keys=True)
+
+    return re.sub(r'backend_config = "(\{\\22custom_call_config.*?\})"',
+                  body, text)
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_STEP_AT_PARENT))
+def test_decode_step_lowers_as_at_the_parent(chip, cell):
+    import hashlib
+    gen = _cell_predictor(chip, cell)
+    pred, _, fixed = next(gen)
+    try:
+        args = [jax.ShapeDtypeStruct(s, I32, sharding=chip)
+                for s in ((pred.B, pred.pages_per_seq), (pred.B,),
+                          (pred.B,))]
+        with pred._trace_lock, pred._kernel_scope():
+            text = jax.jit(pred._raw_decode_step,
+                           donate_argnums=(2, 3)).lower(
+                *fixed, *args).as_text()
+    finally:
+        gen.close()
+    assert "custom_call_config" in text
+    assert hashlib.sha256(_without_debug_locations(text).encode()
+                          ).hexdigest() == DECODE_STEP_AT_PARENT[cell]

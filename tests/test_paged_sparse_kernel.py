@@ -1,0 +1,280 @@
+"""The kernels of learned sparse attention in interpret mode against XLA
+forms and numpy oracles: index scores over a slot's live index pages,
+the exact top-k selection (ties to the lower position), attention over
+the selected keys through the block-table kernel, and the prefill forms
+by query chunks. Contexts under, at and over `topk`, page boundaries,
+tied scores, empty slots, a group of 8 query heads a KV head.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu  # noqa: F401
+from paddle_tpu.kernels import attention
+from paddle_tpu.kernels import paged_attention as pa
+from paddle_tpu.kernels import sparse_attention as sa
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+PAGE, PPS, POOL = 16, 16, 160           # 256 positions a slot
+J, DI, TOPK = 4, 64, 64
+# a slot's keys: one, a page less one, a page, a page and one, topk - 1,
+# topk, topk + 1, well over it and off a page boundary, the whole table
+LENS = [1, 15, 16, 17, TOPK - 1, TOPK, TOPK + 1, 203, PAGE * PPS]
+
+
+def _rng(*stream):
+    return np.random.default_rng([20260930, *stream])
+
+
+def _pool(rng, lens, hkv=1, d=128, dtype=BF16):
+    b = len(lens)
+    tables = rng.permutation(POOL - 1)[:b * PPS].reshape(b, PPS) + 1
+    for i, n in enumerate(lens):            # page 0 is the trash page
+        tables[i, -(-n // PAGE):] = 0
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
+    # index keys lie on whole 128-lane rows, zeros past DI
+    index_pages = jnp.pad(arr(POOL, PAGE, DI), [(0, 0), (0, 0), (0, 128 - DI)])
+    return (arr(POOL, PAGE, hkv, d), arr(POOL, PAGE, hkv, d), index_pages,
+            jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32))
+
+
+def _oracle_keep(scores, lens, k):
+    keep = np.zeros(scores.shape, bool)
+    for b, n in enumerate(lens):
+        order = np.argsort(-scores[b, :n], kind="stable")[:k]
+        keep[b, order] = True
+    return keep
+
+
+# ------------------------------------------------------------ selection --
+
+@pytest.mark.parametrize("k", [1, 7, 32, 100, 101, 400])
+def test_selection_is_the_stable_sort(k):
+    """Scores on a grid of 0.25 (many ties, zeros of both signs) with
+    invalid positions strewn in: the kept set is the first k of a stable
+    descending sort over the valid ones."""
+    rng = _rng(1, k)
+    scores = np.round(rng.normal(size=(6, 100)) * 2) / 4
+    scores[0, ::3] = -0.0
+    scores[1] = 0.5                                     # one value
+    valid = rng.random((6, 100)) < 0.85
+    valid[2] = True
+    keep = np.asarray(sa.select_topk(jnp.asarray(scores, F32),
+                                     jnp.asarray(valid), k))
+    for b in range(6):
+        idx = np.nonzero(valid[b])[0]
+        want = idx[np.argsort(-scores[b, idx], kind="stable")][:k]
+        assert sorted(want) == np.nonzero(keep[b])[0].tolist(), b
+
+
+def test_selection_orders_every_float():
+    """Infinities, tiny values and both zeros keep their order."""
+    vals = np.array([np.inf, 3e38, 1.0, 1e-30, 0.0, -0.0, -1e-30, -1.0,
+                     -3e38, -np.inf], np.float32)
+    for k in range(1, len(vals) + 1):
+        keep = np.asarray(sa.select_topk(jnp.asarray(vals[::-1].copy()),
+                                         jnp.ones(len(vals), bool), k))
+        # 0.0 and -0.0 tie: the lower position (of the reversed row) first
+        want = np.argsort(-vals[::-1], kind="stable")[:k]
+        assert sorted(want) == np.nonzero(keep)[0].tolist(), k
+
+
+# --------------------------------------------------- decode: index scores --
+
+def test_index_scores_over_live_pages():
+    rng = _rng(2)
+    _, _, index_pages, tables, lens = _pool(rng, LENS)
+    qi = jnp.asarray(rng.normal(size=(len(LENS), J, DI)), BF16)
+    w = jnp.asarray(rng.normal(size=(len(LENS), J)), F32)
+    assert pa.index_scores_gate_reason(128, PAGE, PPS) is None
+    got = np.asarray(pa.paged_index_scores(qi, w, index_pages, tables, lens,
+                                           interpret=True))
+    want = np.asarray(pa._index_scores_xla(
+        jnp.pad(qi, [(0, 0), (0, 0), (0, 128 - DI)]), w, index_pages, tables,
+        lens))
+    live = np.arange(PAGE * PPS)[None, :] < np.asarray(lens)[:, None]
+    assert np.array_equal(np.isfinite(got), live)
+    assert np.all(got[~live] == -np.inf)
+    assert np.abs(got[live] - want[live]).max() < 1e-4 * np.abs(
+        want[live]).max()
+    # and against the definition, in float64
+    ki = np.asarray(index_pages, np.float64)[np.asarray(tables)].reshape(
+        len(LENS), -1, 128)[..., :DI]
+    dots = np.einsum("bjd,bld->bjl", np.asarray(qi, np.float64), ki)
+    plain = (np.maximum(dots, 0) * np.asarray(w, np.float64)[..., None]).sum(1)
+    assert np.abs(got[live] - plain[live]).max() < 1e-4 * np.abs(
+        plain[live]).max()
+
+
+@pytest.mark.parametrize("di,page,pps,reason", [
+    (128, 16, 12, "table_tiling"), (64, 16, 16, "index_dim_tiling"),
+    (128, 4, 16, "table_tiling")])
+def test_index_scores_fall_back_by_name(di, page, pps, reason):
+    from paddle_tpu.observability import metrics
+    assert pa.index_scores_gate_reason(di, page, pps) == reason
+
+    def count():
+        return sum(s.value for s in
+                   metrics.counter("kernels.pallas_fallbacks").samples()
+                   if s.labels == {"kernel": "paged_index_scores",
+                                   "reason": reason})
+    rng, before = _rng(3, pps), count()
+    index_pages = jnp.asarray(rng.normal(size=(8, page, di)), F32)
+    tables = jnp.zeros((2, pps), jnp.int32)
+    got = pa.paged_index_scores(
+        jnp.ones((2, J, di), F32), jnp.ones((2, J), F32), index_pages,
+        tables, jnp.asarray([1, page], jnp.int32), interpret=True)
+    assert got.shape == (2, pps * page) and count() == before + 1
+
+
+# -------------------------------------------- decode: select and attend --
+
+@pytest.mark.parametrize("h,hkv", [(8, 1), (16, 2)], ids=["rep8", "rep8x2"])
+def test_sparse_decode_attends_to_the_selected_keys_only(h, hkv):
+    rng = _rng(4, h)
+    k_pages, v_pages, index_pages, tables, lens = _pool(rng, LENS, hkv)
+    b = len(LENS)
+    q = jnp.asarray(rng.normal(size=(b, h, 128)), BF16)
+    qi = jnp.asarray(rng.normal(size=(b, J, DI)), BF16)
+    w = jnp.asarray(rng.normal(size=(b, J)), F32)
+    out, keep = pa.paged_sparse_attention(
+        q, k_pages, v_pages, index_pages, qi, w, tables, lens, TOPK,
+        interpret=True)
+    keep = np.asarray(keep)
+    scores = np.asarray(pa.paged_index_scores(qi, w, index_pages, tables,
+                                              lens, interpret=True))
+    assert np.array_equal(keep, _oracle_keep(scores, LENS, TOPK))
+    assert keep.sum(1).tolist() == [min(n, TOPK) for n in LENS]
+    # the softmax over the kept keys alone, in float64
+    kk = np.asarray(k_pages, np.float64)[np.asarray(tables)].reshape(
+        b, -1, hkv, 128)
+    vv = np.asarray(v_pages, np.float64)[np.asarray(tables)].reshape(
+        b, -1, hkv, 128)
+    qq = np.asarray(q, np.float64).reshape(b, hkv, h // hkv, 128)
+    s = np.einsum("bgrd,blgd->bgrl", qq, kk) / np.sqrt(128)
+    s = np.where(keep[:, None, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bgrl,blgd->bgrd", p / p.sum(-1, keepdims=True), vv)
+    got = np.asarray(out, np.float64).reshape(want.shape)
+    assert np.abs(got - want).max() < 2e-2       # bf16 probabilities
+
+
+def test_at_most_topk_keys_is_plain_paged_attention():
+    """Contexts of at most `topk`: every key is selected and the layer
+    is the block-table kernel's plain GQA, bit for bit."""
+    rng = _rng(5)
+    lens = [n for n in LENS if n <= TOPK]
+    k_pages, v_pages, index_pages, tables, cl = _pool(rng, lens)
+    q = jnp.asarray(rng.normal(size=(len(lens), 8, 128)), BF16)
+    qi = jnp.asarray(rng.normal(size=(len(lens), J, DI)), BF16)
+    w = jnp.asarray(rng.normal(size=(len(lens), J)), F32)
+    out, keep = pa.paged_sparse_attention(
+        q, k_pages, v_pages, index_pages, qi, w, tables, cl, TOPK,
+        interpret=True)
+    plain = pa.paged_attention(q, k_pages, v_pages, tables, cl,
+                               interpret=True)
+    assert np.asarray(keep).sum(1).tolist() == lens
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(plain, np.float32))
+
+
+def test_tied_scores_go_to_the_lower_position():
+    """Index keys that repeat give equal scores: the set is still the
+    stable sort's, and an empty slot (all trash, one key) is served."""
+    rng = _rng(6)
+    lens = [200, 130, 1]
+    k_pages, v_pages, _, tables, cl = _pool(rng, lens)
+    tables = tables.at[2].set(0)                       # the idle slot
+    few = jnp.pad(jnp.asarray(rng.normal(size=(3, DI)), BF16),
+                  [(0, 0), (0, 128 - DI)])
+    index_pages = few[jnp.asarray(rng.integers(0, 3, (POOL, PAGE)))]
+    qi = jnp.asarray(rng.normal(size=(3, J, DI)), BF16)
+    w = jnp.asarray(rng.normal(size=(3, J)), F32)
+    q = jnp.asarray(rng.normal(size=(3, 8, 128)), BF16)
+    out, keep = pa.paged_sparse_attention(
+        q, k_pages, v_pages, index_pages, qi, w, tables, cl, TOPK,
+        interpret=True)
+    scores = np.asarray(pa.paged_index_scores(qi, w, index_pages, tables,
+                                              cl, interpret=True))
+    assert len(np.unique(scores[0, :200])) <= 3
+    assert np.array_equal(np.asarray(keep), _oracle_keep(scores, lens, TOPK))
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
+# ----------------------------------------------------------------- prefill --
+
+def test_prefill_index_scores_by_chunk():
+    rng = _rng(7)
+    n, s, c = 2, 256, 64
+    qi = jnp.asarray(rng.normal(size=(n, c, J, DI)), BF16)
+    w = jnp.asarray(rng.normal(size=(n, c, J)), F32)
+    ki = jnp.asarray(rng.normal(size=(n, s, DI)), BF16)
+    want = np.asarray(sa._index_scores_xla(qi, w, ki))
+    # the chunk ends at position 191: the key block past it is skipped
+    sa_block = sa._SCORE_BLOCK_K
+    try:
+        sa._SCORE_BLOCK_K = 128
+        got = np.asarray(sa.prefill_index_scores(
+            qi, w, ki, jnp.int32(191), interpret=True))
+    finally:
+        sa._SCORE_BLOCK_K = sa_block
+    assert np.abs(got[:, :, :192] - want[:, :, :192]).max() \
+        < 1e-4 * np.abs(want).max()
+
+
+def _dense_oracle(q, k, v, qi, w, ki, valid, topk):
+    """Row by row in float64: score, stable sort, softmax over the kept."""
+    n, s, h, d = q.shape
+    hkv = k.shape[2]
+    f64 = lambda a: np.asarray(a, np.float64)
+    q, k, v, qi, w, ki = map(f64, (q, k, v, qi, w, ki))
+    out = np.zeros((n, s, h, d))
+    kept = np.zeros((n, s, s), bool)
+    for b in range(n):
+        scores = (np.maximum(np.einsum("tjd,sd->tjs", qi[b], ki[b]), 0)
+                  * w[b][..., None]).sum(1)
+        for t in range(s):
+            if not valid[b, t]:
+                continue
+            seen = np.nonzero(valid[b, :t + 1])[0]
+            sel = seen[np.argsort(-scores[t, seen], kind="stable")][:topk]
+            kept[b, t, sel] = True
+            for i in range(h):
+                g = i // (h // hkv)
+                sc = k[b, sel, g] @ q[b, t, i] / np.sqrt(d)
+                p = np.exp(sc - sc.max())
+                out[b, t, i] = (p / p.sum()) @ v[b, sel, g]
+    return out, kept
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "pallas"])
+def test_sparse_prefill_of_a_left_padded_batch(interpret, monkeypatch):
+    """Unequal lengths, left-padded, contexts over `topk`, a prompt that
+    is not whole chunks: every real position equals the row-by-row
+    definition; the padding changes nothing."""
+    from paddle_tpu.framework.flags import set_flags
+    rng = _rng(8)
+    n, s, h, hkv, d, topk, chunk = 2, 160, 8, 2, 64, 24, 32
+    if interpret:
+        set_flags({"use_pallas_kernels": True, "pallas_interpret": True})
+        s, d = 256, 128            # whole key blocks, whole lane rows
+    try:
+        lens = [s, s - 59]
+        valid = np.arange(s)[None, :] >= (s - np.asarray(lens))[:, None]
+        arr = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
+        q, k, v = arr(n, s, h, d), arr(n, s, hkv, d), arr(n, s, hkv, d)
+        qi, w, ki = arr(n, s, J, DI), arr(n, s, J), arr(n, s, DI)
+        got = np.asarray(sa.sparse_prefill_attention(
+            q, k, v, qi, w, ki, jnp.asarray(valid), topk=topk,
+            scale=d ** -0.5, chunk=chunk))
+    finally:
+        if interpret:
+            set_flags({"use_pallas_kernels": True, "pallas_interpret": False})
+    want, kept = _dense_oracle(q, k, v, qi, w, ki, valid, topk)
+    assert kept[0].sum(-1).tolist() == [min(t + 1, topk) for t in range(s)]
+    assert np.abs(got[valid] - want[valid]).max() < 2e-4
+    # short rows are plain causal attention
+    plain = np.asarray(attention._xla_attention(q, k, v, d ** -0.5, True))
+    assert np.abs(got[0, :topk] - plain[0, :topk]).max() < 2e-4

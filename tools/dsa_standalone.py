@@ -1,0 +1,158 @@
+"""Stand-alone times of the decode step's sparse-attention pieces on the
+chip, one layer at one geometry: the index scores (the Pallas kernel
+against the XLA gather of every slot's table), the selection (the
+bisection of `kernels.sparse_attention.select_topk` against
+`jax.lax.top_k`) and the attention over the selected keys (the
+block-table kernel under the selection's mask against a gather of the
+selected rows). PERF.md records what a run of this printed.
+
+    python tools/dsa_standalone.py [--slots 32] [--context 7168] ...
+
+Needs a TPU. Prints one JSON line a piece: seconds of one call on the
+device (50 calls inside one program, the median of 5 such programs).
+"""
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import numpy as np  # noqa: E402
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+import paddle_tpu  # noqa: E402,F401
+from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
+from paddle_tpu.kernels import sparse_attention as sa  # noqa: E402
+
+
+def selected_rows_attention(q, k_pages, v_pages, block_tables, keep, topk,
+                            scale):
+    """The other exact form: gather the selected keys' K and V rows
+    ([B, topk, Hkv, D]) and attend densely over them."""
+    b, n_keys = keep.shape
+    page = k_pages.shape[1]
+    rank = jnp.cumsum(keep.astype(jnp.int32), axis=1, dtype=jnp.int32) - 1
+    pos = jnp.broadcast_to(jnp.arange(n_keys, dtype=jnp.int32), keep.shape)
+    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+    idx = jnp.zeros((b, topk), jnp.int32).at[
+        rows, jnp.where(keep, rank, topk)].set(pos, mode="drop")
+    n_sel = jnp.sum(keep, axis=1, dtype=jnp.int32)
+    pages = jnp.take_along_axis(block_tables, idx // page, axis=1)
+    k = k_pages[pages, idx % page]                      # [B, topk, Hkv, D]
+    v = v_pages[pages, idx % page]
+    hkv = k.shape[2]
+    qg = q.reshape(b, hkv, -1, q.shape[-1])
+    s = jnp.einsum("bgrd,blgd->bgrl", qg, k,
+                   preferred_element_type=jnp.float32) * np.float32(scale)
+    ok = jnp.arange(topk, dtype=jnp.int32)[None, :] < n_sel[:, None]
+    p = jax.nn.softmax(jnp.where(ok[:, None, None, :], s, -1e30), axis=-1)
+    out = jnp.einsum("bgrl,blgd->bgrd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+def timed(name, fn, tables, *args, reps=50, calls=5):
+    """Seconds of ONE call of `fn(tables, *args)` on the device: `reps`
+    calls run inside one program, each reading its block tables through
+    a zero carried from the call before it, so that no gather or kernel
+    is hoisted out of the loop or dropped; a call of that program is
+    timed from the host. (A single dispatch costs the host about half a
+    millisecond, more than most of these pieces.)"""
+    def many(bt, *rest):
+        def one(_, carry):
+            out = jax.tree_util.tree_leaves(fn(bt + carry, *rest))[0]
+            # a zero the compiler cannot fold: every element of the
+            # call's result is needed to know that none is NaN
+            total = jnp.sum(out.astype(jnp.float32))
+            return jnp.where(jnp.isnan(total), jnp.int32(1), jnp.int32(0))
+        return jax.lax.fori_loop(jnp.int32(0), jnp.int32(reps), one,
+                                 jnp.int32(0))
+    prog = jax.jit(many)
+    jax.block_until_ready(prog(tables, *args))
+    times = []
+    for _ in range(calls):
+        t0 = time.perf_counter()
+        jax.block_until_ready(prog(tables, *args))
+        times.append((time.perf_counter() - t0) / reps)
+    print(json.dumps({"piece": name, "median_s": statistics.median(times),
+                      "min_s": min(times)}), flush=True)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--slots", type=int, default=32)
+    ap.add_argument("--context", type=int, default=7168)
+    ap.add_argument("--pages-per-seq", type=int, default=1024)
+    ap.add_argument("--pool", type=int, default=16385)
+    ap.add_argument("--topk", type=int, default=2048)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=4)
+    ap.add_argument("--index-heads", type=int, default=16)
+    ap.add_argument("--index-dim", type=int, default=64)
+    a = ap.parse_args(argv)
+    if jax.devices()[0].platform != "tpu":
+        raise SystemExit("the stand-alone times need a TPU")
+    rng = np.random.default_rng(0)
+    page, d, bf = 16, 128, jnp.bfloat16
+    b, pps = a.slots, a.pages_per_seq
+    arr = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32), bf)
+    k_pages, v_pages = arr(a.pool, page, a.kv_heads, d), \
+        arr(a.pool, page, a.kv_heads, d)
+    index_pages = jnp.pad(arr(a.pool, page, a.index_dim),
+                          [(0, 0), (0, 0), (0, 128 - a.index_dim)])
+    live = -(-a.context // page)
+    tables = np.zeros((b, pps), np.int32)
+    tables[:, :live] = 1 + rng.permutation(a.pool - 1)[:b * live].reshape(
+        b, live)
+    tables = jnp.asarray(tables)
+    lens = jnp.full((b,), a.context, jnp.int32)
+    q, qi = arr(b, a.heads, d), arr(b, a.index_heads, a.index_dim)
+    w = jnp.asarray(rng.normal(size=(b, a.index_heads)), jnp.float32)
+    scale = d ** -0.5
+    print(json.dumps({"geometry": vars(a),
+                      "device": jax.devices()[0].device_kind}), flush=True)
+
+    timed("index_scores.pallas", lambda bt: pa.paged_index_scores(
+        qi, w, index_pages, bt, lens), tables)
+    timed("index_scores.xla_gather", lambda bt: pa._index_scores_xla(
+        pa.index_key_rows(qi, index_pages), w, index_pages, bt, lens), tables)
+    scores = pa.paged_index_scores(qi, w, index_pages, tables, lens)
+    alive = jnp.arange(scores.shape[1])[None, :] < lens[:, None]
+    # the selection reads no table: its scores move with the carry
+    timed("select.bisection", lambda bt: sa.select_topk(
+        scores + bt[:, :1].astype(jnp.float32) * 0, alive, a.topk), tables)
+    timed("select.lax_top_k", lambda bt: jax.lax.top_k(
+        scores + bt[:, :1].astype(jnp.float32) * 0, a.topk)[1], tables)
+    keep = sa.select_topk(scores, alive, a.topk)
+    # what each form makes of the selection (the kernel's bias row, the
+    # gather's row indices) is part of its cost: the mask moves with the
+    # carry too (a table entry is never negative)
+    moving = lambda bt: keep & (bt[:, :1] >= 0)
+    timed("attend.masked_block_table_kernel",
+          lambda bt: pa._paged_attention_pallas(
+              q, k_pages, v_pages, bt, lens, scale, keep=moving(bt)), tables)
+    timed("attend.selected_rows_gather",
+          lambda bt: selected_rows_attention(
+              q, k_pages, v_pages, bt, moving(bt), a.topk, scale), tables)
+    timed("attend.dense_block_table_kernel",
+          lambda bt: pa._paged_attention_pallas(
+              q, k_pages, v_pages, bt, lens, scale), tables)
+    timed("decode_layer.score_select_attend",
+          lambda bt: pa.paged_sparse_attention(
+              q, k_pages, v_pages, index_pages, qi, w, bt, lens, a.topk,
+              scale)[0], tables)
+    got = pa.paged_sparse_attention(q, k_pages, v_pages, index_pages, qi, w,
+                                    tables, lens, a.topk, scale)[0]
+    other = selected_rows_attention(q, k_pages, v_pages, tables, keep,
+                                    a.topk, scale)
+    print(json.dumps({"forms_agree_max_abs_diff": float(jnp.max(jnp.abs(
+        got.astype(jnp.float32) - other.astype(jnp.float32))))}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1227,7 +1227,9 @@ class ContinuousBatchingPredictor:
 
     def _note_counters(self, aux):
         """Add a resolved step's counts to their metrics (the vectors
-        came down with the step's tokens: no read of their own)."""
+        came down with the step's tokens: no read of their own). A
+        program may give the head of a vector only: what it does not
+        count (a decode step, a prefill's chunks) is not in it."""
         for (_, spec), vec in zip(self._step_counters, aux):
             for (ctr, lbl), n in zip(spec, np.asarray(vec).tolist()):
                 if n:

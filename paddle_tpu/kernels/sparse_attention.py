@@ -23,6 +23,9 @@ This module holds what prefill and decode share and the prefill form:
   one matmul against a key block, the chunk's selection comes in once a
   group as an additive tile, and key blocks past the chunk's last query
   are skipped. Without Pallas it is the XLA attention under the mask.
+  A chunk does what its queries can see (`chunk_plan`): a chunk of
+  padding runs nothing, and one whose queries see at most `topk` keys
+  attends to all they see, without scores or a selection.
 
 The decode forms read the paged pool and live in
 kernels/paged_attention.py. Scores, selection and softmax are float32;
@@ -270,17 +273,54 @@ def selected_attention(q, k, v, keep, last, scale, interpret=False):
 # prefill: score, select, attend, a chunk of queries at a time
 # ---------------------------------------------------------------------------
 
+PADDING, DENSE, SELECTED = 0, 1, 2     # what a chunk of queries has to do
+
+
+def chunk_plan(key_valid, chunk, topk):
+    """What each chunk of `chunk` queries of a left-padded batch has to
+    do, from the keys' validity [N, S] alone -> [chunks] int32: PADDING
+    where no row has a real query in it, DENSE where no query of it sees
+    more than `topk` keys (it attends to all it sees, unselected), else
+    SELECTED. The same for every layer of a program: a model computes
+    it once and hands it to `sparse_prefill_attention`."""
+    n, s = key_valid.shape
+    c = min(int(chunk), s)
+    key_valid = jnp.pad(key_valid, [(0, 0), (0, -s % c)])
+    # the most keys a query of the chunk sees: the count at its last one
+    seen = jnp.cumsum(key_valid, axis=1, dtype=jnp.int32)[:, c - 1::c]
+    kind = jnp.where(jnp.max(seen, axis=0) > topk, SELECTED, DENSE)
+    has_query = jnp.any(key_valid.reshape(n, -1, c), axis=(0, 2))
+    return jnp.where(has_query, kind, PADDING).astype(jnp.int32)
+
+
+def plan_counts(plan, n, s):
+    """[5] int32 of a layer's prefill under `plan` over N rows of S
+    keys: chunks of padding, chunks without a selection, chunks with
+    one, then the keys a counting pass ran over (a selection counts
+    over the bucket) and the keys of every chunk's bucket."""
+    kinds = [jnp.sum(plan == kind, dtype=jnp.int32)
+             for kind in (PADDING, DENSE, SELECTED)]
+    return jnp.stack(kinds + [kinds[2] * jnp.int32(n * s),
+                              jnp.int32(plan.shape[0] * n * s)])
+
+
 def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
-                             chunk):
+                             chunk, plan=None):
     """Causal attention of a left-padded batch over each query's
     selected keys. q [N, S, H, D]; k, v [N, S, Hkv, D]; qi [N, S, J,
     Di]; w [N, S, J]; ki [N, S, Di]; key_valid [N, S] bool (False on the
     padding) -> out [N, S, H, D]. A query with at most `topk` visible
     keys attends to all of them (plain causal attention); a padding
     query sees no key and its row is don't-care. Scores, selection and
-    the masked attention run `chunk` queries at a time."""
+    the masked attention run `chunk` queries at a time, and a chunk does
+    what `plan` (`chunk_plan(key_valid, chunk, topk)`, computed here
+    where the caller has none) says its queries need: nothing where all
+    of them are padding, no selection where none sees more than `topk`
+    keys."""
     n, s_real, h, d = q.shape
     c = min(int(chunk), s_real)
+    if plan is None:
+        plan = chunk_plan(key_valid, c, topk)
     tail = -s_real % c
     if tail:        # whole chunks: the tail's keys are seen by no query
         q, k, v, qi, w, ki, key_valid = (
@@ -290,18 +330,29 @@ def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)   # head-major
     kpos = jnp.arange(s, dtype=jnp.int32)
 
-    def one(start):
+    def one(at):
+        start, kind = at
         cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, c, axis=1)
         qpos = start + jnp.arange(c, dtype=jnp.int32)
         last = start + jnp.int32(c - 1)
-        with jax.named_scope("dsa.indexer"):
-            scores = prefill_index_scores(cut(qi), cut(w), ki, last)
-        with jax.named_scope("dsa.select"):
+
+        def real(_):
             seen = key_valid[:, None, :] \
                 & (kpos[None, None, :] <= qpos[None, :, None])
-            keep = select_topk(scores, seen, topk)
-        with jax.named_scope("dsa.attend"):
-            return selected_attention(cut(q), k, v, keep, last, scale)
 
-    out = jax.lax.map(one, jnp.arange(0, s, c, dtype=jnp.int32))
+            def selected(_):
+                with jax.named_scope("dsa.indexer"):
+                    scores = prefill_index_scores(cut(qi), cut(w), ki, last)
+                with jax.named_scope("dsa.select"):
+                    return select_topk(scores, seen, topk)
+
+            keep = jax.lax.cond(kind == SELECTED, selected,
+                                lambda _: seen, None)
+            with jax.named_scope("dsa.attend"):
+                return selected_attention(cut(q), k, v, keep, last, scale)
+
+        return jax.lax.cond(kind != PADDING, real,
+                            lambda _: jnp.zeros((n, c, h, d), q.dtype), None)
+
+    out = jax.lax.map(one, (jnp.arange(0, s, c, dtype=jnp.int32), plan))
     return jnp.moveaxis(out, 0, 1).reshape(n, s, h, d)[:, :s_real]

@@ -63,7 +63,8 @@ from ..ops._dispatch import apply
 from ..generation.kv_cache import (LayerCache, LayerCaches, PagedKVCache,
                                    paged_cache_sparse_update_attend)
 from ..incubate.distributed.models.moe.dropless import DroplessMoELayer
-from ..kernels.sparse_attention import sparse_prefill_attention
+from ..kernels.sparse_attention import (chunk_plan, plan_counts,
+                                        sparse_prefill_attention)
 from .granite_hybrid import GraniteRMSNorm as RMSNorm
 
 F32 = jnp.float32
@@ -209,23 +210,25 @@ class KeyeSparseAttention(Layer):
         w = jnp.dot(x, ww, preferred_element_type=F32)
         return q, k, v, qi, w, ki
 
-    def _whole(self, x, pos, valid, wq, wk, wv, wo, *rest):
+    def _whole(self, x, pos, valid, plan, wq, wk, wv, wo, *rest):
         c = self.config
         q, k, v, qi, w, ki = self._project(x, pos, wq, wk, wv, *rest)
         out = sparse_prefill_attention(
             q, k, v, qi, w, ki, valid, topk=c.index_topk,
-            scale=c.head_dim ** -0.5, chunk=c.q_chunk_size)
+            scale=c.head_dim ** -0.5, chunk=c.q_chunk_size, plan=plan)
         return jnp.dot(out.reshape(x.shape[:2] + (-1,)), wo), k, v, ki
 
-    def forward(self, x, pos, valid=None, cache=None):
+    def forward(self, x, pos, valid=None, cache=None, plan=None):
         """x [B, S, hidden]; pos [B, S] int32. Without `cache`: the
-        whole batch from nothing, `valid` [B, S] its real positions;
-        returns (out, (k, v, kI)). With a `PagedCacheEntry` (S == 1):
-        one decode step; returns (out, entry, counts [2] = keys the
-        step's tokens could see, keys they attended to)."""
+        whole batch from nothing, `valid` [B, S] its real positions and
+        `plan` what each chunk of queries has to do (`chunk_plan`: the
+        model computes it once for all layers); returns (out, (k, v,
+        kI)). With a `PagedCacheEntry` (S == 1): one decode step;
+        returns (out, entry, counts [B] = keys each slot's token
+        attended to)."""
         c = self.config
         if cache is None:
-            out, k, v, ki = apply(self._whole, x, pos, valid,
+            out, k, v, ki = apply(self._whole, x, pos, valid, plan,
                                   *self._weights(), _name="sparse_attention")
             return out, (k, v, ki)
         if x.shape[1] != 1:
@@ -259,8 +262,9 @@ class KeyeDecoderLayer(Layer):
             held=config.experts_held,
             initializer_range=config.initializer_range)
 
-    def forward(self, h, pos, valid, cache):
-        x, *kept = self.self_attn(self.input_layernorm(h), pos, valid, cache)
+    def forward(self, h, pos, valid, cache, plan):
+        x, *kept = self.self_attn(self.input_layernorm(h), pos, valid, cache,
+                                  plan)
         h = h + x
         routed, counts = self.moe(self.post_attention_layernorm(h), valid)
         return h + routed, kept, counts
@@ -301,11 +305,18 @@ class KeyeVL2ForCausalLM(Layer):
 
     def step_counters(self):
         """What the vectors in `caches.counters` count, element by
-        element: {key: [(metric, labels)]} (docs/OBSERVABILITY.md)."""
+        element: {key: [(metric, labels)]} (docs/OBSERVABILITY.md). A
+        decode step gives the first two of "dsa" and a prefill all
+        seven, the first two zero: it counts what its query chunks did
+        (`kernels.sparse_attention.plan_counts`)."""
         c = self.config
         held = range(c.num_experts) if c.experts_held is None \
             else c.experts_held
-        return {"dsa": [("dsa.keys_live", {}), ("dsa.keys_selected", {})],
+        return {"dsa": [("dsa.keys_live", {}), ("dsa.keys_selected", {})]
+                + [("dsa.prefill_chunks", {"kind": kind})
+                   for kind in ("padding", "dense", "selected")]
+                + [("dsa.prefill_keys_counted", {}),
+                   ("dsa.prefill_keys_bucket", {})],
                 "moe": [("moe.assignments", {}),
                         ("moe.assignments_local", {})]
                 + [("moe.expert_tokens", {"expert": str(e)}) for e in held]}
@@ -343,10 +354,15 @@ class KeyeVL2ForCausalLM(Layer):
             valid = apply(lambda mk: mk if mk.ndim == 2
                           else mk[:, 0, -1, :] > -1.0, attn_mask,
                           _name="valid_positions")
+        c = self.config
+        # what a prefill's chunks of queries have to do, once for all layers
+        plan = None if paged else apply(
+            lambda ok: chunk_plan(ok, c.q_chunk_size, c.index_topk), valid,
+            _name="prefill_plan")
         caches, moe, dsa = [], None, None
         for i, layer in enumerate(m.layers):
             cache = past_key_values[i] if paged else None
-            h, kept, n = layer(h, position_ids, valid, cache)
+            h, kept, n = layer(h, position_ids, valid, cache, plan)
             caches.append(kept[0])
             moe = n if moe is None else moe + n
             if paged:
@@ -376,7 +392,8 @@ class KeyeVL2ForCausalLM(Layer):
             dsa = apply(step_counts, dsa, past_key_values[0].context_lens,
                         *(() if valid is None else (valid,)),
                         _name="dsa_counts")
-        else:       # counted by decode steps: one query a slot a step
-            dsa = apply(lambda ids: jnp.zeros((2,), jnp.int32), input_ids,
-                        _name="dsa_counts")
+        else:       # keys are counted by decode steps: one query a slot
+            dsa = apply(lambda p: jnp.pad(
+                plan_counts(p, b, s) * jnp.int32(n_layers), (2, 0)), plan,
+                _name="dsa_counts")
         return logits, LayerCaches(caches, {"dsa": dsa, "moe": moe})

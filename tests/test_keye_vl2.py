@@ -365,6 +365,29 @@ def test_selection_counters_come_down_with_the_tokens(model):
     assert chosen in (layers * TOPK * 5, layers * TOPK * 6)
 
 
+def test_prefill_chunk_counters_come_down_with_the_first_tokens(model):
+    """A 20-token prompt left-padded into a bucket of 32, chunks of 8
+    queries, top-8, a layer: one chunk of padding, one whose queries see
+    at most 4 keys, two that select, each over the bucket's 32 keys."""
+    def read():
+        return {(n, s.labels.get("kind")): s.value
+                for n in ("dsa.prefill_chunks", "dsa.prefill_keys_counted",
+                          "dsa.prefill_keys_bucket")
+                for s in metrics.counter(n).samples()}
+    before = read()
+    pred, _ = _served(model, [_prompts([20], stream=6)[0]], max_new=2,
+                      max_batch_size=2)
+    assert pred._bucket_len(20) == 32
+    after = read()
+    layers = CFG["num_hidden_layers"]
+    grew = {k: after[k] - before.get(k, 0) for k in after}
+    assert grew == {("dsa.prefill_chunks", "padding"): layers,
+                    ("dsa.prefill_chunks", "dense"): layers,
+                    ("dsa.prefill_chunks", "selected"): 2 * layers,
+                    ("dsa.prefill_keys_counted", None): 2 * 32 * layers,
+                    ("dsa.prefill_keys_bucket", None): 4 * 32 * layers}
+
+
 def test_prefix_cache_is_derived_off_and_says_so(model):
     def fallbacks():
         return {tuple(sorted(s.labels.items())): s.value for s in

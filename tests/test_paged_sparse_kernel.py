@@ -5,6 +5,9 @@ the selected keys through the block-table kernel, and the prefill forms
 by query chunks. Contexts under, at and over `topk`, page boundaries,
 tied scores, empty slots, a group of 8 query heads a KV head.
 """
+import contextlib
+import functools
+
 import numpy as np
 import pytest
 
@@ -15,6 +18,7 @@ import paddle_tpu  # noqa: F401
 from paddle_tpu.kernels import attention
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import sparse_attention as sa
+from paddle_tpu.framework.flags import set_flags
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 PAGE, PPS, POOL = 16, 16, 160           # 256 positions a slot
@@ -26,6 +30,19 @@ LENS = [1, 15, 16, 17, TOPK - 1, TOPK, TOPK + 1, 203, PAGE * PPS]
 
 def _rng(*stream):
     return np.random.default_rng([20260930, *stream])
+
+
+@contextlib.contextmanager
+def _pallas_interpreted(on):
+    """The prefill's Pallas kernels through the interpreter where `on`,
+    its XLA forms otherwise."""
+    if on:
+        set_flags({"use_pallas_kernels": True, "pallas_interpret": True})
+    try:
+        yield
+    finally:
+        if on:
+            set_flags({"use_pallas_kernels": True, "pallas_interpret": False})
 
 
 def _pool(rng, lens, hkv=1, d=128, dtype=BF16):
@@ -254,27 +271,142 @@ def test_sparse_prefill_of_a_left_padded_batch(interpret, monkeypatch):
     """Unequal lengths, left-padded, contexts over `topk`, a prompt that
     is not whole chunks: every real position equals the row-by-row
     definition; the padding changes nothing."""
-    from paddle_tpu.framework.flags import set_flags
     rng = _rng(8)
     n, s, h, hkv, d, topk, chunk = 2, 160, 8, 2, 64, 24, 32
     if interpret:
-        set_flags({"use_pallas_kernels": True, "pallas_interpret": True})
         s, d = 256, 128            # whole key blocks, whole lane rows
-    try:
-        lens = [s, s - 59]
-        valid = np.arange(s)[None, :] >= (s - np.asarray(lens))[:, None]
-        arr = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
-        q, k, v = arr(n, s, h, d), arr(n, s, hkv, d), arr(n, s, hkv, d)
-        qi, w, ki = arr(n, s, J, DI), arr(n, s, J), arr(n, s, DI)
+    lens = [s, s - 59]
+    valid = np.arange(s)[None, :] >= (s - np.asarray(lens))[:, None]
+    arr = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
+    q, k, v = arr(n, s, h, d), arr(n, s, hkv, d), arr(n, s, hkv, d)
+    qi, w, ki = arr(n, s, J, DI), arr(n, s, J), arr(n, s, DI)
+    with _pallas_interpreted(interpret):
         got = np.asarray(sa.sparse_prefill_attention(
             q, k, v, qi, w, ki, jnp.asarray(valid), topk=topk,
             scale=d ** -0.5, chunk=chunk))
-    finally:
-        if interpret:
-            set_flags({"use_pallas_kernels": True, "pallas_interpret": False})
     want, kept = _dense_oracle(q, k, v, qi, w, ki, valid, topk)
     assert kept[0].sum(-1).tolist() == [min(t + 1, topk) for t in range(s)]
     assert np.abs(got[valid] - want[valid]).max() < 2e-4
     # short rows are plain causal attention
     plain = np.asarray(attention._xla_attention(q, k, v, d ** -0.5, True))
     assert np.abs(got[0, :topk] - plain[0, :topk]).max() < 2e-4
+
+
+# what a chunk may skip, at chunk 32 and topk 24: (lens, None the whole
+# bucket s and a negative one counted back from it; distinct index keys, 0
+# every key its own; positions cut off the bucket's end; chunks of padding,
+# without a selection, with one: None is the rest)
+_VISIBLE = {
+    # a dummy row (lens == 0) beside a whole one
+    "dummy_row": ((None, 0), 0, 0, (0, 0, None)),
+    # nothing over topk: the one real chunk keeps all it sees
+    "under_topk": ((20, 24), 0, 0, (None, 1, 0)),
+    # 40 keys: 8 in one chunk, then 9..40 visible in the next, which
+    # crosses topk at its 16th query
+    "crosses_mid_chunk": ((40,), 0, 0, (None, 1, 1)),
+    # the short row never needs a selection, the long one does
+    "one_row_needs_it": ((None, 20), 0, 0, (0, 0, None)),
+    # three distinct index keys: a third of what a query sees ties at
+    # the threshold, from its first key to its last
+    "ties_at_both_ends": ((None, -59), 3, 0, (0, 0, None)),
+    # the bucket is not whole chunks
+    "ragged_tail": ((None, -59), 0, 10, (0, 0, None)),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(_VISIBLE))
+def test_sparse_prefill_does_what_a_chunk_sees(case, interpret):
+    """Chunks of padding that run nothing and chunks under `topk` that
+    keep what they see leave every real position at the row-by-row
+    definition, and the keep-mask of every chunk that runs is
+    `select_topk`'s over the whole bucket, bit for bit: a chunk the plan
+    lets off the selection would have got back what it sees."""
+    offsets, distinct, cut_off, chunks = _VISIBLE[case]
+    rng = _rng(9, sorted(_VISIBLE).index(case))
+    h, hkv, topk, chunk = 8, 2, 24, 32
+    # interpreted kernels want whole key blocks and whole lane rows
+    s, d = ((256, 128) if interpret else (160, 64))
+    s -= cut_off
+    lens = [s if o is None else o % s for o in offsets]
+    n = len(lens)
+    valid = np.arange(s)[None, :] >= (s - np.asarray(lens))[:, None]
+    arr = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
+    q, k, v = arr(n, s, h, d), arr(n, s, hkv, d), arr(n, s, hkv, d)
+    qi, w, ki = arr(n, s, J, DI), arr(n, s, J), arr(n, s, DI)
+    if distinct:
+        ki = arr(distinct, DI)[jnp.asarray(rng.integers(0, distinct, (n, s)))]
+    with _pallas_interpreted(interpret):
+        got = sa.sparse_prefill_attention(
+            q, k, v, qi, w, ki, jnp.asarray(valid), topk=topk,
+            scale=d ** -0.5, chunk=chunk)
+        plan = sa.chunk_plan(jnp.asarray(valid), chunk, topk)
+        # scores and the whole-bucket selection, a chunk at a time
+        pad = lambda a: jnp.pad(a, [(0, 0), (0, -s % chunk)]
+                                + [(0, 0)] * (a.ndim - 2))
+        kv, qi_, w_, ki_ = map(pad, (jnp.asarray(valid), qi, w, ki))
+        kinds = np.asarray(plan).tolist()
+        assert len(kinds) == kv.shape[1] // chunk
+        for i, kind in enumerate(kinds):
+            start = i * chunk
+            real = np.asarray(kv)[:, start:start + chunk].any()
+            assert real == (kind != sa.PADDING)
+            if not real:
+                continue
+            scores = sa.prefill_index_scores(
+                qi_[:, start:start + chunk], w_[:, start:start + chunk],
+                ki_, jnp.int32(start + chunk - 1))
+            seen = kv[:, None, :] & (np.arange(kv.shape[1])[None, None, :]
+                                     <= np.arange(start, start + chunk)[
+                                         None, :, None])
+            keep = np.asarray(sa.select_topk(scores, seen, topk))
+            assert np.array_equal(keep, np.asarray(seen)) \
+                == (kind == sa.DENSE), (i, kind)
+            if distinct:
+                assert len(np.unique(np.asarray(scores)[
+                    0, -1, :start + chunk])) <= distinct
+    count = [kinds.count(kind)
+             for kind in (sa.PADDING, sa.DENSE, sa.SELECTED)]
+    assert count == [len(kinds) - sum(filter(None, chunks)) if c is None
+                     else c for c in chunks], kinds
+    assert np.asarray(sa.plan_counts(plan, n, s)).tolist() == count + [
+        count[2] * n * s, len(kinds) * n * s]
+    want, kept = _dense_oracle(q, k, v, qi, w, ki, valid, topk)
+    assert kept.sum(-1).max() == min(topk, max(lens))
+    assert np.abs(np.asarray(got)[valid] - want[valid]).max() < 2e-4
+
+
+@pytest.mark.parametrize("bucket,prompts,c,topk,want", [
+    # the cell's buckets, chunks of 512, top-2048: a prompt of three
+    # quarters of the bucket (0 padding, 1 no selection, 2 a selection)
+    (4096, [3072], 512, 2048, [0] * 2 + [1] * 4 + [2] * 2),
+    (8192, [6144], 512, 2048, [0] * 4 + [1] * 4 + [2] * 8),
+    (16384, [12288], 512, 2048, [0] * 8 + [1] * 4 + [2] * 20),
+    # the longest row decides, a dummy row nothing
+    (8192, [3000, 8192, 0], 512, 2048, [1] * 4 + [2] * 12),
+    # a chunk selects once its LAST query sees more than topk
+    (160, [160], 32, 70, [1, 1, 2, 2, 2]),
+    # a bucket with nothing to leave out, a bucket of dummy rows
+    (1024, [1024], 512, 2048, [1, 1]),
+    (1024, [0], 512, 2048, [0, 0])])
+def test_plan_of_a_bucket(bucket, prompts, c, topk, want):
+    valid = np.arange(bucket)[None, :] >= (bucket - np.asarray(prompts))[:, None]
+    assert (sa.PADDING, sa.DENSE, sa.SELECTED) == (0, 1, 2)
+    assert np.asarray(sa.chunk_plan(jnp.asarray(valid), c, topk)).tolist() \
+        == want
+
+
+def test_a_plan_handed_in_is_the_one_computed():
+    """A model computes the plan once for its layers: the call that is
+    handed it answers as the call that computes it, to the bit."""
+    rng = _rng(10)
+    n, s, h, hkv, d, topk, chunk = 2, 160, 8, 2, 64, 24, 32
+    valid = jnp.asarray(np.arange(s)[None, :] >= np.asarray([[110], [150]]))
+    arr = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
+    args = (arr(n, s, h, d), arr(n, s, hkv, d), arr(n, s, hkv, d),
+            arr(n, s, J, DI), arr(n, s, J), arr(n, s, DI), valid)
+    call = functools.partial(sa.sparse_prefill_attention, *args, topk=topk,
+                             scale=d ** -0.5, chunk=chunk)
+    plan = sa.chunk_plan(valid, chunk, topk)
+    assert np.asarray(plan).tolist() == [0, 0, 0, 1, 2]
+    assert np.array_equal(np.asarray(call(plan=plan)), np.asarray(call()))

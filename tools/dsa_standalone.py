@@ -8,8 +8,15 @@ selected rows). PERF.md records what a run of this printed.
 
     python tools/dsa_standalone.py [--slots 32] [--context 7168] ...
 
+and, with `--prefill`, one layer of a prefill at each of the cell's
+buckets over a left-padded prompt of three quarters of the bucket: the
+selection alone, for every chunk of queries against for the chunks
+that `kernels.sparse_attention.chunk_plan` says need one, and the whole
+layer.
+
 Needs a TPU. Prints one JSON line a piece: seconds of one call on the
-device (50 calls inside one program, the median of 5 such programs).
+device (50 calls inside one program, the median of 5 such programs; 10
+and 3 for the prefill pieces).
 """
 import argparse
 import json
@@ -82,8 +89,67 @@ def timed(name, fn, tables, *args, reps=50, calls=5):
                       "min_s": min(times)}), flush=True)
 
 
+def prefill_pieces(a, rng):
+    """`prefill.select` old form beside new and `prefill.layer`, a
+    bucket."""
+    d, c, bf, f32 = 128, 512, jnp.bfloat16, jnp.float32
+    zero = jnp.zeros((1, 1), jnp.int32)
+    for s in a.buckets:
+        arr = lambda *sh: jnp.asarray(rng.normal(size=sh).astype(np.float32),
+                                      bf)
+        key_valid = jnp.arange(s)[None, :] >= s // 4
+        base = jnp.asarray(rng.normal(size=(1, c, s)), f32)
+        starts = jnp.arange(0, s, c, dtype=jnp.int32)
+        kpos = jnp.arange(s, dtype=jnp.int32)
+
+        # a chunk's scores move with its position and with the carry
+        scores_at = lambda start, bt: base + (start + bt[0, 0]).astype(
+            f32) * f32(1e-6)
+
+        def whole_bucket(bt):
+            def one(start):
+                seen = key_valid[:, None, :] & (
+                    kpos[None, None, :]
+                    <= (start + jnp.arange(c, dtype=jnp.int32))[None, :, None])
+                return jnp.sum(sa.select_topk(scores_at(start, bt), seen,
+                                              a.topk), dtype=jnp.int32)
+            return jax.lax.map(one, starts)
+
+        def planned(bt):
+            def one(at):
+                start, kind = at
+                seen = key_valid[:, None, :] & (
+                    kpos[None, None, :]
+                    <= (start + jnp.arange(c, dtype=jnp.int32))[None, :, None])
+                # branches in the order PADDING, DENSE, SELECTED
+                return jnp.sum(jax.lax.switch(kind, [
+                    lambda: jnp.zeros_like(seen), lambda: seen,
+                    lambda: sa.select_topk(scores_at(start, bt), seen,
+                                           a.topk)]), dtype=jnp.int32)
+            return jax.lax.map(
+                one, (starts, sa.chunk_plan(key_valid, c, a.topk)))
+
+        kw = dict(reps=10, calls=3)
+        timed(f"prefill.select.whole_bucket.{s}", whole_bucket, zero, **kw)
+        timed(f"prefill.select.planned.{s}", planned, zero, **kw)
+        same = jnp.array_equal(whole_bucket(zero), planned(zero))
+        print(json.dumps({"bucket": s, "selections_agree": bool(same)}),
+              flush=True)
+        q = arr(1, s, a.heads, d)
+        k, v = arr(1, s, a.kv_heads, d), arr(1, s, a.kv_heads, d)
+        qi, ki = arr(1, s, a.index_heads, a.index_dim), arr(1, s, a.index_dim)
+        w = jnp.asarray(rng.normal(size=(1, s, a.index_heads)), f32)
+        timed(f"prefill.layer.{s}", lambda bt: sa.sparse_prefill_attention(
+            q, k, v, qi, w + bt[0, 0].astype(f32), ki, key_valid,
+            topk=a.topk, scale=d ** -0.5, chunk=c), zero, **kw)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--prefill", action="store_true",
+                    help="the prefill pieces, and nothing of the decode step")
+    ap.add_argument("--buckets", type=int, nargs="+",
+                    default=[4096, 8192, 16384])
     ap.add_argument("--slots", type=int, default=32)
     ap.add_argument("--context", type=int, default=7168)
     ap.add_argument("--pages-per-seq", type=int, default=1024)
@@ -97,6 +163,11 @@ def main(argv=None):
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("the stand-alone times need a TPU")
     rng = np.random.default_rng(0)
+    if a.prefill:
+        print(json.dumps({"device": jax.devices()[0].device_kind,
+                          "prompt_share_of_bucket": 0.75}), flush=True)
+        prefill_pieces(a, rng)
+        return 0
     page, d, bf = 16, 128, jnp.bfloat16
     b, pps = a.slots, a.pages_per_seq
     arr = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32), bf)

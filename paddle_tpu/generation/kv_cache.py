@@ -19,8 +19,10 @@ prefix caching / SGLang RadixAttention), consumed by
 inference.ContinuousBatchingPredictor (docs/SERVING.md). A model whose
 layers are not all attention declares what each layer keeps
 (`LayerCache`, from the model's `cache_layout()`): K/V pages for the
-attention layers, and for the recurrent ones a row a slot in
-`StatePool`, constant in the context's length (`StateCacheEntry`).
+attention layers (or, for a latent-attention layer, ONE row a token
+for all heads in place of K and V: `LatentCacheEntry`), and for the
+recurrent ones a row a slot in `StatePool`, constant in the context's
+length (`StateCacheEntry`).
 """
 from __future__ import annotations
 
@@ -80,8 +82,13 @@ class LayerCache(NamedTuple):
     head_dim), paged; a layer whose attention selects its keys by a
     learned indexer also declares `index_dim`, the width of the one
     index key a token it keeps in a third paged array under the same
-    page ids. kind "state": `shape` = ((d_conv - 1, channels), (heads,
-    head_dim, d_state)), one row a slot."""
+    page ids. kind "latent": `shape` = (width,), ONE row a token for
+    all heads (multi-head latent attention: the compressed latent and
+    the shared rotated key part), paged under the same page ids in one
+    array INSTEAD of K and V. kind "state": `shape` = ((d_conv - 1,
+    channels), (heads, head_dim, d_state)), one row a slot (a Mamba-2
+    mixer's SSM state or a delta-rule mixer's [heads, d_k, d_v]
+    matrix: the pool holds either as it is)."""
     kind: str
     shape: tuple
     index_dim: int = 0
@@ -99,8 +106,9 @@ class LayerCaches(list):
 
 class StatePool:
     """Per-slot state of the recurrent layers, beside the pages: for
-    each such layer a convolution window and a float32 SSM state, one
-    row a slot and one row more. A slot's row is written whole by its
+    each such layer a convolution window and a float32 state (`ssm`: a
+    Mamba-2 mixer's [heads, head_dim, d_state], a delta-rule mixer's
+    [heads, d_k, d_v]), one row a slot and one row more. A slot's row is written whole by its
     prefill, so a reused slot owes nothing to its last tenant; there is
     nothing to allocate, share or reclaim."""
 
@@ -141,17 +149,29 @@ class PagedKVPool:
 
     def __init__(self, n_layers, num_pages, page_size, n_kv_heads,
                  head_dim, dtype="float32", mesh=None, device=None,
-                 index_dim=0):
+                 index_dim=0, latent_dim=0, latent_layers=()):
         import jax.numpy as jnp
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         shape = (num_pages, page_size, n_kv_heads, head_dim)
+        # a latent layer (`latent_layers`: its places among the
+        # `n_layers` paged ones) keeps ONE array, one row a token for
+        # all heads, in place of K and V: it stands in `k` (the row is
+        # the layer's key, and its first numbers the value) and `v`
+        # holds None there. Same page ids, allocator, trash page and
+        # copy-on-write; rows on whole 128-lane rows, zeros past
+        # `latent_dim`, for the reason the index keys are
+        latent_shape = (num_pages, page_size,
+                        -(-int(latent_dim) // 128) * 128)
+        self.latent_layers = frozenset(int(i) for i in latent_layers)
         # `device` commits an unsharded pool to one device (a router
         # replica's own); None leaves it on the default device
-        self.k = [jnp.zeros(shape, dtype, device=device)
-                  for _ in range(n_layers)]
-        self.v = [jnp.zeros(shape, dtype, device=device)
-                  for _ in range(n_layers)]
+        self.k = [jnp.zeros(latent_shape if i in self.latent_layers
+                            else shape, dtype, device=device)
+                  for i in range(n_layers)]
+        self.v = [None if i in self.latent_layers
+                  else jnp.zeros(shape, dtype, device=device)
+                  for i in range(n_layers)]
         # layers with an indexer: one index key a token, a third array a
         # layer under the SAME page ids, so the allocator, the trash
         # page and copy-on-write cover it with no table of its own. A
@@ -184,6 +204,9 @@ class PagedKVPool:
                     f"model={tp} (head count must divide)")
             self.kv_sharding = NamedSharding(
                 mesh, PartitionSpec(None, None, "model", None))
+            if self.latent_layers:
+                raise ValueError("latent pages have no head axis to shard "
+                                 "over 'model'")
             self.k = [jax.device_put(a, self.kv_sharding) for a in self.k]
             self.v = [jax.device_put(a, self.kv_sharding) for a in self.v]
             self.topology = f"tp{tp}"
@@ -194,6 +217,11 @@ class PagedKVPool:
         # of those pages have the trie as their ONLY holder (refcount 1)
         self._cache_held = {}
         self._reclaimable = 0
+
+    @property
+    def latent(self):
+        """The latent layers' page arrays, in layer order."""
+        return [self.k[i] for i in sorted(self.latent_layers)]
 
     @property
     def free_count(self):
@@ -264,7 +292,8 @@ class PagedKVPool:
         import numpy as np
         if not hasattr(self, "_copy_jit"):
             def _copy(pools, s, d):
-                return [[a.at[d].set(a[s]) for a in pool] for pool in pools]
+                return [[None if a is None else a.at[d].set(a[s])
+                         for a in pool] for pool in pools]
             dn = (0,) if jax.default_backend() != "cpu" else ()
             self._copy_jit = jax.jit(_copy, donate_argnums=dn)
         self.k, self.v, self.index = self._copy_jit(
@@ -754,6 +783,17 @@ class PagedCacheEntry(NamedTuple):
     index_pages: object = None
 
 
+class LatentCacheEntry(NamedTuple):
+    """A latent-attention layer's cache for one decode step. `pages`:
+    [num_pages, page_size, lanes], one row a token for all heads (the
+    layer's `LayerCache.shape[0]` numbers, then zeros to whole 128-lane
+    rows); `block_table` and `context_lens` as in `PagedCacheEntry`.
+    The layer steps through `paged_cache_latent_update_attend`."""
+    pages: object
+    block_table: object
+    context_lens: object
+
+
 class StateCacheEntry(NamedTuple):
     """A recurrent layer's cache for one decode step: the whole state
     pool of that layer, updated in place. `conv`: [slots + 1, d_conv - 1,
@@ -935,3 +975,32 @@ def paged_cache_sparse_update_attend(entry: PagedCacheEntry, q, k, v, qi, w,
         _name="paged_sparse_attention_decode")
     return out, entry._replace(k_pages=kp2, v_pages=vp2,
                                index_pages=ip2), n_sel
+
+
+def paged_cache_latent_update_attend(entry: LatentCacheEntry, q, row,
+                                     scale=None):
+    """Decode-step contract of a latent-attention layer: write this
+    step's row (one token a slot) at each slot's current page position,
+    then attend the absorbed query over the slot's live rows
+    (kernels.latent_attention.paged_latent_attention). q [B, 1, H,
+    width]; row [B, 1, width] -> (out [B, 1, H, lanes]: the softmax-
+    weighted sum of whole rows, of which the caller keeps the latent's
+    part, updated entry). Gradients are not defined (serving path)."""
+    import jax.numpy as jnp
+    from ..ops._dispatch import apply
+    from ..kernels.latent_attention import (latent_rows,
+                                            paged_latent_attention)
+
+    def fn(pages, bt, cl, qv, rv):
+        page = pages.shape[1]
+        rows = jnp.arange(qv.shape[0])
+        at = (bt[rows, (cl // page).astype(jnp.int32)],
+              (cl % page).astype(jnp.int32))
+        pages2 = pages.at[at].set(latent_rows(rv[:, 0], pages))
+        out = paged_latent_attention(qv[:, 0], pages2, bt, cl + 1, scale)
+        return out[:, None].astype(qv.dtype), pages2
+
+    out, pages2 = apply(fn, entry.pages, entry.block_table,
+                        entry.context_lens, q, row,
+                        _name="paged_latent_attention_decode")
+    return out, entry._replace(pages=pages2)

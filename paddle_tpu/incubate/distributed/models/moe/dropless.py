@@ -4,10 +4,11 @@
 what overflows: right for training at scale, and something no plain
 reference can match token for token. Serving needs the other contract:
 every assignment is computed. This layer routes over ALL `num_experts`
-(the router keeps its published width), takes the `top_k` largest
-logits of each token, gates them by the softmax over those `top_k`
-logits alone, and computes the part of the result that the experts in
-`held` give. An assignment to an expert that lives on another chip adds
+(the router keeps its published width) by a routing rule (`route`: the
+`top_k` largest logits of each token, gated by the softmax over those
+`top_k` logits alone, unless the model brings another, such as
+`group_limited_sigmoid_route`), and computes the part of the result
+that the experts in `held` give. An assignment to an expert that lives on another chip adds
 nothing here, and nothing stands in for that chip or for its exchange:
 under expert parallelism the partial results of the ranks add up (the
 shared expert, which every rank computes alike, counted once).
@@ -43,14 +44,52 @@ def _count(ids, n):
     return jnp.zeros((n + 1,), jnp.int32).at[ids].add(1)[:n]
 
 
-def _route_block(x, valid, router_w, w_in, w_out, local_of, n_held, top_k):
+def softmax_topk_route(logits, top_k):
+    """The `top_k` largest logits of each token, gated by the softmax
+    over those alone. logits [n, experts] float32 -> (gates [n, k]
+    float32, expert ids [n, k] int32)."""
+    topv, topi = jax.lax.top_k(logits, top_k)                  # [n, k]
+    return jax.nn.softmax(topv, axis=-1), topi
+
+
+def group_limited_sigmoid_route(logits, bias, top_k, n_group, topk_group,
+                                scale, norm=True):
+    """DeepSeek-V3's rule (arXiv:2412.19437, `noaux_tc`). Scores `s =
+    sigmoid(logits)`; the CHOICE is made on `s + bias`: the experts lie
+    in `n_group` equal groups, a group scores the sum of its two largest
+    choice scores, the `topk_group` best groups stay, and among their
+    experts the `top_k` largest choice scores are taken (ties to the
+    lower id). The GATES are the chosen experts' `s`, without the bias:
+    over their sum (`norm`), times `scale`. logits [n, experts] float32,
+    bias [experts] -> (gates [n, k] float32, ids [n, k] int32)."""
+    n, e = logits.shape
+    s = jax.nn.sigmoid(logits)
+    choice = s + bias.astype(jnp.float32)[None, :]
+    per = choice.reshape(n, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(per, 2)[0], axis=-1)   # [n, groups]
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    open_ = jnp.zeros((n, n_group), jnp.bool_).at[
+        jnp.arange(n, dtype=jnp.int32)[:, None], kept].set(True)
+    masked = jnp.where(jnp.repeat(open_, e // n_group, axis=1), choice,
+                       -jnp.inf)
+    _, topi = jax.lax.top_k(masked, top_k)
+    gates = jnp.take_along_axis(s, topi, axis=1)
+    if norm:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                         + jnp.float32(1e-20))
+    return gates * jnp.float32(scale), topi
+
+
+def _route_block(x, valid, router_w, w_in, w_out, local_of, n_held, top_k,
+                 route=None):
     """x [n, h], valid [n] bool -> (y [n, h] float32, counts int32
     [2 + n_held] = assignments, local assignments, tokens per held
     expert; pad and idle rows are computed but not counted)."""
     n, _ = x.shape
-    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-    topv, topi = jax.lax.top_k(logits, top_k)                  # [n, k]
-    gates = jax.nn.softmax(topv, axis=-1)                      # float32
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+        gates, topi = softmax_topk_route(logits, top_k) if route is None \
+            else route(logits)
     loc = local_of[topi].reshape(-1)          # [n * k], n_held = absent
     rows = jnp.arange(n * top_k, dtype=jnp.int32)
     _, order = jax.lax.sort_key_val(loc, rows)     # stable, by expert
@@ -78,8 +117,11 @@ def _route_block(x, valid, router_w, w_in, w_out, local_of, n_held, top_k):
     return y, jnp.concatenate([head, per])
 
 
-def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k):
-    """x [..., h] -> (y like x, counts int32 [2 + len(held)])."""
+def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k,
+                 route=None):
+    """x [..., h] -> (y like x, counts int32 [2 + len(held)]). `route`
+    (logits [n, experts] float32 -> gates [n, top_k] float32, ids [n,
+    top_k]) replaces the softmax-over-top-k rule."""
     n_experts = router_w.shape[1]
     local_of = np.full((n_experts,), len(held), np.int32)
     local_of[list(held)] = np.arange(len(held), dtype=np.int32)
@@ -90,7 +132,7 @@ def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k):
 
     def block(xv):
         return _route_block(xv[0], xv[1], router_w, w_in, w_out, local_of,
-                            len(held), top_k)
+                            len(held), top_k, route)
 
     n = flat.shape[0]
     if n > BLOCK_TOKENS and n % BLOCK_TOKENS == 0:

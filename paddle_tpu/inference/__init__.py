@@ -444,6 +444,12 @@ class ContinuousBatchingPredictor:
       page ids, threaded and donated with K and V; the same four are
       refused and the prefix cache is derived off (docs/SERVING.md
       "Sparse attention: an index cache beside the pages").
+      A latent-attention layer declares ONE row a token for all
+      heads: one page array in place of K and V, under the same page
+      ids (docs/SERVING.md "Latent pages: one row a token"). A model
+      that sets `long_prefill` gets the prefill for prompts of
+      thousands of tokens: key validity in, the last position's logits
+      out, at most two prompts a program.
     - **Device-resident prefill.** Admission runs ONE jitted program
       per (batch, prompt-bucket) that embeds the causal/padding mask
       in-graph, runs the forward, computes the greedy next token for
@@ -652,38 +658,54 @@ class ContinuousBatchingPredictor:
         self._layout = list(model.cache_layout())
         kv_shapes = {(c.shape, c.index_dim) for c in self._layout
                      if c.kind == "kv"}
+        latent_shapes = {c.shape for c in self._layout if c.kind == "latent"}
         state_shapes = {c.shape for c in self._layout if c.kind == "state"}
-        if len(kv_shapes) != 1 or len(state_shapes) > 1 or any(
-                c.kind not in ("kv", "state") for c in self._layout):
+        if len(kv_shapes) > 1 or len(latent_shapes) > 1 \
+                or not (kv_shapes or latent_shapes) \
+                or len(state_shapes) > 1 or any(
+                    c.kind not in ("kv", "latent", "state")
+                    for c in self._layout):
             raise ValueError(
                 f"cache_layout(): served are layers of kind 'kv' (K/V "
                 f"pages, with or without a page array of index keys) in "
-                f"ONE geometry, at least one of them, and layers of kind "
-                f"'state' (a conv window and an SSM state a slot) in one "
-                f"geometry; got kv (shape, index_dim) {sorted(kv_shapes)}, "
-                f"state {sorted(state_shapes)}, kinds "
-                f"{sorted({c.kind for c in self._layout})}")
-        ((n_kv_heads, head_dim), self._index_dim), = kv_shapes
+                f"ONE geometry, layers of kind 'latent' (one row a token "
+                f"for all heads, paged in place of K and V) in one "
+                f"geometry, at least one paged layer of either kind, and "
+                f"layers of kind 'state' (a conv window and a state "
+                f"matrix a slot) in one geometry; got kv (shape, "
+                f"index_dim) {sorted(kv_shapes)}, latent "
+                f"{sorted(latent_shapes)}, state {sorted(state_shapes)}, "
+                f"kinds {sorted({c.kind for c in self._layout})}")
+        ((n_kv_heads, head_dim), self._index_dim), = \
+            kv_shapes or {((0, 0), 0)}
+        self._latent_dim = next(iter(latent_shapes), (0,))[0]
         self._state_shape = next(iter(state_shapes), None)
         # head-sharded paged KV: pages shard over the KV-head axis of
         # the TP mesh when the head count divides; an indivisible model
         # keeps replicated pages (still served, fast path lost) and the
         # downgrade is recorded like any other lost kernel path
         kv_mesh = self._tp_mesh
-        if kv_mesh is not None and n_kv_heads % self.tp:
+        if kv_mesh is not None and (self._latent_dim
+                                    or n_kv_heads % self.tp):
             from ..kernels._common import note_fallback
             note_fallback("paged_kv_pool", "tp_head_shard")
             kv_mesh = None
+        paged = [c for c in self._layout if c.kind != "state"]
         self.pool = PagedKVPool(
-            sum(c.kind == "kv" for c in self._layout), num_pages + 1,
+            len(paged), num_pages + 1,
             page_size, n_kv_heads, head_dim, dtype=kv_dtype, mesh=kv_mesh,
-            device=self._device, index_dim=self._index_dim)
+            device=self._device, index_dim=self._index_dim,
+            latent_dim=self._latent_dim,
+            latent_layers=[i for i, c in enumerate(paged)
+                           if c.kind == "latent"])
         self.state_pool = None      # built once the refusals have passed
         # cached pages hold the attention layers' K/V only: a hit would
         # resume recurrent layers from no state at all, and a suffix
-        # prefill does not select over cached index pages
+        # prefill neither selects over cached index pages nor reads
+        # latent rows
         lost = "recurrent_state" if self._state_shape is not None \
-            else "sparse_index" if self._index_dim else None
+            else "sparse_index" if self._index_dim \
+            else "latent_pages" if self._latent_dim else None
         if lost and enable_prefix_cache:
             enable_prefix_cache = False
             _obsm.counter("kernels.pallas_fallbacks").inc(
@@ -835,7 +857,8 @@ class ContinuousBatchingPredictor:
                     f"most {fit} at {cfg.num_attention_heads // self.tp} "
                     f"heads x {head_dim} (kernels.paged_attention."
                     f"max_varq_span)")
-        if self._state_shape is not None or self._index_dim:
+        if self._state_shape is not None or self._index_dim \
+                or self._latent_dim:
             refused = [n for n, on in (
                 ("prefill_chunk_tokens", self._chunk_max > 0),
                 ("spec_draft_tokens", self._spec_k > 0),
@@ -848,6 +871,12 @@ class ContinuousBatchingPredictor:
                 "to, a page span carries no state to hand off, and the "
                 "mixer has no sharding rule (docs/SERVING.md 'Hybrid "
                 "models')") if self._state_shape is not None else (
+                "latent pages. A layer's one row a token is written and "
+                "attended to one query token a slot a step: the span "
+                "programs (chunked prefill, speculative verify) and the "
+                "page span of a hand-off carry K and V arrays, and a row "
+                "has no head axis to shard (docs/SERVING.md 'Latent "
+                "pages')") if not self._index_dim else (
                 "an attention indexer. Its keys are selected for one "
                 "query token a slot a step: a query span (chunked "
                 "prefill, speculative verify) would select a set for "
@@ -857,16 +886,24 @@ class ContinuousBatchingPredictor:
             if refused:
                 raise ValueError(f"{', '.join(refused)}: not served for a "
                                  f"model with {why}")
-        # prompts one prefill program takes (None: a round's whole
-        # bucket). A prompt whose attention selects its keys is thousands
-        # of tokens of compute-bound work on its own: a larger batch
+        # the long prefill, for a model that asks for it
+        # (`model.long_prefill`: one served at prompts of thousands of
+        # tokens): the keys' validity and the positions go in, the model
+        # builds causality inside its kernels (no [bucket, bucket] mask)
+        # and gives the logits of the LAST position alone, and a program
+        # takes at most two prompts (None: a round's whole bucket). Such
+        # a prompt is compute-bound work on its own: a larger batch
         # amortises nothing, its temporaries grow with rows x bucket
         # (2 x 16384 at 12 layers: 2.0 GB), and every further row count
         # is one more program a bucket to compile before serving
-        self._prefill_rows = 2 if self._index_dim else None
+        self._long_prefill = bool(getattr(model, "long_prefill", False))
+        self._prefill_rows = 2 if self._long_prefill else None
         if self._index_dim:
             _obsm.gauge("serving.index_pool_bytes").set(
                 sum(a.nbytes for a in self.pool.index), **self._mlbl)
+        if self._latent_dim:
+            _obsm.gauge("serving.latent_pool_bytes").set(
+                sum(a.nbytes for a in self.pool.latent), **self._mlbl)
         if self._state_shape is not None:
             conv_shape, ssm_shape = self._state_shape
             self.state_pool = StatePool(
@@ -1163,8 +1200,9 @@ class ContinuousBatchingPredictor:
 
     # The serve programs take the caches as two lists in layer order
     # (donated): for a "kv" layer its K and V pages, for a "state" layer
-    # its conv window and SSM state rows. A layer with an indexer gives
-    # its keys as the pair (K pages, index-key pages).
+    # its conv window and state rows, for a "latent" layer its one page
+    # array and None. A layer with an indexer gives its keys as the pair
+    # (K pages, index-key pages).
     def _cache_args(self):
         keys = list(zip(self.pool.k, self.pool.index)) if self._index_dim \
             else self.pool.k
@@ -1172,19 +1210,19 @@ class ContinuousBatchingPredictor:
             return keys, self.pool.v
         pages = iter(zip(keys, self.pool.v))
         rows = iter(zip(self.state_pool.conv, self.state_pool.ssm))
-        pairs = [next(pages if c.kind == "kv" else rows)
+        pairs = [next(rows if c.kind == "state" else pages)
                  for c in self._layout]
         return [a for a, _ in pairs], [b for _, b in pairs]
 
     def _cache_store(self, first, second):
         """Adopt a program's output caches (the inputs were donated)."""
         kinds = [c.kind for c in self._layout]
-        keys = [a for a, k in zip(first, kinds) if k == "kv"]
+        keys = [a for a, k in zip(first, kinds) if k != "state"]
         if self._index_dim:
             self.pool.index = [x for _, x in keys]
             keys = [k for k, _ in keys]
         self.pool.k = keys
-        self.pool.v = [a for a, k in zip(second, kinds) if k == "kv"]
+        self.pool.v = [a for a, k in zip(second, kinds) if k != "state"]
         if self.state_pool is not None:
             self.state_pool.conv = [a for a, k in zip(first, kinds)
                                     if k == "state"]
@@ -1196,13 +1234,15 @@ class ContinuousBatchingPredictor:
         its kind. A model that counts what its tokens do (recurrent
         layers, step counters) is also told which rows carry a request
         (an empty slot's table is all trash)."""
-        from ..generation.kv_cache import (PagedCacheEntry, PagedKVCache,
-                                           StateCacheEntry)
+        from ..generation.kv_cache import (LatentCacheEntry, PagedCacheEntry,
+                                           PagedKVCache, StateCacheEntry)
         paged = (Tensor(tables), Tensor(ctx), meta)
 
         def entry(i, c):
             if c.kind == "state":
                 return StateCacheEntry(kl[i], vl[i])
+            if c.kind == "latent":
+                return LatentCacheEntry(kl[i], *paged[:2])
             if c.index_dim:
                 return PagedCacheEntry(kl[i][0], vl[i], *paged,
                                        index_pages=kl[i][1])
@@ -1218,7 +1258,8 @@ class ContinuousBatchingPredictor:
         """A decode step's updated caches, as the two operand lists."""
         first = [(_raw(e.k_pages), _raw(e.index_pages)) if c.index_dim
                  else _raw(e[0]) for c, e in zip(self._layout, caches)]
-        return first, [_raw(e[1]) for e in caches]
+        return first, [None if c.kind == "latent" else _raw(e[1])
+                       for c, e in zip(self._layout, caches)]
 
     def _counter_outputs(self, caches):
         """The model's summed counts, as extra outputs of a program."""
@@ -1247,14 +1288,15 @@ class ContinuousBatchingPredictor:
         dummy's is the pool's last row)."""
         from ..jit.bridge import bound_state
         from ..kernels.paged_attention import index_key_rows
+        from ..kernels.latent_attention import latent_rows
         n, bucket = ids.shape
         j = jnp.arange(bucket, dtype=jnp.int32)
         key_valid = j[None, :] >= (bucket - lens)[:, None]      # [N, S]
-        if self._index_dim:
-            # a model with an indexer takes the keys' validity alone: it
-            # builds causality and its selection from the positions, a
-            # chunk of queries at a time (no [bucket, bucket] array),
-            # and returns the logits of the last position only
+        if self._long_prefill:
+            # the model takes the keys' validity alone: it builds
+            # causality (and a selection, where it has an indexer) from
+            # the positions inside its kernels (no [bucket, bucket]
+            # array), and returns the logits of the last position only
             mask = key_valid
         else:
             causal = j[None, :] <= j[:, None]                   # [Sq, Sk]
@@ -1277,9 +1319,14 @@ class ContinuousBatchingPredictor:
                             0).astype(jnp.int32)
         new_k, new_v = [], []
         for li, (layer, kept) in enumerate(zip(self._layout, caches)):
-            where = (dst_page, dst_off) if layer.kind == "kv" else slots[0]
+            where = slots[0] if layer.kind == "state" \
+                else (dst_page, dst_off)
             put = lambda old, new: old.at[where].set(
                 _raw(new).astype(old.dtype))
+            if layer.kind == "latent":
+                new_k.append(put(kl[li], latent_rows(_raw(kept[0]), kl[li])))
+                new_v.append(None)
+                continue
             new_k.append((put(kl[li][0], kept[0]),
                           put(kl[li][1], index_key_rows(_raw(kept[2]),
                                                         kl[li][1])))

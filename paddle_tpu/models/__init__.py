@@ -13,3 +13,4 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
 from .granite_hybrid import (GraniteMoeHybridConfig,
                              GraniteMoeHybridForCausalLM)
 from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
+from .ling_hybrid import LingHybridConfig, LingHybridForCausalLM

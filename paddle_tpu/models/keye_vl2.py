@@ -288,6 +288,11 @@ class KeyeVL2ForCausalLM(Layer):
     programs make (the module's docstring says what each argument may
     be)."""
 
+    # the serve loop's prefill hands over the keys' validity, not a
+    # dense mask, and takes the last position's logits (inference/
+    # __init__.py, "the long prefill")
+    long_prefill = True
+
     def __init__(self, config: KeyeVL2Config):
         super().__init__()
         self.config = config

@@ -270,8 +270,9 @@ def _cell_predictor(chip, cell):
     # code that asks the backend sees the CPU here: the kernel gates
     # follow the flag alone, as they do on the chip
     patch = pytest.MonkeyPatch()
-    from paddle_tpu.kernels import sparse_attention
-    for mod in (attention, norm, pa, sparse_attention):
+    from paddle_tpu.kernels import kda, latent_attention, sparse_attention
+    for mod in (attention, norm, pa, sparse_attention, latent_attention,
+                kda):
         patch.setattr(mod, "_use_pallas",
                       lambda: bool(flag_value("use_pallas_kernels")))
     cfg = harness.find_cell(root, cell)["cfg"]
@@ -425,6 +426,55 @@ def test_keye_largest_prefill_at_real_size(chip, keye):
     assert live < 14.5e9, live
 
 
+# --- the linear-attention cell at its real geometry -------------------------
+# 32 slots of up to 16384 positions over 25601 pages; 7 layers: six KDA (a
+# float32 [32, 128, 128] state and a conv window over 12288 channels a
+# slot) and one MLA (a 576-wide latent row a token on 640 lanes), two
+# dense and five expert layers with 128 of 512 experts held.
+
+@pytest.fixture(scope="module")
+def ling(chip):
+    yield from _cell_predictor(chip, "ling3f-longdoc-open")
+
+
+def test_ling_decode_step_at_real_size(chip, ling):
+    pred, n_params, fixed = ling
+    assert n_params == 4_454_368_704        # the issue's 4.45 B
+    B, pps = pred.B, pred.pages_per_seq
+    # no K/V anywhere: latent rows in place of keys, nothing for values
+    assert all(v is None for v in pred.pool.v)
+    assert [a.shape for a in pred.pool.k] == [(25601, 16, 640)]
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_decode_step, (B, pps), (B,), (B,))
+    assert " f64[" not in text and " s64[" not in text
+    assert live < 11.0e9, live
+    # latent pages and state rows are updated where they lie
+    pool = pred.state_pool.nbytes + sum(a.nbytes for a in pred.pool.latent)
+    assert ma.alias_size_in_bytes >= pool
+    state = f"f32[{B + 1},32,128,128]"
+    assert not re.search(r"= " + re.escape(state) + r"\S* copy", text)
+    # one state kernel a KDA layer, its first output the donated rows
+    assert len(re.findall(r"%kda\.state_update[.\d]* = \(" + re.escape(state),
+                          text)) == 6
+    # the MLA layer decodes through the latent kernel: no slot's table
+    # of rows is gathered
+    assert f"[{B},{pps},16,640]" not in text
+    assert re.search(r"bf16\[%d,32,640\]\S* custom-call\(" % B, text)
+    assert text.count("ragged-dot-none") >= 10      # 2 an expert layer
+
+
+def test_ling_largest_prefill_at_real_size(chip, ling):
+    pred, _, fixed = ling
+    n, bucket = 2, 16384
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_prefill, (n, bucket), (n, bucket),
+        (n,), (n, bucket // pred.page), (n,))
+    assert " f64[" not in text and " s64[" not in text
+    assert f"[{n},1,{bucket},{bucket}]" not in text
+    assert f"[{n},{bucket},{pred.model.config.vocab_size}]" not in text
+    assert live < 14.5e9, live
+
+
 # --- the other cells' decode steps have not moved --------------------------
 # sha256 of each cell's lowered decode step at the parent of PR 33
 # (0b146e3), every Mosaic kernel's body re-printed without its debug
@@ -439,6 +489,10 @@ DECODE_STEP_AT_PARENT = {
         "d345aebdde0457cc21ca9a1a03dfb08a121547470e722bbdca52590450005a29",
     "granite4h-chat-open":
         "64d706b3c231ec57bbb1bc8f8bb7529b05154f10574fcce7003441b3d0e0e1bf",
+    # at the parent of PR 35 (d1f5296): `dropless.py` takes the routing
+    # rule as an argument and the long prefill is asked for by the model
+    "keye2-longprompt-open":
+        "427276cffc468292a08f13b84731d77ef7c91b97e9fbd8b58e727e7c12c9fb14",
 }
 
 

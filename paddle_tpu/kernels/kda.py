@@ -25,7 +25,7 @@ Three forms of the same recurrence (models/ling_hybrid.py):
 
   which is triangular and does not hold S_0: `T = (I + diag(beta) A)^-1
   diag(beta)` applied to V and to K * exp(G) is computed for all chunks
-  at once (a float32 triangular solve), and only
+  at once, and only
 
       U = T V - (T (K exp(G))) S_0
       O = (Q exp(G)) S_0 + B U          B[t, i] = A's form with q_t, i <= t
@@ -38,6 +38,18 @@ Three forms of the same recurrence (models/ling_hybrid.py):
   sub x |lower bound| (16 x 5 = 80 < 88). The caller takes a long
   prompt a segment at a time and hands the state on, so that what is
   computed for all chunks at once stays a segment's size.
+
+The inverse is built, not solved for (`unit_lower_inverse`): XLA expands
+a triangular solve of systems this small into a sweep over rows that
+took a sixth of a prefill on the chip (PR 36). The `sub`-wide diagonal
+blocks are inverted by forward substitution, a row at a time over every
+block of every chunk at once; the blocks below them follow by block
+forward substitution as float32 matmuls, and one float32 matmul a chunk
+applies the inverse to [V, K exp(G)]. That is as close to a float64
+solve as the solve was (2e-7 of the largest entry, nearly identical
+keys under beta = 0.999 included). The nilpotent doubling product
+(I - N)(I + N^2)(I + N^4)... is not: on those keys its factors reach
+1e11 and cancel to nothing, and it is 6e-4 off on 16 x 16 blocks alone.
 
 The state, the decays and the triangular system are float32 whatever
 the activations' dtype; the matmuls against the state take the
@@ -204,6 +216,45 @@ def kda_sequential(q, k, v, g, beta, state=None):
     return jnp.moveaxis(o, 0, 1).astype(v.dtype), state
 
 
+def unit_lower_inverse(strict, sub):
+    """(I + N)^-1 for N = `strict` [..., c, c] float32, strictly lower
+    triangular, c whole blocks of `sub`: the diagonal blocks by forward
+    substitution, a row at a time over every block at once (multiply
+    and reduce: nothing a matmul), then the blocks below them by block
+    forward substitution, as matmuls."""
+    ns = strict.shape[-1] // sub
+    diag = jnp.stack([strict[..., a * sub:(a + 1) * sub,
+                             a * sub:(a + 1) * sub] for a in range(ns)],
+                     axis=-3)                           # [..., ns, sub, sub]
+    at = jnp.arange(sub, dtype=jnp.int32)
+
+    def row(i, x):      # X[i] = e_i - N[i, :i] X[:i]; X's later rows are e_j
+        n_i = jax.lax.dynamic_index_in_dim(diag, i, diag.ndim - 2, False)
+        new = (at == i).astype(F32) - jnp.sum(n_i[..., None] * x, axis=-2)
+        return jax.lax.dynamic_update_index_in_dim(x, new, i, x.ndim - 2)
+
+    # rolled: unrolled rows run a third slower on the chip and take
+    # twice the set-up (PR 36)
+    x = jax.lax.fori_loop(jnp.int32(1), jnp.int32(sub), row,
+                          jnp.broadcast_to(jnp.eye(sub, dtype=F32),
+                                           diag.shape))
+    # `inv` inverts the leading a blocks; block row a of the inverse is
+    # [-X_a N[a, :a] inv, X_a]
+    inv = x[..., 0, :, :]
+    for a in range(1, ns):
+        below = strict[..., a * sub:(a + 1) * sub, :a * sub]
+        x_a = x[..., a, :, :]
+        inv = jnp.concatenate([
+            jnp.pad(inv, [(0, 0)] * (inv.ndim - 1) + [(0, sub)]),
+            jnp.concatenate([-(x_a @ (below @ inv)), x_a], axis=-1)], axis=-2)
+    return inv
+
+
+# jitted: a prefill program calls it once a KDA layer on one set of
+# shapes, so it is traced once a process and lowered once a program and
+# not once a layer (that is 5 s of a warm set-up on the chip's host, what
+# the inverse's ops had added to it: PR 36); XLA inlines the calls
+@functools.partial(jax.jit, static_argnames=("chunk", "sub"))
 def kda_chunked(q, k, v, g, beta, state=None, chunk=64, sub=16):
     """The same recurrence by chunks, from `state` (zeros when None). q,
     k [b, l, h, dk]; v [b, l, h, dv]; g [b, l, h, dk] and beta [b, l, h]
@@ -250,11 +301,11 @@ def kda_chunked(q, k, v, g, beta, state=None, chunk=64, sub=16):
         a_mat = jnp.where(t[:, None] > t[None, :], pair(k), F32(0))
         b_mat = jnp.where(t[:, None] >= t[None, :], pair(q), F32(0))
         decay = jnp.exp(cum)
-        system = jnp.eye(c, dtype=F32) + beta[..., None] * a_mat
-        solved = jax.lax.linalg.triangular_solve(
-            system,
+        solved = jnp.einsum(
+            "bhnti,bhnix->bhntx",
+            unit_lower_inverse(beta[..., None] * a_mat, sub),
             beta[..., None] * jnp.concatenate([v, k * decay], axis=-1),
-            left_side=True, lower=True, unit_diagonal=True)
+            preferred_element_type=F32)
         w_v, w_k = solved[..., :dv], solved[..., dv:]
         q_in = q * decay
         k_out = k * jnp.exp(cum[:, :, :, -1:] - cum)

@@ -33,7 +33,7 @@ from paddle_tpu.incubate.distributed.models.moe.dropless import (  # noqa: E402
 from paddle_tpu.inference import ContinuousBatchingPredictor  # noqa: E402
 from paddle_tpu.kernels import latent_attention as la  # noqa: E402
 from paddle_tpu.kernels.kda import (kda_chunked, kda_sequential,  # noqa: E402
-                                    kda_step)
+                                    kda_step, unit_lower_inverse)
 from paddle_tpu.models import (LingHybridConfig,  # noqa: E402
                                LingHybridForCausalLM)
 from paddle_tpu.observability import metrics  # noqa: E402
@@ -123,6 +123,77 @@ def test_chunked_delta_rule_is_the_sequential_recurrence(length, kw):
     rounded = s_seq.astype(jnp.bfloat16).astype(F32)
     assert float(jnp.abs(s_seq - rounded).max()) \
         > 1e-3 * float(jnp.abs(s_seq).max())
+
+
+def _strict_systems(case, c, lead=(2, 3, 5), dk=16):
+    """N = diag(beta) tril(K K^T, -1), [2, 3, 5, c, c] float64, from
+    L2-normalised keys (g = 0: no decay, the largest entries)."""
+    rng = np.random.default_rng(c)
+    keys = rng.normal(size=lead + (c, dk))
+    beta = rng.uniform(size=lead + (c,))
+    if case == "near-identical-keys":
+        keys = keys[..., :1, :] + 1e-3 * keys
+        beta = np.full_like(beta, 0.999)
+    if case == "padding-rows":
+        beta[..., ::3] = 0
+        beta[..., :c // 4] = 0
+    keys /= np.linalg.norm(keys, axis=-1, keepdims=True)
+    return beta[..., None] * np.tril(
+        np.einsum("...td,...id->...ti", keys, keys), -1)
+
+
+@pytest.mark.parametrize("case", ["random-keys", "near-identical-keys",
+                                  "padding-rows"])
+@pytest.mark.parametrize("c,sub", [(64, 16), (16, 4), (16, 8), (8, 8)])
+def test_block_inverse_is_the_triangular_solve(case, c, sub):
+    """The chunk's inverse alone, from `sub`-wide diagonal blocks and
+    matmuls, against XLA's float32 solve and a float64 solve of the same
+    systems: 1e-6 of the largest entry, nearly identical keys under
+    beta = 0.999 (the worst conditioning L2-normalised keys allow)
+    included. A row with beta = 0 (padding) is the identity's."""
+    strict = _strict_systems(case, c)
+    rhs = np.random.default_rng(7).normal(size=strict.shape[:-1] + (24,))
+    system = np.eye(c) + strict
+    want = np.linalg.solve(system, rhs)
+    inv = jax.jit(lambda n: unit_lower_inverse(n, sub))(
+        jnp.asarray(strict, F32))
+    assert inv.dtype == F32 and inv.shape == strict.shape
+    got = np.einsum("...ti,...ix->...tx", np.asarray(inv, np.float64), rhs)
+    assert np.abs(got - want).max() < 1e-6 * np.abs(want).max()
+    exact = np.linalg.inv(system)
+    assert np.abs(np.asarray(inv) - exact).max() < 1e-6 * np.abs(exact).max()
+    xla = jax.lax.linalg.triangular_solve(
+        jnp.asarray(system, F32), jnp.asarray(rhs, F32), left_side=True,
+        lower=True, unit_diagonal=True)
+    assert np.abs(got - np.asarray(xla)).max() < 1e-6 * np.abs(want).max()
+    if case == "padding-rows":
+        dead = np.all(strict == 0, axis=-1)
+        assert dead[..., :c // 4].all()
+        assert (np.asarray(inv)[dead] == np.eye(c)[np.nonzero(dead)[-1]]).all()
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_chunked_delta_rule_holds_no_triangular_solve():
+    """The solve was a sixth of a prefill on the chip (XLA expands it
+    into a sweep over rows): it may not come back through a refactoring.
+    The walk finds one where there is one."""
+    args = _kda_inputs(150)
+    names = set(_primitives(jax.make_jaxpr(jax.jit(
+        lambda *a: kda_chunked(*a, chunk=64, sub=16)))(*args).jaxpr))
+    assert "dot_general" in names and "scan" in names
+    assert not [n for n in names if "triangular" in n or "solve" in n]
+    old = jax.make_jaxpr(jax.jit(lambda a, b: jax.lax.scan(
+        lambda c, x: (c, jax.lax.linalg.triangular_solve(
+            a, x, left_side=True, lower=True)), 0, b)[1]))(
+        jnp.eye(4), jnp.ones((3, 4, 2)))
+    assert "triangular_solve" in set(_primitives(old.jaxpr))
 
 
 def test_decay_at_the_lower_bound_stays_inside_float32():

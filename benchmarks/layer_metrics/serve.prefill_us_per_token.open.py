@@ -1,0 +1,9 @@
+"""Prefill seconds (dispatch to first tokens, from the tick ring) a prompt token FORWARDED, over the whole window; nothing once chunks of a chunked prefill were counted."""
+from benchmarks.lib import prefill_account
+
+NAME, UNIT = "serve.prefill_us_per_token.open", "us"
+LAYER, MOVES = "serve loop, host", "ttft_p95_ms"
+
+
+def read(record, trace):
+    return prefill_account.us_per_token(record)

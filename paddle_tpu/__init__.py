@@ -47,8 +47,11 @@ if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in _os.environ:
 # JAX's trace, compile and cache events become the jit.* counters and the
 # time-stamped compile log (observability.runtime) from the first program
 # on, so a run can say what its set-up compiled and what compiled later.
-from .observability.runtime import watch_compiles as _watch_compiles
+# The garbage collector's pauses are stamped into a log of the same shape.
+from .observability.runtime import (watch_compiles as _watch_compiles,
+                                    watch_gc as _watch_gc)
 _watch_compiles()
+_watch_gc()
 
 __version__ = "0.1.0"
 

@@ -741,6 +741,18 @@ class ContinuousBatchingPredictor:
                                       unit="s")
         self._m_prefill = _obsm.histogram("serving.prefill_seconds",
                                           unit="s")
+        # a prefill's own account (docs/OBSERVABILITY.md "What a
+        # prefill forwarded, padded and stalled"): prompt tokens it
+        # forwarded against positions it computed, and the seconds
+        # decoding slots stood still for it
+        self._m_pf_tokens = _obsm.counter("serving.prefill_tokens")
+        self._m_stall = _obsm.counter(
+            "serving.decode_stall_slot_seconds", unit="s")
+        # (dispatch, first tokens on the host) of the last prefill
+        # program on `time.perf_counter`: each end is stamped where it
+        # happens, so the pair still says what a decoding slot waited
+        # once the first tokens come down with a later step's
+        self._prefill_clock = (0.0, 0.0)
         self._m_pfx_hit = _obsm.counter("serving.prefix_cache_hits")
         self._m_pfx_miss = _obsm.counter("serving.prefix_cache_misses")
         self._m_pfx_pages = _obsm.counter(
@@ -1812,6 +1824,7 @@ class ContinuousBatchingPredictor:
         deadlines, arrival, req_sp, samp_of = [], [], [], []
         has_deadlines = False   # no deadlines → expire_queued is a no-op
         out = _coll.deque()          # StreamEvents awaiting the consumer
+        n_tok = n_first = 0          # tokens this pass handed to `out`
         closed = intake is None
         tiers_seen = set()
 
@@ -1834,8 +1847,14 @@ class ContinuousBatchingPredictor:
             # the tick committed (speculative ticks commit several),
             # `token`/`index` stay the last one for single-token
             # consumers (serving/streaming.py StreamEvent)
+            nonlocal n_tok, n_first
             if span is None and token is not None:
                 span = (token,)
+            if kind == "token":
+                # the pass's count for the tick ring: a request's first
+                # token closes no gap between tokens
+                n_tok += len(span)
+                n_first += index == len(span)
             out.append(StreamEvent(r, kind, token, index, _ts(r), st,
                                    metas[r], tuple(span or ())))
 
@@ -2267,14 +2286,58 @@ class ContinuousBatchingPredictor:
             else:
                 emit(r, "token", token=first, index=1)
 
-        def prefill_stage(group, bucket):
+        def owed():
+            """Slots whose next token stands still while a prefill
+            runs: they hold a request that has had its first token and
+            is owed another. Those of the step in flight, whose tokens
+            the host reads only after the prefill's; with none in
+            flight, every occupied slot."""
+            held = inflight["snap"] if inflight is not None \
+                else enumerate(slot_req)
+            return sum(1 for b, r in held
+                       if r >= 0 and slot_req[b] == r
+                       and 0 < len(slot_new[b]) < max_new[r])
+
+        def prefill_facts(kind, group, bucket, rows):
+            """What one prefill program is, computed once where the
+            round builds it and given to every record of it: the
+            stage's annotation, the tick's record, the round's span
+            and the counters (`prefill_account`)."""
+            return {"kind": kind, "n": len(group), "rows": rows,
+                    "bucket": bucket,
+                    "tokens": sum(len(p["prompt"]) - p["covered"]
+                                  for p in group),
+                    "padded": rows * bucket, "stalled": owed()}
+
+        def prefill_stage(group, facts):
             """The stage around one prefill program's dispatch. Its
-            annotation names the requests' traces, so a `serve.request`
-            span (wall clock) is found on the profiler's clock."""
+            annotation carries the prefill's facts and names the
+            requests' traces, so a `serve.request` span (wall clock) is
+            found on the profiler's clock. (A `with` at the call site,
+            not a wrapper around the call: a program traced two Python
+            frames deeper lowered a second slower on the chip's host,
+            PERF.md, PR 37.)"""
             return self._tick.stage(
-                "serve.prefill", n=len(group), bucket=bucket,
+                "serve.prefill", **facts,
                 traces=",".join(str(req_sp[p["r"]].trace_id)
                                 for p in group))
+
+        def prefill_account(facts, acct):
+            """The program that just ran (`_prefill_clock` holds its
+            dispatch and its first tokens' arrival) into the tick's
+            record, the counters and the round's span labels."""
+            dispatched, first_tokens = self._prefill_clock
+            seconds = first_tokens - dispatched
+            self._tick.add(pf_n=facts["n"], pf_tokens=facts["tokens"],
+                           pf_padded=facts["padded"], pf_s=seconds,
+                           pf_stalled=facts["stalled"])
+            self._m_pf_tokens.inc(facts["tokens"], kind="forwarded",
+                                  **mlbl)
+            self._m_pf_tokens.inc(facts["padded"], kind="padded", **mlbl)
+            self._m_stall.inc(facts["stalled"] * seconds, **mlbl)
+            for key in ("tokens", "padded", "stalled"):
+                acct[key] = acct.get(key, 0) + facts[key]
+            acct["seconds"] = acct.get("seconds", 0.0) + seconds
 
         def admission_round():
             """One pass over the queue in discipline order (FIFO, or
@@ -2331,7 +2394,7 @@ class ContinuousBatchingPredictor:
                 req_sp[plan["r"]].event(
                     "prefill", covered=plan["covered"],
                     reused=plan["reused"])
-            firsts = {}
+            firsts, acct = {}, {}
 
             for plan in hits:
                 firsts[plan["r"]] = int(plan["next"])
@@ -2341,9 +2404,11 @@ class ContinuousBatchingPredictor:
                 self._m_pfx_pages.inc(plan["reused"], **mlbl)
 
             for plan in partials:
-                with prefill_stage([plan], self._bucket_len(
-                        len(plan["prompt"]) - plan["covered"])):
+                facts = prefill_facts("suffix", [plan], self._bucket_len(
+                    len(plan["prompt"]) - plan["covered"]), 1)
+                with prefill_stage([plan], facts):
                     firsts[plan["r"]] = self._suffix_prefill(plan)
+                prefill_account(facts, acct)
                 self.stats["prefix_partial_hits"] += 1
                 self.stats["pages_reused"] += plan["reused"]
                 self._m_pfx_hit.inc(kind="partial", **mlbl)
@@ -2360,13 +2425,17 @@ class ContinuousBatchingPredictor:
                 rows = self._prefill_rows or len(group)
                 for at in range(0, len(group), rows):
                     part = group[at:at + rows]
-                    with prefill_stage(part, bucket):
+                    facts = prefill_facts(
+                        "long" if self._long_prefill else "batch", part,
+                        bucket, self._prefill_batch_rows(len(part)))
+                    with prefill_stage(part, facts):
                         firsts.update(self._batch_prefill(bucket, part))
+                    prefill_account(facts, acct)
 
             if now_plans:
                 self._m_prefill.observe(_time.perf_counter() - t0,
                                         **mlbl)
-            pf_sp.end()
+            pf_sp.end(**acct)
             for plan in plans:
                 if plan.get("chunked"):
                     place_chunked(plan["slot"], plan)
@@ -2524,6 +2593,7 @@ class ContinuousBatchingPredictor:
             while True:
                 with _obstr.tick("serve.tick", self.name or "") as tick:
                     self._tick = tick
+                    n_tok = n_first = 0
                     with tick.stage("serve.intake"):
                         apply_cancels()
                         expire_deadlines()
@@ -2591,6 +2661,11 @@ class ContinuousBatchingPredictor:
                         # briefly itself; this is only spin insurance
                         if not out:
                             _time.sleep(0.0002)
+                    # by the pass's order (admit with its prefills,
+                    # dispatch, resolve of the PREVIOUS step) the tokens
+                    # handed out after a prefill are those whose gap
+                    # held it: `pf_s` beside them says for how long
+                    tick.note(tokens=n_tok, first=n_first)
                     if out:
                         # _serve is a generator: the consumer handles
                         # each event on this thread before the loop
@@ -2661,14 +2736,22 @@ class ContinuousBatchingPredictor:
                     gen_sp.end(status=st)
 
     # ---------------------------------------------------- admission ops --
+    @staticmethod
+    def _prefill_batch_rows(n):
+        """Rows of the batched prefill program that takes `n` prompts:
+        the next power of two (the others are dummies)."""
+        nb = 1
+        while nb < n:
+            nb *= 2
+        return nb
+
     def _batch_prefill(self, bucket, group):
         """Batched same-bucket device-resident prefill for a round's
         cache misses; returns {request: first token} and records the
         prompts in the prefix cache."""
+        import time as _time
         n = len(group)
-        nb = 1
-        while nb < n:
-            nb *= 2
+        nb = self._prefill_batch_rows(n)
         W = -(-bucket // self.page)
         ids = np.full((nb, bucket), self.pad_token_id, np.int32)
         pos = np.zeros((nb, bucket), np.int32)
@@ -2687,6 +2770,7 @@ class ContinuousBatchingPredictor:
             slots = (np.full((nb,), self.B, np.int32),)
             for i, plan in enumerate(group):
                 slots[0][i] = plan["slot"]
+        dispatched = _time.perf_counter()
         nexts, new_k, new_v, *aux = self._jit_call(
             ("prefill", ids.shape, rows.shape), self._prefill_jit,
             self._p_vals, self._b_vals, *self._cache_args(),
@@ -2697,6 +2781,7 @@ class ContinuousBatchingPredictor:
         # bucket] small ints (every position's argmax, for the prefix
         # cache's cached-continuation tokens)
         nexts = np.asarray(nexts)
+        self._prefill_clock = (dispatched, _time.perf_counter())
         self._note_counters(aux)
         firsts = {}
         for i, plan in enumerate(group):
@@ -2717,6 +2802,7 @@ class ContinuousBatchingPredictor:
     def _suffix_prefill(self, plan):
         """Partial prefix hit: forward only prompt[covered:] against the
         cached pages; returns the first generated token."""
+        import time as _time
         prompt, covered = plan["prompt"], plan["covered"]
         L = len(prompt)
         suffix = prompt[covered:]
@@ -2734,6 +2820,7 @@ class ContinuousBatchingPredictor:
         past_rows[:wp] = plan["pages"][:wp]
         row = np.full((self.pages_per_seq,), self._trash, np.int32)
         row[:len(plan["pages"])] = plan["pages"]
+        dispatched = _time.perf_counter()
         nexts, new_k, new_v = self._jit_call(
             ("suffix", ids.shape, past_rows.shape), self._suffix_jit,
             self._p_vals, self._b_vals, *self._cache_args(),
@@ -2743,6 +2830,7 @@ class ContinuousBatchingPredictor:
         # graft-lint: ok[GL102] — the suffix-prefill admission
         # download, same contract as _batch_prefill's
         nexts = np.asarray(nexts)
+        self._prefill_clock = (dispatched, _time.perf_counter())
         first = int(nexts[-1])
         if self.prefix_cache is not None:
             toks = [None] * covered + [int(t) for t in nexts[sb - sl:]]
@@ -2853,8 +2941,10 @@ class ContinuousBatchingPredictor:
         span_ids = np.full((self.B, qb), self.pad_token_id, np.int32)
         q_lens = np.ones((self.B,), np.int32)
         mid, final = set(paused), set()
+        took = 0
         for b in chunk_slots:
             take = min(len(slot_pending[b]), qb)
+            took += take
             chunk = slot_pending[b][:take]
             span_ids[b, :take] = chunk
             q_lens[b] = take
@@ -2871,6 +2961,14 @@ class ContinuousBatchingPredictor:
             self._m_chunk_tok.inc(take, **mlbl)
             req_sp[slot_req[b]].event("prefill_chunk", tokens=take,
                                       covered=slot_ingested[b])
+        # the prefill account's kind "chunk": tokens forwarded and
+        # positions computed, no seconds and no stalled slot (nobody
+        # waits; the step is longer)
+        self._tick.add(pf_tokens=took, pf_chunk=took,
+                       pf_padded=len(chunk_slots) * qb)
+        self._m_pf_tokens.inc(took, kind="forwarded", **mlbl)
+        self._m_pf_tokens.inc(len(chunk_slots) * qb, kind="padded",
+                              **mlbl)
         meta_args = ()
         if builder is not None:
             for b in active:

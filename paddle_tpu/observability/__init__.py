@@ -46,6 +46,7 @@ from .runtime import (  # noqa: F401
     jit_callback, device_memory_stats, configure, maybe_export,
     export_record, telemetry_path, RankHeartbeat, rank_identity,
     set_identity, export_identity, watch_compiles, compile_log, jit_tag,
+    watch_gc, gc_log,
 )
 from .slo import (  # noqa: F401
     Ewma, SLOSpec, SLOEngine, default_serving_slos,
@@ -70,7 +71,8 @@ __all__ = [
     "TensorBoardExporter", "jit_callback", "device_memory_stats",
     "configure", "maybe_export", "export_record", "telemetry_path",
     "RankHeartbeat", "rank_identity", "set_identity", "export_identity",
-    "watch_compiles", "compile_log", "jit_tag", "tick", "ticks",
+    "watch_compiles", "compile_log", "jit_tag", "watch_gc", "gc_log",
+    "tick", "ticks",
     "clear_ticks", "Ewma", "SLOSpec", "SLOEngine", "default_serving_slos",
     "FleetAggregator",
     "StragglerDetector", "RankFileTailer",

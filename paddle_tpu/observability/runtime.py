@@ -22,7 +22,8 @@ from .metrics import enabled, get_registry
 __all__ = ["jit_callback", "device_memory_stats", "configure",
            "maybe_export", "export_record", "telemetry_path",
            "RankHeartbeat", "rank_identity", "set_identity",
-           "export_identity", "watch_compiles", "compile_log", "jit_tag"]
+           "export_identity", "watch_compiles", "compile_log", "jit_tag",
+           "watch_gc", "gc_log"]
 
 
 # ------------------------------------------------------- rank identity ------
@@ -225,6 +226,80 @@ def compile_log(since: Optional[float] = None,
     return [{"t": t, "kind": kind, "seconds": seconds, "sig": sig}
             for t, kind, seconds, sig
             in stamped_between(_compile_log, 0, since, until)]
+
+
+# ------------------------------------------------------------ gc log ------
+# CPython's collector stops every thread for as long as a collection
+# lasts: a full (generation-2) one over a serving process's heap takes a
+# third of a second, so a long tick or an outlying first-token wait may
+# be one. `gc.callbacks` names each collection's start and stop; they
+# are stamped here on `time.perf_counter` (the ticks' clock) and kept as
+# a log like the compile log's. A young collection is over in tens of
+# microseconds and there are hundreds a second: it costs one float
+# store and one comparison here and leaves nothing.
+_GC_LOG_CAPACITY = 4096
+_GC_KEEP_SECONDS = 1e-3    # younger generations: kept from this length
+_gc_log: collections.deque = collections.deque(maxlen=_GC_LOG_CAPACITY)
+_gc_watched = False
+_gc_t0 = 0.0
+_gc_ann = None             # the open `host.gc` annotation, if any
+_gc_annotation = None      # jax.profiler.TraceAnnotation, once watched
+
+
+def _on_gc(phase, info):
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        if info["generation"] == 2 and enabled():
+            # on the profiler's clock, so that a device idle gap under
+            # a full collection has a name in the trace
+            _gc_ann = _gc_annotation("host.gc", generation=2)
+            _gc_ann.__enter__()
+        _gc_t0 = time.perf_counter()
+        return
+    seconds = time.perf_counter() - _gc_t0
+    generation = info["generation"]
+    if generation != 2 and seconds < _GC_KEEP_SECONDS:
+        return
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    if not enabled():
+        return
+    # a flat dict of numbers: the collector does not track it
+    _gc_log.append({"t": _gc_t0, "generation": generation,
+                    "seconds": seconds, "collected": info["collected"]})
+    reg = get_registry()
+    reg.counter("host.gc_seconds", unit="s").inc(
+        seconds, generation=str(generation))
+    reg.counter("host.gc_collections").inc(generation=str(generation))
+
+
+def watch_gc():
+    """Stamp the garbage collector's pauses (idempotent; the package
+    does it on import, beside `watch_compiles`). Every generation-2
+    collection, and any younger one of a millisecond or more, is kept in
+    `gc_log()`, counted in host.gc_seconds{generation} and
+    host.gc_collections{generation}, and a generation-2 collection runs
+    under a `host.gc` annotation on the profiler's trace of the thread
+    that met it."""
+    global _gc_watched, _gc_annotation
+    with _compile_watch_lock:
+        if _gc_watched:
+            return
+        _gc_watched = True
+    import gc
+    from jax.profiler import TraceAnnotation
+    _gc_annotation = TraceAnnotation
+    gc.callbacks.append(_on_gc)
+
+
+def gc_log(since: Optional[float] = None,
+           until: Optional[float] = None) -> list:
+    """The logged collections, oldest first: ``{"t": perf_counter at
+    the collection's START, "generation", "seconds", "collected"}``
+    (those that began in [`since`, `until`) when given). The process
+    stood still from `t` for `seconds`."""
+    return [dict(r) for r in stamped_between(_gc_log, "t", since, until)]
 
 
 def device_memory_stats() -> dict:

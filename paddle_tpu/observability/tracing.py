@@ -585,6 +585,13 @@ class Tick:
         requests admitted): numbers and strings."""
         self._rec.update(fields)
 
+    def add(self, **fields):
+        """Numbers the pass sums over its parts (a prefill's tokens and
+        seconds: a pass may run several), where `note` overwrites."""
+        rec = self._rec
+        for k, v in fields.items():
+            rec[k] = rec.get(k, 0) + v
+
     def __enter__(self):
         self._ann.__enter__()
         self._rec["t0"] = time.perf_counter()
@@ -608,6 +615,8 @@ class _NullTick:
 
     def note(self, **fields):
         pass
+
+    add = note
 
     def __enter__(self):
         return self

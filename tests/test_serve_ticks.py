@@ -22,6 +22,7 @@ TOP_STAGES = {"serve.intake", "serve.admit", "serve.gauges",
 NESTED = {"serve.prefill": "serve.admit",
           "serve.resolve.wait": "serve.resolve"}
 ALL_STAGES = TOP_STAGES | set(NESTED)
+PF_FIELDS = {"pf_n", "pf_tokens", "pf_padded", "pf_s", "pf_stalled"}
 
 
 @pytest.fixture(autouse=True)
@@ -187,9 +188,11 @@ class TestTicks:
             assert not gc.is_tracked(rec)
             assert all(isinstance(v, (str, int, float, bool))
                        for v in rec.values())
-        got = tr.ticks()[-1]
+        got = tr.ticks()[-1]              # the pass that left the loop
         assert set(got) == {"name", "replica", "t0", "dur", "stages",
                             "active", "admitted", "prefill"}
+        assert set(tr.ticks()[0]) == set(got) | {"tokens", "first"} \
+            | PF_FIELDS
 
 
 def _host_lines(trace_dir):
@@ -238,8 +241,284 @@ def test_annotations_on_the_profilers_clock(tmp_path):
     assert inside("serve.resolve.wait", "serve.resolve")
     _, _, full, stats = by_name["serve.prefill"][0]
     carried = full + " " + " ".join(f"{k}={v}" for k, v in stats.items())
-    for key in ("n=", "bucket=", "traces="):
+    for key in ("n=", "bucket=", "traces=", "kind=batch", "rows=",
+                "tokens=", "padded=", "stalled="):
         assert key in carried
+
+
+# ------------------------------------------------- the prefill account --
+def _sum(ticks, field):
+    return sum(t.get(field, 0) for t in ticks)
+
+
+def _stream(cb, prompts, max_new):
+    """Serve `prompts` with a budget each; the tokens every stream
+    received, by request."""
+    from paddle_tpu.serving.streaming import ServeRequest
+    reqs = [ServeRequest(p, n) for p, n in zip(prompts, max_new)]
+    got = {}
+    for ev in cb._serve(reqs, None, [], [], set(), None, max(max_new)):
+        if ev.kind == "token":
+            got.setdefault(ev.request, []).extend(ev.span or (ev.token,))
+    return got
+
+
+class TestPrefillAccount:
+    def test_forwarded_padded_and_prompts_of_known_lengths(self):
+        """Two rounds of two prompts: each round's misses go into one
+        program a bucket, power-of-two rows of it."""
+        cb = _predictor()
+        lens = (5, 11, 3, 9)             # buckets 8, 16, 8, 16
+        cb.generate(_prompts(0, lens), max_new_tokens=4)
+        ticks = tr.ticks()
+        assert _sum(ticks, "pf_n") == 4
+        assert _sum(ticks, "pf_tokens") == sum(lens)
+        assert _sum(ticks, "pf_padded") == 8 + 16 + 8 + 16
+        assert cb.stats["prefill_batches"] == 4
+        with_pf = [t for t in ticks if "pf_s" in t]
+        assert len(with_pf) == 2 and all(t["prefill"] for t in with_pf)
+        assert all(t["pf_s"] > 0 and t["pf_s"] <= t["stages"][
+            "serve.prefill"] for t in with_pf)
+        assert all(PF_FIELDS <= set(t) for t in with_pf)
+        assert not any(PF_FIELDS & set(t) for t in ticks
+                       if t not in with_pf)
+
+    def test_one_program_takes_a_round_of_one_bucket(self):
+        cb = _predictor(max_batch_size=4)
+        cb.generate(_prompts(1, (9, 12, 15)), max_new_tokens=2)
+        ticks = tr.ticks()
+        assert _sum(ticks, "pf_n") == 3
+        assert _sum(ticks, "pf_tokens") == 36
+        assert _sum(ticks, "pf_padded") == 4 * 16    # 3 prompts: 4 rows
+        assert cb.stats["prefill_batches"] == 1
+
+    def test_tokens_of_the_ticks_are_the_tokens_the_streams_received(self):
+        cb = _predictor()
+        max_new = [7, 2, 5, 3]
+        got = _stream(cb, _prompts(2), max_new)
+        assert [len(got[r]) for r in range(4)] == max_new
+        ticks = tr.ticks()
+        assert _sum(ticks, "tokens") == sum(max_new)
+        assert _sum(ticks, "first") == 4
+        assert all(t.get("first", 0) <= t.get("tokens", 0) for t in ticks)
+
+    def test_only_a_tick_that_prefilled_counts_stalled_slots(self):
+        """Budgets 9 and 2 on two slots: the second slot frees while the
+        first still decodes, so the next prompt's prefill finds ONE slot
+        owed a token, and the pass that ran it hands that token out."""
+        cb = _predictor(name="st0")
+        stall0 = obs.counter("serving.decode_stall_slot_seconds").value(
+            replica="st0")
+        got = _stream(cb, _prompts(3, (5, 6, 7)), [9, 2, 2])
+        assert [len(got[r]) for r in range(3)] == [9, 2, 2]
+        ticks = tr.ticks()
+        assert all(t.get("pf_stalled", 0) == 0 for t in ticks
+                   if not t.get("pf_s"))
+        stalled = [t for t in ticks if t.get("pf_stalled")]
+        assert stalled and all(t["pf_s"] > 0 for t in stalled)
+        assert _sum(ticks, "pf_stalled") == 1       # the third prompt's
+        for t in stalled:
+            # the tokens it hands out that close a gap are the stalled
+            # slot's (one step was in flight), the rest are firsts
+            assert t["tokens"] - t["first"] == t["pf_stalled"] == 1
+        first_round = next(t for t in ticks if "pf_s" in t)
+        assert first_round["pf_stalled"] == 0       # nobody decoding yet
+        stall = obs.counter("serving.decode_stall_slot_seconds").value(
+            replica="st0") - stall0
+        assert stall == pytest.approx(
+            sum(t["pf_stalled"] * t["pf_s"] for t in stalled))
+
+    def test_prefix_hit_records_no_prefill_and_a_suffix_its_suffix(self):
+        cb = _predictor(name="pf0")
+        (base,) = _prompts(4, (16,))
+
+        def counted(kind):
+            return obs.counter("serving.prefill_tokens").value(
+                kind=kind, replica="pf0")
+
+        f0, p0 = counted("forwarded"), counted("padded")
+        cb.generate([base], max_new_tokens=3)
+        assert counted("forwarded") == f0 + 16
+        tr.clear_ticks()
+        cb.generate([base], max_new_tokens=3)           # a whole hit
+        assert cb.stats["prefix_hits"] == 1
+        ticks = tr.ticks()
+        assert not any(PF_FIELDS & set(t) for t in ticks)
+        assert _sum(ticks, "tokens") == 3
+        assert not any(t["prefill"] for t in ticks)
+        tr.clear_ticks()
+        cb.generate([base + [7, 8, 9]], max_new_tokens=3)   # a partial hit
+        assert cb.stats["prefix_partial_hits"] == 1
+        ticks = tr.ticks()
+        assert _sum(ticks, "pf_n") == 1
+        assert _sum(ticks, "pf_tokens") == 3            # 19 less 16 covered
+        assert _sum(ticks, "pf_padded") == 8            # 1 row of bucket 8
+        assert counted("forwarded") == f0 + 16 + 3
+        assert counted("padded") == p0 + 16 + 8
+
+    def test_the_rounds_span_carries_the_same_numbers(self):
+        cb = _predictor()
+        cb.generate(_prompts(5, (5, 11)), max_new_tokens=2)
+        (sp,) = [s for s in tr.flight_recorder().spans()
+                 if s["name"] == "serve.prefill"]
+        (t,) = [t for t in tr.ticks() if "pf_s" in t]
+        assert sp["labels"]["tokens"] == t["pf_tokens"] == 16
+        assert sp["labels"]["padded"] == t["pf_padded"] == 24
+        assert sp["labels"]["stalled"] == t["pf_stalled"] == 0
+        assert sp["labels"]["seconds"] == pytest.approx(t["pf_s"])
+
+    def test_the_sink_and_the_report_show_the_rounds_numbers(self, tmp_path):
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "trace_report", os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "tools", "trace_report.py"))
+        report = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(report)
+        path = str(tmp_path / "t.jsonl")
+        obs.configure(path)
+        try:
+            _predictor().generate(_prompts(8, (5, 11)), max_new_tokens=2)
+        finally:
+            obs.configure(None)
+        spans = report.load_spans(path)
+        (lab,) = [s["labels"] for s in spans
+                  if s["name"] == "serve.prefill"]
+        assert (lab["tokens"], lab["padded"], lab["stalled"]) == (16, 24, 0)
+        text = report.render(spans)
+        assert "tokens forwarded=16  positions computed=24" in text
+        assert "padding=33.3%" in text
+
+    def test_a_chunk_of_a_chunked_prefill_counts_tokens_and_no_seconds(self):
+        cb = _predictor(prefill_chunk_tokens=8, max_seq_len=96)
+        (long_p,) = _prompts(6, (30,))
+        out = cb.generate([long_p], max_new_tokens=3)
+        assert len(out[0]) == 3 and cb.stats["chunked_requests"] == 1
+        ticks = tr.ticks()
+        assert _sum(ticks, "pf_tokens") == _sum(ticks, "pf_chunk") == 30
+        assert _sum(ticks, "pf_padded") >= 30
+        assert _sum(ticks, "pf_s") == 0 and _sum(ticks, "pf_n") == 0
+        assert _sum(ticks, "pf_stalled") == 0
+        assert _sum(ticks, "tokens") == 3 and _sum(ticks, "first") == 1
+
+    def test_add_sums_where_note_overwrites(self):
+        with tr.tick("t") as t:
+            t.add(pf_s=0.25, pf_n=1)
+            t.add(pf_s=0.5, pf_n=2)
+            t.note(tokens=3)
+            t.note(tokens=4)
+        rec = tr.ticks()[-1]
+        assert rec["pf_s"] == 0.75 and rec["pf_n"] == 3
+        assert rec["tokens"] == 4
+        assert tr.NULL_TICK.add(pf_s=1.0) is None
+
+    def test_disabled_leaves_no_field_no_entry_and_no_operation(self):
+        import gc
+        import jax
+        import jax.numpy as jnp
+        model = _model()
+        prompts = _prompts(7)
+        ref = _predictor(model).generate(prompts, max_new_tokens=4)
+        tr.clear_ticks()
+        n_gc = len(obsrt.gc_log())
+        tokens0 = obs.counter("serving.prefill_tokens").value(
+            kind="forwarded")
+
+        def accounted(x):
+            with tr.tick("t") as t:
+                with t.stage("t.prefill", kind="batch", tokens=3):
+                    y = (x * 2.0).sum()
+                t.add(pf_tokens=3, pf_s=0.1)
+                t.note(tokens=1, first=1)
+            return y
+
+        x = jnp.ones((4,))
+        j_on = jax.make_jaxpr(accounted)(x)
+        obs.enabled(False)
+        try:
+            got = _predictor(model).generate(prompts, max_new_tokens=4)
+            gc.collect()
+            j_off = jax.make_jaxpr(accounted)(x)
+        finally:
+            obs.enabled(True)
+        assert got == ref
+        assert str(j_on) == str(j_off) == str(
+            jax.make_jaxpr(lambda x: (x * 2.0).sum())(x))
+        assert [t for t in tr.ticks() if t["name"] == "serve.tick"] == []
+        assert len(obsrt.gc_log()) == n_gc
+        assert obs.counter("serving.prefill_tokens").value(
+            kind="forwarded") == tokens0
+
+
+# ----------------------------------------------------------------- gc log --
+class TestGcLog:
+    def test_a_full_collection_is_stamped_between_two_clock_reads(self):
+        import gc
+        import time
+        n0 = obs.counter("host.gc_collections").value(generation="2")
+        s0 = obs.counter("host.gc_seconds").value(generation="2")
+        t0 = time.perf_counter()
+        gc.collect()
+        t1 = time.perf_counter()
+        (e,) = [e for e in obsrt.gc_log(since=t0, until=t1)
+                if e["generation"] == 2]
+        assert set(e) == {"t", "generation", "seconds", "collected"}
+        assert t0 <= e["t"] and e["t"] + e["seconds"] <= t1
+        assert e["seconds"] > 0 and e["collected"] >= 0
+        assert obs.counter("host.gc_collections").value(
+            generation="2") == n0 + 1
+        assert obs.counter("host.gc_seconds").value(
+            generation="2") == pytest.approx(s0 + e["seconds"])
+        assert obsrt.gc_log(since=t1) == [] or \
+            obsrt.gc_log(since=t1)[0]["t"] >= t1
+
+    def test_a_short_young_collection_leaves_nothing_a_long_one_an_entry(
+            self, monkeypatch):
+        n = len(obsrt.gc_log())
+        info = {"generation": 0, "collected": 0, "uncollectable": 0}
+        obsrt._on_gc("start", info)
+        obsrt._on_gc("stop", info)
+        assert len(obsrt.gc_log()) == n
+        obsrt._on_gc("start", dict(info, generation=1))
+        monkeypatch.setattr(obsrt, "_gc_t0", obsrt._gc_t0 - 0.002)
+        obsrt._on_gc("stop", dict(info, generation=1, collected=5))
+        e = obsrt.gc_log()[-1]
+        assert len(obsrt.gc_log()) == n + 1
+        assert e["generation"] == 1 and e["collected"] == 5
+        assert 0.002 <= e["seconds"] < 0.1
+
+    def test_hook_registered_once(self):
+        import gc
+        obsrt.watch_gc()
+        obsrt.watch_gc()
+        assert gc.callbacks.count(obsrt._on_gc) == 1
+
+    def test_log_records_stay_off_the_collectors_books_and_are_bounded(self):
+        import gc
+        gc.collect()
+        assert obsrt._gc_log.maxlen == obsrt._GC_LOG_CAPACITY
+        for rec in obsrt._gc_log:
+            assert not gc.is_tracked(rec)
+            assert all(isinstance(v, (int, float)) for v in rec.values())
+        # what the reader hands out is a copy: the log's own stays flat
+        obsrt.gc_log()[-1]["note"] = []
+        assert "note" not in obsrt._gc_log[-1]
+
+    def test_a_full_collection_is_annotated_on_the_profilers_clock(
+            self, tmp_path):
+        import gc
+        import jax
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            gc.collect()
+        finally:
+            jax.profiler.stop_trace()
+        names = set()
+        for pb in glob.glob(os.path.join(str(tmp_path), "**",
+                                         "*.xplane.pb"), recursive=True):
+            for plane in jax.profiler.ProfileData.from_file(pb).planes:
+                for line in plane.lines:
+                    names |= {ev.name.split("#")[0] for ev in line.events}
+        assert "host.gc" in names
 
 
 # ------------------------------------------------------------ compile log --

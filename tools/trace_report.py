@@ -765,6 +765,21 @@ def render(spans: List[dict], top_requests: int = 5,
               f"{percentile(r_ttft, 0.99) * 1e3:>9.2f}ms"
               f"{percentile([r.e2e for r in sub], 0.99) * 1e3:>9.2f}ms")
 
+    # ---- what the admission rounds' prefills forwarded and padded ----
+    rounds = [s.get("labels") or {} for s in spans
+              if s.get("name") == "serve.prefill"
+              and (s.get("labels") or {}).get("padded")]
+    if rounds:
+        fwd, pad = (sum(lab.get(k, 0) for lab in rounds)
+                    for k in ("tokens", "padded"))
+        secs = sum(float(lab.get("seconds", 0.0)) for lab in rounds)
+        w("== prefills (admission rounds that ran a program) ==")
+        w(f"  rounds={len(rounds)}  tokens forwarded={fwd}  positions "
+          f"computed={pad}  padding={100.0 * (1 - fwd / pad):.1f}%")
+        w(f"  dispatch to first tokens {secs * 1e3:.2f}ms"
+          f" ({secs * 1e6 / max(fwd, 1):.1f}us a token)  decoding slots"
+          f" found waiting={sum(lab.get('stalled', 0) for lab in rounds)}")
+
     # ---- disaggregated handoffs (router.request spans) --------------
     hos = a["handoffs"]
     if hos:

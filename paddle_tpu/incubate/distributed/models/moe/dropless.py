@@ -20,6 +20,16 @@ experts' stacked banks (`jax.lax.ragged_dot`, which the TPU compiler
 lowers to a grouped-matmul kernel of its own: each expert's weights are
 read once, and no row of the other experts is computed). Rows past the
 last group belong to absent experts and are zeroed by their gate.
+
+A program's tokens are routed in pieces (`block_tokens`): every piece
+reads every held bank once, so a piece wants enough tokens that the
+routing is expected to give an expert the chip's ridge in rows, or that
+the banks are no longer the larger part of what the piece moves, and no
+more, because the gathered rows of a piece are temporaries. The piece
+follows from the shapes the layer is traced with (tokens, `top_k`, the
+router's width, the activations' and the banks' bytes) and from nothing
+a caller sets; a token's result does not depend on the piece that held
+it.
 """
 from __future__ import annotations
 
@@ -32,11 +42,50 @@ from .....nn.initializer import Normal
 from .....ops._dispatch import apply
 from .....kernels._common import mxu_precision
 
-# Tokens routed in one piece. A prefill of 8192 tokens makes 81,920
-# assignments, whose gathered inputs and outputs at width 4096 are
-# 0.7 GB each; in pieces of 2048 tokens they are a quarter of that, and
-# the held experts' weights are read once a piece.
-BLOCK_TOKENS = 2048
+# Tokens routed in one piece, at least. A prefill of 8192 tokens under
+# 72 experts top-10 makes 81,920 assignments, whose gathered inputs and
+# outputs at width 4096 are 0.7 GB each; in pieces of 2048 tokens they
+# are a quarter of that.
+MIN_BLOCK_TOKENS = 2048
+# Rows an expert has to expect before its grouped matmul stops being
+# bound by reading its bank: a row of bfloat16 does as many operations
+# as the bank has bytes, and a TPU v5e does 197 TFLOP/s beside 819 GB/s.
+# (The compiler's grouped kernel: about 34 us an expert a piece, 2.4
+# times its bank's read, and half the MXU a row; PERF.md section 6.)
+RIDGE_ROWS = 240
+# Times a piece moves its gathered rows: written by the gather and read
+# by the first matmul, written by the second and read by the gather back.
+ROW_PASSES = 4
+
+
+def block_tokens(n, top_k, num_experts, row_bytes, bank_bytes):
+    """How many of a program's `n` tokens are routed in one piece.
+    Every piece reads every held bank once (`bank_bytes`: both banks),
+    so twice the tokens a piece halve that; the piece doubles from
+    `MIN_BLOCK_TOKENS` while both hold:
+
+    - the routing is EXPECTED to give an expert fewer than `RIDGE_ROWS`
+      rows (`block x top_k / num_experts`: the router spreads a token's
+      `top_k` assignments over its whole width): above the ridge the
+      grouped matmuls are bound by the MXU and more tokens buy only
+      temporaries (72 experts top-10 stop here, at 2048);
+    - the banks are the larger part of what a piece moves: more bytes
+      than its `block x top_k` gathered rows of `row_bytes`, moved
+      `ROW_PASSES` times. Under that the read saved is small beside
+      the piece's own traffic, and the compiler's gather costs twice
+      as much a row at 32768 and 65536 rows as at 16384 (16 of 128
+      experts top-8 at width 2048 stop here, at 2048: 0.15 GB of banks
+      beside 0.27 GB; on the chip their layer is a sixth slower at
+      4096).
+
+    128 of 512 experts top-8 at width 2560 (1.5 GB of banks) reach
+    16384. `n` at or under the piece is one piece, as is an `n` the
+    piece does not divide (the serve programs' are powers of two)."""
+    block = MIN_BLOCK_TOKENS
+    while block * top_k < RIDGE_ROWS * num_experts \
+            and ROW_PASSES * block * top_k * row_bytes < bank_bytes:
+        block *= 2
+    return block if n > block and n % block == 0 else n
 
 
 def _count(ids, n):
@@ -117,11 +166,11 @@ def _route_block(x, valid, router_w, w_in, w_out, local_of, n_held, top_k,
     return y, jnp.concatenate([head, per])
 
 
-def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k,
-                 route=None):
-    """x [..., h] -> (y like x, counts int32 [2 + len(held)]). `route`
-    (logits [n, experts] float32 -> gates [n, top_k] float32, ids [n,
-    top_k]) replaces the softmax-over-top-k rule."""
+def _moe_in_blocks(x, valid, router_w, w_in, w_out, *, held, top_k, route,
+                   block):
+    """`dropless_moe` with its tokens cut into pieces of `block` (which
+    divides them). Counts the pieces where the program is traced:
+    `moe.prefill_blocks{tokens}`."""
     n_experts = router_w.shape[1]
     local_of = np.full((n_experts,), len(held), np.int32)
     local_of[list(held)] = np.arange(len(held), dtype=np.int32)
@@ -130,19 +179,34 @@ def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k,
     ok = jnp.ones(flat.shape[:1], jnp.bool_) if valid is None \
         else valid.reshape(-1)
 
-    def block(xv):
+    def piece(xv):
         return _route_block(xv[0], xv[1], router_w, w_in, w_out, local_of,
                             len(held), top_k, route)
 
     n = flat.shape[0]
-    if n > BLOCK_TOKENS and n % BLOCK_TOKENS == 0:
+    from .....observability import metrics as _obsm
+    _obsm.counter("moe.prefill_blocks").inc(n // block, tokens=str(block))
+    if n > block:
         y, counts = jax.lax.map(
-            block, (flat.reshape(-1, BLOCK_TOKENS, flat.shape[1]),
-                    ok.reshape(-1, BLOCK_TOKENS)))
+            piece, (flat.reshape(-1, block, flat.shape[1]),
+                    ok.reshape(-1, block)))
         y, counts = y.reshape(n, -1), counts.sum(axis=0, dtype=jnp.int32)
     else:
-        y, counts = block((flat, ok))
+        y, counts = piece((flat, ok))
     return y.astype(x.dtype).reshape(x.shape), counts
+
+
+def dropless_moe(x, valid, router_w, w_in, w_out, *, held, top_k,
+                 route=None):
+    """x [..., h] -> (y like x, counts int32 [2 + len(held)]). `route`
+    (logits [n, experts] float32 -> gates [n, top_k] float32, ids [n,
+    top_k]) replaces the softmax-over-top-k rule."""
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    return _moe_in_blocks(
+        x, valid, router_w, w_in, w_out, held=held, top_k=top_k, route=route,
+        block=block_tokens(x.size // x.shape[-1], top_k, router_w.shape[1],
+                           x.shape[-1] * x.dtype.itemsize,
+                           nbytes(w_in) + nbytes(w_out)))
 
 
 class DroplessMoELayer(Layer):

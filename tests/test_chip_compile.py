@@ -473,6 +473,16 @@ def test_ling_largest_prefill_at_real_size(chip, ling):
     assert f"[{n},1,{bucket},{bucket}]" not in text
     assert f"[{n},{bucket},{pred.model.config.vocab_size}]" not in text
     assert live < 14.5e9, live
+    # the expert layer routes the 2 x 16384 tokens in the two pieces its
+    # rule gives 128 of 512 experts top-8: 16384 tokens x 8 rows a
+    # grouped matmul, where pieces of 2048 tokens made it
+    # `bf16[16384,1536]`
+    c = pred.model.config
+    piece = 16384
+    rows = set(re.findall(
+        r"= bf16\[(\d+),%d\]\S* custom-call\(" % (2 * c.moe_intermediate_size),
+        "\n".join(ln for ln in text.splitlines() if "ragged-dot" in ln)))
+    assert rows == {str(piece * c.num_experts_per_tok)}, rows
 
 
 # --- the other cells' decode steps have not moved --------------------------

@@ -32,17 +32,23 @@ from paddle_tpu.kernels.kda import kda_chunked, unit_lower_inverse  # noqa: E402
 F32 = jnp.float32
 
 
-def timed(name, fn, *args, reps=10, calls=5):
+def timed(name, fn, *args, reps=10, calls=5, carried=None):
     """Seconds of ONE call of `fn(*args)` on the device: `reps` calls
     run inside one program, each reading EVERY argument through a zero
     carried from the call before it, so that nothing is hoisted out of
     the loop or dropped (the chunk's systems hold no q: a zero on q
     alone leaves them outside the loop); a call of that program is timed
-    from the host (tools/dsa_standalone.py)."""
+    from the host (tools/dsa_standalone.py). `carried` = c hands the
+    zero to the first c arguments alone, where the others are weights
+    that every piece of the work meets an activation with (adding to a
+    bank of 1.5 GB would time the copy)."""
     def many(*args):
+        c = len(args) if carried is None else carried
+
         def one(_, carry):
             out = jax.tree_util.tree_leaves(
-                fn(*(a + carry.astype(a.dtype) for a in args)))[0]
+                fn(*(a + carry.astype(a.dtype) if i < c else a
+                     for i, a in enumerate(args))))[0]
             total = jnp.sum(out.astype(F32))
             return jnp.where(jnp.isnan(total), F32(1), F32(0))
         return jax.lax.fori_loop(jnp.int32(0), jnp.int32(reps), one, F32(0))
@@ -58,6 +64,7 @@ def timed(name, fn, *args, reps=10, calls=5):
     print(json.dumps({"piece": name, "median_s": statistics.median(times),
                       "min_s": min(times), "first_call_s": first_call}),
           flush=True)
+    return statistics.median(times)
 
 
 def block_inverse(strict, rhs, sub):

@@ -85,7 +85,9 @@ class LayerCache(NamedTuple):
     page ids. kind "latent": `shape` = (width,), ONE row a token for
     all heads (multi-head latent attention: the compressed latent and
     the shared rotated key part), paged under the same page ids in one
-    array INSTEAD of K and V. kind "state": `shape` = ((d_conv - 1,
+    array INSTEAD of K and V; with `index_dim`, an index key a token
+    beside the rows, as a "kv" layer's (the indexer selects among the
+    latent rows). kind "state": `shape` = ((d_conv - 1,
     channels), (heads, head_dim, d_state)), one row a slot (a Mamba-2
     mixer's SSM state or a delta-rule mixer's [heads, d_k, d_v]
     matrix: the pool holds either as it is)."""
@@ -788,10 +790,14 @@ class LatentCacheEntry(NamedTuple):
     [num_pages, page_size, lanes], one row a token for all heads (the
     layer's `LayerCache.shape[0]` numbers, then zeros to whole 128-lane
     rows); `block_table` and `context_lens` as in `PagedCacheEntry`.
-    The layer steps through `paged_cache_latent_update_attend`."""
+    The layer steps through `paged_cache_latent_update_attend`, or,
+    where it declares an `index_dim`, with `index_pages` as in
+    `PagedCacheEntry`, through
+    `paged_cache_sparse_latent_update_attend`."""
     pages: object
     block_table: object
     context_lens: object
+    index_pages: object = None
 
 
 class StateCacheEntry(NamedTuple):
@@ -1004,3 +1010,39 @@ def paged_cache_latent_update_attend(entry: LatentCacheEntry, q, row,
                         entry.context_lens, q, row,
                         _name="paged_latent_attention_decode")
     return out, entry._replace(pages=pages2)
+
+
+def paged_cache_sparse_latent_update_attend(entry: LatentCacheEntry, q, row,
+                                            qi, w, ki, topk, scale=None):
+    """Decode-step contract of a latent-attention layer with an indexer:
+    write this step's row and index key (one token a slot) at each
+    slot's current page position, score the slot's index keys for the
+    query, select the `topk` best exactly and attend the absorbed query
+    over those rows alone (kernels.latent_attention.
+    paged_sparse_latent_attention). q [B, 1, H, width]; row [B, 1,
+    width]; qi [B, 1, J, Di]; w [B, 1, J]; ki [B, 1, Di] -> (out [B, 1,
+    H, lanes], updated entry, rows selected a slot [B] int32).
+    Gradients are not defined (serving path)."""
+    import jax.numpy as jnp
+    from ..ops._dispatch import apply
+    from ..kernels.latent_attention import (latent_rows,
+                                            paged_sparse_latent_attention)
+
+    def fn(pages, ip, bt, cl, qv, rv, qiv, wv, kiv):
+        page = pages.shape[1]
+        rows = jnp.arange(qv.shape[0])
+        at = (bt[rows, (cl // page).astype(jnp.int32)],
+              (cl % page).astype(jnp.int32))
+        pages2 = pages.at[at].set(latent_rows(rv[:, 0], pages))
+        ip2 = ip.at[at].set(latent_rows(kiv[:, 0], ip))
+        out, keep = paged_sparse_latent_attention(
+            qv[:, 0], pages2, ip2, qiv[:, 0], wv[:, 0], bt, cl + 1, topk,
+            scale)
+        return (out[:, None].astype(qv.dtype), pages2, ip2,
+                jnp.sum(keep, axis=1, dtype=jnp.int32))
+
+    out, pages2, ip2, n_sel = apply(
+        fn, entry.pages, entry.index_pages, entry.block_table,
+        entry.context_lens, q, row, qi, w, ki,
+        _name="paged_sparse_latent_attention_decode")
+    return out, entry._replace(pages=pages2, index_pages=ip2), n_sel

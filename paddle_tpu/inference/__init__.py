@@ -656,28 +656,33 @@ class ContinuousBatchingPredictor:
         # K/V pages for attention layers (one geometry), a row a slot of
         # (conv window, SSM state) for recurrent ones
         self._layout = list(model.cache_layout())
-        kv_shapes = {(c.shape, c.index_dim) for c in self._layout
-                     if c.kind == "kv"}
+        kv_shapes = {c.shape for c in self._layout if c.kind == "kv"}
         latent_shapes = {c.shape for c in self._layout if c.kind == "latent"}
         state_shapes = {c.shape for c in self._layout if c.kind == "state"}
+        # one index key a token beside the pages (or none): every paged
+        # layer's alike, under the pool's one set of page ids
+        index_dims = {c.index_dim for c in self._layout
+                      if c.kind != "state"}
         if len(kv_shapes) > 1 or len(latent_shapes) > 1 \
-                or not (kv_shapes or latent_shapes) \
+                or len(index_dims) != 1 \
                 or len(state_shapes) > 1 or any(
                     c.kind not in ("kv", "latent", "state")
                     for c in self._layout):
             raise ValueError(
                 f"cache_layout(): served are layers of kind 'kv' (K/V "
-                f"pages, with or without a page array of index keys) in "
-                f"ONE geometry, layers of kind 'latent' (one row a token "
-                f"for all heads, paged in place of K and V) in one "
-                f"geometry, at least one paged layer of either kind, and "
-                f"layers of kind 'state' (a conv window and a state "
-                f"matrix a slot) in one geometry; got kv (shape, "
-                f"index_dim) {sorted(kv_shapes)}, latent "
-                f"{sorted(latent_shapes)}, state {sorted(state_shapes)}, "
-                f"kinds {sorted({c.kind for c in self._layout})}")
-        ((n_kv_heads, head_dim), self._index_dim), = \
-            kv_shapes or {((0, 0), 0)}
+                f"pages) in ONE geometry, layers of kind 'latent' (one "
+                f"row a token for all heads, paged in place of K and V) "
+                f"in one geometry, every paged layer with the same "
+                f"`index_dim` (a page array of index keys beside the "
+                f"pages, or 0), at least one paged layer of either kind, "
+                f"and layers of kind 'state' (a conv window and a state "
+                f"matrix a slot) in one geometry; got kv "
+                f"{sorted(kv_shapes)}, latent {sorted(latent_shapes)}, "
+                f"index_dim {sorted(index_dims)}, state "
+                f"{sorted(state_shapes)}, kinds "
+                f"{sorted({c.kind for c in self._layout})}")
+        (n_kv_heads, head_dim), = kv_shapes or {(0, 0)}
+        self._index_dim, = index_dims
         self._latent_dim = next(iter(latent_shapes), (0,))[0]
         self._state_shape = next(iter(state_shapes), None)
         # head-sharded paged KV: pages shard over the KV-head axis of
@@ -903,13 +908,15 @@ class ContinuousBatchingPredictor:
         # tokens): the keys' validity and the positions go in, the model
         # builds causality inside its kernels (no [bucket, bucket] mask)
         # and gives the logits of the LAST position alone, and a program
-        # takes at most two prompts (None: a round's whole bucket). Such
+        # takes at most two prompts, or as many as the model's
+        # `long_prefill_rows` says (None: a round's whole bucket). Such
         # a prompt is compute-bound work on its own: a larger batch
         # amortises nothing, its temporaries grow with rows x bucket
         # (2 x 16384 at 12 layers: 2.0 GB), and every further row count
         # is one more program a bucket to compile before serving
         self._long_prefill = bool(getattr(model, "long_prefill", False))
-        self._prefill_rows = 2 if self._long_prefill else None
+        self._prefill_rows = getattr(model, "long_prefill_rows", 2) \
+            if self._long_prefill else None
         if self._index_dim:
             _obsm.gauge("serving.index_pool_bytes").set(
                 sum(a.nbytes for a in self.pool.index), **self._mlbl)
@@ -1253,6 +1260,9 @@ class ContinuousBatchingPredictor:
         def entry(i, c):
             if c.kind == "state":
                 return StateCacheEntry(kl[i], vl[i])
+            if c.kind == "latent" and c.index_dim:
+                return LatentCacheEntry(kl[i][0], *paged[:2],
+                                        index_pages=kl[i][1])
             if c.kind == "latent":
                 return LatentCacheEntry(kl[i], *paged[:2])
             if c.index_dim:
@@ -1268,7 +1278,7 @@ class ContinuousBatchingPredictor:
 
     def _step_caches_out(self, caches):
         """A decode step's updated caches, as the two operand lists."""
-        first = [(_raw(e.k_pages), _raw(e.index_pages)) if c.index_dim
+        first = [(_raw(e[0]), _raw(e.index_pages)) if c.index_dim
                  else _raw(e[0]) for c, e in zip(self._layout, caches)]
         return first, [None if c.kind == "latent" else _raw(e[1])
                        for c, e in zip(self._layout, caches)]
@@ -1336,7 +1346,11 @@ class ContinuousBatchingPredictor:
             put = lambda old, new: old.at[where].set(
                 _raw(new).astype(old.dtype))
             if layer.kind == "latent":
-                new_k.append(put(kl[li], latent_rows(_raw(kept[0]), kl[li])))
+                rows, keys = (kl[li], None) if not layer.index_dim \
+                    else kl[li]
+                rows = put(rows, latent_rows(_raw(kept[0]), rows))
+                new_k.append(rows if keys is None else (
+                    rows, put(keys, index_key_rows(_raw(kept[1]), keys))))
                 new_v.append(None)
                 continue
             new_k.append((put(kl[li][0], kept[0]),

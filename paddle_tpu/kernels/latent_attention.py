@@ -41,22 +41,31 @@ from .paged_attention import (_note_decode_kernel,
 _BLOCK_TOKENS = 2048
 
 
-def _latent_attention_xla(q, pages, block_tables, lens, scale):
+def _latent_attention_xla(q, pages, block_tables, lens, scale, keep=None):
     """q [B, H, lanes]; pages [P, page, lanes]; lens [B] rows a slot
     holds -> [B, H, lanes]. Gathers each slot's whole table: the CPU's
-    route and the kernel's oracle."""
+    route and the kernel's oracle. `keep` [B, L] bool restricts each
+    slot to its selected rows."""
     b = q.shape[0]
     rows = pages[block_tables].reshape(b, -1, pages.shape[2])
     s = jnp.einsum("bhd,bld->bhl", q, rows,
                    preferred_element_type=jnp.float32) * np.float32(scale)
     live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < lens[:, None]
+    if keep is not None:
+        live = live & keep
     p = jax.nn.softmax(jnp.where(live[:, None, :], s, _NEG_INF), axis=-1)
     return jnp.einsum("bhl,bld->bhd", p.astype(rows.dtype), rows,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def _latent_kernel(tables_ref, lens_ref, q_ref, rows_hbm, o_ref, buf_ref,
-                   sem, *, scale, page_size, ppb):
+def _latent_kernel(tables_ref, lens_ref, q_ref, *refs, scale, page_size, ppb,
+                   selected=False):
+    # with `selected`, a float32 row a slot (0 on a selected row's
+    # column, _NEG_INF on the others') comes after q: the kernel still
+    # reads every live page and the selection is a mask on the scores
+    # (as `_paged_kernel`'s)
+    bias_ref = refs[0] if selected else None
+    rows_hbm, o_ref, buf_ref, sem = refs[1:] if selected else refs
     b = pl.program_id(0)
     i32 = np.int32
     n = ppb * page_size                    # tokens a block
@@ -104,6 +113,8 @@ def _latent_kernel(tables_ref, lens_ref, q_ref, rows_hbm, o_ref, buf_ref,
             q, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=mxu_precision(q, rows)) * np.float32(scale)
+        if selected:
+            s = s + bias_ref[0, :, pl.ds(pl.multiple_of(blk * i32(n), n), n)]
         tok = blk * i32(n) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(tok < ctx, s, _NEG_INF)              # (H, n)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -131,26 +142,34 @@ def latent_pages_per_block(page, pages_per_seq):
     return ppb
 
 
-def _latent_attention_pallas(q, pages, block_tables, lens, scale, interpret):
+def _latent_attention_pallas(q, pages, block_tables, lens, scale, interpret,
+                             keep=None):
     b, h, d = q.shape
     _, page, _ = pages.shape
     ppb = latent_pages_per_block(page, block_tables.shape[1])
     q_spec = pl.BlockSpec((1, h, d), lambda b_, tr, lr: (b_, _Z, _Z))
+    selection, selection_specs = (), []
+    if keep is not None:
+        bias = jnp.where(keep, np.float32(0), _NEG_INF)[:, None, :]
+        selection = (bias,)
+        selection_specs = [pl.BlockSpec((1, 1, bias.shape[2]),
+                                        lambda b_, tr, lr: (b_, _Z, _Z))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[q_spec, *selection_specs,
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((2, ppb * page, d), pages.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
     )
     return pl.pallas_call(
         functools.partial(_latent_kernel, scale=scale, page_size=page,
-                          ppb=ppb),
+                          ppb=ppb, selected=keep is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), q,
+    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), q, *selection,
       pages.reshape(-1, d))
 
 
@@ -167,27 +186,67 @@ def latent_gate_reason(h, lanes, page):
     return None
 
 
+def sparse_latent_gate_reason(h, lanes, page, pages_per_seq):
+    """As `latent_gate_reason`, for the kernel under a selection: the
+    slot's row of verdicts is read a block's columns at a time, so a
+    table is whole blocks and a block whole 128-lane stretches."""
+    ppb = latent_pages_per_block(page, pages_per_seq)
+    if pages_per_seq % ppb or (ppb * page) % 128:
+        return "table_tiling"
+    return latent_gate_reason(h, lanes, page)
+
+
 def paged_latent_attention(q, pages, block_tables, lens, scale=None,
-                           interpret=False):
+                           interpret=False, keep=None):
     """One decode token a slot over the slot's live latent rows. q [B,
     H, width <= lanes] (the absorbed query); pages [num_pages, page,
     lanes]; block_tables [B, pages_per_seq]; lens [B] rows held, the new
     token's included -> [B, H, lanes], the softmax-weighted sum of whole
-    rows (float32 accumulation, q's dtype)."""
+    rows (float32 accumulation, q's dtype). `keep` [B, pages_per_seq *
+    page] bool restricts each slot to its selected rows (the selection
+    is a mask on the scores: every live page is still read)."""
     sc = scale if scale is not None else 1.0 / pymath.sqrt(q.shape[-1])
     interpret = interpret or pallas_interpret()
     q = latent_rows(q, pages)
+    kernel = "paged_latent_attention" if keep is None \
+        else "paged_sparse_latent_attention"
     with jax.named_scope("mla.attend"):
         if interpret or _use_pallas():
-            reason = latent_gate_reason(q.shape[1], pages.shape[2],
-                                        pages.shape[1])
+            h, page, lanes = q.shape[1], pages.shape[1], pages.shape[2]
+            reason = latent_gate_reason(h, lanes, page) if keep is None \
+                else sparse_latent_gate_reason(h, lanes, page,
+                                               block_tables.shape[1])
             if reason is None and not interpret \
                     and not pallas_dtype_ok(q, pages):
                 reason = "dtype"
             if reason is None:
-                _note_decode_kernel("paged_latent_attention")
+                _note_decode_kernel(kernel)
                 return _latent_attention_pallas(q, pages, block_tables, lens,
-                                                sc, interpret)
-            note_fallback("paged_latent_attention", reason)
+                                                sc, interpret, keep=keep)
+            note_fallback(kernel, reason)
         _note_decode_kernel("xla")
-        return _latent_attention_xla(q, pages, block_tables, lens, sc)
+        return _latent_attention_xla(q, pages, block_tables, lens, sc, keep)
+
+
+def paged_sparse_latent_attention(q, pages, index_pages, qi, w, block_tables,
+                                  lens, topk, scale=None, interpret=False):
+    """One decode token a slot over the `topk` latent rows its indexer
+    selects (every row at a context of at most `topk`): the index
+    scores and the exact selection of kernels/paged_attention.py and
+    kernels/sparse_attention.py, then `paged_latent_attention` under the
+    selection. It reads every live page, as the K/V form does
+    (`paged_sparse_attention`). q [B, H, width <= lanes] (absorbed); qi
+    [B, J, Di] and w [B, J] the token's index queries and their weights;
+    lens [B] rows held, the new token's included -> (out [B, H, lanes],
+    keep [B, L] bool: the selection)."""
+    from .paged_attention import paged_index_scores
+    from .sparse_attention import select_topk
+    with jax.named_scope("dsa.indexer"):
+        scores = paged_index_scores(qi, w, index_pages, block_tables, lens,
+                                    interpret)
+    with jax.named_scope("dsa.select"):
+        live = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :] \
+            < lens[:, None]
+        keep = select_topk(scores, live, topk)
+    return paged_latent_attention(q, pages, block_tables, lens, scale,
+                                  interpret, keep=keep), keep

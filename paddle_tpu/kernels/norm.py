@@ -35,9 +35,17 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
                 ).astype(o_ref.dtype)
 
 
+def _block_rows(n, d, block_rows):
+    """Rows a block: the float32 copies of a block of 256 rows fit the
+    scoped VMEM up to 4096 columns; a wider row takes fewer."""
+    while block_rows * d > 256 * 4096 and block_rows > 8:
+        block_rows //= 2
+    return min(block_rows, n)
+
+
 def _rms_pallas(x2d, w, eps, block_rows=256):
     n, d = x2d.shape
-    block_rows = min(block_rows, n)
+    block_rows = _block_rows(n, d, block_rows)
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         grid=(pl.cdiv(n, block_rows),),
@@ -111,7 +119,7 @@ def _ln_kernel(x_ref, w_ref, b_ref, o_ref, *, eps):
 
 def _ln_pallas(x2d, w, b, eps, block_rows=256):
     n, d = x2d.shape
-    block_rows = min(block_rows, n)
+    block_rows = _block_rows(n, d, block_rows)
     return pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
         grid=(pl.cdiv(n, block_rows),),

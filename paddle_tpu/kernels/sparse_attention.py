@@ -173,6 +173,7 @@ def prefill_index_scores(qi, w, ki, last, interpret=False):
 # ---------------------------------------------------------------------------
 
 _ATTEND_BLOCK_Q, _ATTEND_BLOCK_K = 128, 512
+_ATTEND_TILE_ROWS = 1024      # 8 heads of a group x 128 queries
 
 
 def _attend_kernel(last_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_scr,
@@ -254,11 +255,17 @@ def selected_attention(q, k, v, keep, last, scale, interpret=False):
     """Attention of a chunk of queries over the keys `keep` marks. q
     [N, C, H, D]; k, v [N, Hkv, S, D] (head-major: the whole prompt's);
     keep [N, C, S] bool; `last` the position of the chunk's last query
-    (traced) -> [N, C, H, D]. A query that keeps no key is don't-care."""
+    (traced) -> [N, C, H, D]. A query that keeps no key is don't-care.
+    A tile holds `_ATTEND_TILE_ROWS` rows of queries x heads of a group
+    at most, and `_ATTEND_BLOCK_Q` queries at least: every tile reads
+    the chunk's key blocks again, so a head that shares its keys with
+    no other (`rep` 1) takes the whole chunk in one."""
     from .attention import _xla_attention
     interpret = interpret or pallas_interpret()
     c, d, s = q.shape[1], q.shape[3], k.shape[2]
-    bq, bk = min(_ATTEND_BLOCK_Q, c), min(_ATTEND_BLOCK_K, s)
+    rep = q.shape[2] // k.shape[1]
+    bq = min(max(_ATTEND_BLOCK_Q, _ATTEND_TILE_ROWS // rep), c)
+    bk = min(_ATTEND_BLOCK_K, s)
     if interpret or _use_pallas():
         if c % bq == 0 and bq % 8 == 0 and s % bk == 0 \
                 and bk % _LANES == 0 and d % _LANES == 0:

@@ -546,3 +546,83 @@ def test_decode_step_lowers_as_at_the_parent(chip, cell):
     assert "custom_call_config" in text
     assert hashlib.sha256(_without_debug_locations(text).encode()
                           ).hexdigest() == DECODE_STEP_AT_PARENT[cell]
+
+
+# --- the latent-attention-under-an-indexer cell at its real geometry --------
+# 32 slots of up to 16384 positions over 16385 pages; 6 layers of 64 heads
+# on a 576-wide latent row a token (640 lanes) with a 128-wide index key
+# beside it, 32 index heads; one dense and five expert layers, 16 of 256
+# experts held.
+
+def test_sparse_latent_decode_kernels_at_the_cells_geometry(chip,
+                                                            pallas_by_flag,
+                                                            monkeypatch):
+    from paddle_tpu.framework.flags import flag_value
+    from paddle_tpu.kernels import latent_attention as la
+    monkeypatch.setattr(la, "_use_pallas",
+                        lambda: bool(flag_value("use_pallas_kernels")))
+    slots, pps, pool, heads, j, di = 32, 1024, 16385, 64, 32, 128
+    text = _compile(
+        chip, lambda q, rows, ip, qi, w, bt, cl:
+        la.paged_sparse_latent_attention(q, rows, ip, qi, w, bt, cl, 2048,
+                                         SCALE),
+        ((slots, heads, 576), BF16), ((pool, PAGE, 640), BF16),
+        ((pool, PAGE, 128), BF16), ((slots, j, di), BF16),
+        ((slots, j), jnp.float32), ((slots, pps), I32), ((slots,), I32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " s64[" not in text
+    # neither pool is gathered, copied or widened on the way in
+    for shape in (f"bf16[{pool},{PAGE},640]", f"bf16[{pool},{PAGE},128]"):
+        assert not re.search(r"= " + re.escape(shape) + r"\S* copy\(", text)
+    assert f"[{slots},{pps},{PAGE}," not in text
+
+
+@pytest.fixture(scope="module")
+def glm(chip):
+    yield from _cell_predictor(chip, "glm5-longprompt-open")
+
+
+def test_glm_decode_step_at_real_size(chip, glm):
+    pred, n_params, fixed = glm
+    assert n_params == 4_727_340_800        # the issue's 4.727 B
+    B, pps = pred.B, pred.pages_per_seq
+    # no K/V anywhere: latent rows and index keys under the same page ids
+    assert all(v is None for v in pred.pool.v)
+    assert [a.shape for a in pred.pool.k] == [(16385, 16, 640)] * 6
+    assert [a.shape for a in pred.pool.index] == [(16385, 16, 128)] * 6
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_decode_step, (B, pps), (B,), (B,))
+    assert " f64[" not in text and " s64[" not in text
+    assert live < 12.5e9, live
+    # latent and index pages are updated where they lie
+    pool = sum(a.nbytes for a in pred.pool.latent + pred.pool.index)
+    assert ma.alias_size_in_bytes >= pool
+    # two kernels a layer (index scores, masked latent attention), the
+    # attention's output [slots, heads, lanes]; no slot's table of rows
+    # is gathered
+    assert text.count('custom_call_target="tpu_custom_call"') >= 12
+    assert len(re.findall(r"bf16\[%d,64,640\]\S* custom-call\(" % B,
+                          text)) == 6
+    assert f"[{B},{pps},16,640]" not in text
+    assert text.count("ragged-dot-none") >= 10      # 2 an expert layer
+
+
+@pytest.mark.parametrize("bucket", [8192, 16384])
+def test_glm_prefill_at_real_size(chip, glm, bucket):
+    pred, _, fixed = glm
+    n = pred._prefill_rows
+    assert n == 1
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_prefill, (n, bucket), (n, bucket),
+        (n,), (n, bucket // pred.page))
+    assert " f64[" not in text and " s64[" not in text
+    assert f"[{n},1,{bucket},{bucket}]" not in text
+    assert f"[{n},{bucket},{pred.model.config.vocab_size}]" not in text
+    # under the chip's 16 GB with room for the allocator
+    assert live < 14.5e9, live
+    # nothing of [heads, bucket] extent but the decompressed keys and
+    # values: the queries are made a chunk at a time
+    assert f"bf16[{n},{bucket},64,256]" not in text
+    # the masked flash kernel takes a chunk's 512 queries in one tile
+    # (`rep` 1: every tile would read a head's keys again)
+    assert f"bf16[{n},64,1,512,256]" in text

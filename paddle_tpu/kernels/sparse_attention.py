@@ -19,13 +19,21 @@ This module holds what prefill and decode share and the prefill form:
 - `sparse_prefill_attention`: scores, selection and masked attention in
   query chunks, so that nothing of [prompt, prompt] extent outlives a
   chunk. The attention of a chunk is a flash kernel of its own
-  (`_attend_kernel`): the query heads that share a KV head are rows of
-  one matmul against a key block, the chunk's selection comes in once a
-  group as an additive tile, and key blocks past the chunk's last query
-  are skipped. Without Pallas it is the XLA attention under the mask.
+  (`selected_attention`, `_attend_kernel`): the query heads that share
+  a KV head are rows of one matmul against a key block, and the chunk's
+  selection comes in once a group as an additive tile. Without Pallas
+  it is the XLA attention under the mask.
   A chunk does what its queries can see (`chunk_plan`): a chunk of
   padding runs nothing, and one whose queries see at most `topk` keys
-  attends to all they see, without scores or a selection.
+  attends to all they see, without scores or a selection. And both
+  kernels visit the key blocks its queries can see
+  (`chunk_key_blocks`): a (row, tile of queries) computes the blocks
+  from the row's first real key to the tile's last query, a block of
+  the row's left padding or past that query is skipped, and a skipped
+  grid step names the block that is resident before or after it, so
+  it copies nothing either. Plan and table follow from the keys'
+  validity alone, the same for every layer: a model computes them once
+  a program and the kernels read the table as prefetched scalars.
 
 The decode forms read the paged pool and live in
 kernels/paged_attention.py. Scores, selection and softmax are float32;
@@ -47,6 +55,9 @@ from ._common import (_Z, _NEG_INF, use_pallas as _use_pallas,
 F32 = jnp.float32
 U32 = jnp.uint32
 _LANES = 128
+_BLOCK_K = 512                 # keys a block, of both prefill kernels
+# the rows of a tile's table of key blocks (`chunk_key_blocks`)
+_NAMES, _RUNS = np.int32(0), np.int32(1)
 
 
 # ---------------------------------------------------------------------------
@@ -107,13 +118,14 @@ def _index_scores_xla(qi, w, ki):
     return jnp.sum(jax.nn.relu(s) * w.astype(F32)[..., None], axis=2)
 
 
-def _prefill_scores_kernel(last_ref, q_ref, w_ref, k_ref, o_ref, *, heads,
-                           block_k):
-    j = pl.program_id(1)
+def _prefill_scores_kernel(blocks_ref, q_ref, w_ref, k_ref, o_ref, *, heads,
+                           row):
+    b, j = pl.program_id(0), pl.program_id(1)
 
-    # a key block that starts past the chunk's last query holds no key
-    # any of its queries may see: left as it lies, the caller masks it
-    @pl.when(j * np.int32(block_k) <= last_ref[0])
+    # a key block before the row's first real key or past the chunk's
+    # last query holds no key a query of the row may see: left as it
+    # lies, the caller masks it
+    @pl.when(blocks_ref[b, row, _RUNS, j] != 0)
     def _():
         k = k_ref[0]                                       # (bk, Di)
         w = w_ref[0]                                       # (C, J)
@@ -127,43 +139,47 @@ def _prefill_scores_kernel(last_ref, q_ref, w_ref, k_ref, o_ref, *, heads,
         o_ref[0] = acc
 
 
-def _index_scores_pallas(qi, w, ki, last, block_k, interpret):
+def _index_scores_pallas(qi, w, ki, blocks, block_k, interpret):
     n, c, heads, di = qi.shape
     s = ki.shape[1]
+    row = np.int32(blocks.shape[1] - 1)     # the whole chunk's
+    # keys in and scores out by the block the step NAMES: a step that
+    # does not run names the block resident before or after it, copies
+    # nothing in and writes nothing new back
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n, s // block_k),
         in_specs=[
             pl.BlockSpec((1, heads, c, di),
-                         lambda b, j, lr: (b, _Z, _Z, _Z)),
-            pl.BlockSpec((1, c, heads), lambda b, j, lr: (b, _Z, _Z)),
-            pl.BlockSpec((1, block_k, di), lambda b, j, lr: (b, j, _Z))],
-        out_specs=pl.BlockSpec((1, c, block_k), lambda b, j, lr: (b, _Z, j)))
+                         lambda b, j, t: (b, _Z, _Z, _Z)),
+            pl.BlockSpec((1, c, heads), lambda b, j, t: (b, _Z, _Z)),
+            pl.BlockSpec((1, block_k, di),
+                         lambda b, j, t: (b, t[b, row, _NAMES, j], _Z))],
+        out_specs=pl.BlockSpec(
+            (1, c, block_k), lambda b, j, t: (b, _Z, t[b, row, _NAMES, j])))
     return pl.pallas_call(
-        functools.partial(_prefill_scores_kernel, heads=heads,
-                          block_k=block_k),
+        functools.partial(_prefill_scores_kernel, heads=heads, row=row),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, c, s), F32),
         interpret=interpret,
-    )(jnp.reshape(last, (1,)).astype(jnp.int32), qi.transpose(0, 2, 1, 3),
-      w.astype(F32), ki)
+    )(blocks, qi.transpose(0, 2, 1, 3), w.astype(F32), ki)
 
 
-_SCORE_BLOCK_K = 512
-
-
-def prefill_index_scores(qi, w, ki, last, interpret=False):
+def prefill_index_scores(qi, w, ki, blocks, interpret=False):
     """I of one chunk: qi [N, C, J, Di] (the chunk's index queries), w
-    [N, C, J] float32, ki [N, S, Di] (every key of the prompt), `last`
-    the position of the chunk's last query (traced) -> [N, C, S]
-    float32. Entries of keys past `last` are undefined: the caller's
-    causal mask drops them."""
+    [N, C, J] float32, ki [N, S, Di] (every key of the prompt),
+    `blocks` the chunk's table of key blocks (`chunk_key_blocks`: its
+    last row is the whole chunk's) -> [N, C, S] float32. Entries of
+    keys in blocks the table does not run (before a row's first real
+    key, past the chunk's last query) are undefined: the caller's mask
+    of what a query sees drops them."""
     interpret = interpret or pallas_interpret()
     c, s = qi.shape[1], ki.shape[1]
-    block_k = min(_SCORE_BLOCK_K, s)
+    block_k = min(_BLOCK_K, s)
     if interpret or _use_pallas():
         if c % 8 == 0 and s % block_k == 0 and block_k % _LANES == 0:
-            return _index_scores_pallas(qi, w, ki, last, block_k, interpret)
+            return _index_scores_pallas(qi, w, ki, blocks, block_k,
+                                        interpret)
         note_fallback("prefill_index_scores", "chunk_tiling")
     return _index_scores_xla(qi, w, ki)
 
@@ -172,16 +188,28 @@ def prefill_index_scores(qi, w, ki, last, interpret=False):
 # attention of a chunk of queries over its selected keys
 # ---------------------------------------------------------------------------
 
-_ATTEND_BLOCK_Q, _ATTEND_BLOCK_K = 128, 512
+_ATTEND_BLOCK_Q = 128
 _ATTEND_TILE_ROWS = 1024      # 8 heads of a group x 128 queries
 
 
-def _attend_kernel(last_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_scr,
+def attend_tiles(c, rep, s):
+    """(queries a tile, keys a block) of `selected_attention` for a
+    chunk of `c` queries of `rep` heads a KV head over `s` keys. A tile
+    holds `_ATTEND_TILE_ROWS` rows of queries x heads of a group at
+    most, and `_ATTEND_BLOCK_Q` queries at least: every tile reads the
+    chunk's key blocks again, so a head that shares its keys with no
+    other (`rep` 1) takes the whole chunk in one."""
+    return (min(max(_ATTEND_BLOCK_Q, _ATTEND_TILE_ROWS // rep), c),
+            min(_BLOCK_K, s))
+
+
+def _attend_kernel(blocks_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_scr,
                    l_scr, acc_scr, *, scale, rep, block_k):
     """One (row, KV head, query tile) over the key blocks: q_ref holds
     the tile's queries of the `rep` heads of the group, head-major
     [rep * bq, D]; bias_ref the selection of the tile's queries [bq, bk]
-    (0 kept, _NEG_INF not), shared by the heads."""
+    (0 kept, _NEG_INF not), shared by the heads; blocks_ref the chunk's
+    table of key blocks (`chunk_key_blocks`)."""
     j = pl.program_id(3)
 
     @pl.when(j == 0)
@@ -190,8 +218,9 @@ def _attend_kernel(last_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # a key block that starts past the chunk's last query is seen by none
-    @pl.when(j * np.int32(block_k) <= last_ref[0])
+    # a key block before the row's first real key (its left padding) or
+    # past the tile's last query adds nothing to any query of the tile
+    @pl.when(blocks_ref[pl.program_id(0), pl.program_id(2), _RUNS, j] != 0)
     def _compute():
         q, k, v = q_ref[0, 0, 0], k_ref[0, 0], v_ref[0, 0]
         bq = bias_ref.shape[1]
@@ -219,8 +248,10 @@ def _attend_kernel(last_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_scr,
                           ).astype(o_ref.dtype)
 
 
-def _attend_pallas(q, k, v, keep, last, scale, bq, bk, interpret):
-    """q [N, C, H, D]; k, v [N, Hkv, S, D]; keep [N, C, S] bool."""
+def _attend_pallas(q, k, v, keep, blocks, scale, bq, bk, interpret):
+    """q [N, C, H, D]; k, v [N, Hkv, S, D]; keep [N, C, S] bool; blocks
+    [N, C // bq (+ 1), 2, S // bk] int32: the key blocks each tile's
+    steps name and run (`chunk_key_blocks`)."""
     n, c, h, d = q.shape
     hkv, s = k.shape[1], k.shape[2]
     rep, tiles = h // hkv, c // bq
@@ -229,13 +260,19 @@ def _attend_pallas(q, k, v, keep, last, scale, bq, bk, interpret):
         0, 3, 1, 4, 2, 5).reshape(n, hkv, tiles, rep * bq, d)
     bias = jnp.where(keep, F32(0), _NEG_INF).astype(jnp.bfloat16)
     q_spec = pl.BlockSpec((1, 1, 1, rep * bq, d),
-                          lambda b, g, i, j, lr: (b, g, i, _Z, _Z))
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b, g, i, j, lr: (b, g, j, _Z))
+                          lambda b, g, i, j, t: (b, g, i, _Z, _Z))
+    # K, V and the bias tile by the block the step NAMES: a step that
+    # does not run names the block resident before or after it, so the
+    # pipeline copies nothing for it
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d), lambda b, g, i, j, t: (b, g, t[b, i, _NAMES, j], _Z))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n, hkv, tiles, s // bk),
         in_specs=[q_spec,
-                  pl.BlockSpec((1, bq, bk), lambda b, g, i, j, lr: (b, i, j)),
+                  pl.BlockSpec(
+                      (1, bq, bk),
+                      lambda b, g, i, j, t: (b, i, t[b, i, _NAMES, j])),
                   kv_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((rep * bq, _LANES), F32),
@@ -246,30 +283,27 @@ def _attend_pallas(q, k, v, keep, last, scale, bq, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, hkv, tiles, rep * bq, d), q.dtype),
         interpret=interpret,
-    )(jnp.reshape(last, (1,)).astype(jnp.int32), fold(q), bias, k, v)
+    )(blocks, fold(q), bias, k, v)
     return out.reshape(n, hkv, tiles, rep, bq, d).transpose(
         0, 2, 4, 1, 3, 5).reshape(n, c, h, d)
 
 
-def selected_attention(q, k, v, keep, last, scale, interpret=False):
+def selected_attention(q, k, v, keep, blocks, scale, interpret=False):
     """Attention of a chunk of queries over the keys `keep` marks. q
     [N, C, H, D]; k, v [N, Hkv, S, D] (head-major: the whole prompt's);
-    keep [N, C, S] bool; `last` the position of the chunk's last query
-    (traced) -> [N, C, H, D]. A query that keeps no key is don't-care.
-    A tile holds `_ATTEND_TILE_ROWS` rows of queries x heads of a group
-    at most, and `_ATTEND_BLOCK_Q` queries at least: every tile reads
-    the chunk's key blocks again, so a head that shares its keys with
-    no other (`rep` 1) takes the whole chunk in one."""
+    keep [N, C, S] bool, causality and the padding in it; `blocks` the
+    chunk's table of key blocks (`chunk_key_blocks`: each tile of
+    `attend_tiles` queries visits the blocks between the row's first
+    real key and the tile's last query, a row at a time) -> [N, C, H,
+    D]. A query that keeps no key is don't-care."""
     from .attention import _xla_attention
     interpret = interpret or pallas_interpret()
     c, d, s = q.shape[1], q.shape[3], k.shape[2]
-    rep = q.shape[2] // k.shape[1]
-    bq = min(max(_ATTEND_BLOCK_Q, _ATTEND_TILE_ROWS // rep), c)
-    bk = min(_ATTEND_BLOCK_K, s)
+    bq, bk = attend_tiles(c, q.shape[2] // k.shape[1], s)
     if interpret or _use_pallas():
         if c % bq == 0 and bq % 8 == 0 and s % bk == 0 \
                 and bk % _LANES == 0 and d % _LANES == 0:
-            return _attend_pallas(q, k, v, keep, last, scale, bq, bk,
+            return _attend_pallas(q, k, v, keep, blocks, scale, bq, bk,
                                   interpret)
         note_fallback("sparse_prefill_attention", "chunk_tiling")
     return _xla_attention(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
@@ -300,15 +334,65 @@ def chunk_plan(key_valid, chunk, topk):
     return jnp.where(has_query, kind, PADDING).astype(jnp.int32)
 
 
-def plan_counts(plan, n, s):
-    """[5] int32 of a layer's prefill under `plan` over N rows of S
-    keys: chunks of padding, chunks without a selection, chunks with
-    one, then the keys a counting pass ran over (a selection counts
-    over the bucket) and the keys of every chunk's bucket."""
+def chunk_key_blocks(key_valid, chunk, rep):
+    """Which key blocks each chunk of `chunk` queries of a left-padded
+    batch visits, from the keys' validity [N, S] alone -> [chunks, N,
+    tiles + 1, 2, blocks] int32, a table a (chunk, row of the batch,
+    tile of `attend_tiles` queries at `rep` heads a KV head; the last
+    "tile" is the whole chunk, for the index scores): [..., _RUNS, j] is
+    1 where grid step j computes, from the block of the row's first
+    real key to the block of the tile's last query (what a query of the
+    tile may see: a selection keeps keys among those), and [..., _NAMES,
+    j] is the block step j names: j where it runs, else the nearer end
+    of that range, which is resident before or after it; a tile of
+    padding runs nothing and names one block throughout. The same for
+    every layer of a program, like `chunk_plan`: a model computes both
+    once. The kernels read it as prefetched scalars, so a step costs no
+    index arithmetic, traced or run."""
+    n, s_real = key_valid.shape
+    c = min(int(chunk), s_real)
+    s = s_real + -s_real % c
+    bq, bk = attend_tiles(c, rep, s)
+    tiles, blocks = -(-c // bq), -(-s // bk)
+    at = jnp.arange(blocks * bk, dtype=jnp.int32)
+    first = jnp.min(
+        jnp.where(jnp.pad(key_valid, [(0, 0), (0, at.size - s_real)]), at,
+                  jnp.int32(at.size)).reshape(n, blocks, bk),
+        axis=-1)                                # a block's first real key
+    ends = jnp.minimum((jnp.arange(tiles + 1, dtype=jnp.int32) + 1)
+                       * jnp.int32(bq), c) - 1  # a tile's last query
+    last = jnp.arange(0, s, c, dtype=jnp.int32)[:, None] + ends
+    live = first[None, :, None, :] <= last[:, None, :, None]
+    j = jnp.arange(blocks, dtype=jnp.int32)
+    lo = jnp.min(jnp.where(live, j, jnp.int32(blocks - 1)), axis=-1,
+                 keepdims=True)
+    hi = jnp.max(jnp.where(live, j, jnp.int32(-1)), axis=-1, keepdims=True)
+    return jnp.stack([jnp.maximum(lo, jnp.minimum(j, hi)),
+                      ((j >= lo) & (j <= hi)).astype(jnp.int32)], axis=-2)
+
+
+def plan_counts(plan, blocks, chunk, s):
+    """[7] int32 of a layer's prefill of N rows of `s` keys under
+    `plan` and `blocks` (`chunk_plan`'s and `chunk_key_blocks`'s):
+    chunks of padding, chunks without a selection, chunks with one; the
+    keys a counting pass ran over (a selection counts over the bucket)
+    and the keys of every chunk's bucket; then the (row, query tile, key
+    block) triples `selected_attention` computes, and those from block
+    0 to the block of the chunk's last query, the whole bucket's."""
+    n, tiles = blocks.shape[1], blocks.shape[2] - 1
+    c = min(int(chunk), s)
+    bk = min(_BLOCK_K, s + -s % c)
     kinds = [jnp.sum(plan == kind, dtype=jnp.int32)
              for kind in (PADDING, DENSE, SELECTED)]
-    return jnp.stack(kinds + [kinds[2] * jnp.int32(n * s),
-                              jnp.int32(plan.shape[0] * n * s)])
+    runs = plan != PADDING
+    attended = jnp.sum(blocks[:, :, :tiles, _RUNS], axis=(1, 2, 3),
+                       dtype=jnp.int32)
+    ends = jnp.arange(c - 1, plan.shape[0] * c, c, dtype=jnp.int32)
+    bucket = jnp.int32(n * tiles) * (ends // jnp.int32(bk) + 1)
+    return jnp.stack(kinds + [
+        kinds[2] * jnp.int32(n * s), jnp.int32(plan.shape[0] * n * s),
+        jnp.sum(jnp.where(runs, attended, 0), dtype=jnp.int32),
+        jnp.sum(jnp.where(runs, bucket, 0), dtype=jnp.int32)])
 
 
 def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
@@ -320,14 +404,16 @@ def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
     keys attends to all of them (plain causal attention); a padding
     query sees no key and its row is don't-care. Scores, selection and
     the masked attention run `chunk` queries at a time, and a chunk does
-    what `plan` (`chunk_plan(key_valid, chunk, topk)`, computed here
-    where the caller has none) says its queries need: nothing where all
-    of them are padding, no selection where none sees more than `topk`
-    keys."""
+    what `plan` says its queries need: the pair (`chunk_plan(key_valid,
+    chunk, topk)`, `chunk_key_blocks(key_valid, chunk, H // Hkv)`),
+    computed here where the caller has none: nothing where all of them
+    are padding, no selection where none sees more than `topk` keys,
+    and of the key blocks those that hold a key they may see."""
     n, s_real, h, d = q.shape
     c = min(int(chunk), s_real)
     if plan is None:
-        plan = chunk_plan(key_valid, c, topk)
+        plan = (chunk_plan(key_valid, c, topk),
+                chunk_key_blocks(key_valid, c, h // k.shape[2]))
     tail = -s_real % c
     if tail:        # whole chunks: the tail's keys are seen by no query
         q, k, v, qi, w, ki, key_valid = (
@@ -338,10 +424,9 @@ def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
     kpos = jnp.arange(s, dtype=jnp.int32)
 
     def one(at):
-        start, kind = at
+        start, kind, blocks = at
         cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, c, axis=1)
         qpos = start + jnp.arange(c, dtype=jnp.int32)
-        last = start + jnp.int32(c - 1)
 
         def real(_):
             seen = key_valid[:, None, :] \
@@ -349,17 +434,17 @@ def sparse_prefill_attention(q, k, v, qi, w, ki, key_valid, *, topk, scale,
 
             def selected(_):
                 with jax.named_scope("dsa.indexer"):
-                    scores = prefill_index_scores(cut(qi), cut(w), ki, last)
+                    scores = prefill_index_scores(cut(qi), cut(w), ki, blocks)
                 with jax.named_scope("dsa.select"):
                     return select_topk(scores, seen, topk)
 
             keep = jax.lax.cond(kind == SELECTED, selected,
                                 lambda _: seen, None)
             with jax.named_scope("dsa.attend"):
-                return selected_attention(cut(q), k, v, keep, last, scale)
+                return selected_attention(cut(q), k, v, keep, blocks, scale)
 
         return jax.lax.cond(kind != PADDING, real,
                             lambda _: jnp.zeros((n, c, h, d), q.dtype), None)
 
-    out = jax.lax.map(one, (jnp.arange(0, s, c, dtype=jnp.int32), plan))
+    out = jax.lax.map(one, (jnp.arange(0, s, c, dtype=jnp.int32), *plan))
     return jnp.moveaxis(out, 0, 1).reshape(n, s, h, d)[:, :s_real]

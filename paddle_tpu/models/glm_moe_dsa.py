@@ -75,9 +75,10 @@ from ..nn.initializer import Constant, Normal
 from ..ops._dispatch import apply
 from ..generation.kv_cache import (LayerCache, LayerCaches, PagedKVCache,
                                    paged_cache_sparse_latent_update_attend)
-from ..kernels.sparse_attention import (PADDING, SELECTED, chunk_plan,
-                                        plan_counts, prefill_index_scores,
-                                        select_topk, selected_attention)
+from ..kernels.sparse_attention import (PADDING, SELECTED, chunk_key_blocks,
+                                        chunk_plan, plan_counts,
+                                        prefill_index_scores, select_topk,
+                                        selected_attention)
 from .granite_hybrid import GraniteRMSNorm as RMSNorm
 from .keye_vl2 import _layer_norm, _rms, rope_angles
 from .ling_hybrid import LingMLP, LingSparseMoE, rotate_interleaved
@@ -220,13 +221,14 @@ class GlmSparseLatentAttention(Layer):
         return self._rotate_index(jnp.dot(c_q, wiq).reshape(
             c_q.shape[:2] + (c.index_n_heads, c.index_head_dim)), ang)
 
-    def _whole(self, x, pos, valid, plan, wqa, gqa, wqb, wkva, gkv, wkvb, wo,
-               wiq, *index):
+    def _whole(self, x, pos, valid, plan, blocks, wqa, gqa, wqb, wkva, gkv,
+               wkvb, wo, wiq, *index):
         """Decompressed: every head's keys and values are formed from
         the latent once, head-major; the queries, the index scores, the
         selection and the masked attention (`chunk_plan` says which of
-        them a chunk needs) and the output projection run a chunk of
-        queries at a time."""
+        them a chunk needs, `chunk_key_blocks` which key blocks it
+        visits) and the output projection run a chunk of queries at a
+        time."""
         c = self.config
         n, s_real, hidden = x.shape
         ch = min(c.q_chunk_size, s_real)
@@ -247,10 +249,9 @@ class GlmSparseLatentAttention(Layer):
         kpos = jnp.arange(s, dtype=jnp.int32)
 
         def one(at):
-            start, kind = at
+            start, kind, tab = at
             cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, ch, axis=1)
             qpos = start + jnp.arange(ch, dtype=jnp.int32)
-            last = start + jnp.int32(ch - 1)
 
             def real(_):
                 cq, a = cut(c_q), cut(ang)
@@ -261,14 +262,14 @@ class GlmSparseLatentAttention(Layer):
                 def selected(_):
                     qi = self._index_queries(cq, a, wiq)
                     with jax.named_scope("dsa.indexer"):
-                        scores = prefill_index_scores(qi, cut(w), ki, last)
+                        scores = prefill_index_scores(qi, cut(w), ki, tab)
                     with jax.named_scope("dsa.select"):
                         return select_topk(scores, seen, c.index_topk)
 
                 keep = jax.lax.cond(kind == SELECTED, selected,
                                     lambda _: seen, None)
                 with jax.named_scope("mla.attend"):
-                    o = selected_attention(q, k, v, keep, last,
+                    o = selected_attention(q, k, v, keep, tab,
                                            c.qk_head_dim ** -0.5)
                 return jnp.dot(o.reshape(n, ch, -1), wo)
 
@@ -276,21 +277,23 @@ class GlmSparseLatentAttention(Layer):
                                 lambda _: jnp.zeros((n, ch, hidden), x.dtype),
                                 None)
 
-        out = jax.lax.map(one, (jnp.arange(0, s, ch, dtype=jnp.int32), plan))
+        out = jax.lax.map(one, (jnp.arange(0, s, ch, dtype=jnp.int32), plan,
+                                blocks))
         out = jnp.moveaxis(out, 0, 1).reshape(n, s, hidden)
         return out[:, :s_real], row[:, :s_real], ki[:, :s_real]
 
     def forward(self, x, pos, valid=None, cache=None, plan=None):
         """x [B, S, hidden]; pos [B, S] int32. Without `cache`: the
         whole batch from nothing, `valid` [B, S] its real positions and
-        `plan` what each chunk of queries has to do (`chunk_plan`);
+        `plan` what each chunk of queries has to do and which key blocks
+        it visits (the pair `chunk_plan`, `chunk_key_blocks`);
         returns (out, (rows, index keys)). With a `LatentCacheEntry`
         that carries index pages (S == 1): one decode step in absorbed
         form; returns (out, entry, counts [B] = rows each slot's token
         attended to)."""
         c = self.config
         if cache is None:
-            out, row, ki = apply(self._whole, x, pos, valid, plan,
+            out, row, ki = apply(self._whole, x, pos, valid, *plan,
                                  *self._weights(),
                                  _name="sparse_latent_attention")
             return out, (row, ki)
@@ -406,7 +409,7 @@ class GlmMoeDsaForCausalLM(Layer):
         """What the vectors in `caches.counters` count, element by
         element: {key: [(metric, labels)]} (docs/OBSERVABILITY.md). A
         decode step gives the first two of "dsa" and a prefill all
-        seven, the first two zero, and zero for "mla": those count what
+        nine, the first two zero, and zero for "mla": those count what
         decode steps touch."""
         c = self.config
         held = range(c.n_routed_experts) if c.experts_held is None \
@@ -415,7 +418,9 @@ class GlmMoeDsaForCausalLM(Layer):
                 + [("dsa.prefill_chunks", {"kind": kind})
                    for kind in ("padding", "dense", "selected")]
                 + [("dsa.prefill_keys_counted", {}),
-                   ("dsa.prefill_keys_bucket", {})],
+                   ("dsa.prefill_keys_bucket", {})]
+                + [("dsa.prefill_key_blocks", {"kind": kind})
+                   for kind in ("attended", "bucket")],
                 "mla": [("mla.keys_live", {})],
                 "moe": [("moe.assignments", {}),
                         ("moe.assignments_local", {})]
@@ -450,9 +455,12 @@ class GlmMoeDsaForCausalLM(Layer):
             valid = apply(lambda mk: mk if mk.ndim == 2
                           else mk[:, 0, -1, :] > -1.0, attn_mask,
                           _name="valid_positions")
-        # what a prefill's chunks of queries have to do, once for all layers
+        # what a prefill's chunks of queries have to do and which key
+        # blocks they visit (every head has keys of its own: `rep` 1),
+        # once for all layers
         plan = None if paged else apply(
-            lambda ok: chunk_plan(ok, c.q_chunk_size, c.index_topk), valid,
+            lambda ok: (chunk_plan(ok, c.q_chunk_size, c.index_topk),
+                        chunk_key_blocks(ok, c.q_chunk_size, 1)), valid,
             _name="prefill_plan")
         caches, moe, dsa = [], None, None
         for i, layer in enumerate(m.layers):
@@ -498,9 +506,9 @@ class GlmMoeDsaForCausalLM(Layer):
                         _name="dsa_counts")
             mla = apply(lambda d: d[:1], dsa, _name="mla_counts")
         else:       # rows are counted by decode steps: one query a slot
-            dsa = apply(lambda p: jnp.pad(
-                plan_counts(p, b, s) * jnp.int32(n_layers), (2, 0)), plan,
-                _name="dsa_counts")
+            dsa = apply(lambda p, blocks: jnp.pad(plan_counts(
+                p, blocks, c.q_chunk_size, s) * jnp.int32(n_layers), (2, 0)),
+                *plan, _name="dsa_counts")
             mla = apply(lambda ids: jnp.zeros((1,), jnp.int32), input_ids,
                         _name="mla_counts")
         return logits, LayerCaches(caches, {"dsa": dsa, "mla": mla,

@@ -63,7 +63,8 @@ from ..ops._dispatch import apply
 from ..generation.kv_cache import (LayerCache, LayerCaches, PagedKVCache,
                                    paged_cache_sparse_update_attend)
 from ..incubate.distributed.models.moe.dropless import DroplessMoELayer
-from ..kernels.sparse_attention import (chunk_plan, plan_counts,
+from ..kernels.sparse_attention import (chunk_key_blocks, chunk_plan,
+                                        plan_counts,
                                         sparse_prefill_attention)
 from .granite_hybrid import GraniteRMSNorm as RMSNorm
 
@@ -210,25 +211,27 @@ class KeyeSparseAttention(Layer):
         w = jnp.dot(x, ww, preferred_element_type=F32)
         return q, k, v, qi, w, ki
 
-    def _whole(self, x, pos, valid, plan, wq, wk, wv, wo, *rest):
+    def _whole(self, x, pos, valid, plan, blocks, wq, wk, wv, wo, *rest):
         c = self.config
         q, k, v, qi, w, ki = self._project(x, pos, wq, wk, wv, *rest)
         out = sparse_prefill_attention(
             q, k, v, qi, w, ki, valid, topk=c.index_topk,
-            scale=c.head_dim ** -0.5, chunk=c.q_chunk_size, plan=plan)
+            scale=c.head_dim ** -0.5, chunk=c.q_chunk_size,
+            plan=(plan, blocks))
         return jnp.dot(out.reshape(x.shape[:2] + (-1,)), wo), k, v, ki
 
     def forward(self, x, pos, valid=None, cache=None, plan=None):
         """x [B, S, hidden]; pos [B, S] int32. Without `cache`: the
         whole batch from nothing, `valid` [B, S] its real positions and
-        `plan` what each chunk of queries has to do (`chunk_plan`: the
-        model computes it once for all layers); returns (out, (k, v,
+        `plan` what each chunk of queries has to do and which key
+        blocks it visits (`chunk_plan`, `chunk_key_blocks`: the model
+        computes the pair once for all layers); returns (out, (k, v,
         kI)). With a `PagedCacheEntry` (S == 1): one decode step;
         returns (out, entry, counts [B] = keys each slot's token
         attended to)."""
         c = self.config
         if cache is None:
-            out, k, v, ki = apply(self._whole, x, pos, valid, plan,
+            out, k, v, ki = apply(self._whole, x, pos, valid, *plan,
                                   *self._weights(), _name="sparse_attention")
             return out, (k, v, ki)
         if x.shape[1] != 1:
@@ -312,7 +315,7 @@ class KeyeVL2ForCausalLM(Layer):
         """What the vectors in `caches.counters` count, element by
         element: {key: [(metric, labels)]} (docs/OBSERVABILITY.md). A
         decode step gives the first two of "dsa" and a prefill all
-        seven, the first two zero: it counts what its query chunks did
+        nine, the first two zero: it counts what its query chunks did
         (`kernels.sparse_attention.plan_counts`)."""
         c = self.config
         held = range(c.num_experts) if c.experts_held is None \
@@ -321,7 +324,9 @@ class KeyeVL2ForCausalLM(Layer):
                 + [("dsa.prefill_chunks", {"kind": kind})
                    for kind in ("padding", "dense", "selected")]
                 + [("dsa.prefill_keys_counted", {}),
-                   ("dsa.prefill_keys_bucket", {})],
+                   ("dsa.prefill_keys_bucket", {})]
+                + [("dsa.prefill_key_blocks", {"kind": kind})
+                   for kind in ("attended", "bucket")],
                 "moe": [("moe.assignments", {}),
                         ("moe.assignments_local", {})]
                 + [("moe.expert_tokens", {"expert": str(e)}) for e in held]}
@@ -360,9 +365,12 @@ class KeyeVL2ForCausalLM(Layer):
                           else mk[:, 0, -1, :] > -1.0, attn_mask,
                           _name="valid_positions")
         c = self.config
-        # what a prefill's chunks of queries have to do, once for all layers
+        # what a prefill's chunks of queries have to do and which key
+        # blocks they visit, once for all layers
+        rep = c.num_attention_heads // c.num_key_value_heads
         plan = None if paged else apply(
-            lambda ok: chunk_plan(ok, c.q_chunk_size, c.index_topk), valid,
+            lambda ok: (chunk_plan(ok, c.q_chunk_size, c.index_topk),
+                        chunk_key_blocks(ok, c.q_chunk_size, rep)), valid,
             _name="prefill_plan")
         caches, moe, dsa = [], None, None
         for i, layer in enumerate(m.layers):
@@ -398,7 +406,7 @@ class KeyeVL2ForCausalLM(Layer):
                         *(() if valid is None else (valid,)),
                         _name="dsa_counts")
         else:       # keys are counted by decode steps: one query a slot
-            dsa = apply(lambda p: jnp.pad(
-                plan_counts(p, b, s) * jnp.int32(n_layers), (2, 0)), plan,
-                _name="dsa_counts")
+            dsa = apply(lambda p, blocks: jnp.pad(plan_counts(
+                p, blocks, c.q_chunk_size, s) * jnp.int32(n_layers), (2, 0)),
+                *plan, _name="dsa_counts")
         return logits, LayerCaches(caches, {"dsa": dsa, "moe": moe})

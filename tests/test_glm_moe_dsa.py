@@ -151,7 +151,8 @@ def test_prefill_selects_the_references_sets(model, reference):
     pos = jnp.arange(40, dtype=jnp.int32)[None]
     c_q, ang, _, ki, w = attn._keys(x, pos, wqa, gqa, wkva, gkv, *index)
     qi = attn._index_queries(c_q, ang, wiq)
-    scores = sa.prefill_index_scores(qi, w, ki, jnp.int32(39))
+    scores = sa.prefill_index_scores(qi, w, ki, sa.chunk_key_blocks(
+        jnp.ones((1, 40), jnp.bool_), 40, 1)[0])
     seen = np.tril(np.ones((40, 40), bool))
     got = np.asarray(sa.select_topk(scores, jnp.asarray(seen)[None], TOPK))[0]
     assert np.array_equal(got, keeps[0])
@@ -238,7 +239,8 @@ def test_absorbed_attention_is_the_decompressed(model):
         whole, (rows, keys) = attn(
             paddle.to_tensor(x), paddle.to_tensor(pos),
             paddle.to_tensor(valid), None,
-            paddle.to_tensor(sa.chunk_plan(valid, 8, TOPK)))
+            (paddle.to_tensor(sa.chunk_plan(valid, 8, TOPK)),
+             paddle.to_tensor(sa.chunk_key_blocks(valid, 8, 1))))
     page, pps = 8, 8
     table = 1 + np.arange(pps, dtype=np.int32)[None]
     put = lambda a, lanes: jnp.zeros((pps + 1, page, lanes), jnp.float32).at[
@@ -413,15 +415,16 @@ def test_prefill_kernels_at_mla_shapes_are_their_xla_forms():
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     n, c, s, h, d = 1, 8, 256, 2, 256
     qi, w, ki = f(n, c, 4, 128), f(n, c, 4), f(n, s, 128)
-    last = jnp.int32(s - 1)
-    got = sa.prefill_index_scores(qi, w, ki, last, interpret=True)
+    # the bucket's last chunk: every key block runs
+    blocks = sa.chunk_key_blocks(jnp.ones((n, s), jnp.bool_), c, 1)[-1]
+    got = sa.prefill_index_scores(qi, w, ki, blocks, interpret=True)
     assert np.abs(np.asarray(got) - np.asarray(
         sa._index_scores_xla(qi, w, ki))).max() < 1e-3
     keep = jnp.asarray(rng.random((n, c, s)) < 0.2).at[:, :, 0].set(True)
     q, k, v = f(n, c, h, d), f(n, h, s, d), f(n, h, s, d)
-    got = sa.selected_attention(q, k, v, keep, last, d ** -0.5,
+    got = sa.selected_attention(q, k, v, keep, blocks, d ** -0.5,
                                 interpret=True)
-    want = sa.selected_attention(q, k, v, keep, last, d ** -0.5)
+    want = sa.selected_attention(q, k, v, keep, blocks, d ** -0.5)
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-4
 
 
@@ -568,7 +571,7 @@ def test_prefill_chunk_counters_come_down_with_the_first_tokens(model):
     def read():
         return {(n, s.labels.get("kind")): s.value
                 for n in ("dsa.prefill_chunks", "dsa.prefill_keys_counted",
-                          "dsa.prefill_keys_bucket")
+                          "dsa.prefill_keys_bucket", "dsa.prefill_key_blocks")
                 for s in metrics.counter(n).samples()}
     before = read()
     pred, _ = _served(model, [_prompts([20], stream=6)[0]], max_new=2,
@@ -581,7 +584,11 @@ def test_prefill_chunk_counters_come_down_with_the_first_tokens(model):
                     ("dsa.prefill_chunks", "dense"): layers,
                     ("dsa.prefill_chunks", "selected"): 2 * layers,
                     ("dsa.prefill_keys_counted", None): 2 * 32 * layers,
-                    ("dsa.prefill_keys_bucket", None): 4 * 32 * layers}
+                    ("dsa.prefill_keys_bucket", None): 4 * 32 * layers,
+                    # a tile is a chunk and the bucket one key block: the
+                    # three chunks that run visit it, padding or none
+                    ("dsa.prefill_key_blocks", "attended"): 3 * layers,
+                    ("dsa.prefill_key_blocks", "bucket"): 3 * layers}
 
 
 def test_prefix_cache_is_derived_off_and_says_so(model):

@@ -131,7 +131,8 @@ def test_prefill_selects_the_references_sets(model, reference):
     ws = [p._value for p in attn._weights()]
     pos = jnp.arange(40, dtype=jnp.int32)[None]
     _, _, _, qi, w, ki = attn._project(x, pos, *ws[:3], *ws[4:])
-    scores = sa.prefill_index_scores(qi, w, ki, jnp.int32(39))
+    scores = sa.prefill_index_scores(qi, w, ki, sa.chunk_key_blocks(
+        jnp.ones((1, 40), jnp.bool_), 40, 1)[0])
     seen = np.tril(np.ones((40, 40), bool))
     got = np.asarray(sa.select_topk(scores, jnp.asarray(seen)[None], TOPK))[0]
     assert np.array_equal(got, keeps[0])
@@ -372,7 +373,7 @@ def test_prefill_chunk_counters_come_down_with_the_first_tokens(model):
     def read():
         return {(n, s.labels.get("kind")): s.value
                 for n in ("dsa.prefill_chunks", "dsa.prefill_keys_counted",
-                          "dsa.prefill_keys_bucket")
+                          "dsa.prefill_keys_bucket", "dsa.prefill_key_blocks")
                 for s in metrics.counter(n).samples()}
     before = read()
     pred, _ = _served(model, [_prompts([20], stream=6)[0]], max_new=2,
@@ -385,7 +386,11 @@ def test_prefill_chunk_counters_come_down_with_the_first_tokens(model):
                     ("dsa.prefill_chunks", "dense"): layers,
                     ("dsa.prefill_chunks", "selected"): 2 * layers,
                     ("dsa.prefill_keys_counted", None): 2 * 32 * layers,
-                    ("dsa.prefill_keys_bucket", None): 4 * 32 * layers}
+                    ("dsa.prefill_keys_bucket", None): 4 * 32 * layers,
+                    # a tile is a chunk and the bucket one key block: the
+                    # three chunks that run visit it, padding or none
+                    ("dsa.prefill_key_blocks", "attended"): 3 * layers,
+                    ("dsa.prefill_key_blocks", "bucket"): 3 * layers}
 
 
 def test_prefix_cache_is_derived_off_and_says_so(model):

@@ -222,23 +222,39 @@ def test_tied_scores_go_to_the_lower_position():
 
 # ----------------------------------------------------------------- prefill --
 
-def test_prefill_index_scores_by_chunk():
+@pytest.fixture
+def key_blocks_of_128(monkeypatch):
+    monkeypatch.setattr(sa, "_BLOCK_K", 128)
+
+
+def test_prefill_index_scores_by_chunk(key_blocks_of_128):
+    """Rows of different left padding in one batch: each row's scores
+    are the XLA form's from the block of its first real key to the block
+    of the chunk's last query; the key and the score blocks a step names
+    stay inside that span, so a step outside it copies nothing."""
     rng = _rng(7)
-    n, s, c = 2, 256, 64
+    s, c, bk = 512, 64, 128
+    first = [0, 50, 130, 300, s]    # none, under a block, over one, into
+    n = len(first)                  # the chunk itself, a dummy row
     qi = jnp.asarray(rng.normal(size=(n, c, J, DI)), BF16)
     w = jnp.asarray(rng.normal(size=(n, c, J)), F32)
     ki = jnp.asarray(rng.normal(size=(n, s, DI)), BF16)
     want = np.asarray(sa._index_scores_xla(qi, w, ki))
-    # the chunk ends at position 191: the key block past it is skipped
-    sa_block = sa._SCORE_BLOCK_K
-    try:
-        sa._SCORE_BLOCK_K = 128
-        got = np.asarray(sa.prefill_index_scores(
-            qi, w, ki, jnp.int32(191), interpret=True))
-    finally:
-        sa._SCORE_BLOCK_K = sa_block
-    assert np.abs(got[:, :, :192] - want[:, :, :192]).max() \
-        < 1e-4 * np.abs(want).max()
+    # the chunk 256..319 ends in block 2: the block past it is skipped
+    valid = jnp.asarray(np.arange(s)[None, :] >= np.asarray(first)[:, None])
+    blocks = sa.chunk_key_blocks(valid, c, 1)[4]
+    assert blocks.shape == (n, 2, 2, s // bk)   # one tile, then the chunk's
+    assert np.asarray(blocks[:, -1, sa._RUNS]).tolist() == [
+        [1, 1, 1, 0], [1, 1, 1, 0], [0, 1, 1, 0], [0, 0, 1, 0], [0, 0, 0, 0]]
+    assert np.asarray(blocks[:, -1, sa._NAMES]).tolist() == [
+        [0, 1, 2, 2], [0, 1, 2, 2], [1, 1, 2, 2], [2, 2, 2, 2], [3, 3, 3, 3]]
+    got = np.asarray(sa.prefill_index_scores(qi, w, ki, blocks,
+                                             interpret=True))
+    for b, runs in enumerate(np.asarray(blocks[:, -1, sa._RUNS])):
+        at = np.repeat(runs.astype(bool), bk)
+        if at.any():
+            assert np.abs(got[b][:, at] - want[b][:, at]).max() \
+                < 1e-4 * np.abs(want).max()
 
 
 def _dense_oracle(q, k, v, qi, w, ki, valid, topk):
@@ -341,6 +357,7 @@ def test_sparse_prefill_does_what_a_chunk_sees(case, interpret):
             q, k, v, qi, w, ki, jnp.asarray(valid), topk=topk,
             scale=d ** -0.5, chunk=chunk)
         plan = sa.chunk_plan(jnp.asarray(valid), chunk, topk)
+        blocks = sa.chunk_key_blocks(jnp.asarray(valid), chunk, h // hkv)
         # scores and the whole-bucket selection, a chunk at a time
         pad = lambda a: jnp.pad(a, [(0, 0), (0, -s % chunk)]
                                 + [(0, 0)] * (a.ndim - 2))
@@ -355,7 +372,7 @@ def test_sparse_prefill_does_what_a_chunk_sees(case, interpret):
                 continue
             scores = sa.prefill_index_scores(
                 qi_[:, start:start + chunk], w_[:, start:start + chunk],
-                ki_, jnp.int32(start + chunk - 1))
+                ki_, blocks[i])
             seen = kv[:, None, :] & (np.arange(kv.shape[1])[None, None, :]
                                      <= np.arange(start, start + chunk)[
                                          None, :, None])
@@ -369,7 +386,8 @@ def test_sparse_prefill_does_what_a_chunk_sees(case, interpret):
              for kind in (sa.PADDING, sa.DENSE, sa.SELECTED)]
     assert count == [len(kinds) - sum(filter(None, chunks)) if c is None
                      else c for c in chunks], kinds
-    assert np.asarray(sa.plan_counts(plan, n, s)).tolist() == count + [
+    assert np.asarray(sa.plan_counts(plan, blocks, chunk, s)
+                      ).tolist()[:5] == count + [
         count[2] * n * s, len(kinds) * n * s]
     want, kept = _dense_oracle(q, k, v, qi, w, ki, valid, topk)
     assert kept.sum(-1).max() == min(topk, max(lens))
@@ -409,4 +427,129 @@ def test_a_plan_handed_in_is_the_one_computed():
                              scale=d ** -0.5, chunk=chunk)
     plan = sa.chunk_plan(valid, chunk, topk)
     assert np.asarray(plan).tolist() == [0, 0, 0, 1, 2]
+    plan = (plan, sa.chunk_key_blocks(valid, chunk, h // hkv))
     assert np.array_equal(np.asarray(call(plan=plan)), np.asarray(call()))
+
+
+# ------------------------------------------- a tile's live key blocks --------
+
+# (query heads, KV heads, queries of the chunk): 8 heads a group in two
+# tiles of 128 queries; a head of its own keys, the chunk in one tile
+_TILE_FORMS = {"grouped": (16, 2, 256), "one_tile": (2, 2, 256)}
+_S, _BK, _START = 1024, 128, 512    # 8 key blocks; the chunk's first query
+# left padding of the rows of ONE batch: none, under a block, several
+# blocks and a bit, up into the chunk's first tile, the whole chunk
+_PADS = [0, 50, 421, 600, 824]
+
+
+def _chunk_case(form, seed):
+    """q, k, v, keep of the chunk 512..767 under a selection (a third of
+    what a query sees, its own key among them), its table of key
+    blocks, and which of its queries are real."""
+    h, hkv, c = _TILE_FORMS[form]
+    rng = _rng(11, seed)
+    n, d = len(_PADS), 128
+    valid = np.arange(_S)[None, :] >= np.asarray(_PADS)[:, None]
+    qpos = _START + np.arange(c)
+    own = np.arange(_S)[None, None, :] == qpos[None, :, None]
+    keep = valid[:, None, :] & (np.arange(_S)[None, None, :]
+                                <= qpos[None, :, None]) \
+        & ((rng.random((n, c, _S)) < 0.3) | own)
+    arr = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
+    blocks = sa.chunk_key_blocks(jnp.asarray(valid), c, h // hkv)[_START // c]
+    return (arr(n, c, h, d), arr(n, hkv, _S, d), arr(n, hkv, _S, d),
+            jnp.asarray(keep), blocks, valid[:, qpos])
+
+
+@pytest.mark.parametrize("form", sorted(_TILE_FORMS))
+def test_selected_attention_over_rows_of_different_padding(
+        form, key_blocks_of_128):
+    """Every real query of every row equals the XLA attention under
+    `keep`, and, bit for bit, the kernel made to visit every key block:
+    a block of a row's padding adds nothing. A row whose chunk is all
+    padding runs no block and is left at zeros."""
+    q, k, v, keep, blocks, real = _chunk_case(form, 0)
+    n, c, h, d = q.shape
+    bq, bk = sa.attend_tiles(c, h // k.shape[1], _S)
+    assert (bq, bk) == ((128, _BK) if form == "grouped" else (c, _BK))
+    got = np.asarray(sa.selected_attention(q, k, v, keep, blocks, d ** -0.5,
+                                           interpret=True))
+    want = np.asarray(sa.selected_attention(q, k, v, keep, blocks, d ** -0.5))
+    assert np.abs(got[real] - want[real]).max() < 1e-5
+    j = jnp.arange(_S // bk, dtype=jnp.int32)
+    whole = jnp.broadcast_to(jnp.stack([j, jnp.ones_like(j)]), blocks.shape)
+    everywhere = np.asarray(sa._attend_pallas(
+        q, k, v, keep, whole, d ** -0.5, bq, bk, True))
+    assert np.array_equal(got[real], everywhere[real])
+    assert not real[-1].any() and not got[-1].any()
+
+
+@pytest.mark.parametrize("form", sorted(_TILE_FORMS))
+def test_key_blocks_of_a_chunk_by_hand(form, key_blocks_of_128):
+    """What a chunk at 512..767 sees of keys in blocks of 128: from the
+    block of the row's first real key to the block of the tile's last
+    query; nothing where the tile's queries are all padding. The last
+    row of a table is the whole chunk's."""
+    _, _, _, _, blocks, _ = _chunk_case(form, 1)
+    runs = np.asarray(blocks[:, :, sa._RUNS])
+    span = [[[int(np.flatnonzero(t)[0]), int(np.flatnonzero(t)[-1])]
+             if t.any() else None for t in row] for row in runs]
+    if form == "grouped":       # tiles end at 639 and 767: blocks 4 and 5
+        assert span == [[[0, 4], [0, 5], [0, 5]], [[0, 4], [0, 5], [0, 5]],
+                        [[3, 4], [3, 5], [3, 5]], [[4, 4], [4, 5], [4, 5]],
+                        [None, None, None]]
+    else:
+        assert span == [[[0, 5]] * 2, [[0, 5]] * 2, [[3, 5]] * 2,
+                        [[4, 5]] * 2, [None] * 2]
+    # a range has no hole: what runs is what lies between its ends
+    assert all(t[lo:hi + 1].all() and t.sum() == hi + 1 - lo
+               for row, at in zip(runs, span)
+               for t, (lo, hi) in zip(row, (a for a in at if a)))
+
+
+@pytest.mark.parametrize("form", sorted(_TILE_FORMS))
+def test_named_key_blocks_are_live_and_resident(form, key_blocks_of_128):
+    """The block each grid step names, walked over the grid: a step that
+    runs names its own block; one that does not names the nearer end of
+    the tile's range (the block resident before or after it: nothing is
+    copied) and never an index outside the array, the tile of padding
+    included, which names one block throughout."""
+    _, _, _, _, blocks, _ = _chunk_case(form, 2)
+    tab = np.asarray(blocks)
+    n, rows, _, nb = tab.shape
+    copies = 0
+    for b in range(n):
+        for i in range(rows):
+            named, runs = tab[b, i, sa._NAMES], tab[b, i, sa._RUNS]
+            assert ((0 <= named) & (named < nb)).all()
+            if not runs.any():
+                assert len(set(named.tolist())) == 1
+                continue
+            lo, hi = np.flatnonzero(runs)[[0, -1]]
+            assert named.tolist() == [min(max(j, lo), hi) for j in range(nb)]
+            copies += len(set(named.tolist()))
+    assert copies == tab[:, :, sa._RUNS].sum() < n * rows * nb
+
+
+@pytest.mark.parametrize("bucket,prompts,c,rep,want", [
+    # the issue's example: 10240 tokens in 16384, one tile a chunk; 20
+    # chunks run, the i-th over blocks 0..i of which 12 are padding
+    (16384, [10240], 512, 1, (210, 450)),
+    # four tiles of 128 queries a chunk, two rows: the short row's
+    # chunks of padding run no block while the long row's chunks run
+    (16384, [12288, 3072], 512, 8,
+     (4 * (sum(range(9, 33)) - 8 * 24) + 4 * (sum(range(27, 33)) - 26 * 6),
+      2 * 4 * sum(range(9, 33)))),
+    # a full bucket: nothing to leave out
+    (4096, [4096], 512, 8, (4 * 36, 4 * 36)),
+    # the first real key in the middle of a block, of a tile, of a chunk
+    (2048, [700], 512, 8, (1 + 1 + 4 * 2, 4 * 3 + 4 * 4)),
+    # dummy rows only: no chunk runs
+    (1024, [0, 0], 512, 1, (0, 0))])
+def test_key_block_counts_of_a_bucket(bucket, prompts, c, rep, want):
+    valid = jnp.asarray(
+        np.arange(bucket)[None, :] >= (bucket - np.asarray(prompts))[:, None])
+    plan = sa.chunk_plan(valid, c, 2048)
+    blocks = sa.chunk_key_blocks(valid, c, rep)
+    assert tuple(np.asarray(sa.plan_counts(plan, blocks, c, bucket)
+                            ).tolist()[5:]) == want

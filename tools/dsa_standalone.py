@@ -9,10 +9,13 @@ selected rows). PERF.md records what a run of this printed.
     python tools/dsa_standalone.py [--slots 32] [--context 7168] ...
 
 and, with `--prefill`, one layer of a prefill at each of the cell's
-buckets over a left-padded prompt of three quarters of the bucket: the
+buckets over a prompt under `--padding` tokens of left padding (a
+quarter of the bucket where none is given; 0 is a full bucket): the
 selection alone, for every chunk of queries against for the chunks
-that `kernels.sparse_attention.chunk_plan` says need one, and the whole
-layer.
+that `kernels.sparse_attention.chunk_plan` says need one; the LAST
+chunk's index scores and attention over every key block of the bucket
+(`whole_range`: what both kernels visited before PR 40) beside the
+blocks that hold a real key (`live_blocks`); and the whole layer.
 
 Needs a TPU. Prints one JSON line a piece: seconds of one call on the
 device (50 calls inside one program, the median of 5 such programs; 10
@@ -32,6 +35,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import paddle_tpu  # noqa: E402,F401
+from paddle_tpu.kernels._common import pallas_interpret  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
 from paddle_tpu.kernels import sparse_attention as sa  # noqa: E402
 
@@ -89,15 +93,38 @@ def timed(name, fn, tables, *args, reps=50, calls=5):
                       "min_s": min(times)}), flush=True)
 
 
+def chunk_forms(name, zero, qi, w, ki, q, k, v, keep, blocks, scale, tile,
+                **kw):
+    """A bucket's LAST chunk: its index scores and its attention under
+    `keep`, each over every key block of the bucket (`whole_range`:
+    what both kernels visited before PR 40) and over the blocks
+    `blocks` runs, the chunk's table of `chunk_key_blocks`
+    (`live_blocks`)."""
+    bq, bk = tile
+    j = jnp.arange(ki.shape[1] // bk, dtype=jnp.int32)
+    whole = jnp.broadcast_to(jnp.stack([j, jnp.ones_like(j)]), blocks.shape)
+    moving = lambda bt: keep & (bt[:, :1, None] >= 0)
+    for form, tab in (("whole_range", whole), ("live_blocks", blocks)):
+        timed(f"prefill.index_scores.{form}.{name}",
+              lambda bt: sa.prefill_index_scores(
+                  qi, w + bt[0, 0].astype(jnp.float32), ki, tab),
+              zero, **kw)
+        timed(f"prefill.attend.{form}.{name}",
+              lambda bt: sa._attend_pallas(
+                  q, k, v, moving(bt), tab, scale, bq, bk,
+                  pallas_interpret()), zero, **kw)
+
+
 def prefill_pieces(a, rng):
     """`prefill.select` old form beside new and `prefill.layer`, a
     bucket."""
     d, c, bf, f32 = 128, 512, jnp.bfloat16, jnp.float32
     zero = jnp.zeros((1, 1), jnp.int32)
-    for s in a.buckets:
+    for s, pad in ((s, pad) for s in a.buckets
+                   for pad in (a.padding or [s // 4]) if pad < s):
         arr = lambda *sh: jnp.asarray(rng.normal(size=sh).astype(np.float32),
                                       bf)
-        key_valid = jnp.arange(s)[None, :] >= s // 4
+        key_valid = jnp.arange(s)[None, :] >= pad
         base = jnp.asarray(rng.normal(size=(1, c, s)), f32)
         starts = jnp.arange(0, s, c, dtype=jnp.int32)
         kpos = jnp.arange(s, dtype=jnp.int32)
@@ -130,16 +157,27 @@ def prefill_pieces(a, rng):
                 one, (starts, sa.chunk_plan(key_valid, c, a.topk)))
 
         kw = dict(reps=10, calls=3)
-        timed(f"prefill.select.whole_bucket.{s}", whole_bucket, zero, **kw)
-        timed(f"prefill.select.planned.{s}", planned, zero, **kw)
+        name = f"{s}.pad{pad}"
+        timed(f"prefill.select.whole_bucket.{name}", whole_bucket, zero, **kw)
+        timed(f"prefill.select.planned.{name}", planned, zero, **kw)
         same = jnp.array_equal(whole_bucket(zero), planned(zero))
-        print(json.dumps({"bucket": s, "selections_agree": bool(same)}),
-              flush=True)
+        print(json.dumps({"bucket": s, "padding": pad,
+                          "selections_agree": bool(same)}), flush=True)
         q = arr(1, s, a.heads, d)
         k, v = arr(1, s, a.kv_heads, d), arr(1, s, a.kv_heads, d)
         qi, ki = arr(1, s, a.index_heads, a.index_dim), arr(1, s, a.index_dim)
         w = jnp.asarray(rng.normal(size=(1, s, a.index_heads)), f32)
-        timed(f"prefill.layer.{s}", lambda bt: sa.sparse_prefill_attention(
+        tail = lambda x: x[:, s - c:]       # the chunk that sees every key
+        seen = key_valid[:, None, :] & (
+            kpos[None, None, :] <= (s - c + kpos[:c])[None, :, None])
+        blocks = sa.chunk_key_blocks(key_valid, c, a.heads // a.kv_heads)[-1]
+        keep = sa.select_topk(sa.prefill_index_scores(
+            tail(qi), tail(w), ki, blocks), seen, a.topk)
+        chunk_forms(name, zero, tail(qi), tail(w), ki, tail(q),
+                    k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), keep,
+                    blocks, d ** -0.5,
+                    sa.attend_tiles(c, a.heads // a.kv_heads, s), **kw)
+        timed(f"prefill.layer.{name}", lambda bt: sa.sparse_prefill_attention(
             q, k, v, qi, w + bt[0, 0].astype(f32), ki, key_valid,
             topk=a.topk, scale=d ** -0.5, chunk=c), zero, **kw)
 
@@ -150,6 +188,10 @@ def main(argv=None):
                     help="the prefill pieces, and nothing of the decode step")
     ap.add_argument("--buckets", type=int, nargs="+",
                     default=[4096, 8192, 16384])
+    ap.add_argument("--padding", type=int, nargs="+",
+                    help="with --prefill: tokens of left padding, each at "
+                    "every bucket that is longer (a quarter of the bucket "
+                    "where none is given)")
     ap.add_argument("--slots", type=int, default=32)
     ap.add_argument("--context", type=int, default=7168)
     ap.add_argument("--pages-per-seq", type=int, default=1024)
@@ -165,7 +207,8 @@ def main(argv=None):
     rng = np.random.default_rng(0)
     if a.prefill:
         print(json.dumps({"device": jax.devices()[0].device_kind,
-                          "prompt_share_of_bucket": 0.75}), flush=True)
+                          "left_padding": a.padding or "bucket / 4"}),
+              flush=True)
         prefill_pieces(a, rng)
         return 0
     page, d, bf = 16, 128, jnp.bfloat16

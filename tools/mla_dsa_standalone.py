@@ -8,7 +8,11 @@ under an indexer at GLM-5's shapes (models/glm_moe_dsa.py), one layer:
   one matmul, key and value both), each through the masked flash kernel
   of `kernels.sparse_attention`, for the LAST chunk of 512 queries of a
   bucket (the one that sees every key); with the chunk's index scores
-  at 32 heads of 128 and its selection beside them;
+  at 32 heads of 128 and its selection beside them. Under `--padding`
+  tokens of left padding the kept form and the index scores are timed
+  over every key block of the bucket (`whole_range`: what the kernels
+  visited before PR 40) beside the blocks that hold a real key
+  (`live_blocks`);
 - a decode step's pieces at the cell's geometry: the index scores, the
   selection, and the latent kernel under the selection's mask against
   the same kernel over every live row and against a gather of the
@@ -37,7 +41,7 @@ from paddle_tpu.kernels._common import pallas_interpret  # noqa: E402
 from paddle_tpu.kernels import latent_attention as la  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
 from paddle_tpu.kernels import sparse_attention as sa  # noqa: E402
-from dsa_standalone import timed  # noqa: E402
+from dsa_standalone import chunk_forms, timed  # noqa: E402
 
 BF, F32 = jnp.bfloat16, jnp.float32
 
@@ -76,37 +80,46 @@ def prefill_pieces(a, rng):
     arr = lambda *sh: jnp.asarray(rng.normal(size=sh).astype(np.float32), BF)
     zero = jnp.zeros((1, 1), jnp.int32)
     kw = dict(reps=10, calls=3)
-    for s in a.buckets:
-        last = jnp.int32(s - 1)
+    for s, pad in ((s, pad) for s in a.buckets for pad in a.padding
+                   if pad < s):
         scale = dk ** -0.5
+        name = f"{s}.pad{pad}"
         q, k, v = arr(1, c, h, dk), arr(1, h, s, dk), arr(1, h, s, dk)
-        q_abs, rows = arr(1, c, h, lanes), arr(1, 1, s, lanes)
-        latent, w_kv = arr(1, s, 512), arr(512, h, 448)
         qi, ki = arr(1, c, a.index_heads, a.index_dim), arr(1, s, a.index_dim)
         w = jnp.asarray(rng.normal(size=(1, c, a.index_heads)), F32)
-        seen = jnp.ones((1, c, s), jnp.bool_) & (
+        key_valid = jnp.arange(s)[None, :] >= pad
+        blocks = sa.chunk_key_blocks(key_valid, c, 1)[-1]
+        seen = key_valid[:, None, :] & (
             jnp.arange(s)[None, None, :]
             <= (s - c + jnp.arange(c))[None, :, None])
-        scores = sa.prefill_index_scores(qi, w, ki, last)
+        scores = sa.prefill_index_scores(qi, w, ki, blocks)
         keep = sa.select_topk(scores, seen, a.topk)
         moving = lambda bt: keep & (bt[:, :1, None] >= 0)
-        attempt(f"prefill.index_scores.{s}",
-                lambda bt: sa.prefill_index_scores(
-                    qi, w + bt[0, 0].astype(F32), ki, last), zero, **kw)
-        attempt(f"prefill.select.{s}", lambda bt: sa.select_topk(
+        attempt(f"prefill.select.{name}", lambda bt: sa.select_topk(
             scores + bt[0, 0].astype(F32), seen, a.topk), zero, **kw)
-        for bq in (128, 256, 512):
+        # the kept form (the tile `selected_attention` derives at `rep`
+        # 1), over the whole range of key blocks and over the live ones
+        chunk_forms(name, zero, qi, w, ki, q, k, v, keep, blocks, scale,
+                    sa.attend_tiles(c, 1, s), **kw)
+        if pad:     # the forms that lost are timed on a full bucket
+            continue
+        # every block of the full bucket runs: a table of `tiles` rows
+        whole = lambda bq: jnp.broadcast_to(
+            blocks[:, :1], (1, c // bq) + blocks.shape[2:])
+        for bq in (128, 256):
             attempt(f"prefill.attend.decompressed.bq{bq}.{s}",
                     lambda bt: sa._attend_pallas(
-                        q, k, v, moving(bt), last, scale, bq, 512,
+                        q, k, v, moving(bt), whole(bq), scale, bq, 512,
                         pallas_interpret()), zero, **kw)
+        q_abs, rows = arr(1, c, h, lanes), arr(1, 1, s, lanes)
+        latent, w_kv = arr(1, s, 512), arr(512, h, 448)
         attempt(f"prefill.decompress_k_and_v.{s}", lambda bt: jnp.einsum(
             "nsc,chd->nhsd", latent + bt[0, 0].astype(BF), w_kv), zero, **kw)
         for bq in (16, 32):
             attempt(f"prefill.attend.absorbed.bq{bq}.{s}",
                     lambda bt: sa._attend_pallas(
-                        q_abs, rows, rows, moving(bt), last, scale, bq, 512,
-                        pallas_interpret()), zero, **kw)
+                        q_abs, rows, rows, moving(bt), whole(bq), scale, bq,
+                        512, pallas_interpret()), zero, **kw)
 
 
 def decode_pieces(a, rng):
@@ -155,6 +168,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--buckets", type=int, nargs="+", default=[8192, 16384])
     ap.add_argument("--prefill-only", action="store_true")
+    ap.add_argument("--padding", type=int, nargs="+", default=[0],
+                    help="tokens of left padding of the prefill's prompt, "
+                    "each at every bucket that is longer; the forms that "
+                    "lost are timed at 0 only")
     ap.add_argument("--slots", type=int, default=32)
     ap.add_argument("--context", type=int, default=7168)
     ap.add_argument("--pages-per-seq", type=int, default=1024)

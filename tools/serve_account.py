@@ -3,7 +3,9 @@ print beside its result line what the serve loop's own account says of
 the same window: the tick ring's sums (prefill seconds, tokens forwarded
 and padded, tokens handed out, stalled tokens), the gc log, and how they
 agree with what the runner counted and, in a traced run, with the
-device's time in the prefill programs.
+device's time in the prefill programs; for a model with an indexer, how
+many of the prefill kernel's key blocks held a real key
+(`dsa.prefill_key_blocks`).
 
     python3 tools/serve_account.py --workload <name> --seed <n> \\
         --seconds <s> --trace <0|1> [--out <file.json>]
@@ -145,6 +147,19 @@ def traced_prefills(trace_dir):
             "first_annotation": anns[0][0][:300] if anns else None}
 
 
+def prefill_key_blocks():
+    """`dsa.prefill_key_blocks{kind}` over the whole run (warm-up,
+    window and drain) and `attended` over `bucket`: the share of the
+    masked flash kernel's (row, tile, key block) steps that still
+    compute. None for a cell, or a tree, that counts none."""
+    from paddle_tpu.observability import metrics
+    n = {s.labels.get("kind"): s.value for s in
+         metrics.counter("dsa.prefill_key_blocks").samples()}
+    if not n.get("bucket"):
+        return None
+    return dict(n, share=n.get("attended", 0) / n["bucket"])
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -191,6 +206,7 @@ def main(argv=None):
             "attempted", "failed", "tokens_out", "requests_sent",
             "prompt_tokens_sent", "pred_stats", "pred_stats_window")},
         "metrics": rec["metrics"], "timings": rec.get("timings"),
+        "prefill_key_blocks": prefill_key_blocks(),
     }
     if rec.get("runner") == "serve_open":
         account["runner"]["sample_prompt_tokens"] = sum(

@@ -96,14 +96,30 @@ class LayerCache(NamedTuple):
     index_dim: int = 0
 
 
+class Drafter(NamedTuple):
+    """What a model that drafts for itself declares to the serve loop
+    (`model.drafter()`, beside `cache_layout()`): `depth` tokens drafted
+    a tick by a multi-token-prediction module whose own layer keeps its
+    rows in entry `layer` of `cache_layout()`. The trunk's forward
+    passes that entry through; `model.draft(hidden, next_ids,
+    position_ids, entry, valid)` advances it."""
+    depth: int
+    layer: int
+
+
 class LayerCaches(list):
     """The per-layer caches a forward pass returns, with `counters`:
     small device vectors the model summed over its layers ({name:
-    int32 array}), brought down with the step's tokens."""
+    int32 array}), brought down with the step's tokens. A model with a
+    drafter also gives `hidden`, the last layer's output before the
+    final norm at every position of the step (what its drafter reads),
+    and a prefill gives `draft`, the first drafted token of each row."""
 
-    def __init__(self, caches, counters=None):
+    def __init__(self, caches, counters=None, hidden=None, draft=None):
         super().__init__(caches)
         self.counters = counters or {}
+        self.hidden = hidden
+        self.draft = draft
 
 
 class StatePool:
@@ -1009,6 +1025,43 @@ def paged_cache_latent_update_attend(entry: LatentCacheEntry, q, row,
     out, pages2 = apply(fn, entry.pages, entry.block_table,
                         entry.context_lens, q, row,
                         _name="paged_latent_attention_decode")
+    return out, entry._replace(pages=pages2)
+
+
+def paged_cache_latent_span_update_attend(entry: LatentCacheEntry, q, row,
+                                          scale=None):
+    """Span contract of a latent-attention layer (a speculative verify,
+    the draft pass after it): write the span's S rows a slot at
+    positions `context_lens` .. `context_lens + S - 1`, then attend each
+    of its S absorbed queries over the slot's rows up to its own
+    (causal within the span; `paged_latent_attention` with `span`: the
+    live rows are read once for all S). q [B, S, H, width]; row [B, S,
+    width] -> (out [B, S, H, lanes], updated entry). Every slot carries
+    a whole span. A position that is not kept afterwards needs no
+    restoring: every reader of latent pages goes by the slot's length,
+    and the next step's write at that position comes before any read of
+    it."""
+    import jax.numpy as jnp
+    from ..ops._dispatch import apply
+    from ..kernels.latent_attention import (latent_rows,
+                                            paged_latent_attention)
+
+    def fn(pages, bt, cl, qv, rv):
+        page = pages.shape[1]
+        b, s, h, _ = qv.shape
+        pos = cl[:, None].astype(jnp.int32) \
+            + jnp.arange(s, dtype=jnp.int32)[None, :]
+        at = (jnp.take_along_axis(
+            bt, jnp.clip(pos // page, 0, bt.shape[1] - 1), axis=1),
+            pos % page)
+        pages2 = pages.at[at].set(latent_rows(rv, pages))
+        out = paged_latent_attention(qv.reshape(b, s * h, -1), pages2, bt,
+                                     cl + s, scale, span=s)
+        return out.reshape(b, s, h, -1).astype(qv.dtype), pages2
+
+    out, pages2 = apply(fn, entry.pages, entry.block_table,
+                        entry.context_lens, q, row,
+                        _name="paged_latent_attention_span")
     return out, entry._replace(pages=pages2)
 
 

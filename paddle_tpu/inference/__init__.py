@@ -852,7 +852,8 @@ class ContinuousBatchingPredictor:
             from ..kernels.paged_attention import paged_gate_reason
             self.use_ragged = False
             self.span_ragged = (
-                span > 1 and (_use_pallas() or pallas_interpret())
+                span > 1 and bool(kv_shapes)
+                and (_use_pallas() or pallas_interpret())
                 and paged_gate_reason(
                     "paged_attention_ragged_varq", cfg.num_attention_heads,
                     cfg.num_key_value_heads, head_dim, self.tp) is None)
@@ -874,11 +875,31 @@ class ContinuousBatchingPredictor:
                     f"most {fit} at {cfg.num_attention_heads // self.tp} "
                     f"heads x {head_dim} (kernels.paged_attention."
                     f"max_varq_span)")
+        # a model that drafts for itself (`model.drafter()`: a multi-
+        # token-prediction module with latent rows of its own) is the
+        # one speculation served over latent pages: the self-drafting
+        # tick (`_raw_mtp_step`) verifies its draft as a two-query span
+        # over the rows, greedily
+        drafter = getattr(model, "drafter", lambda: None)()
+        drafts_itself = drafter is not None and bool(self._latent_dim) \
+            and not kv_shapes and self._state_shape is None \
+            and not self._index_dim
+        self._drafter = drafter if drafts_itself and self._spec_k else None
+        if self._drafter is not None:
+            if self._spec_k != drafter.depth:
+                raise ValueError(
+                    f"spec_draft_tokens={self._spec_k}: the model's "
+                    f"drafter drafts {drafter.depth} a tick")
+            if self.sampling_enabled:
+                raise ValueError(
+                    "sampling_enabled: not served with a model's own "
+                    "drafter, whose tick verifies greedily")
         if self._state_shape is not None or self._index_dim \
                 or self._latent_dim:
             refused = [n for n, on in (
                 ("prefill_chunk_tokens", self._chunk_max > 0),
-                ("spec_draft_tokens", self._spec_k > 0),
+                ("spec_draft_tokens",
+                 self._spec_k > 0 and self._drafter is None),
                 ("tp_degree", self.tp > 1),
                 (f"role={self.role!r}", self.role != "unified")) if on]
             why = (
@@ -892,7 +913,8 @@ class ContinuousBatchingPredictor:
                 "attended to one query token a slot a step: the span "
                 "programs (chunked prefill, speculative verify) and the "
                 "page span of a hand-off carry K and V arrays, and a row "
-                "has no head axis to shard (docs/SERVING.md 'Latent "
+                "has no head axis to shard; the one span served is the "
+                "verify of a model's own drafter (docs/SERVING.md 'Latent "
                 "pages')") if not self._index_dim else (
                 "an attention indexer. Its keys are selected for one "
                 "query token a slot a step: a query span (chunked "
@@ -1087,6 +1109,7 @@ class ContinuousBatchingPredictor:
                 self._raw_decode_sample_step, donate_argnums=dn)
             self._spec_jit = jax.jit(self._raw_spec_step,
                                      donate_argnums=dn)
+            self._mtp_jit = jax.jit(self._raw_mtp_step, donate_argnums=dn)
             # identity snapshot of the RAW tensor values: the sharded
             # device_put copies below are different objects, so change
             # detection must compare against what the model holds, not
@@ -1303,7 +1326,8 @@ class ContinuousBatchingPredictor:
         """One admission program per (batch, bucket): forward + on-device
         argmax + K/V scatter into the paged pool. ids/pos [N, bucket]
         (left-padded), lens [N], page_rows [N, ceil(bucket/page)].
-        Returns (next_tokens [N, bucket] int32, new_k, new_v[, counts]).
+        Returns (next_tokens [N, bucket] int32, new_k, new_v[, first
+        drafts [N]][, counts]).
         Rows with lens == 0 are dummies: every write lands on the trash
         page. A model with recurrent layers also gets `slots` [N] (the
         state row each prompt's final state is written to, whole; a
@@ -1358,7 +1382,10 @@ class ContinuousBatchingPredictor:
                                                         kl[li][1])))
                          if layer.index_dim else put(kl[li], kept[0]))
             new_v.append(put(vl[li], kept[1]))
-        return (nexts, new_k, new_v) + self._counter_outputs(caches)
+        # a model that drafts for itself: each row's first draft, the
+        # token after the first one, comes down with it
+        draft = () if self._drafter is None else (_raw(caches.draft),)
+        return (nexts, new_k, new_v) + draft + self._counter_outputs(caches)
 
     def _raw_suffix_prefill(self, p_vals, b_vals, kl, vl, ids, pos, m,
                             slen, past_rows, page_rows):
@@ -1600,6 +1627,75 @@ class ContinuousBatchingPredictor:
         else:
             done = jnp.zeros(bonus.shape, jnp.bool_)
         return bonus, accepted, done, new_k, new_v
+
+    def _raw_mtp_step(self, p_vals, b_vals, kl, vl, tables, ctx, span_ids):
+        """One self-drafting tick, ONE program, for a model that
+        declares a drafter of depth 1 over latent pages
+        (`model.drafter()`): the verify span through the trunk, then the
+        draft pass through the model's MTP module (scope `mtp.draft`).
+        (One program and not two: measured on the chip, PERF.md section
+        6, PR 41; the name is what the benchmark's readers look for.)
+
+        VERIFY. span_ids [B, 2] = each slot's committed last token `x_t`
+        and the token drafted after it, `d_{t+1}`, at positions ctx,
+        ctx + 1: the span goes through every trunk layer's latent pages
+        (two rows written a layer; the second query sees the first's
+        row; absorbed form). `y = argmax logits`; the draft is accepted
+        iff `y[0] == d_{t+1}` (`generation.sampling.verify_spans`,
+        greedy: lossless), and `y[accepted]` is the token after the last
+        kept one.
+
+        DRAFT. The MTP module over the same two positions, from the
+        trunk's output there (`caches.hidden`) and the tokens that
+        followed them, `y` (the second position counts only where the
+        draft was accepted). It writes its own layer's rows (the pool's
+        `drafter().layer`) and drafts the token after each position;
+        the next tick's draft is row `accepted`'s.
+
+        A rejected position's rows (the trunk's and the MTP layer's, at
+        ctx + 1) are not restored: every reader of latent pages goes by
+        the slot's length, and the next tick writes that position before
+        any length reaches it (generation/kv_cache.
+        paged_cache_latent_span_update_attend). Returns (span_next [B,
+        2] int32 = the next tick's span: the last committed token
+        `y[accepted]` and its draft; accepted [B] int32; new_k; new_v;
+        counts): the host commits the accepted draft and `span_next[:,
+        0]`, and syncs those two small arrays only."""
+        from ..jit.bridge import bound_state
+        from ..generation import sampling as _samp
+        at = self._drafter.layer
+        pos = ctx[:, None].astype(jnp.int32) \
+            + jnp.arange(2, dtype=jnp.int32)[None, :]
+        with no_grad(), bound_state(self._p_tensors, p_vals,
+                                    self._b_tensors, b_vals):
+            cache = self._step_cache(kl, vl, tables, ctx, None)
+            logits, caches = self.model(
+                Tensor(span_ids), position_ids=Tensor(pos),
+                past_key_values=cache, use_cache=True)
+            greedy = jnp.argmax(logits._value, axis=-1).astype(jnp.int32)
+            accepted, _ = _samp.verify_spans(
+                logits._value, span_ids,
+                jnp.full(span_ids.shape[:1], 2, jnp.int32), 0.0, 0, 1.0,
+                0, 0, sampled_mode=False)
+            kept = cache.active[:, None] & (
+                jnp.arange(2, dtype=jnp.int32)[None, :] <= accepted[:, None])
+            drafts, entry, more = self.model.draft(
+                caches.hidden, Tensor(greedy), Tensor(pos), caches[at],
+                Tensor(kept))
+        caches[at] = entry
+        n_active = jnp.sum(cache.active, dtype=jnp.int32)
+        n_accepted = jnp.sum(jnp.where(cache.active, accepted, 0),
+                             dtype=jnp.int32)
+        caches.counters = dict(
+            {key: _raw(vec) + _raw(more[key]) if key in more else vec
+             for key, vec in caches.counters.items()},
+            mtp=jnp.stack([n_active, n_accepted, n_active + n_accepted]))
+        both = jnp.stack([greedy, jnp.argmax(drafts._value, axis=-1).astype(
+            jnp.int32)], axis=1)                    # [B, token | draft, 2]
+        span_next = jnp.take_along_axis(both, accepted[:, None, None],
+                                        axis=2)[:, :, 0]
+        return (span_next, accepted, *self._step_caches_out(caches)) \
+            + self._counter_outputs(caches)
 
     # ------------------------------------------------------------ serve --
     def generate(self, prompts, max_new_tokens=32, strict=True,
@@ -1856,11 +1952,14 @@ class ContinuousBatchingPredictor:
                 return evs[-1]["ts"]
             return _time.time()
 
-        def emit(r, kind, token=None, index=0, st=None, span=None):
+        def emit(r, kind, token=None, index=0, st=None, span=None,
+                 drafted=()):
             # one "token" event per TICK: `span` carries every token
             # the tick committed (speculative ticks commit several),
             # `token`/`index` stay the last one for single-token
-            # consumers (serving/streaming.py StreamEvent)
+            # consumers (serving/streaming.py StreamEvent); `drafted`
+            # is what the model's own drafter proposed for the span's
+            # first places, accepted or not
             nonlocal n_tok, n_first
             if span is None and token is not None:
                 span = (token,)
@@ -1870,7 +1969,8 @@ class ContinuousBatchingPredictor:
                 n_tok += len(span)
                 n_first += index == len(span)
             out.append(StreamEvent(r, kind, token, index, _ts(r), st,
-                                   metas[r], tuple(span or ())))
+                                   metas[r], tuple(span or ()),
+                                   tuple(drafted)))
 
         def add_request(sreq):
             nonlocal has_deadlines
@@ -2010,6 +2110,9 @@ class ContinuousBatchingPredictor:
         slot_hist = [[] for _ in range(self.B)]
         slot_await_first = [False] * self.B
         spec_mode = self._spec_k > 0
+        # the token each slot's model drafted after its last committed
+        # one (a model with a drafter): the next tick's second query
+        draft_host = np.zeros((self.B,), np.int32)
 
         def set_samp(b, sp):
             if sp is None:
@@ -2279,6 +2382,7 @@ class ContinuousBatchingPredictor:
             slot_hist[b].append(first)
             ctx[b] = L
             last_tok_host[b] = first
+            draft_host[b] = plan.get("draft", 0)
             override[b] = True
             if builder is not None:
                 builder.set_slot(b, tables[b], L + 1)
@@ -2511,7 +2615,7 @@ class ContinuousBatchingPredictor:
                             prev, slot_req, slot_new, slot_hist,
                             last_tok_host, max_new, ctx, override,
                             builder, evict, req_sp, emit,
-                            chunk_first_token)
+                            chunk_first_token, draft_host)
                     else:
                         self._resolve_step(
                             prev, slot_req, slot_new, last_tok_host,
@@ -2565,7 +2669,11 @@ class ContinuousBatchingPredictor:
                         override, builder, inflight, req_sp,
                         paused=paused)
                 elif useful:
-                    if spec_mode:
+                    if self._drafter is not None:
+                        cur = self._dispatch_mtp_step(
+                            active, slot_req, tables, ctx, last_tok_host,
+                            draft_host, override)
+                    elif spec_mode:
                         sv = samp_vec(set()) \
                             if self.sampling_enabled else None
                         cur = self._dispatch_spec_step(
@@ -2670,6 +2778,10 @@ class ContinuousBatchingPredictor:
                             break
                     elif cur is None:
                         if closed:
+                            if n_tok:   # a speculative tick resolved at
+                                # this pass's head: its tokens are the
+                                # last this loop hands out
+                                tick.note(tokens=n_tok, first=n_first)
                             break
                         # idle dynamic loop: intake() is expected to block
                         # briefly itself; this is only spin insurance
@@ -2796,6 +2908,13 @@ class ContinuousBatchingPredictor:
         # cache's cached-continuation tokens)
         nexts = np.asarray(nexts)
         self._prefill_clock = (dispatched, _time.perf_counter())
+        if self._drafter is not None:
+            drafts, *aux = aux
+            # graft-lint: ok[GL102] — [nb] small ints beside the first
+            # tokens: each row's first draft
+            drafts = np.asarray(drafts)
+            for plan, d in zip(group, drafts.tolist()):
+                plan["draft"] = d
         self._note_counters(aux)
         firsts = {}
         for i, plan in enumerate(group):
@@ -3106,9 +3225,44 @@ class ContinuousBatchingPredictor:
                 "drafts": drafts,
                 "qlen": {b: int(q_lens[b]) for b in active}}
 
+    def _dispatch_mtp_step(self, active, slot_req, tables, ctx,
+                           last_tok_host, draft_host, override):
+        """Dispatch one self-drafting tick (`_raw_mtp_step`: the verify
+        and, in the same program, the draft pass): every slot's span is
+        its committed last token and the token its model drafted after
+        it, both host-committed by the resolver (or by admission), as
+        the n-gram verify's are. ctx advances over the whole span; the
+        resolver rewinds it to what was kept. Resolved by
+        `_resolve_spec_step`, as a verify of one draft a slot."""
+        import time as _time
+        t0 = _time.perf_counter()
+        span_ids = self._place(jnp.asarray(
+            np.stack([last_tok_host, draft_host], axis=1)))
+        override[:] = False
+        # .copy(): the resolver rewinds ctx before this step's buffers
+        # are read back
+        at = tables.copy(), ctx.copy()
+        span_next, accepted, new_k, new_v, *aux = self._jit_call(
+            ("mtp", tables.shape), self._mtp_jit,
+            self._p_vals, self._b_vals, *self._cache_args(), *at, span_ids)
+        self._cache_store(new_k, new_v)
+        snap = [(b, slot_req[b]) for b in active]
+        ctx0 = {b: int(ctx[b]) for b in active}
+        ctx[active] += 2                # optimistic; resolve rewinds
+        self.stats["decode_steps"] += 1
+        self.stats["spec_ticks"] += 1
+        self.stats["spec_proposed"] += len(active)
+        self._m_steps.inc(**self._mlbl)
+        self._m_spec_prop.inc(len(active), **self._mlbl)
+        return {"spec": True, "span": span_next, "acc": accepted,
+                "snap": snap, "t": t0, "ctx0": ctx0, "aux": (aux,),
+                "drafts": {b: [int(draft_host[b])] for b in active},
+                "qlen": {b: 2 for b in active}}
+
     def _resolve_spec_step(self, step, slot_req, slot_new, slot_hist,
                            last_tok_host, max_new, ctx, override,
-                           builder, evict, req_sp, emit, first_cb):
+                           builder, evict, req_sp, emit, first_cb,
+                           draft_host=None):
         """Sync one speculative verify step — three [B] vectors, the
         decode loop's one designed sync point — and commit each slot's
         accepted drafts plus the bonus/correction token: tokens append
@@ -3118,17 +3272,32 @@ class ContinuousBatchingPredictor:
         program), the drafting history extends, and the whole tick
         streams as ONE multi-token StreamEvent span. Slots marked
         chunk_final are resolving their first (sampled) token — TTFT
-        lands here via `first_cb`."""
+        lands here via `first_cb`. A self-drafting tick
+        (`_dispatch_mtp_step`) gives `span`, the next tick's span, in
+        place of the bonus: its first column is the bonus, its second
+        the slot's next draft, kept in `draft_host`; the tick's event
+        carries the draft it verified as `drafted`."""
         import time as _time
+        mtp = "span" in step
         with self._tick.stage("serve.resolve.wait"):
-            self._await_step(step, (step["tok"], step["acc"],
-                                    step["done"]))
-            # graft-lint: ok[GL102] — THE decode-loop sync point: three
-            # [B] vectors of the verify step (spec mode resolves before
-            # the next dispatch; the multi-token step replaces the
-            # one-step pipeline at the same one sync per tick)
-            bonus = np.asarray(step["tok"])
+            if mtp:
+                self._await_step(step, (step["span"], step["acc"]))
+                # graft-lint: ok[GL102] — the same sync point for the
+                # self-drafting tick: [B, 2] and [B]
+                span_next = np.asarray(step["span"])
+                bonus = span_next[:, 0]
+            else:
+                self._await_step(step, (step["tok"], step["acc"],
+                                        step["done"]))
+                # graft-lint: ok[GL102] — THE decode-loop sync point:
+                # three [B] vectors of the verify step (spec mode
+                # resolves before the next dispatch; the multi-token
+                # step replaces the one-step pipeline at the same one
+                # sync per tick)
+                bonus = np.asarray(step["tok"])
             acc = np.asarray(step["acc"])    # graft-lint: ok[GL102] (ditto)
+        for aux in step.get("aux") or ():
+            self._note_counters(aux)
         self._m_tok.observe(_time.perf_counter() - step["t"],
                             **self._mlbl)
         firsts = step.get("chunk_final") or ()
@@ -3165,8 +3334,11 @@ class ContinuousBatchingPredictor:
                 slot_hist[b].extend(span_toks)
                 last_tok_host[b] = span_toks[-1]
                 override[b] = True
+                if mtp:
+                    draft_host[b] = span_next[b, 1]
                 emit(r, "token", token=span_toks[-1],
-                     index=len(slot_new[b]), span=tuple(span_toks))
+                     index=len(slot_new[b]), span=tuple(span_toks),
+                     drafted=drafts if mtp else ())
             if ended or len(slot_new[b]) >= max_new[r]:
                 evict(b)
         if accepted_total:

@@ -18,6 +18,13 @@ a slot's LIVE pages by its own DMAs, two buffers deep, loops rolled)
 with one buffer. It returns the weighted sum of whole rows [B, H,
 lanes]: the caller keeps the latent's part. Operands stay in the pool's
 dtype; scores, softmax and accumulation are float32.
+
+A query SPAN (`span` > 1: a speculative verify of a drafted token, the
+draft pass after it) folds its queries into the row axis: `span` x H
+rows, the oldest query's heads first, against the same blocks, so a
+slot's live rows are still read once. The span's own rows are in the
+pages already; query j of the span does not see the rows of the span's
+later tokens (the last `span - 1 - j` of the slot's rows).
 """
 from __future__ import annotations
 
@@ -39,13 +46,18 @@ from .paged_attention import (_note_decode_kernel,
 
 # Tokens of one block: as the block-table kernel's `_BLOCK_KEY_COLUMNS`
 _BLOCK_TOKENS = 2048
+# ... at up to this many query rows (heads, or a span's heads)
+_BLOCK_ROWS = 128
 
 
-def _latent_attention_xla(q, pages, block_tables, lens, scale, keep=None):
-    """q [B, H, lanes]; pages [P, page, lanes]; lens [B] rows a slot
-    holds -> [B, H, lanes]. Gathers each slot's whole table: the CPU's
-    route and the kernel's oracle. `keep` [B, L] bool restricts each
-    slot to its selected rows."""
+def _latent_attention_xla(q, pages, block_tables, lens, scale, keep=None,
+                          span=1):
+    """q [B, span x H, lanes]; pages [P, page, lanes]; lens [B] rows a
+    slot holds -> [B, span x H, lanes]. Gathers each slot's whole table:
+    the CPU's route and the kernel's oracle. `keep` [B, L] bool
+    restricts each slot to its selected rows; under a `span` the rows
+    of q are its queries' heads, the oldest query first, and query j
+    sees `lens - (span - 1 - j)` rows."""
     b = q.shape[0]
     rows = pages[block_tables].reshape(b, -1, pages.shape[2])
     s = jnp.einsum("bhd,bld->bhl", q, rows,
@@ -53,13 +65,19 @@ def _latent_attention_xla(q, pages, block_tables, lens, scale, keep=None):
     live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < lens[:, None]
     if keep is not None:
         live = live & keep
-    p = jax.nn.softmax(jnp.where(live[:, None, :], s, _NEG_INF), axis=-1)
+    live = live[:, None, :]
+    if span > 1:
+        later = np.int32(span - 1) - jnp.arange(
+            q.shape[1], dtype=jnp.int32) // np.int32(q.shape[1] // span)
+        live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :] \
+            < (lens[:, None, None] - later[None, :, None])
+    p = jax.nn.softmax(jnp.where(live, s, _NEG_INF), axis=-1)
     return jnp.einsum("bhl,bld->bhd", p.astype(rows.dtype), rows,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 def _latent_kernel(tables_ref, lens_ref, q_ref, *refs, scale, page_size, ppb,
-                   selected=False):
+                   selected=False, span=1):
     # with `selected`, a float32 row a slot (0 on a selected row's
     # column, _NEG_INF on the others') comes after q: the kernel still
     # reads every live page and the selection is a mask on the scores
@@ -98,6 +116,10 @@ def _latent_kernel(tables_ref, lens_ref, q_ref, *refs, scale, page_size, ppb,
         start_block(i32(0), i32(0))
 
     q = q_ref[0]                                           # (H, lanes)
+    seen = ctx
+    if span > 1:        # (span x H, 1): the rows query j of the span sees
+        row = jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0)
+        seen = ctx - (i32(span - 1) - jax.lax.div(row, i32(h // span)))
 
     def block(blk, carry):
         m_prev, l_prev, acc = carry
@@ -116,7 +138,7 @@ def _latent_kernel(tables_ref, lens_ref, q_ref, *refs, scale, page_size, ppb,
         if selected:
             s = s + bias_ref[0, :, pl.ds(pl.multiple_of(blk * i32(n), n), n)]
         tok = blk * i32(n) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(tok < ctx, s, _NEG_INF)              # (H, n)
+        s = jnp.where(tok < seen, s, _NEG_INF)             # (H, n)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -135,18 +157,22 @@ def _latent_kernel(tables_ref, lens_ref, q_ref, *refs, scale, page_size, ppb,
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
 
 
-def latent_pages_per_block(page, pages_per_seq):
+def latent_pages_per_block(page, pages_per_seq, rows=0):
+    """Pages of one block. Past `_BLOCK_ROWS` query rows (a span of two
+    at 128 heads) a block holds fewer tokens, so that its float32
+    scores [rows, tokens] stay the size they are at `_BLOCK_ROWS`."""
+    tokens = _BLOCK_TOKENS * _BLOCK_ROWS // max(rows, _BLOCK_ROWS)
     ppb = 1
-    while 2 * ppb <= pages_per_seq and 2 * ppb * page <= _BLOCK_TOKENS:
+    while 2 * ppb <= pages_per_seq and 2 * ppb * page <= tokens:
         ppb *= 2
     return ppb
 
 
 def _latent_attention_pallas(q, pages, block_tables, lens, scale, interpret,
-                             keep=None):
+                             keep=None, span=1):
     b, h, d = q.shape
     _, page, _ = pages.shape
-    ppb = latent_pages_per_block(page, block_tables.shape[1])
+    ppb = latent_pages_per_block(page, block_tables.shape[1], h)
     q_spec = pl.BlockSpec((1, h, d), lambda b_, tr, lr: (b_, _Z, _Z))
     selection, selection_specs = (), []
     if keep is not None:
@@ -165,7 +191,7 @@ def _latent_attention_pallas(q, pages, block_tables, lens, scale, interpret,
     )
     return pl.pallas_call(
         functools.partial(_latent_kernel, scale=scale, page_size=page,
-                          ppb=ppb, selected=keep is not None),
+                          ppb=ppb, selected=keep is not None, span=span),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
@@ -197,15 +223,23 @@ def sparse_latent_gate_reason(h, lanes, page, pages_per_seq):
 
 
 def paged_latent_attention(q, pages, block_tables, lens, scale=None,
-                           interpret=False, keep=None):
+                           interpret=False, keep=None, span=1):
     """One decode token a slot over the slot's live latent rows. q [B,
     H, width <= lanes] (the absorbed query); pages [num_pages, page,
     lanes]; block_tables [B, pages_per_seq]; lens [B] rows held, the new
     token's included -> [B, H, lanes], the softmax-weighted sum of whole
     rows (float32 accumulation, q's dtype). `keep` [B, pages_per_seq *
     page] bool restricts each slot to its selected rows (the selection
-    is a mask on the scores: every live page is still read)."""
+    is a mask on the scores: every live page is still read). With a
+    `span` of s tokens a slot (their rows written, `lens` counting them
+    all), q is [B, s x H, width], the oldest token's heads first, and
+    token j attends to the first `lens - (s - 1 - j)` rows: causal
+    inside the span, the live rows read once for all s."""
     sc = scale if scale is not None else 1.0 / pymath.sqrt(q.shape[-1])
+    if span > 1 and keep is not None:
+        raise ValueError(
+            "a query span under a selection: one set of rows a slot cannot "
+            "serve the span's several positions")
     interpret = interpret or pallas_interpret()
     q = latent_rows(q, pages)
     kernel = "paged_latent_attention" if keep is None \
@@ -213,7 +247,8 @@ def paged_latent_attention(q, pages, block_tables, lens, scale=None,
     with jax.named_scope("mla.attend"):
         if interpret or _use_pallas():
             h, page, lanes = q.shape[1], pages.shape[1], pages.shape[2]
-            reason = latent_gate_reason(h, lanes, page) if keep is None \
+            reason = latent_gate_reason(h // span, lanes, page) \
+                if keep is None \
                 else sparse_latent_gate_reason(h, lanes, page,
                                                block_tables.shape[1])
             if reason is None and not interpret \
@@ -222,10 +257,12 @@ def paged_latent_attention(q, pages, block_tables, lens, scale=None,
             if reason is None:
                 _note_decode_kernel(kernel)
                 return _latent_attention_pallas(q, pages, block_tables, lens,
-                                                sc, interpret, keep=keep)
+                                                sc, interpret, keep=keep,
+                                                span=span)
             note_fallback(kernel, reason)
         _note_decode_kernel("xla")
-        return _latent_attention_xla(q, pages, block_tables, lens, sc, keep)
+        return _latent_attention_xla(q, pages, block_tables, lens, sc, keep,
+                                     span)
 
 
 def paged_sparse_latent_attention(q, pages, index_pages, qi, w, block_tables,

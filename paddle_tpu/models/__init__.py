@@ -15,3 +15,4 @@ from .granite_hybrid import (GraniteMoeHybridConfig,
 from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
 from .ling_hybrid import LingHybridConfig, LingHybridForCausalLM
 from .glm_moe_dsa import GlmMoeDsaConfig, GlmMoeDsaForCausalLM
+from .openpangu_moe import OpenPanguMoEConfig, OpenPanguMoEForCausalLM

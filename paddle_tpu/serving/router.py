@@ -130,7 +130,8 @@ class RequestHandle:
             self.span.event("first_token")
         if len(fresh) < len(toks):       # partial overlap: trim
             ev = ev._replace(span=tuple(t for _, t in fresh),
-                             token=fresh[-1][1], index=fresh[-1][0])
+                             token=fresh[-1][1], index=fresh[-1][0],
+                             drafted=ev.drafted[len(toks) - len(fresh):])
         self._q.put(ev)
 
     def _finish(self, status: str, ts: Optional[float] = None):

@@ -46,7 +46,15 @@ class StreamEvent(NamedTuple):
     the span's LAST token and `index` its ordinal, so single-token
     consumers keep working unchanged (`span == (token,)` on ordinary
     ticks). Consumers that must see every token iterate `span`; the
-    first span token's ordinal is ``index - len(span) + 1``."""
+    first span token's ordinal is ``index - len(span) + 1``.
+
+    **Drafted tokens.** When the served model drafts for itself (a
+    multi-token-prediction module behind ``spec_draft_tokens``),
+    `drafted` carries what the tick's verify was given: ``drafted[i]``
+    was proposed for the place of ``span[i]``, accepted (then the two
+    are equal and the span goes on) or not. A client that wants the
+    drafts reads the field; nothing else changes for one that does
+    not. Empty on every other tick."""
     request: int
     kind: str                      # "token" | "end"
     token: Optional[int] = None
@@ -55,6 +63,7 @@ class StreamEvent(NamedTuple):
     status: Optional[str] = None
     meta: object = None
     span: tuple = ()
+    drafted: tuple = ()
 
 
 class ServeRequest(NamedTuple):

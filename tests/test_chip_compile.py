@@ -503,6 +503,12 @@ DECODE_STEP_AT_PARENT = {
     # rule as an argument and the long prefill is asked for by the model
     "keye2-longprompt-open":
         "427276cffc468292a08f13b84731d77ef7c91b97e9fbd8b58e727e7c12c9fb14",
+    # at the parent of PR 41 (f794e08): the latent kernel takes a query
+    # span, and a span of one lowers as the kernel did
+    "ling3f-longdoc-open":
+        "eb9b6921f5831374d340b58082e92867e852a80281bd835407b629f9595179f3",
+    "glm5-longprompt-open":
+        "98d42b64d58b936d23c0c6e24b45553e23a6f903aba94f581b046715acecf710",
 }
 
 
@@ -626,3 +632,112 @@ def test_glm_prefill_at_real_size(chip, glm, bucket):
     # the masked flash kernel takes a chunk's 512 queries in one tile
     # (`rep` 1: every tile would read a head's keys again)
     assert f"bf16[{n},64,1,512,256]" in text
+
+
+# --- the self-drafting cell at its real geometry ----------------------------
+# 32 slots of up to 4096 positions over 8193 pages; 5 trunk layers and the
+# MTP layer of 128 heads on a 576-wide latent row a token (640 lanes); a
+# verify span of two queries a slot: 256 query rows against a slot's rows.
+
+@pytest.mark.parametrize("span", [1, 2])
+def test_latent_span_kernel_at_the_cells_geometry(chip, pallas_by_flag,
+                                                  monkeypatch, span):
+    from paddle_tpu.framework.flags import flag_value
+    from paddle_tpu.kernels import latent_attention as la
+    monkeypatch.setattr(la, "_use_pallas",
+                        lambda: bool(flag_value("use_pallas_kernels")))
+    slots, pps, pool, heads = 32, 256, 8193, 128
+    text = _compile(
+        chip, lambda q, rows, bt, cl: la.paged_latent_attention(
+            q, rows, bt, cl, SCALE, span=span),
+        ((slots, span * heads, 576), BF16), ((pool, PAGE, 640), BF16),
+        ((slots, pps), I32), ((slots,), I32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " s64[" not in text
+    # the span's queries ride the row axis of ONE call: a slot's rows
+    # are read once, and the pool is taken as it lies
+    assert re.search(r"bf16\[%d,%d,640\]\S* custom-call\("
+                     % (slots, span * heads), text)
+    assert not re.search(r"= bf16\[%d,%d,640\]\S* copy\(" % (pool, PAGE),
+                         text)
+    assert f"[{slots},{pps},{PAGE}," not in text
+
+
+@pytest.fixture(scope="module")
+def pangu(chip):
+    yield from _cell_predictor(chip, "pangu-reason-open")
+
+
+def test_pangu_tick_at_real_size(chip, pangu):
+    """The tick's ONE program: the verify span through the trunk, then
+    the draft pass through the MTP layer."""
+    pred, n_params, fixed = pangu
+    assert n_params == 6_037_863_680        # the issue's 6037.7 M, norms in
+    B, pps = pred.B, pred.pages_per_seq
+    assert pred._drafter == (1, 5)
+    assert all(v is None for v in pred.pool.v)
+    assert [a.shape for a in pred.pool.k] == [(8193, 16, 640)] * 6
+    pool = sum(a.nbytes for a in pred.pool.latent)
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_mtp_step, (B, pps), (B,), (B, 2))
+    assert " f64[" not in text and " s64[" not in text
+    assert live < 13.6e9, live
+    assert ma.alias_size_in_bytes >= pool       # rows written in place
+    # one span kernel a trunk layer and one in the draft pass, [slots,
+    # 2 x heads, lanes]; no slot's table of rows is gathered
+    assert len(re.findall(r"bf16\[%d,256,640\]\S* custom-call\(" % B,
+                          text)) == 6
+    assert f"[{B},{pps},16,640]" not in text
+    assert text.count("ragged-dot-none") >= 10      # 2 an expert layer
+
+
+@pytest.mark.parametrize("bucket", [256, 2048])
+def test_pangu_prefill_at_real_size(chip, pangu, bucket):
+    pred, _, fixed = pangu
+    n = pred._prefill_rows
+    assert n == 1
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_prefill, (n, bucket), (n, bucket),
+        (n,), (n, bucket // pred.page))
+    assert " f64[" not in text and " s64[" not in text
+    assert f"[{n},1,{bucket},{bucket}]" not in text
+    assert f"[{n},{bucket},{pred.model.config.vocab_size}]" not in text
+    # under the chip's 16 GB with room for the allocator
+    assert live < 14.5e9, live
+    # a flash kernel a layer: five trunk layers and the MTP layer
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6
+
+
+def test_pangu_reference_programs_fit_the_chip(chip):
+    """The cell's plain reference at the published widths, a sequence
+    padded to 4096: its weights are drawn by programs of their own (in
+    the layer's program the draws' temporaries and the activations took
+    24 GB on the chip, PR 41) and every program keeps under 4 GB."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks.lib import harness
+    ref = harness.load_module(root, "reference", "pangu_ultra_moe")
+    cfg = harness.find_cell(root, "pangu-reason-open")["cfg"]
+    cfg_s = ref._static(cfg)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+    key, index, x = on_chip((
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((), I32),
+        jax.ShapeDtypeStruct((4096, cfg["hidden_size"]), jnp.float32)))
+
+    def live(compiled):
+        ma = compiled.memory_analysis()
+        return ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            + ma.temp_size_in_bytes
+
+    for kind in ("attn", "dense"):
+        w = on_chip(jax.eval_shape(
+            lambda k, i: getattr(ref.pw, kind)(cfg, k, i), key, index))
+        assert live(ref._weights.lower(key, index, kind, cfg_s).compile()) \
+            < 1.0e9
+        step = ref._attend.lower(x, w, cfg_s, None) if kind == "attn" \
+            else ref._feed_forward.lower(x, w, key, index, True, cfg_s,
+                                         "int8")
+        assert live(step.compile()) < 4.0e9

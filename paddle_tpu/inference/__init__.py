@@ -965,6 +965,8 @@ class ContinuousBatchingPredictor:
         self._m_spec_acc = _obsm.counter("serving.spec.accepted_tokens")
         self._m_spec_rate = _obsm.gauge("serve.spec.accept_rate")
         self.stats["spec_ticks"] = 0
+        self.stats["spec_ticks_chained"] = 0    # self-drafting ticks
+        # dispatched with their predecessor still in flight
         self.stats["spec_proposed"] = 0
         self.stats["spec_accepted"] = 0
         self._m_chunks = _obsm.counter("serving.chunked_prefill.chunks")
@@ -1628,13 +1630,26 @@ class ContinuousBatchingPredictor:
             done = jnp.zeros(bonus.shape, jnp.bool_)
         return bonus, accepted, done, new_k, new_v
 
-    def _raw_mtp_step(self, p_vals, b_vals, kl, vl, tables, ctx, span_ids):
+    def _raw_mtp_step(self, p_vals, b_vals, kl, vl, tables, ctx, span_ids,
+                      fresh, ctx_chain, span_chain):
         """One self-drafting tick, ONE program, for a model that
         declares a drafter of depth 1 over latent pages
         (`model.drafter()`): the verify span through the trunk, then the
         draft pass through the model's MTP module (scope `mtp.draft`).
         (One program and not two: measured on the chip, PERF.md section
         6, PR 41; the name is what the benchmark's readers look for.)
+
+        CHAIN. A slot that continues the request of the tick before
+        takes its span and its position from that tick's outputs,
+        `span_chain` / `ctx_chain` (its `span_next` / `ctx_next`, still
+        on the device: the tick is dispatched before its predecessor is
+        read back); a slot marked `fresh [B]` (newly placed, idle or
+        evicted) takes the host's `span_ids` / `ctx`. Selected here, so
+        that the host launches no op of its own. A slot whose request
+        ended in the tick before rides this one as a junk row, one span
+        past its budget: the position is held inside the table (a kept
+        row never reaches the bound), so its rows land in its own
+        released pages or the trash page.
 
         VERIFY. span_ids [B, 2] = each slot's committed last token `x_t`
         and the token drafted after it, `d_{t+1}`, at positions ctx,
@@ -1658,12 +1673,17 @@ class ContinuousBatchingPredictor:
         any length reaches it (generation/kv_cache.
         paged_cache_latent_span_update_attend). Returns (span_next [B,
         2] int32 = the next tick's span: the last committed token
-        `y[accepted]` and its draft; accepted [B] int32; new_k; new_v;
-        counts): the host commits the accepted draft and `span_next[:,
-        0]`, and syncs those two small arrays only."""
+        `y[accepted]` and its draft; accepted [B] int32; ctx_next; new_k;
+        new_v; counts): the host commits the accepted draft and `span_next[:,
+        0]`, and syncs those two small arrays only; `ctx_next [B]` (the
+        position + accepted + 1, the next tick's) comes third and stays
+        on the device beside `span_next`."""
         from ..jit.bridge import bound_state
         from ..generation import sampling as _samp
         at = self._drafter.layer
+        ctx = jnp.minimum(jnp.where(fresh, ctx, ctx_chain),
+                          jnp.int32(tables.shape[1] * self.page - 2))
+        span_ids = jnp.where(fresh[:, None], span_ids, span_chain)
         pos = ctx[:, None].astype(jnp.int32) \
             + jnp.arange(2, dtype=jnp.int32)[None, :]
         with no_grad(), bound_state(self._p_tensors, p_vals,
@@ -1694,7 +1714,8 @@ class ContinuousBatchingPredictor:
             jnp.int32)], axis=1)                    # [B, token | draft, 2]
         span_next = jnp.take_along_axis(both, accepted[:, None, None],
                                         axis=2)[:, :, 0]
-        return (span_next, accepted, *self._step_caches_out(caches)) \
+        return (span_next, accepted, ctx + accepted + 1,
+                *self._step_caches_out(caches)) \
             + self._counter_outputs(caches)
 
     # ------------------------------------------------------------ serve --
@@ -2672,7 +2693,7 @@ class ContinuousBatchingPredictor:
                     if self._drafter is not None:
                         cur = self._dispatch_mtp_step(
                             active, slot_req, tables, ctx, last_tok_host,
-                            draft_host, override)
+                            draft_host, inflight)
                     elif spec_mode:
                         sv = samp_vec(set()) \
                             if self.sampling_enabled else None
@@ -2720,16 +2741,23 @@ class ContinuousBatchingPredictor:
                         apply_cancels()
                         expire_deadlines()
                     if inflight is not None and (
-                            spec_mode or (self.sampling_enabled
-                                          and "chunk_mid" in inflight)):
+                            (spec_mode and self._drafter is None)
+                            or (self.sampling_enabled
+                                and "chunk_mid" in inflight)):
                         # resolve BEFORE dispatching when the next
                         # dispatch depends on this step's host-state
-                        # transitions: (a) speculative mode — the
-                        # drafter needs the freshly committed tokens in
-                        # the slot histories and ctx/ragged meta rewound
-                        # to the accepted prefix (the multi-token step
-                        # replaces the one-step pipeline at the same
-                        # single sync per tick); (b) a MIXED step on a
+                        # transitions: (a) the n-gram drafter — it
+                        # looks its drafts up in the slot histories, so
+                        # it needs the freshly committed tokens there
+                        # and ctx/ragged meta rewound to the accepted
+                        # prefix (its multi-token step replaces the
+                        # one-step pipeline at the same single sync per
+                        # tick). Not a model's OWN drafter: it is part
+                        # of the tick's program, its next span and
+                        # position are on the device when the tick
+                        # ends, and the self-drafting tick is pipelined
+                        # like a plain decode step (`_dispatch_mtp_step`
+                        # chains them); (b) a MIXED step on a
                         # sampling-enabled predictor — its resolve flips
                         # sampled slots into first-token replay
                         # (sampled_chunk_first) and un-pauses sampled
@@ -3226,38 +3254,50 @@ class ContinuousBatchingPredictor:
                 "qlen": {b: int(q_lens[b]) for b in active}}
 
     def _dispatch_mtp_step(self, active, slot_req, tables, ctx,
-                           last_tok_host, draft_host, override):
+                           last_tok_host, draft_host, inflight):
         """Dispatch one self-drafting tick (`_raw_mtp_step`: the verify
-        and, in the same program, the draft pass): every slot's span is
-        its committed last token and the token its model drafted after
-        it, both host-committed by the resolver (or by admission), as
-        the n-gram verify's are. ctx advances over the whole span; the
-        resolver rewinds it to what was kept. Resolved by
+        and, in the same program, the draft pass) WITHOUT waiting for
+        the tick before it, as `_dispatch_step` does for a decode step:
+        a slot that continues the in-flight tick's request chains that
+        tick's device-resident `span_next` / `ctx_next`; every other
+        slot (newly placed, idle, evicted) is `fresh` and takes the
+        host's span (its committed last token and the token drafted
+        after it, from admission) and the host's `ctx`. With nothing in
+        flight every slot is fresh and the host's arrays stand in for
+        the chain: one program signature. The host's `ctx` is what the
+        resolver has committed and is not advanced here. Resolved by
         `_resolve_spec_step`, as a verify of one draft a slot."""
         import time as _time
         t0 = _time.perf_counter()
         span_ids = self._place(jnp.asarray(
             np.stack([last_tok_host, draft_host], axis=1)))
-        override[:] = False
-        # .copy(): the resolver rewinds ctx before this step's buffers
-        # are read back
-        at = tables.copy(), ctx.copy()
-        span_next, accepted, new_k, new_v, *aux = self._jit_call(
+        chained = inflight is not None
+        if chained:
+            held = set(inflight["snap"])
+            fresh = np.fromiter(((b, r) not in held
+                                 for b, r in enumerate(slot_req)),
+                                bool, self.B)
+            chain = inflight["ctx_next"], inflight["span"]
+        else:
+            fresh = np.ones((self.B,), bool)
+            chain = self._place(jnp.asarray(ctx.copy())), span_ids
+        self.stats["spec_ticks_chained"] += chained
+        self._tick.note(chained=int(chained))
+        # .copy(): evictions and admissions rewrite tables and ctx
+        # while this tick is still in flight
+        span_next, accepted, ctx_next, new_k, new_v, *aux = self._jit_call(
             ("mtp", tables.shape), self._mtp_jit,
-            self._p_vals, self._b_vals, *self._cache_args(), *at, span_ids)
+            self._p_vals, self._b_vals, *self._cache_args(),
+            tables.copy(), ctx.copy(), span_ids, fresh, *chain)
         self._cache_store(new_k, new_v)
-        snap = [(b, slot_req[b]) for b in active]
-        ctx0 = {b: int(ctx[b]) for b in active}
-        ctx[active] += 2                # optimistic; resolve rewinds
         self.stats["decode_steps"] += 1
         self.stats["spec_ticks"] += 1
         self.stats["spec_proposed"] += len(active)
         self._m_steps.inc(**self._mlbl)
         self._m_spec_prop.inc(len(active), **self._mlbl)
         return {"spec": True, "span": span_next, "acc": accepted,
-                "snap": snap, "t": t0, "ctx0": ctx0, "aux": (aux,),
-                "drafts": {b: [int(draft_host[b])] for b in active},
-                "qlen": {b: 2 for b in active}}
+                "ctx_next": ctx_next, "t": t0, "aux": (aux,),
+                "snap": [(b, slot_req[b]) for b in active]}
 
     def _resolve_spec_step(self, step, slot_req, slot_new, slot_hist,
                            last_tok_host, max_new, ctx, override,
@@ -3272,28 +3312,40 @@ class ContinuousBatchingPredictor:
         program), the drafting history extends, and the whole tick
         streams as ONE multi-token StreamEvent span. Slots marked
         chunk_final are resolving their first (sampled) token — TTFT
-        lands here via `first_cb`. A self-drafting tick
-        (`_dispatch_mtp_step`) gives `span`, the next tick's span, in
-        place of the bonus: its first column is the bonus, its second
-        the slot's next draft, kept in `draft_host`; the tick's event
-        carries the draft it verified as `drafted`."""
+        lands here via `first_cb`.
+
+        An n-gram verify (`_dispatch_spec_step`) is resolved before the
+        next is dispatched. A self-drafting tick (`_dispatch_mtp_step`)
+        is resolved while its SUCCESSOR runs, like a decode step in
+        `_resolve_step`: a slot recycled since the dispatch is skipped
+        by `snap`, and a slot whose budget or eos is met here rides the
+        successor as a junk row of which nothing is committed. Such a
+        tick gives `span`, the next tick's span, in place of the bonus:
+        its first column is the bonus, its second the slot's next
+        draft. The draft THIS tick verified was not on the host when it
+        was dispatched: it is what `draft_host` holds now, written by
+        the resolve before this one or by the slot's admission, and it
+        goes out as the event's `drafted` before the next draft
+        replaces it. ctx was not advanced at dispatch: it moves on by
+        what was kept."""
         import time as _time
         mtp = "span" in step
         with self._tick.stage("serve.resolve.wait"):
             if mtp:
                 self._await_step(step, (step["span"], step["acc"]))
                 # graft-lint: ok[GL102] — the same sync point for the
-                # self-drafting tick: [B, 2] and [B]
+                # self-drafting tick, whose successor is already
+                # dispatched: [B, 2] and [B]
                 span_next = np.asarray(step["span"])
                 bonus = span_next[:, 0]
             else:
                 self._await_step(step, (step["tok"], step["acc"],
                                         step["done"]))
                 # graft-lint: ok[GL102] — THE decode-loop sync point:
-                # three [B] vectors of the verify step (spec mode
-                # resolves before the next dispatch; the multi-token
-                # step replaces the one-step pipeline at the same one
-                # sync per tick)
+                # three [B] vectors of the verify step (an n-gram
+                # verify resolves before the next dispatch; the
+                # multi-token step replaces the one-step pipeline at
+                # the same one sync per tick)
                 bonus = np.asarray(step["tok"])
             acc = np.asarray(step["acc"])    # graft-lint: ok[GL102] (ditto)
         for aux in step.get("aux") or ():
@@ -3305,10 +3357,13 @@ class ContinuousBatchingPredictor:
         for b, r in step["snap"]:
             if slot_req[b] != r:
                 continue             # evicted (and maybe re-admitted)
-            drafts = step["drafts"].get(b, [])
+            drafts = [int(draft_host[b])] if mtp \
+                else step["drafts"].get(b, [])
             a = min(int(acc[b]), len(drafts))
             emitted = drafts[:a] + [int(bonus[b])]
-            new_ctx = step["ctx0"][b] + a + 1
+            # an n-gram verify advanced ctx over its whole span at
+            # dispatch; a self-drafting tick left it where it was
+            new_ctx = (int(ctx[b]) if mtp else step["ctx0"][b]) + a + 1
             ctx[b] = new_ctx
             if builder is not None and a + 1 < step["qlen"][b]:
                 builder.rollback_slot(b, new_ctx)

@@ -294,8 +294,11 @@ def granite(chip):
     yield from _cell_predictor(chip, "granite4h-chat-open")
 
 
-def _compile_program(chip, pred, fixed, fn, *shapes):
-    args = [jax.ShapeDtypeStruct(s, I32, sharding=chip) for s in shapes]
+def _compile_program(chip, pred, fixed, fn, *shapes, **typed):
+    """`shapes` of int32 operands; then `typed` ones, `name=(shape,
+    dtype)`, in the order given."""
+    args = [jax.ShapeDtypeStruct(s, I32, sharding=chip) for s in shapes] \
+        + [jax.ShapeDtypeStruct(*st, sharding=chip) for st in typed.values()]
     with pred._trace_lock, pred._kernel_scope():
         compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(
             *fixed, *args).compile()
@@ -678,8 +681,12 @@ def test_pangu_tick_at_real_size(chip, pangu):
     assert all(v is None for v in pred.pool.v)
     assert [a.shape for a in pred.pool.k] == [(8193, 16, 640)] * 6
     pool = sum(a.nbytes for a in pred.pool.latent)
+    # the host's table, positions and span; which slots take them; the
+    # tick before's positions and span, chained on the device
     text, live, ma = _compile_program(
-        chip, pred, fixed, pred._raw_mtp_step, (B, pps), (B,), (B, 2))
+        chip, pred, fixed, pred._raw_mtp_step, (B, pps), (B,), (B, 2),
+        fresh=((B,), jnp.bool_), ctx_chain=((B,), I32),
+        span_chain=((B, 2), I32))
     assert " f64[" not in text and " s64[" not in text
     assert live < 13.6e9, live
     assert ma.alias_size_in_bytes >= pool       # rows written in place

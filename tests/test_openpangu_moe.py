@@ -7,10 +7,13 @@ weights, through the model alone and through
 `ContinuousBatchingPredictor` with the drafter on and off; the
 self-drafting tick's contract (lossless, the drafts the reference's,
 accepted and rejected ticks leaving the pool as a fresh prefill would,
-budgets and eos inside a two-token commit, cancellation); the span
-kernel in interpret mode against its XLA form; the expert shares against
+budgets and eos inside a two-token commit, cancellation) and its place
+in the one-step pipeline (a tick chained to the one in flight: requests
+that join and leave between two ticks, the junk row a finished request
+leaves behind, the counter); the span kernel in interpret mode against its XLA form; the expert shares against
 the uncut layer; what is declared, counted and refused.
 """
+import itertools
 import os
 import sys
 
@@ -29,6 +32,7 @@ from paddle_tpu.generation.kv_cache import (Drafter,  # noqa: E402
 from paddle_tpu.inference import ContinuousBatchingPredictor  # noqa: E402
 from paddle_tpu.kernels import latent_attention as la  # noqa: E402
 from paddle_tpu.observability import metrics  # noqa: E402
+from paddle_tpu.serving.streaming import ServeRequest  # noqa: E402
 
 from benchmarks.lib import harness  # noqa: E402
 
@@ -89,13 +93,16 @@ def _prompts(lengths, stream=0, vocab=CFG["vocab_size"]):
     return [rng.integers(2, vocab, n).tolist() for n in lengths]
 
 
-def _streamed(pred, prompts, max_new=10):
+def _collected(stream, n, each=None):
     """([served tokens], [[(index of the served token, its draft)]],
-    [token events]) a prompt."""
-    served = [[] for _ in prompts]
-    drafted = [[] for _ in prompts]
-    events = [[] for _ in prompts]
-    for ev in pred.generate_stream(prompts, max_new_tokens=max_new):
+    [token events]) a request, of `n`; `each(ev)` sees every event as
+    the consumer would."""
+    served = [[] for _ in range(n)]
+    drafted = [[] for _ in range(n)]
+    events = [[] for _ in range(n)]
+    for ev in stream:
+        if each is not None:
+            each(ev)
         if ev.kind != "token":
             continue
         r = ev.request
@@ -104,6 +111,26 @@ def _streamed(pred, prompts, max_new=10):
         served[r] += list(ev.span)
         events[r].append(ev)
     return served, drafted, events
+
+
+def _streamed(pred, prompts, max_new=10):
+    return _collected(pred.generate_stream(prompts, max_new_tokens=max_new),
+                      len(prompts))
+
+
+def _served_in_waves(pred, waves, each=None):
+    """Requests with budgets of their own, handed to the loop a wave at
+    a time: `waves` = {pass of the loop: [(prompt, budget)]}."""
+    calls = itertools.count()
+
+    def intake():
+        at = next(calls)
+        if at > max(waves):
+            return None
+        return [ServeRequest(p, n) for p, n in waves.get(at, [])]
+
+    return _collected(pred.serve_stream(intake),
+                      sum(len(w) for w in waves.values()), each)
 
 
 # ------------------------------------------- model against the reference --
@@ -268,24 +295,40 @@ def test_proposed_drafts_are_the_references(small_runs, reference):
 
 
 def test_accepted_and_rejected_ticks_leave_a_fresh_prefills_pool(small):
-    """One slot, watched before every tick: the slot's length, its live
-    rows in every trunk layer and in the MTP layer, its last token and
-    its standing draft are those of a fresh prefill of the tokens
-    committed so far, after an accepted tick and after a rejected one
-    (whose row at the rejected position nobody reads)."""
+    """One slot, watched before its first tick and after every resolve
+    (the next tick is in flight by then, and writes at the committed
+    length and past it): the slot's length, its live rows in every trunk
+    layer and in the MTP layer, its last token and its standing draft
+    are those of a fresh prefill of the tokens committed so far, after
+    an accepted tick and after a rejected one (whose row at the rejected
+    position nobody reads)."""
     prompt = _prompts([13], stream=8, vocab=SMALL["vocab_size"])[0]
     pred = ContinuousBatchingPredictor(small, **dict(ON, max_batch_size=1))
-    seen = []
-    real = pred._dispatch_mtp_step
+    seen, host = [], {}
+    dispatch, resolve = pred._dispatch_mtp_step, pred._resolve_spec_step
 
-    def watch(active, slot_req, tables, ctx, last, draft, override):
-        seen.append((tables[0].copy(), int(ctx[0]), int(last[0]),
-                     int(draft[0]), [np.asarray(a) for a in pred.pool.k],
-                     pred.stats["spec_accepted"]))
-        return real(active, slot_req, tables, ctx, last, draft, override)
+    def look():
+        if host["slot_req"][0] >= 0:
+            seen.append((host["tables"][0].copy(), int(host["ctx"][0]),
+                         int(host["last"][0]), int(host["draft"][0]),
+                         [np.asarray(a) for a in pred.pool.k],
+                         pred.stats["spec_accepted"]))
 
-    pred._dispatch_mtp_step = watch
+    def watch_dispatch(active, slot_req, tables, ctx, last, draft, inflight):
+        if not host:
+            host.update(slot_req=slot_req, tables=tables, ctx=ctx,
+                        last=last, draft=draft)
+            look()
+        return dispatch(active, slot_req, tables, ctx, last, draft, inflight)
+
+    def watch_resolve(*args, **kw):
+        resolve(*args, **kw)
+        look()
+
+    pred._dispatch_mtp_step = watch_dispatch
+    pred._resolve_spec_step = watch_resolve
     out = pred.generate([prompt], max_new_tokens=40)[0]
+    assert pred.stats["spec_ticks_chained"] >= len(seen) - 2
     accepts = np.diff([s[-1] for s in seen])
     assert accepts.max() == 1 and accepts.min() == 0
     page = GEO["page_size"]
@@ -382,6 +425,142 @@ def test_a_reused_slot_and_page_owe_nothing_to_their_last_tenant(small):
     junk = ContinuousBatchingPredictor(small, **dict(ON, max_batch_size=1))
     junk.pool.k = [jnp.full_like(a, 37.0) for a in junk.pool.k]
     assert junk.generate([long], max_new_tokens=12)[0] == first
+
+
+# ------------------------------------- the tick in the one-step pipeline --
+# A tick is dispatched while the one before it is in flight: a slot that
+# goes on takes its span and position from that tick's outputs on the
+# device, any other slot from the host.
+
+@pytest.mark.parametrize("which", ["rejected", "accepted"])
+def test_chained_ticks_serve_and_draft_what_the_reference_does(
+        model, small, reference, check, which):
+    """Requests with budgets of their own that join (three waves) and
+    leave between two ticks, where no draft is accepted (384 tokens) and
+    where some are (24): every served token and every draft a tick
+    verified, as its event names it, against the reference's trunk and
+    MTP module; the tokens the plain decode's."""
+    both, served_tokens = check
+    cfg, net = (CFG, model) if which == "rejected" else (SMALL, small)
+    lengths = [5, 17, 8, 30, 16, 7, 23, 12, 3, 21]
+    budgets = [9, 3, 24, 6, 1, 16, 2, 12, 18, 5]
+    reqs = list(zip(_prompts(lengths, stream=23, vocab=cfg["vocab_size"]),
+                    budgets))
+    pred = ContinuousBatchingPredictor(net, **ON)
+    served, drafted, events = _served_in_waves(
+        pred, {0: reqs[:3], 4: reqs[3:7], 9: reqs[7:]})
+    assert [len(o) for o in served] == budgets
+    plain = ContinuousBatchingPredictor(net, **GEO)
+    assert served == [plain.generate([p], max_new_tokens=n)[0]
+                      for p, n in reqs]
+    rec = both.compare(reference, served_tokens, cfg, SEED,
+                       [(p, o, d) for (p, _), o, d in zip(
+                           reqs, served, drafted)], TIGHT, len(reqs))
+    assert rec["correct"], rec
+    assert rec["positions_compared"] == sum(budgets)
+    assert rec["argmax_share"] == rec["draft_argmax_share"] == 1.0
+    # a draft a tick that committed anything, and nothing after a budget
+    ticks = sum(len(evs) - 1 for evs in events)
+    assert rec["drafts_compared"] == ticks
+    accepted = sum(len(ev.span) == 2 for evs in events for ev in evs)
+    assert (accepted > 0) == (which == "accepted")
+    assert accepted < ticks
+    stats = pred.stats
+    assert 0 < stats["spec_ticks_chained"] < stats["spec_ticks"]
+    assert pred.pool.free_count == pred.capacity
+
+
+@pytest.mark.parametrize("ending", ["budget", "eos", "cancel"])
+def test_a_request_that_ends_under_its_successor_tick_leaves_a_junk_row(
+        small, ending):
+    """Two slots. One request ends (its budget met, its eos, a cancel)
+    while the next tick, which carries its slot, is in flight; the other
+    keeps the pipeline going. Nothing of that row is committed, every
+    page returns, and the request that waited for the slot and for the
+    pages (the pool holds no others) is served and drafted for as it is
+    alone: from its own span and position."""
+    goes_on, ends, waits = _prompts([19, 30, 33], stream=16,
+                                    vocab=SMALL["vocab_size"])
+    kw = dict(ON, max_batch_size=2, num_pages=14)
+    solo = lambda prompt, n, **more: _streamed(
+        ContinuousBatchingPredictor(small, **dict(kw, **more)), [prompt],
+        max_new=n)
+    more, cut = {}, 6
+    if ending == "eos":
+        whole = solo(ends, 10)[0][0]
+        at = next(i for i in range(2, 10) if whole[i] not in whole[:i])
+        more, cut = {"eos_token_id": whole[at]}, at
+    pred = ContinuousBatchingPredictor(small, **dict(kw, **more))
+    reqs = [(goes_on, 30), (ends, 6 if ending == "budget" else 10),
+            (waits, 6)]
+    sent = iter([[ServeRequest(p, n) for p, n in reqs]])
+    stream = pred.serve_stream(lambda: next(sent, None))
+
+    def each(ev):
+        if ending == "cancel" and ev.kind == "token" and ev.request == 1 \
+                and ev.index >= 3:
+            stream.cancel(1)         # its next tick is dispatched already
+
+    served, drafted, events = _collected(stream, 3, each)
+    assert pred.last_status == ["ok", "cancelled" if ending == "cancel"
+                                else "ok", "ok"]
+    for r in (0, 2):
+        alone = solo(reqs[r][0], reqs[r][1], **more)
+        assert (served[r], drafted[r]) == (alone[0][0], alone[1][0])
+    alone = solo(ends, reqs[1][1], **more)
+    if ending == "cancel":
+        assert 3 <= len(served[1]) < 10
+        cut = len(served[1])
+    assert served[1] == alone[0][0][:cut] and len(served[1]) == cut
+    assert drafted[1] == [d for d in alone[1][0] if d[0] < cut]
+    # a row of a tick that was dispatched and committed nothing
+    committed = sum(len(evs) - 1 for evs in events)
+    assert pred.stats["spec_proposed"] > committed
+    assert pred.stats["spec_ticks_chained"] > 0
+    assert pred.pool.free_count == pred.capacity == 14
+
+
+def test_a_junk_row_at_the_tables_end_stays_inside_it(small):
+    """A request whose prompt and budget fill its table to the last row:
+    the row it leaves in the successor tick would lie two positions past
+    the table. The other request is served as it is alone and every page
+    returns."""
+    full, other = _prompts([98, 11], stream=17, vocab=SMALL["vocab_size"])
+    pred = ContinuousBatchingPredictor(small, **dict(ON, max_batch_size=2))
+    sent = iter([[ServeRequest(full, 30), ServeRequest(other, 50)]])
+    served, drafted, _ = _collected(
+        pred.serve_stream(lambda: next(sent, None)), 2)
+    plain = ContinuousBatchingPredictor(small, **dict(GEO, max_batch_size=2))
+    assert served == [plain.generate([full], max_new_tokens=30)[0],
+                      plain.generate([other], max_new_tokens=50)[0]]
+    assert pred.pool.free_count == pred.capacity
+
+
+def test_chained_ticks_are_the_ticks_less_those_on_an_empty_pipeline(small):
+    """One slot, three requests one after another: the pipeline runs
+    empty at each one's end, and the next one's first tick has nothing
+    to chain. The counter in `stats` and the ring's `chained`."""
+    from paddle_tpu.observability import tracing as tr
+    prompts = _prompts([11, 6, 20], stream=18, vocab=SMALL["vocab_size"])
+    pred = ContinuousBatchingPredictor(small, **dict(ON, max_batch_size=1))
+    empty = []
+    real = pred._dispatch_mtp_step
+
+    def watch(*args):
+        empty.append(args[-1] is None)
+        return real(*args)
+
+    pred._dispatch_mtp_step = watch
+    tr.clear_ticks()
+    pred.generate(prompts, max_new_tokens=12)
+    ticks = [t for t in tr.ticks() if "chained" in t]
+    tr.clear_ticks()
+    stats = pred.stats
+    assert sum(empty) >= 3 and len(empty) == stats["spec_ticks"]
+    assert stats["spec_ticks_chained"] == stats["spec_ticks"] - sum(empty)
+    assert stats["spec_ticks_chained"] > stats["spec_ticks"] // 2
+    assert len(ticks) == stats["spec_ticks"]
+    assert sum(t["chained"] for t in ticks) == stats["spec_ticks_chained"]
 
 
 def test_drafted_tokens_reach_a_routers_client(small):

@@ -419,6 +419,33 @@ class TestSpecServeLoop:
         for r in (0, 1):
             assert spans[r] == stream.results[r]
 
+    def test_ngram_verify_resolves_before_the_next_is_dispatched(self):
+        """The n-gram drafter looks its drafts up in the host's token
+        histories: every step is resolved before the next is drafted
+        and dispatched (a model's own drafter, part of the tick's
+        program, is pipelined instead: tests/test_openpangu_moe.py)."""
+        m = _model()
+        cb = _cb(m, spec_draft_tokens=4)
+        order = []
+
+        def logged(name, mark):
+            real = getattr(cb, name)
+
+            def call(*args, **kw):
+                order.append(mark)
+                return real(*args, **kw)
+
+            setattr(cb, name, call)
+
+        logged("_dispatch_spec_step", "D")
+        logged("_resolve_spec_step", "R")
+        logged("_resolve_step", "R")        # a tick that drew no drafts
+        cb.generate(_cyclic_prompts(m.config.vocab_size),
+                    max_new_tokens=24)
+        assert "".join(order) == "DR" * cb.stats["decode_steps"]
+        assert cb.stats["spec_ticks"] > 0
+        assert cb.stats["spec_ticks_chained"] == 0
+
 
 # ---------------------------------------------------------------------------
 # serve loop: on-device sampling

@@ -791,6 +791,15 @@ class PagedCacheEntry(NamedTuple):
     (`LayerCache.index_dim`, zero-padded to the pool's lanes), under
     the same page ids; such a layer steps through
     `paged_cache_sparse_update_attend`.
+
+    `live` (optional, [B] bool): the slots that carry a request. The
+    one-token decode contracts of a layer with an indexer, or over
+    latent pages, attend over `attend_lens`: nothing for a slot that
+    carries none (the serve loop's idle slots, whose table is all
+    trash), so that the slot-walk kernels neither fetch nor contract a
+    block for it. Without it, and under `paged_cache_update_attend`
+    and `paged_cache_latent_span_update_attend`, which do not read it
+    (PERF.md section 7), every slot attends over what it holds.
     """
     k_pages: object
     v_pages: object
@@ -799,6 +808,7 @@ class PagedCacheEntry(NamedTuple):
     ragged_meta: object = None
     q_lens: object = None
     index_pages: object = None
+    live: object = None
 
 
 class LatentCacheEntry(NamedTuple):
@@ -809,11 +819,13 @@ class LatentCacheEntry(NamedTuple):
     The layer steps through `paged_cache_latent_update_attend`, or,
     where it declares an `index_dim`, with `index_pages` as in
     `PagedCacheEntry`, through
-    `paged_cache_sparse_latent_update_attend`."""
+    `paged_cache_sparse_latent_update_attend`. `live` as in
+    `PagedCacheEntry`."""
     pages: object
     block_table: object
     context_lens: object
     index_pages: object = None
+    live: object = None
 
 
 class StateCacheEntry(NamedTuple):
@@ -848,6 +860,19 @@ class PagedKVCache:
 
     def __iter__(self):
         return iter(self.entries)
+
+
+def attend_lens(cl, n, live=None):
+    """The keys or rows each slot attends over in a step that writes
+    `n` tokens a slot at `cl`: `cl + n`, and 0 for a slot that carries
+    no request (`live`, a [B] bool; None: every slot carries one). At a
+    length of 0 the slot-walk kernels start no DMA and run no block:
+    zeros out, a row of -inf scores, an empty selection. A length of 1
+    or more costs a whole block, however little of it is live. The WRITE
+    still goes by `cl`: an idle slot's token lands on its table's page,
+    the trash page."""
+    import jax.numpy as jnp
+    return cl + n if live is None else jnp.where(live, cl + n, 0)
 
 
 def paged_cache_update_attend(entry: PagedCacheEntry, q, k, v, scale=None):
@@ -977,7 +1002,7 @@ def paged_cache_sparse_update_attend(entry: PagedCacheEntry, q, k, v, qi, w,
     from ..kernels.paged_attention import (index_key_rows,
                                            paged_sparse_attention)
 
-    def fn(kp, vp, ip, bt, cl, qv, kv, vv, qiv, wv, kiv):
+    def fn(kp, vp, ip, bt, cl, qv, kv, vv, qiv, wv, kiv, live):
         page = kp.shape[1]
         rows = jnp.arange(qv.shape[0])
         at = (bt[rows, (cl // page).astype(jnp.int32)],
@@ -987,14 +1012,14 @@ def paged_cache_sparse_update_attend(entry: PagedCacheEntry, q, k, v, qi, w,
         ip2 = ip.at[at].set(index_key_rows(kiv[:, 0], ip))
         out, keep = paged_sparse_attention(
             qv[:, 0], kp2, vp2, ip2, qiv[:, 0], wv[:, 0],
-            bt, cl + 1, topk, scale)
+            bt, attend_lens(cl, 1, live), topk, scale)
         return (out[:, None].astype(qv.dtype), kp2, vp2, ip2,
                 jnp.sum(keep, axis=1, dtype=jnp.int32))
 
     out, kp2, vp2, ip2, n_sel = apply(
         fn, entry.k_pages, entry.v_pages, entry.index_pages,
         entry.block_table, entry.context_lens, q, k, v, qi, w, ki,
-        _name="paged_sparse_attention_decode")
+        entry.live, _name="paged_sparse_attention_decode")
     return out, entry._replace(k_pages=kp2, v_pages=vp2,
                                index_pages=ip2), n_sel
 
@@ -1013,17 +1038,18 @@ def paged_cache_latent_update_attend(entry: LatentCacheEntry, q, row,
     from ..kernels.latent_attention import (latent_rows,
                                             paged_latent_attention)
 
-    def fn(pages, bt, cl, qv, rv):
+    def fn(pages, bt, cl, qv, rv, live):
         page = pages.shape[1]
         rows = jnp.arange(qv.shape[0])
         at = (bt[rows, (cl // page).astype(jnp.int32)],
               (cl % page).astype(jnp.int32))
         pages2 = pages.at[at].set(latent_rows(rv[:, 0], pages))
-        out = paged_latent_attention(qv[:, 0], pages2, bt, cl + 1, scale)
+        out = paged_latent_attention(qv[:, 0], pages2, bt,
+                                     attend_lens(cl, 1, live), scale)
         return out[:, None].astype(qv.dtype), pages2
 
     out, pages2 = apply(fn, entry.pages, entry.block_table,
-                        entry.context_lens, q, row,
+                        entry.context_lens, q, row, entry.live,
                         _name="paged_latent_attention_decode")
     return out, entry._replace(pages=pages2)
 
@@ -1081,7 +1107,7 @@ def paged_cache_sparse_latent_update_attend(entry: LatentCacheEntry, q, row,
     from ..kernels.latent_attention import (latent_rows,
                                             paged_sparse_latent_attention)
 
-    def fn(pages, ip, bt, cl, qv, rv, qiv, wv, kiv):
+    def fn(pages, ip, bt, cl, qv, rv, qiv, wv, kiv, live):
         page = pages.shape[1]
         rows = jnp.arange(qv.shape[0])
         at = (bt[rows, (cl // page).astype(jnp.int32)],
@@ -1089,13 +1115,13 @@ def paged_cache_sparse_latent_update_attend(entry: LatentCacheEntry, q, row,
         pages2 = pages.at[at].set(latent_rows(rv[:, 0], pages))
         ip2 = ip.at[at].set(latent_rows(kiv[:, 0], ip))
         out, keep = paged_sparse_latent_attention(
-            qv[:, 0], pages2, ip2, qiv[:, 0], wv[:, 0], bt, cl + 1, topk,
-            scale)
+            qv[:, 0], pages2, ip2, qiv[:, 0], wv[:, 0], bt,
+            attend_lens(cl, 1, live), topk, scale)
         return (out[:, None].astype(qv.dtype), pages2, ip2,
                 jnp.sum(keep, axis=1, dtype=jnp.int32))
 
     out, pages2, ip2, n_sel = apply(
         fn, entry.pages, entry.index_pages, entry.block_table,
-        entry.context_lens, q, row, qi, w, ki,
+        entry.context_lens, q, row, qi, w, ki, entry.live,
         _name="paged_sparse_latent_attention_decode")
     return out, entry._replace(pages=pages2, index_pages=ip2), n_sel

@@ -741,6 +741,9 @@ class ContinuousBatchingPredictor:
         self._m_rej = _obsm.counter("serving.rejected_requests")
         self._m_done = _obsm.counter("serving.completed_requests")
         self._m_steps = _obsm.counter("serving.decode_steps")
+        # slots that rode a decode step (or a self-drafting tick) with
+        # no request
+        self._m_idle = _obsm.counter("serving.idle_slot_steps")
         self._m_ttft = _obsm.histogram("serving.ttft_seconds", unit="s")
         self._m_tok = _obsm.histogram("serving.token_latency_seconds",
                                       unit="s")
@@ -1275,31 +1278,35 @@ class ContinuousBatchingPredictor:
 
     def _step_cache(self, kl, vl, tables, ctx, meta):
         """The decode step's `past_key_values`: one entry a layer, of
-        its kind. A model that counts what its tokens do (recurrent
-        layers, step counters) is also told which rows carry a request
-        (an empty slot's table is all trash)."""
+        its kind. Every page entry is told which slots carry a request
+        (`live`: an empty slot's table is all trash). An empty slot
+        still WRITES its token, at the `ctx` of 1 the loop keeps for it,
+        which lands on the trash page; under the contracts that read
+        `live` (an indexer's, latent pages') it READS nothing: they
+        give it a length of 0 (`generation.kv_cache.attend_lens`), at
+        which the slot-walk kernels fetch and contract no block, where
+        a length of 2 cost a whole one. A model that counts what its
+        tokens do (recurrent layers, step counters) gets the same rows
+        as the cache's `active`."""
         from ..generation.kv_cache import (LatentCacheEntry, PagedCacheEntry,
                                            PagedKVCache, StateCacheEntry)
         paged = (Tensor(tables), Tensor(ctx), meta)
+        live = tables[:, 0] != jnp.int32(self._trash)
 
         def entry(i, c):
             if c.kind == "state":
                 return StateCacheEntry(kl[i], vl[i])
-            if c.kind == "latent" and c.index_dim:
-                return LatentCacheEntry(kl[i][0], *paged[:2],
-                                        index_pages=kl[i][1])
+            pages, index = kl[i] if c.index_dim else (kl[i], None)
             if c.kind == "latent":
-                return LatentCacheEntry(kl[i], *paged[:2])
-            if c.index_dim:
-                return PagedCacheEntry(kl[i][0], vl[i], *paged,
-                                       index_pages=kl[i][1])
-            return PagedCacheEntry(kl[i], vl[i], *paged)
+                return LatentCacheEntry(pages, *paged[:2],
+                                        index_pages=index, live=live)
+            return PagedCacheEntry(pages, vl[i], *paged, index_pages=index,
+                                   live=live)
 
         entries = [entry(i, c) for i, c in enumerate(self._layout)]
         if self.state_pool is None and not self._step_counters:
             return PagedKVCache(entries)
-        return PagedKVCache(entries,
-                            active=tables[:, 0] != jnp.int32(self._trash))
+        return PagedKVCache(entries, active=live)
 
     def _step_caches_out(self, caches):
         """A decode step's updated caches, as the two operand lists."""
@@ -2108,7 +2115,12 @@ class ContinuousBatchingPredictor:
         slot_ingested = [0] * self.B
         tables = np.full((self.B, self.pages_per_seq), self._trash,
                          np.int32)
-        ctx = np.ones((self.B,), np.int32)   # inactive slots: 1 dummy tok
+        # an inactive slot keeps a position of 1 on a table that is all
+        # trash: its step's token is written there (the write needs a
+        # place, and the host-metadata kernels of the span programs one
+        # valid entry a slot), but where a contract reads `live` it
+        # attends over nothing (`_step_cache`: its read length is 0)
+        ctx = np.ones((self.B,), np.int32)
         last_tok_host = np.zeros((self.B,), np.int32)
         override = np.zeros((self.B,), bool)  # host token overrides device
         # kept in step on every tick, decode ticks included, so that a
@@ -3056,6 +3068,7 @@ class ContinuousBatchingPredictor:
         ctx[active] += 1
         self.stats["decode_steps"] += 1
         self._m_steps.inc(**self._mlbl)
+        self._m_idle.inc(self.B - len(active), **self._mlbl)
         return {"tok": nxt, "done": done, "snap": snap, "t": t0,
                 "aux": aux}
 
@@ -3294,6 +3307,7 @@ class ContinuousBatchingPredictor:
         self.stats["spec_ticks"] += 1
         self.stats["spec_proposed"] += len(active)
         self._m_steps.inc(**self._mlbl)
+        self._m_idle.inc(self.B - len(active), **self._mlbl)
         self._m_spec_prop.inc(len(active), **self._mlbl)
         return {"spec": True, "span": span_next, "acc": accepted,
                 "ctx_next": ctx_next, "t": t0, "aux": (aux,),

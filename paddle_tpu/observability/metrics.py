@@ -14,6 +14,11 @@ work treats these as first-class). Design constraints:
   overhead, asserted by tests/test_observability.py.
 - Exporters (exporters.py) pull from `collect()`; recording never
   blocks on I/O.
+- Re-entrant locks: a garbage collection starts between two bytecodes
+  of whatever the thread is doing, inside one of these critical
+  sections too, and its callback records into this registry
+  (runtime.py `_on_gc`), as may a finalizer it runs. Under a plain
+  lock that thread then waits for itself for ever.
 """
 from __future__ import annotations
 
@@ -36,6 +41,8 @@ DEFAULT_BUCKETS = (
     1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2, 5e-2,
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 )
+
+_Lock = threading.RLock  # every lock here: see "Re-entrant locks" above
 
 _RAW_CAP = 2048  # per-series reservoir for exact quantiles
 _EXEMPLAR_CAP = 4  # per-series tail exemplars (largest observations)
@@ -105,7 +112,7 @@ class _Metric:
         self.name = name
         self.help = help
         self.unit = unit
-        self._lock = threading.Lock()
+        self._lock = _Lock()
         self._series: Dict[Tuple, object] = {}
         if registry is not None:
             registry._register(self)
@@ -146,7 +153,7 @@ class _CounterSeries:
     def __init__(self):
         self._value = 0.0
         self._labels = {}
-        self._lock = threading.Lock()
+        self._lock = _Lock()
 
     def inc(self, amount: float = 1.0):
         if not _state.enabled:
@@ -187,7 +194,7 @@ class _GaugeSeries:
     def __init__(self):
         self._value = 0.0
         self._labels = {}
-        self._lock = threading.Lock()
+        self._lock = _Lock()
 
     def set(self, value: float):
         if not _state.enabled:
@@ -246,7 +253,7 @@ class _HistogramSeries:
         # carried a trace id — the forensic bridge from an aggregate
         # upper quantile to the exact requests behind it
         self._exemplars: List[Tuple[float, str]] = []
-        self._lock = threading.Lock()
+        self._lock = _Lock()
 
     def observe(self, value: float, exemplar: Optional[str] = None):
         if not _state.enabled:
@@ -361,7 +368,7 @@ class MetricRegistry:
     for exporters, reset between runs."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = _Lock()
         self._metrics: Dict[str, _Metric] = {}
 
     def _register(self, metric: _Metric):

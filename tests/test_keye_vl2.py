@@ -204,6 +204,32 @@ def test_decode_selects_the_references_sets(model, reference, monkeypatch):
         assert not keep[n_keys:].any() and keep.sum() == TOPK
 
 
+def test_idle_slots_attend_over_nothing(model, reference, monkeypatch):
+    """One request in a predictor of four slots: every decode step hands
+    the kernels the request's keys and a length of 0 for the three slots
+    that carry none, and the served tokens are the reference's."""
+    seen = []
+    real = pa.paged_sparse_attention
+
+    def spy(q, k_pages, v_pages, index_pages, qi, w, tables, lens, *rest):
+        jax.debug.callback(lambda n: seen.append(np.asarray(n)), lens,
+                           ordered=True)
+        return real(q, k_pages, v_pages, index_pages, qi, w, tables, lens,
+                    *rest)
+
+    monkeypatch.setattr(pa, "paged_sparse_attention", spy)
+    prompt = _prompts([19], stream=16)[0]
+    pred, outs = _served(model, [prompt], max_new=9)
+    jax.effects_barrier()
+    rec = served_tokens.compare(reference, CFG, SEED, [(prompt, outs[0])],
+                                TIGHT, 1)
+    assert rec["correct"], rec
+    layers = CFG["num_hidden_layers"]
+    assert len(seen) == pred.stats["decode_steps"] * layers >= 8 * layers
+    for call, lens in enumerate(seen):
+        assert sorted(lens.tolist()) == [0, 0, 0, 20 + call // layers]
+
+
 def test_short_contexts_are_plain_gqa(builder, reference):
     """At most `topk` keys everywhere: the indexer changes nothing, and
     the model is the same model with dense causal attention."""

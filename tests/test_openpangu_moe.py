@@ -250,6 +250,47 @@ def test_verify_logits_are_the_references(model, reference, monkeypatch):
         assert np.abs(got[i] - want[i]).max() < 2e-5 * np.abs(want).max()
 
 
+def test_idle_slots_ride_every_tick_and_are_counted(model, reference, check,
+                                                    monkeypatch):
+    """One request in a predictor of four slots, the drafter on: what
+    is served and drafted is the reference's, `serving.idle_slot_steps`
+    counts the three empty slots of every tick, and the span kernel is
+    handed, for them, what its contract gives an idle slot: 0 if it
+    reads `live`, else their `ctx` of 1 and the span's two rows
+    (`IGNORES_LIVE` in tests/test_idle_slot_lengths.py says which)."""
+    from tests.test_idle_slot_lengths import IGNORES_LIVE
+    idle_len = 3 if "paged_cache_latent_span_update_attend" \
+        in IGNORES_LIVE else 0
+    both, served_tokens = check
+    seen = []
+    real = la.paged_latent_attention
+
+    def spy(q, pages, tables, lens, *rest, **kw):
+        jax.debug.callback(lambda n: seen.append(np.asarray(n)), lens,
+                           ordered=True)
+        return real(q, pages, tables, lens, *rest, **kw)
+
+    monkeypatch.setattr(la, "paged_latent_attention", spy)
+    prompt = _prompts([21], stream=16)[0]
+    pred = ContinuousBatchingPredictor(model, **ON)
+    idle0 = metrics.counter("serving.idle_slot_steps").value()
+    served, drafted, _ = _streamed(pred, [prompt], max_new=8)
+    jax.effects_barrier()
+    assert metrics.counter("serving.idle_slot_steps").value() - idle0 \
+        == 3 * pred.stats["spec_ticks"]
+    rec = both.compare(reference, served_tokens, CFG, SEED,
+                       [(prompt, served[0], drafted[0])], TIGHT, 1)
+    assert rec["correct"], rec
+    calls = CFG["num_hidden_layers"] + 1      # a tick's span kernels
+    assert len(seen) == pred.stats["spec_ticks"] * calls >= 7 * calls
+    for lens in seen:
+        assert sorted(lens.tolist())[:3] == [idle_len] * 3
+    # a tick a token (nothing accepted), then one junk tick in flight
+    assert pred.stats["spec_accepted"] == 0
+    assert [int(lens.max()) for lens in seen[::calls]][:7] == \
+        list(range(23, 30))
+
+
 @pytest.fixture(scope="module")
 def small_runs(small):
     """The small-vocabulary model over eight prompts, drafter off and

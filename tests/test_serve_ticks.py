@@ -81,6 +81,48 @@ class TestTicks:
         starts = [t["t0"] for t in ticks]
         assert starts == sorted(starts)
 
+    def test_idle_slot_steps_are_the_slots_less_the_active_of_each_step(
+            self, monkeypatch):
+        """`serving.idle_slot_steps`: the slots that rode a dispatched
+        decode step with no request, and `tools/serve_account.py` prints
+        it beside the steps."""
+        import importlib.util
+        from paddle_tpu.inference import ContinuousBatchingPredictor
+        rode = []
+        real = ContinuousBatchingPredictor._dispatch_step
+
+        def spy(self, active, *args, **kw):
+            rode.append(len(active))
+            return real(self, active, *args, **kw)
+
+        monkeypatch.setattr(ContinuousBatchingPredictor, "_dispatch_step",
+                            spy)
+        read = lambda name: obs.counter(name).value(replica="idle0")
+        cb = _predictor(max_batch_size=4, name="idle0")
+        assert read("serving.idle_slot_steps") == 0
+        # five requests over four slots: the fifth decodes alone at the end
+        cb.generate(_prompts(lens=(5, 11, 3, 9, 7)), max_new_tokens=5)
+        assert len(rode) == cb.stats["decode_steps"] \
+            == read("serving.decode_steps")
+        assert 1 in rode and 4 in rode
+        assert read("serving.idle_slot_steps") == sum(4 - n for n in rode) > 0
+        # a step is dispatched on a tick that has active slots, not on all
+        assert read("serving.idle_slot_steps") <= sum(
+            4 - t["active"] for t in tr.ticks() if t["active"])
+        spec = importlib.util.spec_from_file_location(
+            "serve_account", os.path.join(os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__))),
+                "tools", "serve_account.py"))
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+        total = lambda name: sum(
+            s.value for s in obs.counter(name).samples())
+        assert tool.decode_steps() == {
+            "steps": total("serving.decode_steps"),
+            "idle_slot_steps": total("serving.idle_slot_steps")}
+        assert tool.decode_steps()["idle_slot_steps"] >= sum(
+            4 - n for n in rode)
+
     def test_window_cut(self):
         cb = _predictor()
         cb.generate(_prompts(), max_new_tokens=4)
@@ -485,6 +527,33 @@ class TestGcLog:
         assert len(obsrt.gc_log()) == n + 1
         assert e["generation"] == 1 and e["collected"] == 5
         assert 0.002 <= e["seconds"] < 0.1
+
+    @pytest.mark.parametrize("held", ["registry", "family", "series"])
+    def test_a_collection_met_inside_the_registry_does_not_wait_for_itself(
+            self, held, monkeypatch):
+        """A collection starts between two bytecodes of whatever the
+        thread does, inside the registry's critical sections too, and
+        its callback counts into the registry: under plain locks the
+        thread waited for itself for ever (tier-1 runs that never
+        ended: PRs 40 and 43). On a registry of its own, in a thread of
+        its own, so that a fault costs this test and not the process."""
+        import gc
+        from paddle_tpu.observability.metrics import MetricRegistry
+        reg = MetricRegistry()
+        monkeypatch.setattr(obsrt, "get_registry", lambda: reg)
+        family = reg.counter("host.gc_collections")
+        lock = {"registry": reg, "family": family,
+                "series": family.labels(generation="2")}[held]._lock
+
+        def collect_inside():
+            with lock:
+                gc.collect()
+
+        t = threading.Thread(target=collect_inside, daemon=True)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert family.value(generation="2") >= 1
 
     def test_hook_registered_once(self):
         import gc

@@ -5,7 +5,8 @@ and padded, tokens handed out, stalled tokens), the gc log, and how they
 agree with what the runner counted and, in a traced run, with the
 device's time in the prefill programs; for a model with an indexer, how
 many of the prefill kernel's key blocks held a real key
-(`dsa.prefill_key_blocks`).
+(`dsa.prefill_key_blocks`); the decode steps dispatched and the slots
+that rode them with no request (`serving.idle_slot_steps`).
 
     python3 tools/serve_account.py --workload <name> --seed <n> \\
         --seconds <s> --trace <0|1> [--out <file.json>]
@@ -160,6 +161,20 @@ def prefill_key_blocks():
     return dict(n, share=n.get("attended", 0) / n["bucket"])
 
 
+def decode_steps():
+    """`serving.decode_steps` beside `serving.idle_slot_steps` over the
+    whole run: the steps dispatched and the slots that rode them with no
+    request (None on a tree that does not count them)."""
+    from paddle_tpu.observability import metrics
+
+    def total(name):
+        m = metrics.get_registry().get(name)
+        return None if m is None else sum(s.value for s in m.samples())
+
+    return {"steps": total("serving.decode_steps"),
+            "idle_slot_steps": total("serving.idle_slot_steps")}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -207,6 +222,7 @@ def main(argv=None):
             "prompt_tokens_sent", "pred_stats", "pred_stats_window")},
         "metrics": rec["metrics"], "timings": rec.get("timings"),
         "prefill_key_blocks": prefill_key_blocks(),
+        "decode_steps": decode_steps(),
     }
     if rec.get("runner") == "serve_open":
         account["runner"]["sample_prompt_tokens"] = sum(

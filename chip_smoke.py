@@ -260,7 +260,6 @@ def serve_phase(*, config, seed=0, dtype="bfloat16", max_batch_size=8,
     rec["completed"] = sum(s == "ok" for s in statuses)
     rec["statuses"] = statuses
     rec["tokens_generated"] = sum(len(g) for g in got)
-    rec["use_ragged"] = bool(pred.use_ragged)
     rec["decode_kernels_traced"] = _decode_kernels_since(dk0)
     rec["pallas_fallbacks"] = _fallbacks() - fb0
     rec["kv_pool_bytes"] = int(sum(a.nbytes for a in pred.pool.k + pred.pool.v))
@@ -283,7 +282,7 @@ def serve_phase(*, config, seed=0, dtype="bfloat16", max_batch_size=8,
     try:
         t0 = time.perf_counter()
         oracle = ContinuousBatchingPredictor(
-            model, use_ragged=False, enable_prefix_cache=False, **geometry)
+            model, enable_prefix_cache=False, **geometry)
         want = oracle.generate(prompts, max_new_tokens=new_tokens)
         rec["oracle_seconds_incl_compile"] = round(
             time.perf_counter() - t0, 2)
@@ -507,7 +506,6 @@ def tp_serve_phase(*, config, seed=0, dtype="bfloat16", tp=4,
     cb = ContinuousBatchingPredictor(model, tp_degree=tp, **geometry)
     got = cb.generate(prompts, max_new_tokens=new_tokens)
     rec["tp_seconds_incl_compile"] = round(time.perf_counter() - t0, 2)
-    rec["use_ragged"] = bool(cb.use_ragged)
     rec["decode_kernels_traced"] = _decode_kernels_since(dk0)
     rec["pallas_fallbacks"] = _fallbacks() - fb0
     rec["tp_devices"] = [d.id for d in cb.tp_devices]

@@ -774,9 +774,8 @@ class PagedCacheEntry(NamedTuple):
     `block_table`: [B, pages_per_seq] int32 page ids per slot;
     `context_lens`: [B] int32 tokens already cached per slot (BEFORE the
     token being decoded). `ragged_meta` (optional): host-built metadata
-    from kernels.paged_attention.build_ragged_meta for the POST-write
-    lengths (context_lens + 1) — when present, attention runs the
-    ragged-grid kernel (only valid (seq, page) pairs enter the grid).
+    from kernels.paged_attention.build_ragged_meta, read by a step with
+    `q_lens` only (a single-token decode step attends by block table).
 
     `q_lens` (optional, [B] int32): per-slot QUERY SPAN lengths for the
     MIXED prefill+decode step — slot b's forward carries q_lens[b]
@@ -884,16 +883,13 @@ def paged_cache_update_attend(entry: PagedCacheEntry, q, k, v, scale=None):
     path)."""
     import jax.numpy as jnp
     from ..ops._dispatch import apply
-    from ..kernels.paged_attention import (paged_attention,
-                                           paged_attention_ragged)
+    from ..kernels.paged_attention import paged_attention
 
     if entry.q_lens is not None:
         # mixed prefill+decode step: variable-length query spans
         return paged_cache_mixed_update_attend(entry, q, k, v, scale)
 
-    meta = entry.ragged_meta
-
-    def fn(kp, vp, bt, cl, qv, kv, vv, *meta_arrs):
+    def fn(kp, vp, bt, cl, qv, kv, vv):
         bsz = qv.shape[0]
         page = kp.shape[1]
         rows = jnp.arange(bsz)
@@ -901,21 +897,12 @@ def paged_cache_update_attend(entry: PagedCacheEntry, q, k, v, scale=None):
         off = (cl % page).astype(jnp.int32)
         kp2 = kp.at[pidx, off].set(kv[:, 0].astype(kp.dtype))
         vp2 = vp.at[pidx, off].set(vv[:, 0].astype(vp.dtype))
-        if meta_arrs:
-            mk = dict(zip(("seq", "page", "ordinal", "first", "last",
-                           "valid"), meta_arrs))
-            out = paged_attention_ragged(qv[:, 0], kp2, vp2, cl + 1, mk,
-                                         scale)
-        else:
-            out = paged_attention(qv[:, 0], kp2, vp2, bt, cl + 1, scale)
+        out = paged_attention(qv[:, 0], kp2, vp2, bt, cl + 1, scale)
         return out[:, None].astype(qv.dtype), kp2, vp2
 
-    extra = () if meta is None else tuple(
-        meta[k] for k in ("seq", "page", "ordinal", "first", "last",
-                          "valid"))
     out, kp2, vp2 = apply(fn, entry.k_pages, entry.v_pages,
                           entry.block_table, entry.context_lens, q, k, v,
-                          *extra, _name="paged_attention_decode")
+                          _name="paged_attention_decode")
     new_entry = PagedCacheEntry(kp2, vp2, entry.block_table,
                                 entry.context_lens, entry.ragged_meta)
     return out, new_entry

@@ -493,6 +493,13 @@ class ContinuousBatchingPredictor:
     Greedy decoding (argmax), matching model.generate's default.
     """
 
+    # Tombstone of a deleted option, accepted and ignored by the
+    # constructor: benchmarks/rehearse.py:102 passes the keyword, and
+    # rehearse.py:118, runners/serve_open.py:129 and
+    # runners/serve_closed.py:140 read the attribute (as does an AOT
+    # bundle manifest written with the key). Goes when they do.
+    use_ragged = property(lambda self: False)
+
     def __init__(self, model, max_batch_size=None, page_size=None,
                  num_pages=None, max_seq_len=None, pad_token_id=0,
                  eos_token_id=None, kv_dtype=None, use_ragged="auto",
@@ -837,36 +844,29 @@ class ContinuousBatchingPredictor:
         self.sampling_enabled = bool(sampling_enabled)
         # which paged kernel a program attends through. The single-token
         # decode programs take the block-table kernel (`paged_attention`:
-        # live pages by its own DMAs, any group ratio, no host metadata)
-        # unless `use_ragged=True` is set by hand, which keeps them on
-        # `paged_attention_ragged` and its metadata operands. The
-        # programs with a query span (mixed, verify) ride the ragged varq
-        # kernel, which needs the metadata and MHA: "auto" gives it to
-        # them when a Pallas path exists, such a program is compiled in
-        # and the varq gate admits the head geometry (kernels.
+        # live pages by its own DMAs, any group ratio, no host metadata).
+        # The programs with a query span (mixed, verify) ride the ragged
+        # varq kernel, which needs the metadata and MHA: they get it
+        # when a Pallas path exists, such a program is compiled in and
+        # the varq gate admits the head geometry (kernels.
         # paged_attention.paged_gate_reason: H == Hkv, D % 128 == 0, 8
         # heads a shard); else they attend through the XLA varq path.
         # The metadata grid is the constant B * pages_per_seq, so every
         # step reuses one compile.
+        from ..kernels._common import (use_pallas as _use_pallas,
+                                       pallas_interpret)
+        from ..kernels.paged_attention import paged_gate_reason
         span = max(self._chunk_max, self._spec_k + 1 if self._spec_k else 0)
-        if use_ragged == "auto":
-            from ..kernels._common import (use_pallas as _use_pallas,
-                                           pallas_interpret)
-            from ..kernels.paged_attention import paged_gate_reason
-            self.use_ragged = False
-            self.span_ragged = (
-                span > 1 and bool(kv_shapes)
-                and (_use_pallas() or pallas_interpret())
-                and paged_gate_reason(
-                    "paged_attention_ragged_varq", cfg.num_attention_heads,
-                    cfg.num_key_value_heads, head_dim, self.tp) is None)
-        else:
-            self.use_ragged = self.span_ragged = bool(use_ragged)
+        self.span_ragged = (
+            span > 1 and bool(kv_shapes)
+            and (_use_pallas() or pallas_interpret())
+            and paged_gate_reason(
+                "paged_attention_ragged_varq", cfg.num_attention_heads,
+                cfg.num_key_value_heads, head_dim, self.tp) is None)
         # the varq kernel's VMEM need grows with the span bucket: refuse
         # a bucket the TPU compiler would refuse, here and by name,
         # instead of at the first long prompt
-        if self.span_ragged and span > 1:
-            from ..kernels._common import pallas_interpret
+        if self.span_ragged:
             from ..kernels.paged_attention import max_varq_span
             fit = max_varq_span(cfg.num_attention_heads // self.tp,
                                 head_dim, self.page,
@@ -1044,25 +1044,6 @@ class ContinuousBatchingPredictor:
                 "handed to the serve loop through the trie")
         return self.pool.import_span(span, self.prefix_cache)
 
-    def export_request_span(self, prompt):
-        """Deprecated alias for :meth:`export_page_span`. The method
-        serializes a KV *page* span; it was renamed so request tracing
-        *spans* (observability.tracing) don't collide with it."""
-        import warnings
-        warnings.warn(
-            "export_request_span is renamed export_page_span",
-            DeprecationWarning, stacklevel=2)
-        return self.export_page_span(prompt)
-
-    def import_request_span(self, span):
-        """Deprecated alias for :meth:`import_page_span` (see
-        :meth:`export_request_span` for the rename rationale)."""
-        import warnings
-        warnings.warn(
-            "import_request_span is renamed import_page_span",
-            DeprecationWarning, stacklevel=2)
-        return self.import_page_span(span)
-
     def _bucket_len(self, n):
         """Admission prompt bucket: smallest tuned-table entry covering
         n (RuntimeConfig.prompt_buckets), else the historical
@@ -1228,22 +1209,15 @@ class ContinuousBatchingPredictor:
     def lower_decode_step(self):
         """The greedy decode step, lowered for this predictor's weights,
         pool and batch geometry exactly as the serve loop dispatches it
-        (ragged metadata included under a hand-set `use_ragged=True`),
         without running it: ``.as_text()`` shows whether the paged
         kernel is in the program, ``.compile()`` gives its memory
         analysis and the collectives a tensor-parallel replica got."""
         self._ensure_ready()
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-        meta = ()
-        if self.use_ragged:
-            from ..kernels.paged_attention import RaggedMetaBuilder
-            meta = tuple(i32(self.B * self.pages_per_seq)
-                         for _ in RaggedMetaBuilder.FIELDS)
         with self._trace_lock, self._kernel_scope():
             return self._decode_jit.lower(
                 self._p_vals, self._b_vals, *self._cache_args(),
-                i32(self.B, self.pages_per_seq), i32(self.B), i32(self.B),
-                *meta)
+                i32(self.B, self.pages_per_seq), i32(self.B), i32(self.B))
 
     # The serve programs take the caches as two lists in layer order
     # (donated): for a "kv" layer its K and V pages, for a "state" layer
@@ -1276,7 +1250,7 @@ class ContinuousBatchingPredictor:
             self.state_pool.ssm = [a for a, k in zip(second, kinds)
                                    if k == "state"]
 
-    def _step_cache(self, kl, vl, tables, ctx, meta):
+    def _step_cache(self, kl, vl, tables, ctx):
         """The decode step's `past_key_values`: one entry a layer, of
         its kind. Every page entry is told which slots carry a request
         (`live`: an empty slot's table is all trash). An empty slot
@@ -1290,7 +1264,7 @@ class ContinuousBatchingPredictor:
         as the cache's `active`."""
         from ..generation.kv_cache import (LatentCacheEntry, PagedCacheEntry,
                                            PagedKVCache, StateCacheEntry)
-        paged = (Tensor(tables), Tensor(ctx), meta)
+        paged = (Tensor(tables), Tensor(ctx))
         live = tables[:, 0] != jnp.int32(self._trash)
 
         def entry(i, c):
@@ -1298,7 +1272,7 @@ class ContinuousBatchingPredictor:
                 return StateCacheEntry(kl[i], vl[i])
             pages, index = kl[i] if c.index_dim else (kl[i], None)
             if c.kind == "latent":
-                return LatentCacheEntry(pages, *paged[:2],
+                return LatentCacheEntry(pages, *paged,
                                         index_pages=index, live=live)
             return PagedCacheEntry(pages, vl[i], *paged, index_pages=index,
                                    live=live)
@@ -1450,23 +1424,19 @@ class ContinuousBatchingPredictor:
         return nexts, new_k, new_v
 
     def _raw_decode_step(self, p_vals, b_vals, kl, vl, tables, ctx,
-                         last_tok, *meta_flat):
+                         last_tok):
         """ONE compiled decode step for all slots: paged cache write +
         paged attention + greedy argmax + eos detection, all on device.
         Returns (next_token [B] int32, done [B] bool, new_k, new_v) —
         the host fetches only the two small vectors, and only AFTER
         dispatching the next step (double buffering)."""
         from ..jit.bridge import bound_state
-        meta = None
-        if meta_flat:
-            from ..kernels.paged_attention import RaggedMetaBuilder
-            meta = dict(zip(RaggedMetaBuilder.FIELDS, meta_flat))
         with no_grad(), bound_state(self._p_tensors, p_vals,
                                     self._b_tensors, b_vals):
             logits, caches = self.model(
                 Tensor(last_tok[:, None]),
                 position_ids=Tensor(ctx[:, None]),
-                past_key_values=self._step_cache(kl, vl, tables, ctx, meta),
+                past_key_values=self._step_cache(kl, vl, tables, ctx),
                 use_cache=True)
         nxt = jnp.argmax(logits._value[:, -1], axis=-1).astype(jnp.int32)
         if self.eos_token_id is not None:
@@ -1531,7 +1501,7 @@ class ContinuousBatchingPredictor:
 
     def _raw_decode_sample_step(self, p_vals, b_vals, kl, vl, tables,
                                 ctx, last_tok, s_temp, s_topk, s_topp,
-                                s_seed, s_ctr, *meta_flat):
+                                s_seed, s_ctr):
         """The sampling variant of THE decode step: identical cache
         write + paged attention, but the next token comes from the
         on-device sampling kernel (generation.sampling.sample_tokens)
@@ -1542,16 +1512,12 @@ class ContinuousBatchingPredictor:
         compiled program serves any greedy/sampled tenant mix."""
         from ..jit.bridge import bound_state
         from ..generation import sampling as _samp
-        meta = None
-        if meta_flat:
-            from ..kernels.paged_attention import RaggedMetaBuilder
-            meta = dict(zip(RaggedMetaBuilder.FIELDS, meta_flat))
         with no_grad(), bound_state(self._p_tensors, p_vals,
                                     self._b_tensors, b_vals):
             logits, caches = self.model(
                 Tensor(last_tok[:, None]),
                 position_ids=Tensor(ctx[:, None]),
-                past_key_values=self._step_cache(kl, vl, tables, ctx, meta),
+                past_key_values=self._step_cache(kl, vl, tables, ctx),
                 use_cache=True)
         nxt, _ = _samp.sample_tokens(logits._value[:, -1], s_temp,
                                      s_topk, s_topp, s_seed, s_ctr)
@@ -1695,7 +1661,7 @@ class ContinuousBatchingPredictor:
             + jnp.arange(2, dtype=jnp.int32)[None, :]
         with no_grad(), bound_state(self._p_tensors, p_vals,
                                     self._b_tensors, b_vals):
-            cache = self._step_cache(kl, vl, tables, ctx, None)
+            cache = self._step_cache(kl, vl, tables, ctx)
             logits, caches = self.model(
                 Tensor(span_ids), position_ids=Tensor(pos),
                 past_key_values=cache, use_cache=True)
@@ -3027,14 +2993,10 @@ class ContinuousBatchingPredictor:
         raw argmax in-graph, token-identical to the plain program)."""
         import time as _time
         t0 = _time.perf_counter()
-        meta_args = ()
         if builder is not None:
+            # a later mixed or verify tick reads the metadata
             for b in active:
                 builder.advance_slot(b, int(ctx[b]) + 1)
-        if self.use_ragged:
-            m = builder.meta()
-            from ..kernels.paged_attention import RaggedMetaBuilder
-            meta_args = tuple(m[k].copy() for k in RaggedMetaBuilder.FIELDS)
         if inflight is None:
             tok_in = jnp.asarray(last_tok_host.copy())
         else:
@@ -3044,24 +3006,23 @@ class ContinuousBatchingPredictor:
         tok_in = self._place(tok_in)
         override[:] = False
         # .copy(): the CPU backend may alias numpy memory zero-copy into
-        # the device buffer, and the host mutates tables/ctx/meta in
+        # the device buffer, and the host mutates tables/ctx in
         # place while this step is still in flight (double buffering) —
-        # snapshot them at dispatch
+        # snapshot them at dispatch. The signatures keep the empty third
+        # part where the span programs' carry their metadata shapes: they
+        # are the AOT bundles' table keys
         if samp is not None:
             st, sk, sp_, ss, sc = samp
             nxt, done, new_k, new_v, *aux = self._jit_call(
-                ("decode_sample", tables.shape,
-                 tuple(np.shape(m) for m in meta_args)),
+                ("decode_sample", tables.shape, ()),
                 self._decode_sample_jit,
                 self._p_vals, self._b_vals, *self._cache_args(),
-                tables.copy(), ctx.copy(), tok_in, st, sk, sp_, ss, sc,
-                *meta_args)
+                tables.copy(), ctx.copy(), tok_in, st, sk, sp_, ss, sc)
         else:
             nxt, done, new_k, new_v, *aux = self._jit_call(
-                ("decode", tables.shape,
-                 tuple(np.shape(m) for m in meta_args)), self._decode_jit,
+                ("decode", tables.shape, ()), self._decode_jit,
                 self._p_vals, self._b_vals, *self._cache_args(),
-                tables.copy(), ctx.copy(), tok_in, *meta_args)
+                tables.copy(), ctx.copy(), tok_in)
         self._cache_store(new_k, new_v)
         self._tp_account(self.B)
         snap = [(b, slot_req[b]) for b in active]

@@ -49,7 +49,7 @@ _logger = logging.getLogger("paddle_tpu.aot")
 # cache, queue/shed/watchdog knobs) is runtime-only and never does.
 COMPILED_GEOMETRY_KEYS = frozenset({
     "max_batch_size", "page_size", "max_seq_len", "num_pages",
-    "pad_token_id", "eos_token_id", "kv_dtype", "use_ragged",
+    "pad_token_id", "eos_token_id", "kv_dtype",
     # chunked prefill: the mixed-step programs' span buckets derive
     # from it, so a different threshold means different executables
     "prefill_chunk_tokens",

@@ -89,10 +89,11 @@ def _paged_gate(kernel, q, k_pages, v_pages, interpret, tp_degree=None):
 
 def _note_decode_kernel(kernel):
     """Count which kernel a single-token decode attention was traced
-    with: ``kernels.paged_decode{kernel}``, `kernel` one of
-    "paged_attention", "paged_attention_ragged" or "xla". Like
-    `note_fallback` it runs at trace time only, once a layer of each
-    compiled decode program, and adds nothing to the program."""
+    with: ``kernels.paged_decode{kernel}``, `kernel` the Pallas
+    kernel's own name ("paged_attention", or a masked or latent one's)
+    or "xla". Like `note_fallback` it runs at trace time only, once a
+    layer of each compiled decode program, and adds nothing to the
+    program."""
     from ..observability import metrics as _obsm
     _obsm.counter("kernels.paged_decode").inc(kernel=kernel)
 
@@ -572,20 +573,9 @@ def paged_sparse_attention(q, k_pages, v_pages, index_pages, qi, w,
 
 
 # ---------------------------------------------------------------------------
-# Ragged variant: the grid runs over the (sequence, page) pairs of
-# host-built metadata (build_ragged_meta / RaggedMetaBuilder), which
-# enters via scalar prefetch (cf. PAPERS.md "Ragged Paged Attention").
-#
-# Who still calls it: the serve loop's decode programs only under a
-# hand-set `use_ragged=True` (tools/chip_rehearsal.py, benchmarks/
-# rehearse.py, tests). `use_ragged="auto"` decodes every geometry
-# through the block-table kernel above, which reads live pages only and
-# contracts on the MXU; this one fetches a block every grid step of the
-# constant B * pages_per_seq grid and scores on the VPU in float32 (on a
-# v5e at 32 slots of 32 heads of 128 it reached a third of the live
-# bytes' roofline: PERF.md). The METADATA lives on: the variable-query
-# kernel below, which the mixed and verify steps ride, has no
-# block-table form.
+# Ragged metadata: the (sequence, page) pairs a grid runs over, built on
+# the host and scalar-prefetched by `paged_attention_ragged_varq` (the
+# mixed and verify steps), which has no block-table form.
 # ---------------------------------------------------------------------------
 
 def build_ragged_meta(block_tables, context_lens, page_size, bucket_to=None):
@@ -733,101 +723,6 @@ class RaggedMetaBuilder:
         return {"seq": self.seq, "page": self.page_ids,
                 "ordinal": self.ordinal, "first": self.first,
                 "last": self.last, "valid": self.valid}
-
-
-def _ragged_kernel(seq_ref, page_ref, ord_ref, first_ref, last_ref,
-                   valid_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, page_size):
-    g = pl.program_id(0)
-
-    @pl.when(first_ref[g] == 1)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(valid_ref[g] == 1)
-    def _compute():
-        ctx = lens_ref[seq_ref[g]]
-        q = q_ref[0].astype(jnp.float32)   # (H, D)
-        k = k_ref[0].astype(jnp.float32)   # (page, H, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.sum(q[None, :, :] * k, axis=-1) * np.float32(scale)
-        tok = ord_ref[g] * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(tok < ctx, s, _NEG_INF)
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        p = jnp.exp(s - m_new[None, :])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0] * alpha + jnp.sum(p, axis=0)
-        acc_scr[:] = (acc_scr[:] * alpha[:, None]
-                      + jnp.sum(p[:, :, None] * v, axis=0))
-        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
-
-    @pl.when(last_ref[g] == 1)
-    def _finalize():
-        l = l_scr[:, 0]
-        safe_l = jnp.where(l == np.float32(0.0), np.float32(1.0), l)
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
-
-
-def paged_attention_ragged(q, k_pages, v_pages, context_lens, meta,
-                           scale=None, interpret=False):
-    """Ragged-grid paged decode attention. q: [B, H, D]; meta from
-    build_ragged_meta (same page_size as the pools). Sequences with
-    context_lens == 0 produce zeros. H == Hkv, D % 128 == 0, H % 8 == 0
-    (the fixed-grid `paged_attention` covers the rest)."""
-    sc = scale if scale is not None else 1.0 / pymath.sqrt(q.shape[-1])
-    interpret = interpret or pallas_interpret()
-    lens = jnp.asarray(context_lens, jnp.int32)
-    _note_decode_kernel("paged_attention_ragged")
-    out = partitioned(
-        lambda q_, k_, v_, ln, *m: _paged_attention_ragged_pallas(
-            q_, k_, v_, ln, m, sc, interpret),
-        [_Q_HEADS, _PAGE_HEADS, _PAGE_HEADS] + [None] * 7, _Q_HEADS,
-        q, k_pages, v_pages, lens,
-        *[jnp.asarray(meta[f], jnp.int32) for f in _META_FIELDS])
-    # sequences with no pages never write their output row
-    return jnp.where((lens > 0)[:, None, None], out, 0)
-
-
-def _paged_attention_ragged_pallas(q, k_pages, v_pages, lens, meta, scale,
-                                   interpret):
-    """`meta`: the six ragged arrays in _META_FIELDS order."""
-    b, h, d = q.shape
-    page = k_pages.shape[1]
-    G = int(meta[0].shape[0])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec((1, h, d),
-                         lambda g, sq, pg, od, fr, ls, va, ln: (sq[g], _Z, _Z)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda g, sq, pg, od, fr, ls, va, ln:
-                         (pg[g], _Z, _Z, _Z)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda g, sq, pg, od, fr, ls, va, ln:
-                         (pg[g], _Z, _Z, _Z)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, h, d), lambda g, sq, pg, od, fr, ls, va, ln: (sq[g], _Z, _Z)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_ragged_kernel, scale=scale, page_size=page)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(*meta, lens, q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -1026,10 +921,10 @@ def paged_attention_ragged_varq(q, k_pages, v_pages, kv_lens, q_lens,
                                 meta, scale=None, interpret=False,
                                 block_tables=None):
     """Ragged-grid mixed prefill+decode attention. q: [B, Qb, H, D];
-    `meta` is the same 6-array ragged metadata the decode kernel uses
-    (build_ragged_meta / RaggedMetaBuilder) built for the POST-write
-    kv_lens; kv_lens [B] = q_start + q_lens. Padding query rows and
-    kv_lens == 0 slots produce zeros.
+    `meta` is the 6-array ragged metadata (build_ragged_meta /
+    RaggedMetaBuilder) built for the POST-write kv_lens; kv_lens [B] =
+    q_start + q_lens. Padding query rows and kv_lens == 0 slots produce
+    zeros.
 
     Runs the Pallas kernel under the shared `_paged_gate` (H == Hkv,
     D % 128 == 0, H % 8 == 0, Mosaic dtype); a lost fast path falls

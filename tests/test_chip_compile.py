@@ -153,12 +153,6 @@ def test_paged_attention_block_tables_cells(chip, slots, pps, pool,
         assert not re.search(r"= " + re.escape(shape) + r"\S* copy\(", text)
 
 
-def test_paged_attention_ragged(chip):
-    _compile(chip, lambda q, k, v, ln, *m: pa._paged_attention_ragged_pallas(
-        q, k, v, ln, m, SCALE, False),
-        ((B, H, D), BF16), _pool(), _pool(), ((B,), I32), *_meta())
-
-
 # every span bucket the predictor can request at these widths: chunk
 # buckets page * 2^k up to the VMEM bound, a speculative-verify span
 # (k + 1 = 5), and the single decode token
@@ -228,21 +222,27 @@ def test_xla_block_table_path_keeps_the_table_bf16(chip, span):
     assert max(sizes) == table, max(sizes)
 
 
-def test_predictor_refuses_a_span_over_the_bound():
+def test_predictor_refuses_a_span_over_the_bound(monkeypatch):
     """The bound reaches the user at construction: a chunk size whose
     bucket the kernel cannot hold is a ValueError that names it, not a
     compiler refusal at the first long prompt."""
+    from paddle_tpu.framework.flags import flag_value
     from paddle_tpu.inference import ContinuousBatchingPredictor
+    from paddle_tpu.kernels import _common
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    # the constructor asks the backend and sees the CPU here: the gate
+    # follows the flag alone, as it does on the chip
+    monkeypatch.setattr(_common, "use_pallas",
+                        lambda: bool(flag_value("use_pallas_kernels")))
     cfg = LlamaConfig.tiny(hidden_size=1024, num_attention_heads=8,
                            num_key_value_heads=8, num_hidden_layers=1,
                            intermediate_size=128, vocab_size=64,
                            tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
     fit = pa.max_varq_span(8, 128, 16, 4)
-    geometry = dict(max_batch_size=2, page_size=16, max_seq_len=4 * fit,
-                    use_ragged=True)
-    ContinuousBatchingPredictor(model, prefill_chunk_tokens=fit, **geometry)
+    geometry = dict(max_batch_size=2, page_size=16, max_seq_len=4 * fit)
+    assert ContinuousBatchingPredictor(model, prefill_chunk_tokens=fit,
+                                       **geometry).span_ragged
     with pytest.raises(ValueError, match="max_varq_span"):
         ContinuousBatchingPredictor(model, prefill_chunk_tokens=2 * fit,
                                     **geometry)

@@ -48,7 +48,7 @@ def test_serve_phase_record(interpret):
     assert rec["requests"] == rec["completed"] == 6
     assert rec["tokens_generated"] == 6 * 4
     # "auto" decodes MHA through the block-table kernel, no metadata
-    assert not rec["use_ragged"] and rec["pallas_fallbacks"] == 0
+    assert rec["pallas_fallbacks"] == 0
     assert set(rec["decode_kernels_traced"]) == {"paged_attention"}
     # the prefix cache did its three jobs: a full hit, and two partial
     # hits (one through copy-on-write)

@@ -683,9 +683,9 @@ class TestContinuousBatching:
 
 
 class TestRaggedPagedAttention:
-    """Ragged-grid paged decode kernel (PAPERS.md ragged paged
-    attention): grid over valid (seq, page) pairs only, scalar-prefetch
-    metadata, bucketed entry count."""
+    """The ragged metadata (PAPERS.md ragged paged attention): a grid
+    over valid (seq, page) pairs only, scalar-prefetched, bucketed entry
+    count; read by the variable-query kernel, here at one query a slot."""
 
     def test_parity_with_xla_oracle(self):
         import jax.numpy as jnp
@@ -694,7 +694,7 @@ class TestRaggedPagedAttention:
         set_flags({"use_pallas_kernels": True, "pallas_interpret": True})
         try:
             from paddle_tpu.kernels.paged_attention import (
-                paged_attention_ragged, build_ragged_meta,
+                paged_attention_ragged_varq, build_ragged_meta,
                 _paged_attention_xla)
             rs = np.random.RandomState(1)
             B, H, D, page, P = 5, 8, 128, 16, 40
@@ -712,8 +712,9 @@ class TestRaggedPagedAttention:
             meta = build_ragged_meta(tables, lens, page)
             # ragged: only the 9 real pages enter the grid (bucketed 16)
             assert int(meta["valid"].sum()) == 9
-            out = paged_attention_ragged(q, kp, vp, jnp.asarray(lens),
-                                         meta)
+            out = paged_attention_ragged_varq(
+                q[:, None], kp, vp, jnp.asarray(lens),
+                jnp.ones(B, jnp.int32), meta)[:, 0]
             ref = _paged_attention_xla(q, kp, vp, jnp.asarray(tables),
                                        jnp.asarray(lens), 1 / np.sqrt(D))
             ref = jnp.where((jnp.asarray(lens) > 0)[:, None, None],
@@ -726,11 +727,9 @@ class TestRaggedPagedAttention:
 
 
 def test_continuous_batching_ragged_decode_parity():
-    """Under `use_ragged="auto"` an MHA model (H == Hkv, D % 128 == 0)
-    decodes through the block-table kernel with no metadata operands;
-    the ragged-grid kernel (`use_ragged=True`, by hand) stays
-    token-exact with it, with `use_ragged=False` and with the static
-    greedy oracle."""
+    """An MHA model (H == Hkv, D % 128 == 0) decodes through the
+    block-table kernel with no metadata operands, token-exact with the
+    static greedy oracle."""
     from paddle_tpu.framework.flags import set_flags, get_flags
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.inference import (ContinuousBatchingPredictor,
@@ -749,18 +748,9 @@ def test_continuous_batching_ragged_decode_parity():
                    for n in (5, 11, 3, 8)]
         cb = ContinuousBatchingPredictor(model, max_batch_size=2,
                                          page_size=8, max_seq_len=48)
-        assert not cb.use_ragged and not cb.span_ragged
+        assert not cb.span_ragged
         out = cb.generate(prompts, max_new_tokens=6)
-        cbr = ContinuousBatchingPredictor(model, max_batch_size=2,
-                                          page_size=8, max_seq_len=48,
-                                          use_ragged=True)
-        assert cbr.use_ragged and cbr.span_ragged
-        assert out == cbr.generate(prompts, max_new_tokens=6)
-        cbf = ContinuousBatchingPredictor(model, max_batch_size=2,
-                                          page_size=8, max_seq_len=48,
-                                          use_ragged=False)
-        ref = LLMPredictor(model, max_batch_size=1).generate(
+        assert out == LLMPredictor(model, max_batch_size=1).generate(
             prompts, max_new_tokens=6)
-        assert out == ref == cbf.generate(prompts, max_new_tokens=6)
     finally:
         set_flags({k.removeprefix("FLAGS_"): v for k, v in old.items()})

@@ -346,7 +346,7 @@ LLAMA_OPS_AT_PARENT = {'decode': {'stablehlo.add': 36,
 def _llama_programs():
     paddle.seed(7)
     llama = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
-    pred = ContinuousBatchingPredictor(llama, use_ragged=False, **GEO)
+    pred = ContinuousBatchingPredictor(llama, **GEO)
     pred._ensure_ready()
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     B, pps = pred.B, pred.pages_per_seq

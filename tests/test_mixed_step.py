@@ -85,18 +85,16 @@ class TestVarqKernel:
         finally:
             _restore_flags(old)
 
-    def test_single_token_spans_match_decode_kernel_bitwise(self):
-        """q_lens == 1 everywhere degenerates to the ragged decode
-        kernel — bitwise, since the mixed kernel runs the same
-        online-softmax math over the same page grid. The block-table
-        decode kernel contracts a block of pages on the MXU, so it
-        agrees to float32 rounding."""
+    def test_single_token_spans_match_decode_kernel(self):
+        """q_lens == 1 everywhere degenerates to single-token decode
+        attention. The block-table decode kernel contracts a block of
+        pages on the MXU, so the two agree to float32 rounding."""
         import jax.numpy as jnp
         old = _interpret_flags()
         try:
             from paddle_tpu.kernels.paged_attention import (
-                paged_attention, paged_attention_ragged,
-                paged_attention_ragged_varq, RaggedMetaBuilder)
+                paged_attention, paged_attention_ragged_varq,
+                RaggedMetaBuilder)
             rs = np.random.RandomState(1)
             kp, vp, tables, trash = self._setup(rs)
             B = 3
@@ -106,15 +104,12 @@ class TestVarqKernel:
             builder = RaggedMetaBuilder(B, 6, 8, trash)
             for b in range(B):
                 builder.set_slot(b, tables[b], int(kv_lens[b]))
-            meta = lambda: {k: v.copy() for k, v in builder.meta().items()}
-            o_dec = paged_attention_ragged(q[:, 0], kp, vp, kv_lens, meta())
             o_v = paged_attention_ragged_varq(q, kp, vp, kv_lens, ones,
-                                              meta())
-            assert np.array_equal(np.asarray(o_dec),
-                                  np.asarray(o_v)[:, 0])
+                                              builder.meta())
             o_bt = paged_attention(q[:, 0], kp, vp, jnp.asarray(tables),
                                    kv_lens)
-            np.testing.assert_allclose(np.asarray(o_bt), np.asarray(o_dec),
+            np.testing.assert_allclose(np.asarray(o_bt),
+                                       np.asarray(o_v)[:, 0],
                                        rtol=0, atol=1e-6)
         finally:
             _restore_flags(old)
@@ -309,10 +304,9 @@ class TestChunkedPrefill:
 
     def test_parity_on_interpret_ragged_route(self):
         """The full mixed program through the interpret-mode Pallas
-        varq kernel stays token-identical: under "auto" an MHA
-        predictor with chunked prefill keeps the metadata for its span
-        programs, one without has none anywhere, and `use_ragged=True`
-        (the ragged decode kernel) serves the same tokens."""
+        varq kernel stays token-identical: an MHA predictor with
+        chunked prefill keeps the metadata for its span programs, one
+        without has none anywhere."""
         old = _interpret_flags()
         try:
             from paddle_tpu.inference import ContinuousBatchingPredictor
@@ -325,20 +319,15 @@ class TestChunkedPrefill:
             cb0 = ContinuousBatchingPredictor(
                 model, max_batch_size=2, page_size=8, max_seq_len=64,
                 enable_prefix_cache=False)
-            assert not cb0.use_ragged and not cb0.span_ragged
+            assert not cb0.span_ragged
             ref = cb0.generate(prompts, max_new_tokens=4)
             cb1 = ContinuousBatchingPredictor(
                 model, max_batch_size=2, page_size=8, max_seq_len=64,
                 enable_prefix_cache=False, prefill_chunk_tokens=8)
-            assert cb1.span_ragged and not cb1.use_ragged
+            assert cb1.span_ragged
             out = cb1.generate(prompts, max_new_tokens=4)
             assert out == ref
             assert cb1.stats["chunked_requests"] == 1
-            cb2 = ContinuousBatchingPredictor(
-                model, max_batch_size=2, page_size=8, max_seq_len=64,
-                enable_prefix_cache=False, prefill_chunk_tokens=8,
-                use_ragged=True)
-            assert cb2.generate(prompts, max_new_tokens=4) == ref
         finally:
             _restore_flags(old)
 

@@ -1,9 +1,9 @@
-"""Which paged kernel a decode program attends through under
-`use_ragged="auto"`: the block-table kernel (`kernels.paged_attention.
-_paged_kernel`) for every head geometry its gate admits, MHA included,
-with no ragged metadata in the decode program's signature; the span
-programs (mixed, verify) of an MHA model keep the ragged varq kernel
-and its metadata; a hand-set `use_ragged` means what it meant. All in
+"""Which paged kernel a decode program attends through: the block-table
+kernel (`kernels.paged_attention._paged_kernel`) for every head geometry
+its gate admits, MHA included, with no ragged metadata among the decode
+programs' operands; the span programs (mixed, verify) of an MHA model
+keep the ragged varq kernel and its metadata; `use_ragged`, the option
+that once chose another decode kernel, is an ignored keyword. All in
 interpret mode on the CPU.
 
 - the kernel at `rep` = 1 against the XLA block-table path over batches
@@ -12,14 +12,16 @@ interpret mode on the CPU.
   end;
 - `kernels.paged_decode{kernel}`, the counter that says which kernel a
   decode program was traced with;
-- the predictor's signatures, tokens and metadata under "auto", `True`
-  and `False`, with and without a span program, for MHA and GQA;
-- a bundle built under "auto" with chunked prefill serves at warm start
-  without compiling: builder and dispatcher signatures in lockstep.
+- the predictor's signatures, tokens and metadata, with and without a
+  span program, for MHA and GQA, and the operands of each serve program;
+- a bundle built with chunked prefill serves at warm start without
+  compiling: builder and dispatcher signatures in lockstep; one whose
+  manifest still carries a `use_ragged` key loads.
 """
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -103,21 +105,14 @@ def test_mha_kernel_matches_the_xla_block_table_path(
                                rtol=0, atol=atol)
 
 
-@pytest.mark.parametrize("entry,flags,want", [
-    ("paged_attention", True, "paged_attention"),
-    ("paged_attention", False, "xla"),
-    ("paged_attention_ragged", True, "paged_attention_ragged"),
-])
+@pytest.mark.parametrize("flags,want", [(True, "paged_attention"),
+                                        (False, "xla")])
 def test_the_counter_names_the_kernel_that_was_traced(
-        interpret, decode_kernels, entry, flags, want):
+        interpret, decode_kernels, flags, want):
     # the fixture restores the flags
     set_flags({"use_pallas_kernels": flags, "pallas_interpret": flags})
     q, kp, vp, bt, lens = _batch(BATCHES["every-kind"], "float32")
-    if entry == "paged_attention":
-        out = pa.paged_attention(q, kp, vp, bt, lens)
-    else:
-        meta = pa.build_ragged_meta(np.asarray(bt), np.asarray(lens), PAGE)
-        out = pa.paged_attention_ragged(q, kp, vp, lens, meta)
+    out = pa.paged_attention(q, kp, vp, bt, lens)
     assert decode_kernels() == {want: 1}
     np.testing.assert_allclose(
         np.asarray(out),
@@ -165,31 +160,98 @@ def test_auto_decodes_mha_through_the_block_table_kernel(
     model = _llama()
     prompts = _prompts(5, 11, 3, 8)
     pred = _predictor(model)
-    assert not pred.use_ragged and not pred.span_ragged
+    assert not pred.span_ragged
     out = pred.generate(prompts, max_new_tokens=6)
     assert _sigs(pred, "decode") == {()}
     assert decode_kernels() == {"paged_attention": LAYERS}
     assert out == LLMPredictor(model, max_batch_size=1).generate(
         prompts, max_new_tokens=6)
-    ragged = _predictor(model, use_ragged=True)
-    assert ragged.use_ragged and ragged.span_ragged
-    assert ragged.generate(prompts, max_new_tokens=6) == out
-    assert _sigs(ragged, "decode") == {META}
-    assert decode_kernels() == {"paged_attention": LAYERS,
-                                "paged_attention_ragged": LAYERS}
 
 
-@pytest.mark.parametrize("use_ragged,n_meta", [("auto", 0), (False, 0),
-                                               (True, 6)])
-def test_lowered_decode_step_mirrors_the_dispatcher(interpret, use_ragged,
-                                                    n_meta):
-    """`lower_decode_step` lowers what the serve loop dispatches: the
-    program takes the six metadata arrays only under a hand-set True."""
-    pred = _predictor(_llama(), use_ragged=use_ragged)
-    import jax
-    flat = [a for a in jax.tree_util.tree_leaves(
-        pred.lower_decode_step().in_avals) if a.shape == META[0]]
-    assert len(flat) == n_meta
+@pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("kernels,label", [(True, "paged_attention"),
+                                           (False, "xla")])
+def test_a_decode_step_is_counted_under_one_of_two_labels(
+        interpret, decode_kernels, kv_heads, kernels, label):
+    """One way to attend a single token over K/V pages: the block-table
+    kernel, or its XLA path where there is no Pallas path. The
+    ragged-grid kernel that was a third label is gone: the module's
+    other two entries attend a query span."""
+    set_flags({"use_pallas_kernels": kernels, "pallas_interpret": kernels})
+    assert {n for n in dir(pa) if n.startswith("paged_attention")} == {
+        "paged_attention", "paged_attention_varq",
+        "paged_attention_ragged_varq"}
+    _predictor(_llama(kv_heads)).generate(_prompts(5, 11, 3),
+                                          max_new_tokens=4)
+    assert decode_kernels() == {label: LAYERS}
+
+
+@pytest.fixture(scope="module")
+def dispatched():
+    """{kind: (jitted program, its lowered input avals)} of the serve
+    programs two MHA predictors with a chunk and a draft span
+    dispatched, each lowered with the operands the dispatcher handed
+    it; the second predictor samples on the device (its decode step is
+    the sampling variant)."""
+    old = get_flags(["use_pallas_kernels", "pallas_interpret"])
+    set_flags({"use_pallas_kernels": True, "pallas_interpret": True})
+    try:
+        model, seen = _llama(), {}
+        motif = _prompts(6, seed=2)[0]
+        for sampling in (False, True):
+            pred = _predictor(model, prefill_chunk_tokens=8,
+                              spec_draft_tokens=3, sampling_enabled=sampling)
+            assert pred.span_ragged
+            call = pred._jit_call
+
+            def spy(sig, fn, *args, _call=call):
+                if sig[0] not in seen:
+                    shapes = jax.tree_util.tree_map(
+                        lambda a: jax.ShapeDtypeStruct(
+                            np.shape(a), jnp.asarray(a).dtype), args)
+                    seen[sig[0]] = (fn, jax.tree_util.tree_leaves(
+                        fn.lower(*shapes).in_avals))
+                return _call(sig, fn, *args)
+            pred._jit_call = spy
+            pred.generate(
+                [motif * 4, motif[:3] * 5] + _prompts(20, 4, seed=4),
+                max_new_tokens=8)
+        return seen
+    finally:
+        set_flags({k.removeprefix("FLAGS_"): v for k, v in old.items()})
+
+
+@pytest.mark.parametrize("kind,n_meta", [("decode", 0), ("decode_sample", 0),
+                                         ("mixed", 6), ("spec", 6)])
+def test_only_the_span_programs_take_ragged_metadata(dispatched, kind,
+                                                     n_meta):
+    """The inputs of each serve program as the dispatcher hands them,
+    lowered: the six `[B * pages_per_seq]` int32 arrays on the mixed and
+    verify programs, none on the two decode programs, whose functions
+    have no operands past their named ones."""
+    import inspect
+    fn, avals = dispatched[kind]
+    assert sum(a.shape == META[0] and a.dtype == jnp.int32
+               for a in avals) == n_meta
+    raw = inspect.unwrap(fn)
+    assert any(p.kind is p.VAR_POSITIONAL for p in
+               inspect.signature(raw).parameters.values()) == bool(n_meta)
+
+
+@pytest.mark.parametrize("value", ["auto", True, False])
+def test_the_use_ragged_keyword_is_ignored(interpret, value):
+    """`benchmarks/rehearse.py` still passes the keyword and the runners
+    still read the attribute: whatever is passed, the span programs get
+    the metadata by the gate alone and the decode step is the one
+    program (its text carries no locations)."""
+    model = _llama()
+    want = _predictor(model, prefill_chunk_tokens=8)
+    pred = _predictor(model, prefill_chunk_tokens=8, use_ragged=value)
+    assert pred.use_ragged is False and want.use_ragged is False
+    assert pred.span_ragged and want.span_ragged
+    assert pred.lower_decode_step().as_text() \
+        == want.lower_decode_step().as_text()
+    assert not _predictor(model, use_ragged=value).span_ragged
 
 
 def _count_varq_kernel(monkeypatch):
@@ -211,7 +273,7 @@ def test_chunked_prefill_keeps_the_varq_kernel_and_its_metadata(
     calls = _count_varq_kernel(monkeypatch)
     decode_kernels()
     pred = _predictor(model, prefill_chunk_tokens=8)
-    assert pred.span_ragged and not pred.use_ragged
+    assert pred.span_ragged
     assert pred.generate(prompts, max_new_tokens=4) == want
     assert pred.stats["chunked_requests"] == 1
     assert pred.stats["mixed_steps"] >= 2
@@ -232,7 +294,7 @@ def test_speculative_verify_keeps_the_varq_kernel_and_its_metadata(
     want = _predictor(model).generate(prompts, max_new_tokens=8)
     calls = _count_varq_kernel(monkeypatch)
     pred = _predictor(model, spec_draft_tokens=3)
-    assert pred.span_ragged and not pred.use_ragged
+    assert pred.span_ragged
     assert pred.generate(prompts, max_new_tokens=8) == want
     assert pred.stats["spec_ticks"] >= 1
     assert _sigs(pred, "spec") == {META}
@@ -250,7 +312,7 @@ def test_a_gqa_predictor_has_no_metadata_anywhere(
     prompts = _prompts(20, 4, seed=4)
     calls = _count_varq_kernel(monkeypatch)
     pred = _predictor(model, prefill_chunk_tokens=chunk)
-    assert not pred.use_ragged and not pred.span_ragged
+    assert not pred.span_ragged
     out = pred.generate(prompts, max_new_tokens=4)
     assert _sigs(pred, "decode") == {()}
     assert _sigs(pred, "mixed") == ({()} if chunk else set())
@@ -260,9 +322,9 @@ def test_a_gqa_predictor_has_no_metadata_anywhere(
     assert _predictor(model).generate(prompts, max_new_tokens=4) == out
 
 
-def test_without_a_pallas_path_auto_has_no_metadata(decode_kernels):
+def test_without_a_pallas_path_there_is_no_metadata(decode_kernels):
     pred = _predictor(_llama(), prefill_chunk_tokens=8)
-    assert not pred.use_ragged and not pred.span_ragged
+    assert not pred.span_ragged
     pred.generate(_prompts(20, 4, seed=4), max_new_tokens=2)
     assert _sigs(pred, "mixed") == {()} and _sigs(pred, "decode") == {()}
     assert decode_kernels().keys() == {"xla"}
@@ -286,7 +348,7 @@ def test_auto_bundle_with_chunked_prefill_serves_zero_compile(
     kinds = [rec.get("kind") for rec in manifest["artifacts"].values()]
     assert kinds.count("mixed") == 2 and kinds.count("decode") == 1
     pred, eng = aot.warm_start(model, d, wire_cache=False)
-    assert pred.span_ragged and not pred.use_ragged
+    assert pred.span_ragged
     # a chunked prompt through the mixed buckets, then a short one
     # through the decode step
     long, short = _prompts(prompt_len, 5, seed=7)
@@ -297,4 +359,25 @@ def test_auto_bundle_with_chunked_prefill_serves_zero_compile(
         + ref.generate([short], max_new_tokens=2)
     assert pred.stats["chunked_requests"] == 1
     assert pred.stats["decode_steps"] > pred.stats["mixed_steps"]
+    assert eng.stats["misses"] == 0, eng.stats
+
+
+def test_a_manifest_with_a_use_ragged_key_warm_starts(interpret, tmp_path):
+    """A bundle written while `use_ragged` was a compiled-geometry key
+    has it in its manifest. It is ignored on read: the constructor
+    takes the keyword and drops it, and a request that disagrees with
+    the manifest's value no longer invalidates the bundle."""
+    from paddle_tpu.inference import aot
+    model = _llama()
+    d = str(tmp_path / "engine")
+    manifest = aot.build_engine(model, d, prompt_buckets=(8,),
+                                batch_sizes=(1,), max_new_tokens=2,
+                                wire_cache=False, use_ragged=True, **GEO)
+    assert manifest["geometry"]["use_ragged"] is True
+    pred, eng = aot.warm_start(model, d, wire_cache=False, strict=True,
+                               use_ragged=False)
+    assert pred.use_ragged is False
+    prompt = _prompts(5, seed=7)
+    assert pred.generate(prompt, max_new_tokens=2) \
+        == _predictor(model).generate(prompt, max_new_tokens=2)
     assert eng.stats["misses"] == 0, eng.stats

@@ -165,8 +165,7 @@ def test_two_tensor_parallel_shards(interpret, small_blocks):
     ("paged_attention", 32, 32, 128, 1, None),
     ("paged_attention", 24, 8, 128, 1, None),
     ("paged_attention", 32, 12, 128, 1, "gqa_ratio"),
-    ("paged_attention_ragged", 32, 8, 128, 1, "gqa_ratio"),
-    ("paged_attention_ragged", 32, 32, 128, 1, None),
+    ("paged_attention_ragged_varq", 32, 32, 128, 1, None),
     ("paged_attention_ragged_varq", 32, 8, 128, 1, "gqa_ratio"),
     ("paged_attention", 32, 8, 64, 1, "head_dim_tiling"),
     ("paged_attention", 12, 4, 128, 1, "head_count_tiling"),
@@ -181,7 +180,7 @@ def test_gate_reasons(kernel, h, hkv, d, tp, reason):
 
 def test_gate_admits_a_group_for_the_kernel_that_takes_one():
     """GQA runs the block-table kernel and is still counted as
-    `gqa_ratio` for the ragged ones, the varq kernel among them."""
+    `gqa_ratio` for the ragged varq kernel."""
     from paddle_tpu.observability import metrics
     q = jnp.zeros((2, 8, 128))
     pages = jnp.zeros((4, 8, 2, 128))
@@ -263,7 +262,6 @@ def test_gqa_llama_serves_the_same_tokens_with_the_kernel():
     metrics.get_registry().reset()
     got, pred = _serve(model, rounds, True, **geometry)
     assert got == want
-    assert not pred.use_ragged and not ref.use_ragged
     assert pred.prefix_cache is not None
     assert pred.stats["prefix_partial_hits"] + pred.stats["prefix_hits"] >= 3
     assert _fallbacks() == {}
@@ -285,4 +283,4 @@ def test_hybrid_model_serves_the_same_tokens_with_the_kernel():
     want, _ = _serve(model, prompts, False, **GEO)
     got, pred = _serve(model, prompts, True, **GEO)
     assert got == want
-    assert not pred.use_ragged and pred.state_pool is not None
+    assert pred.state_pool is not None

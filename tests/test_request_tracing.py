@@ -369,21 +369,24 @@ class TestRouterPropagation:
         exes = {t for _, t in m.exemplars()}
         assert exes and exes <= {r["trace"] for r in roots}
 
-    def test_page_span_shims_warn_and_delegate(self):
+    def test_page_span_round_trips_without_a_warning(self):
+        """The handoff endpoints under their one name (the
+        `*_request_span` aliases are gone)."""
+        import warnings
         from paddle_tpu.inference import ContinuousBatchingPredictor
         cb = ContinuousBatchingPredictor(
             _serve_model(), max_batch_size=2, page_size=8,
             max_seq_len=48)
         prompt = _prompts(1)[0]
         cb.generate([prompt], max_new_tokens=2)
-        with pytest.warns(DeprecationWarning,
-                          match="export_page_span"):
-            span = cb.export_request_span(prompt)
-        assert span is not None
-        with pytest.warns(DeprecationWarning,
-                          match="import_page_span"):
-            stats = cb.import_request_span(span)
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", DeprecationWarning)
+            span = cb.export_page_span(prompt)
+            assert span is not None
+            stats = cb.import_page_span(span)
         assert stats is not None
+        assert not hasattr(cb, "export_request_span")
+        assert not hasattr(cb, "import_request_span")
 
 
 class TestDisaggWaterfall:

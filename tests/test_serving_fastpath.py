@@ -272,9 +272,9 @@ class TestQueuePolicy:
 class TestRaggedMetaBuilder:
     def test_matches_from_scratch_flatten_through_kernel(self):
         """The incrementally maintained segment layout must drive the
-        ragged kernel to the same output as build_ragged_meta's compact
-        layout, across admissions, page-boundary advances, and
-        evictions."""
+        ragged varq kernel (one query a slot) to the same output as
+        build_ragged_meta's compact layout, across admissions,
+        page-boundary advances, and evictions."""
         import jax.numpy as jnp
         from paddle_tpu.framework.flags import set_flags, get_flags
         old = get_flags(["use_pallas_kernels", "pallas_interpret"])
@@ -282,7 +282,7 @@ class TestRaggedMetaBuilder:
         try:
             from paddle_tpu.kernels.paged_attention import (
                 RaggedMetaBuilder, build_ragged_meta,
-                paged_attention_ragged)
+                paged_attention_ragged_varq)
             rs = np.random.RandomState(2)
             B, H, D, page, pps = 3, 8, 128, 8, 4
             P = B * pps + 1
@@ -296,15 +296,16 @@ class TestRaggedMetaBuilder:
                 builder.clear_slot(b)
 
             def check():
-                q = jnp.asarray(rs.randn(B, H, D).astype("f") * 0.3)
+                q = jnp.asarray(rs.randn(B, 1, H, D).astype("f") * 0.3)
+                ones = jnp.ones(B, jnp.int32)
                 m1 = builder.meta()
                 m2 = build_ragged_meta(tables, lens, page,
                                        bucket_to=B * pps)
-                o1 = paged_attention_ragged(q, kp, vp, jnp.asarray(lens),
-                                            {k: v.copy()
-                                             for k, v in m1.items()})
-                o2 = paged_attention_ragged(q, kp, vp, jnp.asarray(lens),
-                                            m2)
+                o1 = paged_attention_ragged_varq(
+                    q, kp, vp, jnp.asarray(lens), ones,
+                    {k: v.copy() for k, v in m1.items()})
+                o2 = paged_attention_ragged_varq(
+                    q, kp, vp, jnp.asarray(lens), ones, m2)
                 np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                            atol=1e-5)
 

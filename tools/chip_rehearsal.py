@@ -14,12 +14,13 @@ reports ``memory_analysis()``: the first honest answer to "what depth
 fits". Nothing runs, so this says nothing about results or times.
 
 Code that asks ``jax.default_backend()`` sees the CPU here and would
-lower no kernel at all. This script therefore patches the ``_use_pallas``
-name each kernel module bound at import so that it follows
-``FLAGS_use_pallas_kernels`` alone, builds predictors with
-``use_ragged=True``, and donates the pools as the chip path does. It
-does not set ``FLAGS_pallas_interpret`` (that would lower the
-interpreter), and the package gains no option for any of this.
+lower no kernel at all. This script therefore patches the gate
+(``kernels._common.use_pallas`` and the ``_use_pallas`` name each kernel
+module bound at import) so that it follows ``FLAGS_use_pallas_kernels``
+alone, builds its predictors as chip_smoke.py does, and donates the
+pools as the chip path does. It does not set ``FLAGS_pallas_interpret``
+(that would lower the interpreter), and the package gains no option for
+any of this.
 """
 from __future__ import annotations
 
@@ -58,11 +59,15 @@ def force_kernels():
     """Make every kernel gate follow FLAGS_use_pallas_kernels alone, as
     it does on the chip."""
     from paddle_tpu.framework.flags import flag_value
-    from paddle_tpu.kernels import attention, norm, paged_attention
+    from paddle_tpu.kernels import (_common, attention, norm,
+                                    paged_attention)
 
     def wanted():
         return bool(flag_value("use_pallas_kernels"))
 
+    # the predictor asks `_common` itself whether its span programs
+    # carry ragged metadata
+    _common.use_pallas = wanted
     for mod in (attention, norm, paged_attention):
         mod._use_pallas = wanted
 
@@ -131,7 +136,7 @@ def serve_programs(depth, tp=1, chunk=0):
     model, _ = cs.build_model(cs.llama_config(depth), 0, "bfloat16")
     pred = ContinuousBatchingPredictor(
         model, max_batch_size=8, page_size=16, max_seq_len=1024,
-        use_ragged=True, tp_degree=tp, prefill_chunk_tokens=chunk)
+        tp_degree=tp, prefill_chunk_tokens=chunk)
     pred._ensure_ready()
     mesh = None
     if tp > 1:
@@ -156,12 +161,13 @@ def serve_programs(depth, tp=1, chunk=0):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=scalar)
 
     B, pps, page = pred.B, pred.pages_per_seq, pred.page
-    meta = tuple(i32(B * pps) for _ in RaggedMetaBuilder.FIELDS)
     dn = (2, 3)
     recs = []
     from paddle_tpu.kernels._common import kernel_partition_scope
     with pred._trace_lock, kernel_partition_scope(mesh):
         if chunk:
+            meta = tuple(i32(B * pps) for _ in RaggedMetaBuilder.FIELDS) \
+                if pred.span_ragged else ()
             return [report(
                 f"mixed[B={B}, span {chunk}] tp={tp}",
                 jax.jit(pred._raw_mixed_step, donate_argnums=dn).lower(
@@ -180,9 +186,9 @@ def serve_programs(depth, tp=1, chunk=0):
                     p, b, kl, vl, i32(1, sb), i32(1, sb), i32(), i32(),
                     i32(wpb), i32(pps)), tp))
         recs.append(report(
-            f"decode[B={B}, {pps} pages/seq, ragged] tp={tp}",
+            f"decode[B={B}, {pps} pages/seq] tp={tp}",
             jax.jit(pred._raw_decode_step, donate_argnums=dn).lower(
-                p, b, kl, vl, i32(B, pps), i32(B), i32(B), *meta), tp))
+                p, b, kl, vl, i32(B, pps), i32(B), i32(B)), tp))
 
     def cow(kl_, vl_, s, d):
         return ([k.at[d].set(k[s]) for k in kl_],

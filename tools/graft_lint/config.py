@@ -66,15 +66,10 @@ HOT_PATH_FUNCTIONS = (
     # prefill→decode handoff endpoints on the predictor: run on the
     # replica worker thread between serve-loop ticks — any sync beyond
     # the span payload itself stalls that replica's decode clock
-    # (export/import_request_span are the deprecated-shim aliases)
     ("paddle_tpu/inference/__init__.py",
      "ContinuousBatchingPredictor.export_page_span"),
     ("paddle_tpu/inference/__init__.py",
      "ContinuousBatchingPredictor.import_page_span"),
-    ("paddle_tpu/inference/__init__.py",
-     "ContinuousBatchingPredictor.export_request_span"),
-    ("paddle_tpu/inference/__init__.py",
-     "ContinuousBatchingPredictor.import_request_span"),
     # eager (dygraph) generation decode loop + seq2seq beam decode
     ("paddle_tpu/generation/__init__.py",
      "GenerationMixin._generate_eager_batch"),

@@ -958,10 +958,13 @@ class ContinuousBatchingPredictor:
             _obsm.gauge("serving.state_pool_bytes").set(
                 self.state_pool.nbytes, **self._mlbl)
         # counts the model sums over its layers on the device, a vector a
-        # name: each element is one (metric, labels) of `step_counters`
+        # name: each element is one (metric, labels) of `step_counters`,
+        # or (metric, labels, "max"): a gauge that keeps the largest
+        # value any step gave, where a sum over steps would mean nothing
         self._step_counters = [
-            (key, [(_obsm.counter(name), dict(lbl, **self._mlbl))
-                   for name, lbl in spec])
+            (key, [(_obsm.gauge(name) if kind else _obsm.counter(name),
+                    dict(lbl, **self._mlbl))
+                   for name, lbl, *kind in spec])
             for key, spec in sorted(
                 getattr(model, "step_counters", dict)().items())]
         self._m_spec_prop =_obsm.counter("serving.spec.proposed_tokens")
@@ -1301,7 +1304,11 @@ class ContinuousBatchingPredictor:
         count (a decode step, a prefill's chunks) is not in it."""
         for (_, spec), vec in zip(self._step_counters, aux):
             for (ctr, lbl), n in zip(spec, np.asarray(vec).tolist()):
-                if n:
+                if not n:
+                    continue
+                if isinstance(ctr, _obsm.Gauge):
+                    ctr.set(max(n, ctr.value(**lbl)), **lbl)
+                else:
                     ctr.inc(n, **lbl)
 
     def _raw_prefill(self, p_vals, b_vals, kl, vl, ids, pos, lens,

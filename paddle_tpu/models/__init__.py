@@ -16,3 +16,4 @@ from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
 from .ling_hybrid import LingHybridConfig, LingHybridForCausalLM
 from .glm_moe_dsa import GlmMoeDsaConfig, GlmMoeDsaForCausalLM
 from .openpangu_moe import OpenPanguMoEConfig, OpenPanguMoEForCausalLM
+from .xing_moe import XingMoEConfig, XingMoEForCausalLM

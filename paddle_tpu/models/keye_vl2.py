@@ -114,11 +114,14 @@ class KeyeVL2Config:
         return KeyeVL2Config(**base)
 
 
-def rope_angles(pos, dim, theta):
+def rope_angles(pos, dim, theta, inv_freq=None):
     """pos [...] int -> angles [..., dim / 2] float32: pair i turns by
-    pos * theta^(-2i / dim)."""
+    pos * theta^(-2i / dim). A model whose frequencies are scaled (YaRN:
+    models/xing_moe.py `yarn_frequencies`) hands in its own table
+    `inv_freq` [dim / 2] in their place."""
     inv = F32(1.0) / (F32(theta) ** (jnp.arange(0, dim, 2, dtype=F32)
-                                     / F32(dim)))
+                                     / F32(dim))) if inv_freq is None \
+        else jnp.asarray(inv_freq, F32)
     return pos.astype(F32)[..., None] * inv
 
 

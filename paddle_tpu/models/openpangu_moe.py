@@ -168,12 +168,23 @@ class PanguLatentAttention(Layer):
                 self.kv_a_proj.weight, self.kv_a_norm, self.kv_b_proj.weight,
                 self.o_proj.weight]
 
+    def _angles(self, pos):
+        """pos [B, S] -> the rotation's angles [B, S, rope / 2]: plain
+        `rope_theta` here; a model with scaled frequencies overrides
+        this and `_scale` (models/xing_moe.py)."""
+        c = self.config
+        return rope_angles(pos, c.qk_rope_head_dim, c.rope_theta)
+
+    def _scale(self):
+        """What the scores are multiplied by."""
+        return self.config.qk_head_dim ** -0.5
+
     def _project(self, x, pos, wqa, gqa, wqb, wkva, gkv):
         """x [B, S, hidden], pos [B, S] -> q_nope [B, S, H, nope],
         q_rope [B, S, H, rope] (rotated) and the row [c | k_r] [B, S,
         latent width] (c normed, k_r rotated)."""
         c = self.config
-        ang = rope_angles(pos, c.qk_rope_head_dim, c.rope_theta)
+        ang = self._angles(pos)
         c_q = _rms(jnp.dot(x, wqa), gqa, c.rms_norm_eps)
         q = jnp.dot(c_q, wqb).reshape(x.shape[:2] + (
             c.num_attention_heads, c.qk_head_dim))
@@ -202,7 +213,7 @@ class PanguLatentAttention(Layer):
         v = jnp.pad(kv[..., dn:], [(0, 0)] * 3 + [(0, c.qk_head_dim - dv)])
         with jax.named_scope("mla.attend"):
             o = flash_attention_jax(q, k, v, causal=True,
-                                    scale=c.qk_head_dim ** -0.5,
+                                    scale=self._scale(),
                                     mask=valid[:, None, None, :])[..., :dv]
         return jnp.dot(o.reshape(b, s, nh * dv), wo), row
 
@@ -234,7 +245,7 @@ class PanguLatentAttention(Layer):
         q, row = apply(absorb, x, pos, *self._weights(), _name="mla_absorb")
         attend = paged_cache_latent_update_attend if x.shape[1] == 1 \
             else paged_cache_latent_span_update_attend
-        summed, entry = attend(cache, q, row, c.qk_head_dim ** -0.5)
+        summed, entry = attend(cache, q, row, self._scale())
 
         def expand(o_lat, wkvb, wo):
             with jax.named_scope("mla.absorb"):

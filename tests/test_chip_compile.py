@@ -270,9 +270,10 @@ def _cell_predictor(chip, cell):
     # code that asks the backend sees the CPU here: the kernel gates
     # follow the flag alone, as they do on the chip
     patch = pytest.MonkeyPatch()
-    from paddle_tpu.kernels import kda, latent_attention, sparse_attention
+    from paddle_tpu.kernels import (hyper_connections, kda, latent_attention,
+                                    sparse_attention)
     for mod in (attention, norm, pa, sparse_attention, latent_attention,
-                kda):
+                kda, hyper_connections):
         patch.setattr(mod, "_use_pallas",
                       lambda: bool(flag_value("use_pallas_kernels")))
     cfg = harness.find_cell(root, cell)["cfg"]
@@ -762,5 +763,128 @@ def test_pangu_reference_programs_fit_the_chip(chip):
             < 1.0e9
         step = ref._attend.lower(x, w, cfg_s, None) if kind == "attn" \
             else ref._feed_forward.lower(x, w, key, index, True, cfg_s,
+                                         "int8")
+        assert live(step.compile()) < 4.0e9
+
+
+# --- the four-stream cell at its real geometry ------------------------------
+# 32 slots of up to 8192 positions over 16385 pages; 6 layers of 32 heads
+# on a 576-wide latent row a token (640 lanes); four residual streams of
+# 3584 a token, read and mixed by two kernels at each of 12 sublayers;
+# all 64 experts and the whole 131072-wide vocabulary.
+
+@pytest.mark.parametrize("rows", [32, 2048, 8192],
+                         ids=["step", "prompt2048", "prompt8192"])
+def test_mhc_kernels_at_the_cells_geometry(chip, rows):
+    """`mhc_pre` and `mhc_post` at a decode step's rows and a prompt's:
+    whole rows of 4 x 3584 numbers a block (the scoped VMEM limit is
+    raised for them), the streams written back in place."""
+    from paddle_tpu.kernels import hyper_connections as hc
+    n, c = 4, 3584
+    text = _compile(
+        chip, lambda x, phi, a, b: hc._mhc_pre_pallas(
+            x, phi, a, b, n, 20, 1e-6, 1e-6, (-30.0, 30.0), False),
+        ((rows, n * c), BF16), ((n * c, 24), BF16), ((3,), jnp.float32),
+        ((24,), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"\(bf16\[%d,%d\]\S*, f32\[%d,128\]\S*\) custom-call\("
+                     % (rows, c, rows), text)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in (
+        ((rows, n * c), BF16), ((rows, c), BF16), ((rows, 128), jnp.float32))]
+    compiled = jax.jit(lambda x, f, cf: hc._mhc_post_pallas(x, f, cf, n,
+                                                            False),
+                       donate_argnums=(0,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == rows * n * c * 2   # in place
+    assert ma.temp_size_in_bytes == 0
+
+
+@pytest.fixture(scope="module")
+def xing(chip):
+    yield from _cell_predictor(chip, "xing4-code-open")
+
+
+def test_xing_decode_step_at_real_size(chip, xing):
+    pred, n_params, fixed = xing
+    # 128.2 M dense layer + 5 x 745.0 M expert layers + 939.5 M of
+    # embedding and head (the issue's 4792.6 M, norms and biases in)
+    assert n_params == 4_792_669_828
+    B, pps = pred.B, pred.pages_per_seq
+    assert pred._drafter is None and pred._prefill_rows == 1
+    assert all(v is None for v in pred.pool.v)
+    assert [a.shape for a in pred.pool.k] == [(16385, 16, 640)] * 6
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_decode_step, (B, pps), (B,), (B,))
+    assert " f64[" not in text and " s64[" not in text
+    assert live < 15.5e9, live
+    pool = sum(a.nbytes for a in pred.pool.latent)
+    assert ma.alias_size_in_bytes >= pool       # rows written in place
+    # a layer: two `mhc.pre`, two `mhc.post`, one latent decode kernel
+    assert len(re.findall(r"bf16\[%d,14336\]\S* custom-call\(.*tpu_custom_call"
+                          % B, text)) == 12
+    assert len(re.findall(r"\(bf16\[%d,3584\]\S*, f32\[%d,128\]\S*\) "
+                          r"custom-call\(" % (B, B), text)) == 12
+    assert len(re.findall(r"bf16\[%d,32,640\]\S* custom-call\(" % B,
+                          text)) == 6
+    assert f"[{B},{pps},16,640]" not in text
+    assert text.count("ragged-dot-none") >= 10      # 2 an expert layer
+
+
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096, 8192])
+def test_xing_prefill_at_real_size(chip, xing, bucket):
+    """The long prefill at every bucket the cell warms."""
+    pred, _, fixed = xing
+    n = pred._prefill_rows
+    text, live, ma = _compile_program(
+        chip, pred, fixed, pred._raw_prefill, (n, bucket), (n, bucket),
+        (n,), (n, bucket // pred.page))
+    assert " f64[" not in text and " s64[" not in text
+    assert f"[{n},1,{bucket},{bucket}]" not in text
+    assert f"[{n},{bucket},{pred.model.config.vocab_size}]" not in text
+    assert live < 15.5e9, live
+    # a layer: the flash kernel and the four stream kernels
+    assert text.count('custom_call_target="tpu_custom_call"') >= 30
+    # (the compiler's own `ConcatBitcast` custom calls give the same shape)
+    assert len(re.findall(r"bf16\[%d,14336\]\S* custom-call\(.*tpu_custom_call"
+                          % bucket, text)) == 12
+
+
+def test_xing_reference_programs_fit_the_chip(chip):
+    """The cell's plain reference at the published widths, a sequence
+    padded to 8192 with four float32 streams a token: its weights are
+    drawn by programs of their own and every program keeps under 4
+    GB."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks.lib import harness
+    ref = harness.load_module(root, "reference", "xing_moe")
+    cfg = harness.find_cell(root, "xing4-code-open")["cfg"]
+    cfg_s = ref._static(cfg)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+    key, index, x = on_chip((
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((), I32),
+        jax.ShapeDtypeStruct((8192, cfg["hc_mult"], cfg["hidden_size"]),
+                             jnp.float32)))
+
+    def live(compiled):
+        ma = compiled.memory_analysis()
+        return ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            + ma.temp_size_in_bytes
+
+    maps = on_chip(jax.eval_shape(
+        lambda k, i: ref.xw.mhc(cfg, k, i, "attn"), key, index))
+    assert live(ref._weights.lower(key, index, "mhc", cfg_s,
+                                   sub="attn").compile()) < 1.0e9
+    for kind in ("attn", "moe"):
+        w = on_chip(jax.eval_shape(
+            lambda k, i: getattr(ref.xw, kind)(cfg, k, i), key, index))
+        assert live(ref._weights.lower(key, index, kind, cfg_s).compile()) \
+            < 1.0e9
+        step = ref._attend.lower(x, maps, w, cfg_s, None) if kind == "attn" \
+            else ref._feed_forward.lower(x, maps, w, key, index, False, cfg_s,
                                          "int8")
         assert live(step.compile()) < 4.0e9
